@@ -1,12 +1,18 @@
 # test-t1 uses `set -o pipefail`/PIPESTATUS, which POSIX sh lacks
 SHELL := /bin/bash
 
-.PHONY: test test-t1 lint lint-robust lint-selfcheck native bench bench-aug bench-dispatch bench-serve bench-overload bench-router bench-serve-hotpath bench-compile bench-pipeline bench-fleet-search bench-control trace status clean reproduce chaos gameday gameday-smoke
+.PHONY: smoke test test-t1 lint lint-robust lint-selfcheck native bench bench-aug bench-dispatch bench-serve bench-overload bench-router bench-serve-hotpath bench-compile bench-pipeline bench-fleet-search bench-control trace status clean reproduce chaos gameday gameday-smoke
 
 # telemetry journal dir for the trace/status targets (override:
 #   make trace TELEMETRY=/shared/run TRACE_OUT=overlap.json)
 TELEMETRY ?= telemetry
 TRACE_OUT ?= trace.json
+
+# the quickest proof the system still starts on the chip (run it
+# through the chip tool; without a TPU it exits non-zero and says why).
+# `python chip_smoke.py --rehearse` rehearses the plumbing on the CPU.
+smoke:
+	python chip_smoke.py
 
 test:
 	JAX_PLATFORMS=cpu python -m pytest tests/ -q
@@ -16,7 +22,7 @@ test:
 # the concurrency (C1-C3), dispatch-hazard (D1-D3) and determinism
 # (T1-T3) passes, with suppression/baseline hygiene (S1/S2).
 # Pure-host; prints its measured wall time (must stay well under ~10s
-# on this 1-core host so the tier-1 preamble never eats test budget).
+# so the tier-1 preamble never eats test budget).
 lint:
 	python -m tools.faalint
 
@@ -53,7 +59,7 @@ gameday:
 gameday-smoke:
 	JAX_PLATFORMS=cpu python -m fast_autoaugment_tpu.launch.gameday_cli --suite --smoke
 
-# real-data fire-drill (VERDICT r3, next-step 8): fetch CIFAR-10 with
+# real-data fire-drill: fetch CIFAR-10 with
 # md5 verification, train WRN-40-2 + fa_reduced_cifar10 at the headline
 # config, evaluate any reference .pth under ./ckpts via the manifest —
 # skips gracefully when offline (this build environment is zero-egress)
@@ -112,8 +118,9 @@ bench-serve-hotpath:
 	python tools/bench_serve_hotpath.py --out BENCH_r09_serve_hotpath.json
 
 # cold/warm compile-tax bench: the same train-step workload in two
-# fresh processes sharing one FAA_COMPILE_CACHE dir — the warm process
-# must report cache hits and a first step in seconds, not minutes
+# fresh processes sharing the persistent compile cache
+# (JAX_COMPILATION_CACHE_DIR, else the fixed in-checkout path) — the
+# warm process must report cache hits and a first step in seconds
 bench-compile:
 	python tools/bench_compile.py
 

@@ -1,32 +1,35 @@
-"""Persistent-compile-cache wiring + the instrumented compile seam.
+"""Persistent-compile-cache placement + the instrumented compile seam.
 
-Every process in this stack used to re-pay 23-55 s of XLA compile
-(BENCH_r02-r05) before its first real step, and the PR 5/6 resilience
-machinery multiplies that tax: every exit-77 resume, fleet retry and
-reclaimed work unit is a FRESH process that recompiled everything from
-scratch.  This module kills the recurrence with two pieces:
+Every process in this stack pays an XLA compile before its first real
+step, and the resilience machinery multiplies that tax: every exit-77
+resume, fleet retry and reclaimed work unit is a FRESH process that
+would recompile everything from scratch.  Two pieces remove the
+recurrence:
 
-1. **Persistent compilation cache** (``--compile-cache {off,DIR}``,
-   env ``FAA_COMPILE_CACHE``): JAX's on-disk executable cache
-   (``jax_compilation_cache_dir``) is pointed at a shared directory so
-   a relaunched process DESERIALIZES the executables its predecessor
-   compiled instead of re-lowering them — the pjit compilation-cache
-   discipline of the TPUv4 pjit trainers (PAPERS.md: *Scalable Training
-   of Language Models using JAX pjit and TPUv4*).  ``off`` (the
-   default) is bit-for-bit the historical behavior: nothing is read or
-   written, and the cache never changes numerics either way — only
-   where executables come from.
+1. **One resolver for where the cache lives**
+   (:func:`configure_compile_cache`).  If ``JAX_COMPILATION_CACHE_DIR``
+   is set, JAX itself already keeps its on-disk executable cache there
+   and this module sets no directory in code — the machine that runs
+   the program decides the place.  If it is unset, the cache is on at
+   one fixed path inside the checkout (:data:`DEFAULT_CACHE_DIR`): the
+   directory is part of what makes a second process hit, so it is never
+   a temp name, a pid or a time.  There is no flag and no off spec;
+   JAX's own ``JAX_ENABLE_COMPILATION_CACHE=0`` is the off switch.
+   Either way the persistence floor is dropped (JAX's 1 s
+   ``jax_persistent_cache_min_compile_time_secs`` default would skip
+   most of the small modules a warm process must also find) and the
+   hit/miss listener is registered.  Caching never changes numerics —
+   only where executables come from.
 
 2. **The compile seam** (:func:`seam_jit` / :func:`aot_compile`): every
    jit entry point in ``train/``, ``search/`` and ``serve/`` routes
-   through one wrapper (the ``compile_step_with_plan`` pattern,
-   SNIPPETS [3]) that times each first-call lowering, classifies it
-   hit/miss against the persistent cache's monitoring events, and
+   through one wrapper that times each first-call lowering, classifies
+   it hit/miss against the persistent cache's monitoring events, and
    aggregates the evidence so ``search_result.json``, the bench JSON
-   lines and the resilience resume path can PROVE a warm process
-   reached its first step in seconds (``compile_cache{dir, hits,
-   misses, first_step_secs}``).  Rule R5 in ``tools/lint_robustness.py``
-   keeps future hot paths on the seam.
+   lines, the trainer result and the resilience resume path can PROVE a
+   warm process reached its first step in seconds (``compile_cache{dir,
+   hits, misses, first_step_secs}``).  faalint rule R5 keeps future hot
+   paths on the seam.
 
 The hit/miss counters come from JAX's own monitoring events
 (``/jax/compilation_cache/cache_{hits,misses}``), so they count every
@@ -50,7 +53,6 @@ import functools
 import os
 import threading
 import time
-import warnings
 from typing import Any, Callable
 
 from fast_autoaugment_tpu.core import telemetry
@@ -58,9 +60,8 @@ from fast_autoaugment_tpu.utils.logging import get_logger
 
 __all__ = [
     "ENV_VAR",
-    "resolve_compile_cache",
+    "DEFAULT_CACHE_DIR",
     "configure_compile_cache",
-    "enable_compile_cache",
     "seam_jit",
     "instrument_jitted",
     "aot_compile",
@@ -71,10 +72,14 @@ __all__ = [
 
 logger = get_logger("faa_tpu.compilecache")
 
-#: env handoff: the CLIs export the resolved dir here so every child
-#: process (fleet-launched hosts, exit-77 relaunches, subprocess e2e
-#: reruns) inherits the shared cache without re-plumbing flags
-ENV_VAR = "FAA_COMPILE_CACHE"
+#: JAX's own variable: whoever runs the program places the cache with it
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+
+#: the cache's place when :data:`ENV_VAR` is unset — fixed, inside the
+#: checkout (git-ignored), shared by every process started from it
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 _HIT_EVENT = "/jax/compilation_cache/cache_hits"
 _MISS_EVENT = "/jax/compilation_cache/cache_misses"
@@ -103,69 +108,36 @@ def _listener(event: str, **_kwargs: Any) -> None:
         _MISSES.inc()
 
 
-def resolve_compile_cache(spec: str | None = None) -> str | None:
-    """``--compile-cache {off,DIR}`` (or None) -> cache dir or None.
+def configure_compile_cache() -> str | None:
+    """Arm the persistent compilation cache where it was placed.
 
-    An unset/``off`` spec falls back to the :data:`ENV_VAR` environment
-    handoff — that is how fleet-launched hosts and exit-77 relaunches
-    inherit the shared dir without carrying the flag.  ``off`` in the
-    environment disables too.
-    """
-    spec = ("" if spec is None else str(spec)).strip()
-    if spec.lower() in ("", "off"):
-        env = os.environ.get(ENV_VAR, "").strip()
-        if env.lower() in ("", "off"):
-            return None
-        return env
-    return spec
-
-
-def enable_compile_cache(directory: str) -> str:
-    """Point JAX's persistent compilation cache at `directory`.
-
-    Creates the dir, drops the min-compile-time/min-entry-size floors
-    (JAX's 1 s default would silently skip exactly the small dev/test
-    compiles the warm-start tests pin), registers the hit/miss event
-    listener, and exports :data:`ENV_VAR` for child processes.
-    Idempotent; re-enabling with a different dir re-points the cache
-    (logged — the stats keep accumulating process-wide).
+    With :data:`ENV_VAR` set, JAX read the directory at import and this
+    function leaves it alone; unset, it turns the cache on at
+    :data:`DEFAULT_CACHE_DIR`.  Both branches drop the compile-time
+    persistence floor and register the hit/miss listener.  Idempotent —
+    every entry point (trainer, search driver, serve CLI, benches) calls
+    it before its first compile.  Returns the directory JAX is using, or
+    None when ``JAX_ENABLE_COMPILATION_CACHE=0`` switched the cache off.
     """
     global _dir, _listener_registered
     import jax
-    from jax.experimental.compilation_cache import compilation_cache as jax_cc
 
-    directory = os.path.abspath(directory)
-    os.makedirs(directory, exist_ok=True)
-    jax_cc.set_cache_dir(directory)
-    jax.config.update(
-        "jax_persistent_cache_min_compile_time_secs",
-        float(os.environ.get("FAA_COMPILE_CACHE_MIN_COMPILE_SECS", "0")))
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    if _dir is None or _dir != directory:
-        # the cache-used verdict is a one-shot per-process latch inside
-        # jax: a process that compiled ANYTHING before the dir was set
-        # has latched "disabled" — reset so enabling mid-process works
-        # (the trainers/driver configure after import-time jits)
-        jax_cc.reset_cache()
+    if (not os.environ.get(ENV_VAR, "").strip()
+            and jax.config.jax_compilation_cache_dir != DEFAULT_CACHE_DIR):
+        jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    directory = (jax.config.jax_compilation_cache_dir
+                 if jax.config.jax_enable_compilation_cache else None)
     with _lock:
         if not _listener_registered:
             jax.monitoring.register_event_listener(_listener)
             _listener_registered = True
-        if _dir is not None and _dir != directory:
-            logger.warning("compile cache re-pointed %s -> %s", _dir, directory)
+        changed = directory != _dir
         _dir = directory
-    os.environ[ENV_VAR] = directory
-    logger.info("persistent compile cache enabled at %s", directory)
+    if changed:
+        logger.info("persistent compile cache: %s",
+                    directory or "off (JAX_ENABLE_COMPILATION_CACHE=0)")
     return directory
-
-
-def configure_compile_cache(spec: str | None = None) -> str | None:
-    """Resolve `spec` (flag value, ``None`` = env only) and enable the
-    cache when it names a directory.  Returns the active dir or None."""
-    directory = resolve_compile_cache(spec)
-    if directory:
-        return enable_compile_cache(directory)
-    return None
 
 
 def cache_dir() -> str | None:
@@ -279,10 +251,10 @@ def aot_compile(fn: Callable, *, label: str, example_args: tuple,
     `donate_argnums` compiles a DONATING executable: the named input
     buffers alias the outputs, so the device never holds input and
     output live at once — the zero-allocation serving dispatch
-    (docs/BENCHMARKS.md "Serving data plane").  A donated input must
-    never be read after dispatch; backends without donation support
-    (CPU) ignore the aliasing and stay bitwise-identical, which is
-    what lets the donation tests pin donated == undonated output.
+    (docs/BENCHMARKS.md "Serving data plane").  A donated input is
+    deleted by the dispatch and must never be read after it; the output
+    is bitwise-identical to the undonated executable's, which the
+    donation tests pin.
     """
     import jax
 
@@ -291,12 +263,7 @@ def aot_compile(fn: Callable, *, label: str, example_args: tuple,
         kw["donate_argnums"] = tuple(donate_argnums)
     h0, m0 = _snapshot()
     t0 = time.perf_counter()
-    with warnings.catch_warnings():
-        # CPU/backends without donation warn-and-ignore per executable;
-        # the fallback is part of the contract (bitwise tests), not news
-        warnings.filterwarnings(
-            "ignore", message=".*[Dd]onation.*not implemented.*")
-        compiled = jax.jit(fn, **kw).lower(*example_args).compile()
+    compiled = jax.jit(fn, **kw).lower(*example_args).compile()
     sec = time.perf_counter() - t0
     verdict = _classify(h0, m0)
     _record(label, sec, verdict)
@@ -341,18 +308,3 @@ def _reset_stats_for_tests() -> None:
     _MISSES._reset()
     with _lock:
         _labels.clear()
-
-
-def _disable_for_tests() -> None:
-    """Detach the cache dir (config side too) — test isolation only."""
-    global _dir
-    import jax
-    from jax.experimental.compilation_cache import compilation_cache as jax_cc
-
-    enabled = _dir is not None
-    with _lock:
-        _dir = None
-    jax.config.update("jax_compilation_cache_dir", None)
-    if enabled:
-        jax_cc.reset_cache()  # clear the process latch too
-    os.environ.pop(ENV_VAR, None)

@@ -96,9 +96,9 @@ __all__ = [
 
 logger = get_logger("faa_tpu.telemetry")
 
-#: env handoff, mirroring FAA_COMPILE_CACHE: the CLIs export the
-#: resolved journal dir so fleet-launched hosts, exit-77 relaunches and
-#: subprocess drills inherit the shared telemetry dir without flags
+#: env handoff: the CLIs export the resolved journal dir so
+#: fleet-launched hosts, exit-77 relaunches and subprocess drills
+#: inherit the shared telemetry dir without flags
 ENV_VAR = "FAA_TELEMETRY"
 
 #: the journal's closed event taxonomy (docs/OBSERVABILITY.md) — a typo

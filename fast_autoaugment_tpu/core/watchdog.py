@@ -18,8 +18,9 @@ with a deadline:
   time** (``auto`` mode: ``max(min_deadline, hang_factor x EMA)``) or
   is a fixed operator-supplied number of seconds;
 - the **first call per label gets a separate, generous compile
-  allowance** — XLA compiles on first dispatch and a 30-55 s compile
-  (BENCH_r02-r05) must never read as a hang;
+  allowance** — XLA compiles on first dispatch, and a cold compile
+  (86-88 s for the WRN-40-2 batch-128 train dispatch on a TPU v5e,
+  chip run of PR 21 — PERF.md) must never read as a hang;
 - expiry raises the typed
   :class:`~fast_autoaugment_tpu.core.resilience.DispatchHungError`.
   The hung computation holds the donated state buffers, so there is
@@ -95,8 +96,12 @@ def dispatch_enqueue_guard():
 
 logger = get_logger("faa_tpu.watchdog")
 
-#: first-call-per-label deadline: covers XLA compile (observed 23-55 s
-#: per process on this repo's models, BENCH_r02-r05) with slack
+#: first-call-per-label deadline: covers XLA compile with slack.  The
+#: one model measured on the chip (WRN-40-2: 86-101 s cold for the
+#: train dispatch, 74-84 s for the candidate-vmapped TTA step —
+#: PERF.md) leaves ~6x; a deeper family
+#: (PyramidNet-272) has not been compiled there yet, so re-check this
+#: before relying on ``--watchdog auto`` for it
 DEFAULT_COMPILE_ALLOWANCE_SEC = 600.0
 #: first-call deadline once the compile tax is KNOWN paid (persistent
 #: compile cache hit / AOT-loaded executable): covers executable

@@ -22,9 +22,16 @@ One :func:`run_scenario` call owns a full drill lifecycle:
    so ``make status`` and ``make trace`` can replay the whole drill
    from the journal alone.
 
-:func:`run_suite` runs a list of named scenarios back to back sharing
-one AOT compile cache (the first scenario pays the warm; the rest ride
-it) and renders the verdict table docs/BENCHMARKS.md pins.
+:func:`run_suite` runs a list of named scenarios back to back; every
+child process shares the persistent compile cache placed by
+``JAX_COMPILATION_CACHE_DIR`` / the in-checkout default
+(``core/compilecache.py`` — the first scenario pays the AOT warm, the
+rest ride it) and the suite renders the verdict table
+docs/BENCHMARKS.md pins.
+
+CPU-only: every child is spawned with ``JAX_PLATFORMS=cpu`` — several
+replicas at once cannot share one chip (one process owns it), so the
+game days are host-side drills, not device measurements.
 
 This module owns every filesystem touch of the game-day stack — the
 ``launch/gameday_cli.py`` front end stays FS-free (faalint F1).
@@ -187,13 +194,13 @@ def _base_env() -> dict:
 
 
 def _replica_cmd(scn: Scenario, policy_path: str, tel_dir: str,
-                 cc_dir: str, pol_dir: str | None) -> list[str]:
+                 pol_dir: str | None) -> list[str]:
     pl = scn.plane
     cmd = [sys.executable, "-m", "fast_autoaugment_tpu.serve.serve_cli",
            "--policy", policy_path,
            "--image", str(pl.image), "--shapes", pl.shapes,
            "--max-wait-ms", str(pl.max_wait_ms),
-           "--telemetry", tel_dir, "--compile-cache", cc_dir,
+           "--telemetry", tel_dir,
            "--traffic-stats", "--drain-timeout", "8"]
     if pl.dispatch_floor_ms > 0:
         cmd += ["--dispatch-floor-ms", str(pl.dispatch_floor_ms)]
@@ -212,7 +219,7 @@ def _replica_cmd(scn: Scenario, policy_path: str, tel_dir: str,
     return cmd
 
 
-def _bring_up(scn: Scenario, workdir: str, cc_dir: str,
+def _bring_up(scn: Scenario, workdir: str,
               policies: list[str]) -> _PlaneHandle:
     """Spawn the plane and block until it answers: every replica (or
     the autoscaler's minimum fleet) proves ``/readyz``, then the router
@@ -223,20 +230,20 @@ def _bring_up(scn: Scenario, workdir: str, cc_dir: str,
     os.makedirs(port_dir, exist_ok=True)
     handle = _PlaneHandle(workdir, tel_dir, port_dir)
     try:
-        return _bring_up_inner(scn, handle, cc_dir, policies)
+        return _bring_up_inner(scn, handle, policies)
     except BaseException:
         _teardown(handle)  # no orphans on a failed bring-up
         raise
 
 
-def _bring_up_inner(scn: Scenario, handle: _PlaneHandle, cc_dir: str,
+def _bring_up_inner(scn: Scenario, handle: _PlaneHandle,
                     policies: list[str]) -> _PlaneHandle:
     pl = scn.plane
     tel_dir, port_dir = handle.tel_dir, handle.port_dir
     workdir = handle.workdir
     pol_dir = os.path.dirname(policies[0])
     env = _base_env()
-    rep_cmd = _replica_cmd(scn, policies[0], tel_dir, cc_dir,
+    rep_cmd = _replica_cmd(scn, policies[0], tel_dir,
                            pol_dir if pl.tenant_capacity > 0 else None)
 
     expected = []
@@ -462,8 +469,7 @@ def _has_terminal(tel_dir: str) -> bool:
     return bool(_read_journal(tel_dir, types={"promote", "rollback"}))
 
 
-def run_scenario(scn: Scenario, *, workdir: str,
-                 compile_cache: str) -> dict:
+def run_scenario(scn: Scenario, *, workdir: str) -> dict:
     """One full drill: bring-up -> traffic (+ kill + sustain) ->
     teardown -> verdict record (see module docstring)."""
     os.makedirs(workdir, exist_ok=True)
@@ -489,7 +495,7 @@ def run_scenario(scn: Scenario, *, workdir: str,
     logger.info("gameday %s: %d requests over %.0fs (digest %s)",
                 scn.name, len(schedule), scn.traffic.duration_s, digest)
 
-    handle = _bring_up(scn, workdir, compile_cache, policies)
+    handle = _bring_up(scn, workdir, policies)
     watcher = None
     router_stats = None
     report = None
@@ -584,8 +590,6 @@ def run_suite(names: list[str] | None = None, *, smoke: bool = False,
         raise KeyError(f"unknown scenario(s): {', '.join(unknown)} "
                        f"(known: {', '.join(suite_names())})")
     root = root or tempfile.mkdtemp(prefix="faa-gameday-")
-    compile_cache = os.path.join(root, "compile-cache")
-    os.makedirs(compile_cache, exist_ok=True)
     records = []
     try:
         for name in names:
@@ -596,8 +600,7 @@ def run_suite(names: list[str] | None = None, *, smoke: bool = False,
                 scn = scaled(scn, smoke_factor)
             try:
                 records.append(run_scenario(
-                    scn, workdir=os.path.join(root, name),
-                    compile_cache=compile_cache))
+                    scn, workdir=os.path.join(root, name)))
             except Exception as e:  # noqa: BLE001 — one crashed drill
                 # must not take the rest of the suite (or its verdict
                 # table) down with it; a harness crash is NEVER "as

@@ -329,10 +329,12 @@ def _supervise(fleet: _Fleet, host_id: int, host: str, command: list[str],
 
 
 #: env vars forwarded to every host launch AND retry by default — the
-#: whole fleet-sharing contract for the compile cache, the telemetry
-#: journal, the serial-baseline dispatch trace, and the fleet-search
-#: role/transport handoff (pinned by tests/test_fleet_search.py)
-DEFAULT_ENV_PASSTHROUGH = ("JAX_PLATFORMS", "FAA_COMPILE_CACHE",
+#: whole fleet-sharing contract for the compile cache (JAX's own
+#: placement variable: set it in the launcher's environment to a
+#: directory all hosts mount), the telemetry journal, the
+#: serial-baseline dispatch trace, and the fleet-search role/transport
+#: handoff (pinned by tests/test_fleet_search.py)
+DEFAULT_ENV_PASSTHROUGH = ("JAX_PLATFORMS", "JAX_COMPILATION_CACHE_DIR",
                            "FAA_TELEMETRY", "FAA_PIPELINE_TRACE",
                            "FAA_SEARCH_ROLE", "FAA_FLEET_TRANSPORT")
 
@@ -495,14 +497,6 @@ def main(argv=None):
                         "DIR/hosts/host<id>.json beat is older than this "
                         "many seconds — the interpreter-level wedge the "
                         "in-process --watchdog cannot catch.  0 = off")
-    p.add_argument("--compile-cache", default=None, metavar="DIR",
-                   help="shared persistent XLA compilation cache: "
-                        "exported to every host (and every RETRY — the "
-                        "relaunch deserializes the executables its "
-                        "predecessor compiled) as FAA_COMPILE_CACHE.  "
-                        "Point it at a directory all hosts mount; the "
-                        "worker CLIs pick it up without extra flags "
-                        "(core/compilecache.py)")
     p.add_argument("--roles", default=None, metavar="R1,R2,...",
                    help="per-host fleet role (learner/actor for a "
                         "--fleet-transport search; control for a "
@@ -518,7 +512,7 @@ def main(argv=None):
                         "exported to every host (and every retry) as "
                         "FAA_FLEET_TRANSPORT, so the worker CLIs pick "
                         "up the transport without extra flags — the "
-                        "same contract as --compile-cache/--telemetry "
+                        "same contract as --telemetry "
                         "(docs/RESILIENCE.md 'Fleet search')")
     p.add_argument("--telemetry", default=None, metavar="DIR",
                    help="shared flight-recorder journal dir: exported to "
@@ -535,14 +529,10 @@ def main(argv=None):
         command = command[1:]
     if not command:
         p.error("no command given")
-    if args.compile_cache and args.compile_cache.lower() != "off":
-        # the env-passthrough list already forwards FAA_COMPILE_CACHE to
-        # every host launch (retries included) — setting it here is the
-        # whole fleet-sharing contract
-        os.environ["FAA_COMPILE_CACHE"] = args.compile_cache
     if args.telemetry and args.telemetry.lower() != "off":
-        # same contract as the compile cache: the env-passthrough list
-        # forwards FAA_TELEMETRY to every host launch and retry
+        # the env-passthrough list forwards FAA_TELEMETRY to every host
+        # launch (retries included) — setting it here is the whole
+        # fleet-sharing contract
         os.environ["FAA_TELEMETRY"] = args.telemetry
     if args.fleet_transport and args.fleet_transport.lower() != "off":
         # and again for the fleet-search round transport

@@ -86,7 +86,9 @@ def main(argv=None) -> int:
 
     # provenance stamps ride the suite JSON: a verdict captured on a
     # contended host is evidence about the host, not the plane
-    extra = {"single_core_caveat": True}
+    # "platform": every plane process is spawned with JAX_PLATFORMS=cpu
+    # (gameday/runner.py) — a game day never touches the chip
+    extra = {"single_core_caveat": True, "platform": "cpu"}
     try:
         if _REPO not in sys.path:
             sys.path.insert(0, _REPO)

@@ -317,19 +317,6 @@ def build_parser() -> argparse.ArgumentParser:
                         "passes it; only used when --workqueue is unset)")
     p.add_argument("--num-hosts", type=int, default=None)
     p.add_argument("--host-id", type=int, default=None)
-    p.add_argument("--compile-cache", default="off", metavar="{off,DIR}",
-                   help="persistent XLA compilation cache shared by "
-                        "every compile this search pays (phase-1 "
-                        "training, TTA, audit, phase-3 retrains): a "
-                        "fresh process — exit-77 resume, fleet retry, "
-                        "reclaimed work unit — deserializes its "
-                        "executables from DIR instead of re-paying the "
-                        "23-55s compile tax; hit/miss counts land in "
-                        "search_result.json['compile_cache'].  'off' "
-                        "(default) = historical behavior (still honors "
-                        "an inherited FAA_COMPILE_CACHE; caching never "
-                        "changes numerics).  The fleet launcher's "
-                        "--compile-cache exports the dir to every host")
     p.add_argument("--telemetry", default="off", metavar="{off,DIR}",
                    help="flight-recorder journal (core/telemetry.py): "
                         "typed dispatch/compile/checkpoint/lease/trial "
@@ -431,7 +418,7 @@ def _resolve_fleet_transport(args):
     """``(transport, role)``: the cross-host round transport (or None)
     plus this host's resolved role.  The dir falls back to the
     FAA_FLEET_TRANSPORT env handoff (the fleet launcher exports it to
-    every host launch and retry, like FAA_COMPILE_CACHE)."""
+    every host launch and retry)."""
     import os
 
     from fast_autoaugment_tpu.search.pipeline import (
@@ -482,7 +469,6 @@ def _run_actor(args, conf, transport):
         aug_dispatch=args.aug_dispatch,
         aug_groups=args.aug_groups,
         watchdog=args.watchdog,
-        compile_cache=args.compile_cache,
         telemetry_spec=args.telemetry,
         ckpt_timeout=args.ckpt_publish_timeout,
     )
@@ -526,7 +512,6 @@ def _run(args, conf, t_start):
         ckpt_keep=args.ckpt_keep,
         watchdog=args.watchdog,
         work_queue=work_queue,
-        compile_cache=args.compile_cache,
         async_pipeline=args.async_pipeline,
         pipeline_actors=args.pipeline_actors,
         pipeline_queue_depth=args.pipeline_queue_depth,
@@ -561,7 +546,7 @@ def _run(args, conf, t_start):
         import jax
 
         hours = (time.time() - t_start) * jax.device_count() / 3600.0
-        # honest name + legacy alias; `backend` (from search_policies)
+        # honest name + legacy alias; `platform` (from search_policies)
         # says what actually measured these hours
         result["device_hours_total"] = hours
         result["tpu_hours_total"] = hours
@@ -659,7 +644,6 @@ def _run(args, conf, t_start):
                 ckpt_keep=args.ckpt_keep,
                 checkpoint_every_dispatch=args.ckpt_every_dispatch,
                 watchdog=args.watchdog, heartbeat=phase3_hb,
-                compile_cache=args.compile_cache,
             )
             outcomes[mode].append(float(res.get("top1_test", 0.0)))
             logger.info("phase3 %s run %d: top1_test=%.4f", mode, run,
@@ -683,7 +667,7 @@ def _run(args, conf, t_start):
         transport.mark_host_done()
     persist()
     logger.info("search complete: %.3f device-hours on %s",
-                result["tpu_hours_total"], result.get("backend", "?"))
+                result["device_hours_total"], result.get("platform", "?"))
     return result
 
 

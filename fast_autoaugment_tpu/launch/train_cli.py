@@ -97,16 +97,6 @@ def build_parser() -> argparse.ArgumentParser:
                         "--ckpt-every-dispatch to bound replayed work).  "
                         "'off' (default) keeps the historical async "
                         "dispatch bit-for-bit (docs/RESILIENCE.md)")
-    p.add_argument("--compile-cache", default="off", metavar="{off,DIR}",
-                   help="persistent XLA compilation cache: point JAX's "
-                        "on-disk executable cache at DIR so a fresh "
-                        "process (exit-77 resume, fleet retry) "
-                        "deserializes its executables instead of "
-                        "re-paying the 23-55s first compile; hit/miss "
-                        "counts are logged and stamped in the result.  "
-                        "'off' (default) = the historical behavior "
-                        "(still honors an inherited FAA_COMPILE_CACHE; "
-                        "caching never changes numerics)")
     p.add_argument("--telemetry", default="off", metavar="{off,DIR}",
                    help="flight-recorder journal (core/telemetry.py): "
                         "typed dispatch/compile/checkpoint events under "
@@ -169,7 +159,6 @@ def main(argv=None):
             ckpt_keep=args.ckpt_keep,
             checkpoint_every_dispatch=args.ckpt_every_dispatch,
             watchdog=args.watchdog,
-            compile_cache=args.compile_cache,
         )
     except PreemptedError as e:
         logger.warning("preempted (%s) — exiting %d so the supervisor "
@@ -203,5 +192,12 @@ def main(argv=None):
     return result
 
 
+def _cli() -> None:
+    """Script entry: the result (device stamp, steps, losses, compile-
+    cache evidence) is the one line on stdout, so a supervisor such as
+    ``chip_smoke.py`` can check the run from outside the process."""
+    print(json.dumps(main()), flush=True)
+
+
 if __name__ == "__main__":
-    main()
+    _cli()

@@ -45,6 +45,7 @@ __all__ = [
     "place_stacked_index_matrix",
     "distributed_init",
     "local_batch_to_global",
+    "device_stamp",
 ]
 
 
@@ -224,20 +225,40 @@ def local_batch_to_global(batch_per_device: int, mesh: Mesh) -> int:
     return batch_per_device * mesh.size
 
 
+def device_stamp() -> dict:
+    """What JAX is running on, as JAX reports it — stamped into every
+    trainer/search result and bench line so a number can never be read
+    without the device that produced it (a CPU rehearsal says ``cpu``)."""
+    devices = jax.devices()
+    return {"platform": devices[0].platform,
+            "device_kind": devices[0].device_kind,
+            "device_count": len(devices)}
+
+
 def distributed_init(coordinator_address: str | None = None,
                      num_processes: int | None = None,
                      process_id: int | None = None):
     """Multi-host rendezvous (replaces torch.distributed.launch env-var
     plumbing, reference ``train_dist.py:126-131``).  On TPU pods the
-    arguments are auto-detected from the environment."""
-    if jax.process_count() > 1:
-        return  # already initialized
+    arguments are auto-detected from the environment.
+
+    Must run before anything touches the backend:
+    ``jax.distributed.initialize`` refuses once a device has been
+    queried, so nothing here may ask JAX for a process or device count
+    first.  With a coordinator given, a failed rendezvous raises — a
+    host that silently trained alone would report success for a job
+    that never ran.  Without one, a failed auto-detection means
+    single-process (tests, one chip)."""
+    if jax.distributed.is_initialized():
+        return
     try:
         jax.distributed.initialize(
             coordinator_address=coordinator_address,
             num_processes=num_processes,
             process_id=process_id,
         )
-    except (ValueError, RuntimeError):
-        # single-process (tests, single-chip); nothing to do
-        pass
+    except (ValueError, RuntimeError) as e:
+        if coordinator_address is not None:
+            raise RuntimeError(
+                f"multi-host initialisation against coordinator "
+                f"{coordinator_address!r} failed: {e}") from e

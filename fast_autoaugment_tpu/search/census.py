@@ -1,64 +1,17 @@
-"""Executable-census helpers for the compiled search steps.
+"""Executable census for the compiled search steps.
 
 The policy-as-tensor TTA design promises ONE executable per argument
 shape for the whole search (SURVEY.md hard-part 3); the census is how
 the driver PROVES it in every `search_result.json` instead of claiming
-it.  The probe used to be a bare call to jit's private
-``_cache_size()``, silently recording ``None`` whenever a jax upgrade
-moved the attribute (VERDICT r5 weak 6) — which would have turned the
-zero-recompile gate into a no-op without anyone noticing.
-
-:func:`executable_census` is the version-guarded replacement:
-
-1. prefer ``_cache_size()`` (private, exact — counts cache entries);
-2. fall back to the explicit trace-event counter the step factories in
-   ``search/tta.py`` attach (``_faa_trace_count``: a retrace happens
-   exactly once per new cache entry, so the count is equivalent), with
-   a WARNING that the private API is gone;
-3. warn loudly and return ``None`` only when neither probe exists —
-   never a silent no-op.
+it, and a count above the expected one fails the search.
 """
 
 from __future__ import annotations
 
-from fast_autoaugment_tpu.utils.logging import get_logger
-
 __all__ = ["executable_census"]
 
-logger = get_logger("faa_tpu.census")
 
-
-def executable_census(step) -> int | None:
-    """Best-effort count of compiled executables held by a jitted step.
-
-    Returns an int from jit's private cache probe when available, else
-    from the trace-event counter attached by the ``search/tta.py``
-    factories, else ``None`` (after warning).  A return of ``None``
-    means the zero-recompile invariant CANNOT be asserted — callers
-    must treat it as "unknown", not "one".
-    """
-    cache_probe = getattr(step, "_cache_size", None)
-    if callable(cache_probe):
-        try:
-            return int(cache_probe())
-        except Exception as e:  # noqa: BLE001 — private, version-dependent
-            logger.warning(
-                "jit _cache_size() probe failed (%s: %s) — falling back to "
-                "the trace-event counter", type(e).__name__, e,
-            )
-    trace_probe = getattr(step, "_faa_trace_count", None)
-    if callable(trace_probe):
-        if not callable(cache_probe):
-            logger.warning(
-                "jit no longer exposes _cache_size (jax upgrade?) — "
-                "executable census now counts explicit trace events; "
-                "the zero-recompile assertion still holds, but consider "
-                "updating search/census.py for the new jax version"
-            )
-        return int(trace_probe())
-    logger.warning(
-        "executable census UNAVAILABLE for %r: neither jit._cache_size nor "
-        "the _faa_trace_count counter exists — the zero-recompile invariant "
-        "is NOT being verified for this step", step,
-    )
-    return None
+def executable_census(step) -> int:
+    """Compiled executables held by a jitted step: the size of jit's
+    own cache (``_cache_size``, which the seam wrapper delegates)."""
+    return int(step._cache_size())

@@ -14,7 +14,8 @@ Differences by design:
   the launcher for multi-host fold sharding (fold k -> host k % n).
 - TPE is in-tree (``search/tpe.py``).
 - "GPU-hours" accounting (``search.py:132-133,251``) becomes
-  TPU-seconds = wall x device_count, reported per phase.
+  device-seconds = wall x device_count, reported per phase next to
+  the platform/device_kind that spent them.
 
 Additions beyond the reference (round-2 post-mortem,
 ``docs/search_postmortem_r2.md`` — the reference has neither and its
@@ -62,7 +63,7 @@ from fast_autoaugment_tpu.core.watchdog import resolve_watchdog
 from fast_autoaugment_tpu.data.datasets import cv_split, load_dataset
 from fast_autoaugment_tpu.models import get_model, num_class
 from fast_autoaugment_tpu.ops.augment import SEARCH_OP_NAMES
-from fast_autoaugment_tpu.parallel.mesh import make_mesh
+from fast_autoaugment_tpu.parallel.mesh import device_stamp, make_mesh
 from fast_autoaugment_tpu.policies.archive import (
     policy_decoder,
     policy_to_tensor,
@@ -533,7 +534,6 @@ def search_policies(
     ckpt_keep: int = 2,
     watchdog="off",
     work_queue=None,
-    compile_cache: str = "off",
     async_pipeline: str | bool = "off",
     pipeline_actors: int = 1,
     pipeline_queue_depth: int = 1,
@@ -693,14 +693,14 @@ def search_policies(
     schedule) and is mutually exclusive with `work_queue` (which
     scatters whole folds instead of rounds).
 
-    `compile_cache` ("off" default / a directory) wires JAX's
-    persistent compilation cache through every compile this search
-    pays — phase-1 training, TTA, audit, retrains — so a fresh process
-    (exit-77 resume, fleet retry, reclaimed unit) deserializes its
-    executables instead of re-lowering them; hit/miss counts and
-    per-label first-call seconds are stamped into
-    ``search_result.json['compile_cache']`` (``core/compilecache.py``;
-    "off" still honors an inherited ``FAA_COMPILE_CACHE``).
+    Every compile this search pays — phase-1 training, TTA, audit,
+    retrains — goes through the persistent compilation cache where
+    ``JAX_COMPILATION_CACHE_DIR`` (or the fixed in-checkout default)
+    places it, so a fresh process (exit-77 resume, fleet retry,
+    reclaimed unit) deserializes its executables instead of re-lowering
+    them; hit/miss counts and per-label first-call seconds are stamped
+    into ``search_result.json['compile_cache']``
+    (``core/compilecache.py``).
 
     `topup_trials` (0 default) is the WARM-START entry point the
     control plane's incremental re-search uses (``control/research.py``,
@@ -739,12 +739,11 @@ def search_policies(
         base_num_search = num_search
         num_search += topup_trials
 
-    # persistent compile cache (core/compilecache.py): "off" (default,
-    # bit-for-bit historical) still honors an inherited
-    # FAA_COMPILE_CACHE, which is how fleet retries and reclaimed work
-    # units warm-start; every compile this search pays is classified
-    # hit/miss and stamped into search_result.json['compile_cache']
-    configure_compile_cache(compile_cache)
+    # persistent compile cache (core/compilecache.py): every compile
+    # this search pays is classified hit/miss and stamped into
+    # search_result.json['compile_cache'] — how fleet retries and
+    # reclaimed work units prove they warm-started
+    configure_compile_cache()
     # flight-recorder journal (core/telemetry.py): "off" (default,
     # bit-for-bit — no file I/O, no new artifact keys) still honors an
     # inherited FAA_TELEMETRY, the fleet/relaunch handoff
@@ -756,14 +755,10 @@ def search_policies(
     mesh = make_mesh()
     watch = {"start": wall()}
     result = SearchResult()
-    # device-hours ledger provenance (VERDICT r4 weak 5): the ``tpu_
-    # secs_*`` fields are wall x device_count on WHATEVER backend ran —
-    # a CPU dev-box run must not read as TPU-hours.  Every consumer can
-    # now tell from the artifact alone.
-    dev0 = jax.devices()[0]
-    result["backend"] = dev0.platform
-    result["device_kind"] = getattr(dev0, "device_kind", dev0.platform)
-    result["device_count"] = mesh.size
+    # device-hours ledger provenance: the ``device_secs_*`` fields are
+    # wall x device_count on WHATEVER backend ran — a CPU rehearsal must
+    # not read as TPU-hours.  Every consumer can tell from the artifact.
+    result.update(device_stamp())
     # the guard settings this run actually used — the defaults-safety
     # regression test reads these back from the committed artifact
     result["guards"] = {
@@ -1558,9 +1553,7 @@ def search_policies(
     # compile-cache census: the whole point of policy-as-tensor TTA is
     # that EVERY trial reuses one executable (SURVEY.md hard-part 3) —
     # record it so the search-cost artifact can assert zero recompiles
-    # across all num_search x folds evaluations.  executable_census is
-    # the version-guarded probe (jit private _cache_size, else the
-    # explicit trace-event counter, else a loud warning + None).
+    # across all num_search x folds evaluations.
     # a fully-resumed run never builds the TTA machinery — there were
     # no evaluations in this process, so there is nothing to census
     result["tta_executables"] = (
@@ -1735,8 +1728,11 @@ def search_policies(
         fleet_transport.mark_search_done(
             {"num_sub_policies": len(final_policy_set)})
     logger.info(
-        "search done: %d sub-policies; phase1 %.1f TPU-s, phase2 %.1f TPU-s",
-        len(final_policy_set), result["tpu_secs_phase1"], result["tpu_secs_phase2"],
+        "search done: %d sub-policies; phase1 %.1f device-s, phase2 %.1f "
+        "device-s on %s (%s x%d)",
+        len(final_policy_set), result["device_secs_phase1"],
+        result["device_secs_phase2"], result["platform"],
+        result["device_kind"], result["device_count"],
     )
     result["elapsed_total"] = wall() - watch["start"]
     return result
@@ -1757,7 +1753,6 @@ def search_actor(
     aug_dispatch: str = "exact",
     aug_groups: int = 8,
     watchdog="off",
-    compile_cache: str = "off",
     telemetry_spec: str = "off",
     poll_sec: float = 0.5,
     ckpt_timeout: float = 900.0,
@@ -1778,7 +1773,7 @@ def search_actor(
     peers) once the learner marks the search done."""
     from fast_autoaugment_tpu.search.pipeline import run_fleet_actor
 
-    configure_compile_cache(compile_cache)
+    configure_compile_cache()
     telemetry.configure_telemetry(telemetry_spec)
     mesh = make_mesh()
     wd = resolve_watchdog(watchdog)

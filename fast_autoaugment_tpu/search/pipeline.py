@@ -117,8 +117,8 @@ def resolve_async_pipeline(spec) -> bool:
 #: the same launcher/worker env handoff as FAA_HOST_ID/FAA_ATTEMPT)
 SEARCH_ROLE_ENV_VAR = "FAA_SEARCH_ROLE"
 #: shared transport-dir handoff (the fleet launcher's
-#: ``--fleet-transport`` exports it, mirroring FAA_COMPILE_CACHE /
-#: FAA_TELEMETRY — every host launch AND retry inherits it)
+#: ``--fleet-transport`` exports it, mirroring FAA_TELEMETRY — every
+#: host launch AND retry inherits it)
 FLEET_TRANSPORT_ENV_VAR = "FAA_FLEET_TRANSPORT"
 
 _SEARCH_ROLES = ("learner", "actor")

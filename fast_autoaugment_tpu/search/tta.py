@@ -38,30 +38,6 @@ from fast_autoaugment_tpu.ops.preprocess import cifar_train_batch
 __all__ = ["make_tta_step", "make_audit_step", "eval_tta", "eval_tta_batched"]
 
 
-def _jit_with_trace_counter(fn, label: str):
-    """jit `fn` (through the compile seam) with an explicit trace-event
-    counter attached.
-
-    Each retrace of a jitted function corresponds to one new executable
-    in its compile cache (a cache hit never re-traces), so counting
-    trace events is a public-API-only census of compiles — the fallback
-    :func:`search.census.executable_census` uses when jit's private
-    ``_cache_size`` disappears in a jax upgrade.  The counter fires at
-    trace time only; it costs nothing on the steady-state call path.
-    The seam (``core/compilecache.py``) times the first-call lowering
-    and classifies it against the persistent compile cache; `label`
-    matches the watchdog's dispatch label for the same entry point."""
-    events: list = []
-
-    def counted(*args, **kwargs):
-        events.append(1)  # trace-time side effect: once per (re)lowering
-        return fn(*args, **kwargs)
-
-    jitted = seam_jit(counted, label=label)
-    jitted._faa_trace_count = lambda: len(events)
-    return jitted
-
-
 def _default_augment_fn(cutout_length: int, aug_dispatch: str = "exact",
                         aug_groups: int = 8) -> Callable:
     """CIFAR-family train stack (crop/flip/normalize + policy + cutout)."""
@@ -164,7 +140,7 @@ def make_tta_step(model, *, num_policy: int = 5, cutout_length: int = 16,
         return score_augmented(params, batch_stats, augmented, labels, mask)
 
     if num_candidates is None:
-        return _jit_with_trace_counter(one_candidate, "tta")
+        return seam_jit(one_candidate, label="tta")
 
     def tta_step_batched(params, batch_stats, images, labels, mask,
                          policies, keys):
@@ -184,7 +160,7 @@ def make_tta_step(model, *, num_policy: int = 5, cutout_length: int = 16,
                 params, batch_stats, images, labels, mask, pol, k)
         )(policies, keys)
 
-    return _jit_with_trace_counter(tta_step_batched, "tta_batched")
+    return seam_jit(tta_step_batched, label="tta_batched")
 
 
 def make_audit_step(model, *, num_policy: int = 5, cutout_length: int = 16,
@@ -247,7 +223,7 @@ def make_audit_step(model, *, num_policy: int = 5, cutout_length: int = 16,
             "cnt": mask.sum().astype(jnp.float32),
         }
 
-    return _jit_with_trace_counter(audit_step, "audit")
+    return seam_jit(audit_step, label="audit")
 
 
 def eval_tta(tta_step, params, batch_stats, batches, policy, key,

@@ -286,7 +286,13 @@ class LocalReplicaFleet:
     ``scale_down`` SIGTERMs the NEWEST replica: serve_cli's graceful
     drain stops admitting, finishes in-flight requests, removes its
     discovery record and exits 0 — zero dropped in-flight requests by
-    construction (docs/RESILIENCE.md serving exit contract)."""
+    construction (docs/RESILIENCE.md serving exit contract).
+
+    CPU-only today: every replica inherits this process's environment,
+    and a chip has one owner process — N replicas launched this way on a
+    one-chip machine would contend for it.  Run the fleet under
+    ``JAX_PLATFORMS=cpu`` (the game days and benches do) until replicas
+    can be pinned one to a device or served in-process."""
 
     def __init__(self, replica_cmd: list[str], port_dir: str, *,
                  extra_env: dict | None = None, tag_prefix: str = "replica"):

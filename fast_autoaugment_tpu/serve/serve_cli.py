@@ -2,12 +2,12 @@
 augmentation endpoint.
 
     python -m fast_autoaugment_tpu.serve.serve_cli \
-        --policy search_out/final_policy.json --image 32 \
-        --compile-cache /shared/xla-cache --port 8765
+        --policy search_out/final_policy.json --image 32 --port 8765
 
 Loads the learned policy, AOT-compiles the application kernels over the
-padded batch shapes (through the compile seam — with ``--compile-cache``
-a restarted server deserializes them in seconds), and serves:
+padded batch shapes (through the compile seam — the persistent cache
+under ``JAX_COMPILATION_CACHE_DIR`` lets a restarted server deserialize
+them instead), and serves:
 
 - ``POST /augment`` — body is an ``.npz`` with ``images``
   (``[n, H, W, C]`` uint8 or float32) and optionally ``seeds``
@@ -45,7 +45,8 @@ a restarted server deserializes them in seconds), and serves:
   ``--policy-dir`` recipe — kicks a background warm
   (docs/SERVING.md "Multi-policy tenancy").
 - ``GET /stats`` — serving accounting (admission/shed/breaker/reload
-  counters included) + the ``compile_cache`` stamp.
+  counters included) + the ``compile_cache`` stamp and the device
+  (``platform``/``device_kind``/``device_count``) the replica runs on.
 - ``GET /healthz`` — LIVENESS: 200 while the process runs.
 - ``GET /readyz`` — READINESS: 200 only while the server is admitting
   and the circuit breaker is closed; 503 while draining or broken (a
@@ -423,8 +424,10 @@ def make_handler(server, applier, state: ServeState | None = None,
                 from fast_autoaugment_tpu.core.compilecache import (
                     compile_cache_stats,
                 )
+                from fast_autoaugment_tpu.parallel.mesh import device_stamp
 
                 stats = server.stats()
+                stats.update(device_stamp())
                 stats["compile_cache"] = compile_cache_stats()
                 stats["aot_compile"] = {
                     str(s): r for s, r in getattr(
@@ -921,10 +924,6 @@ def build_parser() -> argparse.ArgumentParser:
                    help="coalescer cap (default: the largest AOT shape)")
     p.add_argument("--max-wait-ms", type=float, default=5.0,
                    help="coalescing window after the first queued request")
-    p.add_argument("--compile-cache", default="off", metavar="{off,DIR}",
-                   help="persistent XLA compilation cache: a restarted "
-                        "server deserializes its AOT executables from DIR "
-                        "instead of re-lowering them (core/compilecache.py)")
     p.add_argument("--telemetry", default="off", metavar="{off,DIR}",
                    help="flight-recorder journal dir (core/telemetry.py): "
                         "typed dispatch/shed/breaker/reload events with "
@@ -1071,7 +1070,7 @@ def main(argv=None):
         PolicyServer,
     )
 
-    configure_compile_cache(args.compile_cache)
+    configure_compile_cache()
     from fast_autoaugment_tpu.core.telemetry import configure_telemetry
 
     configure_telemetry(args.telemetry)

@@ -63,6 +63,7 @@ from fast_autoaugment_tpu.models import get_model, num_class
 from fast_autoaugment_tpu.ops.optim import build_optimizer
 from fast_autoaugment_tpu.ops.schedules import build_schedule
 from fast_autoaugment_tpu.parallel.mesh import (
+    device_stamp,
     make_fold_mesh,
     make_mesh,
     place_index_matrix,
@@ -263,7 +264,6 @@ def train_and_eval(
     checkpoint_every_dispatch: int = 0,
     watchdog="off",
     heartbeat: Callable | None = None,
-    compile_cache: str = "off",
 ) -> dict:
     """Train (or just evaluate) one model under `conf`.
 
@@ -312,16 +312,16 @@ def train_and_eval(
     boundary (cache path) and epoch boundary — a raised
     ``LeaseLostError`` propagates and aborts the unit.
 
-    ``compile_cache`` ("off" default / a directory) points JAX's
-    persistent compilation cache at a shared dir so a fresh process —
-    an exit-77 resume, a fleet retry, a reclaimed work unit — reaches
-    its first step in seconds instead of re-paying the 23-55 s compile
-    tax (``core/compilecache.py``; "off" still honors an inherited
-    ``FAA_COMPILE_CACHE``).  Caching never changes numerics — only
-    where executables come from; the result carries the evidence under
-    ``result['compile_cache']``.
+    The persistent compilation cache is always armed where
+    ``JAX_COMPILATION_CACHE_DIR`` (or the fixed in-checkout default)
+    places it (``core/compilecache.py``), so a fresh process — an
+    exit-77 resume, a fleet retry, a reclaimed work unit — reaches its
+    first step without re-paying the compile; the evidence rides under
+    ``result['compile_cache']``.  The result also names the device that
+    ran it (``platform``/``device_kind``/``device_count``) and the
+    optimizer ``steps`` taken.
     """
-    cache_dir_active = configure_compile_cache(compile_cache)
+    cache_dir_active = configure_compile_cache()
     if mesh is None:
         mesh = make_mesh()
     is_master = jax.process_index() == 0
@@ -376,6 +376,9 @@ def train_and_eval(
 
     batch_per_device = int(conf["batch"])
     global_batch = batch_per_device * mesh.size
+    logger.info("mesh %s over %d %s device(s); global batch %d",
+                dict(mesh.shape), mesh.size,
+                mesh.devices.flat[0].platform, global_batch)
     if not only_eval and len(train_idx) < global_batch:
         raise ValueError(
             f"training set has {len(train_idx)} examples < global batch "
@@ -521,6 +524,15 @@ def train_and_eval(
     elif only_eval and save_path:
         raise FileNotFoundError(f"--only-eval requires a checkpoint at {save_path}")
 
+    if use_cache:
+        # commit the carried state to the mesh BEFORE the first dispatch
+        # or eval: an uncommitted state compiled against the
+        # mesh-committed cache knocks every later call off the C++ fast
+        # dispatch path (make_multistep_train_step note), and an
+        # --only-eval restore must lower the SAME replay_eval program
+        # the training run cached, not an uncommitted variant of it
+        state = jax.device_put(state, replicated(mesh))
+
     result: dict = {"epoch": epoch_start - 1}
     best_metric = -1e9
     # device-cache eval replay: each split is placed once on first
@@ -582,6 +594,7 @@ def train_and_eval(
             for k, v in m.items():
                 result[f"{k}_{split}"] = v
         result["epoch"] = epoch_start - 1
+        result.update(steps=int(state.step), **device_stamp())
         result["compile_cache"] = compile_cache_stats()
         return result
 
@@ -604,11 +617,7 @@ def train_and_eval(
             "device cache: %d examples (%.1f MiB uint8) resident, "
             "steps_per_dispatch=%d", train_cache.num_examples,
             train_cache.nbytes / 2**20, steps_per_dispatch)
-        # commit the carried state + replicated inputs to the mesh
-        # BEFORE the first dispatch: an uncommitted state compiled
-        # against the mesh-committed cache knocks every later call off
-        # the C++ fast dispatch path (make_multistep_train_step note)
-        state = jax.device_put(state, replicated(mesh))
+        # replicated inputs join the committed state on the mesh
         rng = jax.device_put(rng, replicated(mesh))
 
     t_start = wall()
@@ -878,6 +887,7 @@ def train_and_eval(
         epoch += 1
 
     result["elapsed_sec"] = wall() - t_start
+    result.update(steps=int(state.step), **device_stamp())
     # compile-tax evidence (hit/miss counts + per-label first-call
     # seconds through the seam): a resumed/warm process proves here
     # that it reached its first step in seconds, not minutes
@@ -906,7 +916,6 @@ def train_folds_stacked(
     ckpt_keep: int = 2,
     watchdog="off",
     heartbeat: Callable | None = None,
-    compile_cache: str = "off",
 ) -> dict[int, dict]:
     """Train K phase-1 fold models as ONE vmapped program per step.
 
@@ -962,7 +971,7 @@ def train_folds_stacked(
     (deadline-guarded dispatches; lease renewal per dispatch/epoch
     boundary).
     """
-    configure_compile_cache(compile_cache)
+    configure_compile_cache()
     if len(folds) != len(save_paths):
         raise ValueError(f"{len(folds)} folds but {len(save_paths)} paths")
     num_folds = len(folds)
@@ -995,6 +1004,9 @@ def train_folds_stacked(
     )
     batch_per_device = int(conf["batch"])
     global_batch = batch_per_device * data_size
+    logger.info("stacked: %d folds on mesh %s over %d %s device(s); "
+                "per-fold global batch %d", num_folds, dict(mesh.shape),
+                mesh.size, mesh.devices.flat[0].platform, global_batch)
     for fold, tr in zip(folds, fold_train_idx):
         if len(tr) < global_batch:
             raise ValueError(

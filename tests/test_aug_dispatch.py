@@ -2,10 +2,14 @@
 
 Covers the three contracts of the dispatch split:
 
-- ``exact`` (the default) is bit-for-bit the historical path — pinned
-  against a committed golden capture (``tests/data/aug_exact_golden.npz``,
-  generated from the pre-grouped-kernel tree) so a silent default flip
-  or kernel drift fails loudly;
+- ``exact`` (the default) is pinned against a committed golden capture
+  (``tests/data/aug_exact_golden.npz``) so a silent default flip or
+  kernel drift fails loudly.  The capture was regenerated in PR 21
+  under the installed JAX's default PRNG (partitionable threefry)
+  after checking that the previous capture — drawn from the
+  pre-grouped-kernel tree — still reproduces bit-for-bit under
+  ``JAX_THREEFRY_PARTITIONABLE=0``: the kernels are unchanged, only
+  the random stream moved;
 - ``grouped`` is a *documented distributional deviation* with identical
   per-image marginals: stratified (per-chunk) sub-policy selection,
   exactly per-image `prob` gating — checked statistically (chi-square on
@@ -46,8 +50,8 @@ def _rand_imgs(seed, b=32, h=16, w=16):
 
 def test_exact_default_bitwise_unchanged_golden():
     """The exact path (and the DEFAULT dispatch) must reproduce the
-    pre-grouped-kernel tree's outputs bit-for-bit on seeded inputs —
-    the guard against a silent default flip or kernel drift."""
+    committed capture bit-for-bit on seeded inputs — the guard against
+    a silent default flip or kernel drift."""
     g = np.load(GOLDEN)
     imgs, policy = jnp.asarray(g["images"]), jnp.asarray(g["policy"])
     key = jax.random.PRNGKey(99)
@@ -539,13 +543,47 @@ def test_cli_dispatch_flags():
 # ------------------------------------------------------------- bench
 
 
-def test_bench_vs_baseline_null_on_cpu_fallback():
-    """A cpu-fallback bench run must not compare its plumbing number
-    against the TPU baseline (BENCH_r05.json's vs_baseline 0.003)."""
+def test_bench_vs_baseline_only_on_a_tpu():
+    """Only a TPU number is compared against the reference-pipeline
+    estimate; whatever else JAX ran on reports null, from the platform
+    it observed — there is no fallback flag to forget."""
     import bench
 
-    assert bench.vs_baseline(46.4, cpu_fallback=True) is None
-    assert bench.vs_baseline(65046.3, cpu_fallback=False) == 43.364
+    assert bench.vs_baseline(46.4, "cpu") is None
+    assert bench.vs_baseline(65046.3, "tpu") == 43.364
+
+
+def test_bench_peak_flops_keyed_by_exact_device_kind():
+    """MFU needs the chip's peak: known by exact device_kind, None on
+    the CPU, and an unknown TPU is an error — never a null MFU."""
+    import types
+
+    import bench
+
+    dev = lambda platform, kind: types.SimpleNamespace(  # noqa: E731
+        platform=platform, device_kind=kind)
+    assert bench._chip_peak_flops(dev("tpu", "TPU v5 lite")) == 197e12
+    assert bench._chip_peak_flops(dev("cpu", "cpu")) is None
+    with pytest.raises(KeyError, match="TPU v9 imaginary"):
+        bench._chip_peak_flops(dev("tpu", "TPU v9 imaginary"))
+    with pytest.raises(KeyError):  # a substring is not a match
+        bench._chip_peak_flops(dev("tpu", "TPU v5"))
+
+
+def test_bench_fails_when_a_sub_bench_raises(monkeypatch, capsys):
+    """A failed phase fails the run: the exception leaves main() (exit
+    code 1 as a script) and no JSON line is printed — where the old
+    bench wrote a null and exited 0."""
+    import bench
+
+    monkeypatch.setattr(bench, "bench_headline",
+                        lambda: ({"metric": "x"}, [0.1]))
+    monkeypatch.setattr(bench, "bench_tta_scheduler", lambda: (_ for _ in ())
+                        .throw(RuntimeError("tta probe broke")))
+    monkeypatch.setattr(bench.sys, "argv", ["bench.py"])
+    with pytest.raises(RuntimeError, match="tta probe broke"):
+        bench.main()
+    assert capsys.readouterr().out.strip() == ""
 
 
 def test_bench_aug_full19_policy_covers_every_op():

@@ -197,38 +197,6 @@ def test_tta_batched_census_one_executable_across_rounds():
                           for i in range(4)])
         step(variables["params"], {}, images, labels, mask, policies, keys)
     assert executable_census(step) == 1
-    # the trace-event fallback agrees with the cache probe
-    assert step._faa_trace_count() == 1
-
-
-# ------------------------------------------------------------ census
-
-def test_executable_census_fallbacks(monkeypatch):
-    from fast_autoaugment_tpu.search import census
-
-    warnings = []
-    monkeypatch.setattr(census.logger, "warning",
-                        lambda *a, **k: warnings.append(a))
-
-    class CacheOnly:
-        def _cache_size(self):
-            return 2
-
-    assert census.executable_census(CacheOnly()) == 2
-    assert not warnings
-
-    class TraceOnly:
-        def _faa_trace_count(self):
-            return 3
-
-    assert census.executable_census(TraceOnly()) == 3
-    assert len(warnings) == 1  # loud: private probe gone
-
-    class Neither:
-        pass
-
-    assert census.executable_census(Neither()) is None
-    assert len(warnings) == 2  # loud: census unavailable, never silent
 
 
 # ---------------------------------------------------- driver / CLI

@@ -115,7 +115,8 @@ def test_warm_start_topup_driver_contract(tmp_path):
 # ------------------------------- fleet-routed re-search (ISSUE 15 sat)
 
 
-def test_research_through_fleet_learner_actor_byte_identical(tmp_path):
+def test_research_through_fleet_learner_actor_byte_identical(tmp_path,
+                                                             spawn_logged):
     """The PR-14 REMAINING item, measured: the control loop's
     warm-started re-search pointed at a REAL PR-13 learner+actor fleet
     launch (``search_cli --search-role``) produces artifacts
@@ -125,7 +126,6 @@ def test_research_through_fleet_learner_actor_byte_identical(tmp_path):
     from fast_autoaugment_tpu.control.research import seed_research_dir
 
     tmp = str(tmp_path)
-    cc = os.path.join(tmp, "cc")
     conf_yaml = os.path.join(tmp, "conf.yaml")
     with open(conf_yaml, "w") as fh:
         fh.write(CONF_YAML)
@@ -136,10 +136,11 @@ def test_research_through_fleet_learner_actor_byte_identical(tmp_path):
         "--until", "2", "--fold-quality-floor", "off",
         "--audit-floor", "0", "--async-pipeline", "on",
         "--pipeline-actors", "2", "--pipeline-queue-depth", "2",
-        "--seed", "0", "--compile-cache", cc]
+        "--seed", "0"]
     cli = [sys.executable, "-m",
            "fast_autoaugment_tpu.launch.search_cli"]
-    env = dict(os.environ, JAX_PLATFORMS="cpu", FAA_COMPILE_CACHE=cc)
+    # every subprocess shares the session's compile cache (conftest.py)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("FAA_FAULT", None)
 
     # ---- the base search whose log both re-searches warm-start from
@@ -165,18 +166,16 @@ def test_research_through_fleet_learner_actor_byte_identical(tmp_path):
     fleet_flags = flags + ["--save-dir", out_b, "--topup-trials", "2",
                            "--fleet-transport", tr, "--lease-ttl", "30"]
     t0 = time.monotonic()
-    learner = subprocess.Popen(
+    learner = spawn_logged(
         cli + fleet_flags + ["--search-role", "learner",
                              "--host-id", "0"],
-        env=dict(env, FAA_HOST_ID="0"), stdout=subprocess.PIPE,
-        stderr=subprocess.STDOUT, text=True)
-    actor = subprocess.Popen(
+        env=dict(env, FAA_HOST_ID="0"), name="learner")
+    actor = spawn_logged(
         cli + fleet_flags + ["--search-role", "actor",
                              "--host-id", "1"],
-        env=dict(env, FAA_HOST_ID="1"), stdout=subprocess.PIPE,
-        stderr=subprocess.STDOUT, text=True)
-    out_l = learner.communicate(timeout=900)[0]
-    out_ac = actor.communicate(timeout=300)[0]
+        env=dict(env, FAA_HOST_ID="1"), name="actor")
+    out_l = spawn_logged.finish(learner, 900)
+    out_ac = spawn_logged.finish(actor, 300)
     fleet_wall = time.monotonic() - t0
     assert learner.returncode == 0, out_l[-3000:]
     assert actor.returncode == 0, out_ac[-3000:]
@@ -255,23 +254,19 @@ def test_drift_detect_research_canary_promote_drill(tmp_path):
     tmp = str(tmp_path)
     tel_dir = os.path.join(tmp, "telemetry")
     port_dir = os.path.join(tmp, "replicas")
-    cc_dir = os.path.join(tmp, "compile-cache")
     base_dir = os.path.join(tmp, "base_search")
     conf_yaml = os.path.join(tmp, "conf.yaml")
     with open(conf_yaml, "w") as fh:
         fh.write(CONF_YAML)
 
     # ---- the one-shot search whose policy the fleet serves ----------
+    # (every subprocess below is warmed by the session's shared
+    # compile cache — conftest.py)
     conf = _tiny_conf()
-    os.environ["FAA_COMPILE_CACHE"] = cc_dir  # warm every subprocess
-    try:
-        search_policies(conf, tmp, base_dir, cv_num=1, cv_ratio=0.4,
-                        num_policy=1, num_op=1, num_search=4, num_top=1,
-                        trial_batch=2, async_pipeline="on",
-                        fold_quality_floor=None, seed=0,
-                        compile_cache=cc_dir)
-    finally:
-        os.environ.pop("FAA_COMPILE_CACHE", None)
+    search_policies(conf, tmp, base_dir, cv_num=1, cv_ratio=0.4,
+                    num_policy=1, num_op=1, num_search=4, num_top=1,
+                    trial_batch=2, async_pipeline="on",
+                    fold_quality_floor=None, seed=0)
     baseline_policy = os.path.join(base_dir, "final_policy.json")
     baseline_digest = policy_file_digest(baseline_policy)
 
@@ -281,7 +276,6 @@ def test_drift_detect_research_canary_promote_drill(tmp_path):
     stop = threading.Event()
     try:
         env = dict(os.environ, JAX_PLATFORMS="cpu",
-                   FAA_COMPILE_CACHE=cc_dir,
                    # the seeded drill fault: every replica's input
                    # stream shifts from its 12th coalesced dispatch on
                    FAA_FAULT="drift@dispatch=12,shift=60")
@@ -298,7 +292,6 @@ def test_drift_detect_research_canary_promote_drill(tmp_path):
                 # may not change dispatch mode (serving contract)
                 "--dispatch", "exact",
                 "--traffic-stats", "--telemetry", tel_dir,
-                "--compile-cache", cc_dir,
                 "--port", "0", "--port-dir", port_dir,
                 "--host-tag", f"replica{i}",
             ], env=env_i, cwd=_REPO))
@@ -338,7 +331,7 @@ def test_drift_detect_research_canary_promote_drill(tmp_path):
             f" --num-fold 1 --num-search 4 --topup-trials 2"
             f" --num-policy 1 --num-op 1 --num-top 2 --trial-batch 2"
             f" --until 2 --fold-quality-floor off --audit-floor 0"
-            f" --async-pipeline on --seed 0 --compile-cache {cc_dir}")
+            f" --async-pipeline on --seed 0")
         stats_file = os.path.join(tmp, "control_stats.json")
         ctl_env = dict(env)
         ctl_env.pop("FAA_FAULT", None)

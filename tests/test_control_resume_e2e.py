@@ -68,7 +68,6 @@ def test_controller_sigkilled_mid_canary_resumes_to_promote(tmp_path):
     tmp = str(tmp_path)
     tel_dir = os.path.join(tmp, "telemetry")
     port_dir = os.path.join(tmp, "replicas")
-    cc_dir = os.path.join(tmp, "compile-cache")
     baseline_policy = os.path.join(tmp, "baseline.json")
     candidate_policy = os.path.join(tmp, "candidate.json")
     with open(baseline_policy, "w") as fh:
@@ -97,7 +96,6 @@ def test_controller_sigkilled_mid_canary_resumes_to_promote(tmp_path):
     stop = threading.Event()
     try:
         env = dict(os.environ, JAX_PLATFORMS="cpu",
-                   FAA_COMPILE_CACHE=cc_dir,
                    FAA_FAULT="drift@dispatch=12,shift=60")
         env.pop("FAA_TELEMETRY", None)
         for i in range(3):
@@ -108,7 +106,6 @@ def test_controller_sigkilled_mid_canary_resumes_to_promote(tmp_path):
                 "--shapes", "1,8", "--max-wait-ms", "2",
                 "--dispatch", "exact",
                 "--traffic-stats", "--telemetry", tel_dir,
-                "--compile-cache", cc_dir,
                 "--port", "0", "--port-dir", port_dir,
                 "--host-tag", f"replica{i}",
             ], env=dict(env, FAA_HOST_ID=str(i)), cwd=_REPO))

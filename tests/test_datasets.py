@@ -258,6 +258,73 @@ def test_prefetch_early_abandon_releases_worker(monkeypatch):
     assert not any(t.is_alive() for t in spawned), "prefetch worker leaked"
 
 
+_THREADED_FEED_CHILD = r"""
+import json, os, threading
+import jax, jax.numpy as jnp, numpy as np
+from fast_autoaugment_tpu.data import pipeline
+from fast_autoaugment_tpu.data.datasets import ArrayDataset
+from fast_autoaugment_tpu.models import get_model
+from fast_autoaugment_tpu.parallel.mesh import make_mesh
+from fast_autoaugment_tpu.train.steps import make_eval_step
+from fast_autoaugment_tpu.train.trainer import _run_eval
+
+assert len(jax.devices()) == 1, jax.devices()
+mesh = make_mesh()
+model = get_model({"type": "wresnet10_1"}, 10)
+variables = model.init(jax.random.PRNGKey(0), jnp.zeros((2, 8, 8, 3)),
+                       train=False)
+eval_step = make_eval_step(model, num_classes=10)
+rng = np.random.default_rng(0)
+ds = ArrayDataset(rng.integers(0, 256, (100, 8, 8, 3), dtype=np.uint8),
+                  rng.integers(0, 10, (100,), dtype=np.int32), 10)
+
+def run():
+    return _run_eval(eval_step, variables["params"],
+                     variables.get("batch_stats", {}),
+                     pipeline.eval_batches(ds, None, 4, pad_multiple=1), mesh)
+
+workers = []
+real_thread = threading.Thread
+class Spy(real_thread):
+    def __init__(self, *a, **k):
+        super().__init__(*a, **k)
+        workers.append(self)
+pipeline.threading.Thread = Spy
+threaded = run()
+n_workers = len(workers)
+os.environ["FAA_PREFETCH_SYNC"] = "1"
+inline = run()
+print(json.dumps({"threaded": threaded, "inline": inline,
+                  "workers": n_workers,
+                  "inline_workers": len(workers) - n_workers}))
+"""
+
+
+def test_threaded_prefetch_device_put_on_one_cpu_device():
+    """The DEFAULT feed — prefetch's worker thread running the
+    device_put transform while the consumer dispatches — on a single
+    device, the case the chip has (the suite itself runs the sync feed:
+    tests/conftest.py).  `_run_eval` through the threaded feed must
+    match the inline feed exactly, over 25 batches, without aborting.
+    Runs in a child so the 1-device backend is real and a crash of the
+    client cannot take pytest with it."""
+    import json
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH=repo, JAX_PLATFORMS="cpu")
+    env.pop("FAA_PREFETCH_SYNC", None)
+    env.pop("XLA_FLAGS", None)  # one CPU device, not the 8-device mesh
+    r = subprocess.run([sys.executable, "-c", _THREADED_FEED_CHILD], env=env,
+                       capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0, r.stderr[-2000:]
+    rec = json.loads(r.stdout.strip().splitlines()[-1])
+    assert rec["workers"] == 1 and rec["inline_workers"] == 0
+    assert rec["threaded"]["num"] == 100.0
+    assert rec["threaded"] == rec["inline"]
+
+
 def test_synthetic_shapes_difficulty_knobs():
     """The render knobs grade task difficulty: higher noise / lower glyph
     contrast measurably corrupts the clean image."""

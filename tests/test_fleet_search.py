@@ -417,25 +417,29 @@ def test_env_passthrough_pin_includes_fleet_search_vars(tmp_path,
                                                        monkeypatch):
     """The satellite pin: FAA_PIPELINE_TRACE and the fleet-search
     transport env ride the default passthrough to every host launch
-    AND retry, exactly like FAA_COMPILE_CACHE/FAA_TELEMETRY."""
-    for var in ("FAA_PIPELINE_TRACE", "FAA_SEARCH_ROLE",
-                "FAA_FLEET_TRANSPORT", "FAA_COMPILE_CACHE",
-                "FAA_TELEMETRY"):
-        assert var in fleet_mod.DEFAULT_ENV_PASSTHROUGH
+    AND retry, exactly like FAA_TELEMETRY and the compile cache's
+    placement — which is JAX's own variable, not a private handoff."""
+    assert fleet_mod.DEFAULT_ENV_PASSTHROUGH == (
+        "JAX_PLATFORMS", "JAX_COMPILATION_CACHE_DIR", "FAA_TELEMETRY",
+        "FAA_PIPELINE_TRACE", "FAA_SEARCH_ROLE", "FAA_FLEET_TRANSPORT")
     log = tmp_path / "env.log"
     monkeypatch.setenv("FAA_PIPELINE_TRACE", "1")
     monkeypatch.setenv("FAA_FLEET_TRANSPORT", "/shared/tr")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/shared/xla")
     monkeypatch.setattr(
         fleet_mod, "_remote_argv",
-        lambda host, wire: ["bash", "-c", wire])
+        lambda host, wire: ["env", "-u", "JAX_COMPILATION_CACHE_DIR",
+                            "bash", "-c", wire])
     code = fleet_mod.launch_fleet(
         ["a"],
         ["sh", "-c",
-         f'echo "$FAA_PIPELINE_TRACE $FAA_FLEET_TRANSPORT" >> {log}; '
-         "exit 1"],
+         f'echo "$FAA_PIPELINE_TRACE $FAA_FLEET_TRANSPORT '
+         f'$JAX_COMPILATION_CACHE_DIR" >> {log}; exit 1'],
         "x:1", host_retries=1, retry_backoff=0.01, rank_args=False)
     assert code == 1
-    assert log.read_text().splitlines() == ["1 /shared/tr"] * 2
+    # launch AND retry: the wire command itself carries the variables
+    # (the "remote" shell starts without the cache variable)
+    assert log.read_text().splitlines() == ["1 /shared/tr /shared/xla"] * 2
 
 
 def test_telemetry_round_event_type_is_in_taxonomy():
@@ -574,7 +578,8 @@ _CONF_YAML = (
 
 
 @pytest.mark.slow
-def test_fleet_search_e2e_bit_identical_through_actor_sigkill(tmp_path):
+def test_fleet_search_e2e_bit_identical_through_actor_sigkill(tmp_path,
+                                                              spawn_logged):
     """THE acceptance drill: a 3-process fleet (1 learner+trainer, 2
     actor hosts) over a shared transport + compile cache produces
     search_trials.json and final_policy.json BYTE-IDENTICAL to the
@@ -584,14 +589,13 @@ def test_fleet_search_e2e_bit_identical_through_actor_sigkill(tmp_path):
     tmp = str(tmp_path)
     conf = tmp_path / "conf.yaml"
     conf.write_text(_CONF_YAML)
-    cache = f"{tmp}/cc"
     base = [sys.executable, "-m",
             "fast_autoaugment_tpu.launch.search_cli",
             "-c", str(conf), "--dataroot", tmp,
             "--num-fold", "2", "--num-search", "4", "--num-policy", "1",
             "--num-op", "1", "--num-top", "2", "--trial-batch", "2",
             "--until", "2", "--fold-quality-floor", "off",
-            "--seed", "0", "--compile-cache", cache,
+            "--seed", "0",
             "--async-pipeline", "on", "--pipeline-actors", "2",
             "--pipeline-queue-depth", "2"]
     env = dict(os.environ, JAX_PLATFORMS="cpu")
@@ -606,22 +610,26 @@ def test_fleet_search_e2e_bit_identical_through_actor_sigkill(tmp_path):
     tr, save = f"{tmp}/transport", f"{tmp}/fleet"
     fleet_base = base + ["--save-dir", save, "--fleet-transport", tr,
                          "--lease-ttl", "6"]
-    learner = subprocess.Popen(
+    learner = spawn_logged(
         fleet_base + ["--search-role", "learner", "--host-id", "0"],
-        env=dict(env, FAA_HOST_ID="0"), stdout=subprocess.PIPE,
-        stderr=subprocess.STDOUT, text=True)
-    doomed = subprocess.Popen(
+        env=dict(env, FAA_HOST_ID="0"), name="learner")
+    # trial=1 + a head start, as in the fsfault drills: the doomed actor
+    # dies on the FIRST round it evaluates (any round covers a trial
+    # index >= 1) and reliably wins a claim before the survivor exists —
+    # with every process warm-started from the shared compile cache, two
+    # actors launched together race evenly and the doomed one could
+    # finish having only drawn rounds below its kill index
+    doomed = spawn_logged(
         fleet_base + ["--search-role", "actor", "--host-id", "1"],
         env=dict(env, FAA_HOST_ID="1",
-                 FAA_FAULT="sigkill_trial@trial=2"),
-        stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True)
-    survivor = subprocess.Popen(
+                 FAA_FAULT="sigkill_trial@trial=1"), name="doomed")
+    time.sleep(5.0)
+    survivor = spawn_logged(
         fleet_base + ["--search-role", "actor", "--host-id", "2"],
-        env=dict(env, FAA_HOST_ID="2"), stdout=subprocess.PIPE,
-        stderr=subprocess.STDOUT, text=True)
-    out_l = learner.communicate(timeout=900)[0]
-    out_d = doomed.communicate(timeout=120)[0]
-    out_s = survivor.communicate(timeout=300)[0]
+        env=dict(env, FAA_HOST_ID="2"), name="survivor")
+    out_l = spawn_logged.finish(learner, 900)
+    out_d = spawn_logged.finish(doomed, 120)
+    out_s = spawn_logged.finish(survivor, 300)
     assert learner.returncode == 0, out_l[-3000:]
     assert survivor.returncode == 0, out_s[-3000:]
     assert doomed.returncode == -9, (doomed.returncode, out_d[-1500:])

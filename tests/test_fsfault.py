@@ -376,20 +376,21 @@ _CONF_YAML = (
     "  nesterov: true\n")
 
 
-def _fleet_cmd(conf, tmp, cache):
+def _fleet_cmd(conf, tmp):
     return [sys.executable, "-m",
             "fast_autoaugment_tpu.launch.search_cli",
             "-c", str(conf), "--dataroot", tmp,
             "--num-fold", "2", "--num-search", "4", "--num-policy", "1",
             "--num-op", "1", "--num-top", "2", "--trial-batch", "2",
             "--until", "2", "--fold-quality-floor", "off",
-            "--seed", "0", "--compile-cache", cache,
+            "--seed", "0",
             "--async-pipeline", "on", "--pipeline-actors", "2",
             "--pipeline-queue-depth", "2"]
 
 
 @pytest.mark.slow
-def test_fleet_search_byte_identical_under_lag_skew_eio(tmp_path):
+def test_fleet_search_byte_identical_under_lag_skew_eio(tmp_path,
+                                                        spawn_logged):
     """THE ISSUE-15 acceptance drill: a 3-process fleet search under
     ``FAA_FSFAULT=lag@dir=work,secs=2;skew@host=1,offset=45;
     eio@p=0.05,seed=7`` — publish->claim visibility lag, a +45s wall
@@ -402,8 +403,7 @@ def test_fleet_search_byte_identical_under_lag_skew_eio(tmp_path):
     tmp = str(tmp_path)
     conf = tmp_path / "conf.yaml"
     conf.write_text(_CONF_YAML)
-    cache = f"{tmp}/cc"
-    base = _fleet_cmd(conf, tmp, cache)
+    base = _fleet_cmd(conf, tmp)
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("FAA_FAULT", None)
     env.pop("FAA_FSFAULT", None)
@@ -418,26 +418,23 @@ def test_fleet_search_byte_identical_under_lag_skew_eio(tmp_path):
     tr, save = f"{tmp}/transport", f"{tmp}/fleet"
     fleet_base = base + ["--save-dir", save, "--fleet-transport", tr,
                          "--lease-ttl", "6"]
-    learner = subprocess.Popen(
+    learner = spawn_logged(
         fleet_base + ["--search-role", "learner", "--host-id", "0"],
-        env=dict(env, FAA_HOST_ID="0", FAA_FSFAULT=fsf),
-        stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True)
+        env=dict(env, FAA_HOST_ID="0", FAA_FSFAULT=fsf), name="learner")
     # trial=1: the doomed actor dies on the FIRST round it evaluates
     # (any round covers a trial index >= 1), and it launches ahead of
     # the survivor so it reliably wins a claim race before dying
-    doomed = subprocess.Popen(
+    doomed = spawn_logged(
         fleet_base + ["--search-role", "actor", "--host-id", "1"],
         env=dict(env, FAA_HOST_ID="1", FAA_FSFAULT=fsf,
-                 FAA_FAULT="sigkill_trial@trial=1"),
-        stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True)
+                 FAA_FAULT="sigkill_trial@trial=1"), name="doomed")
     time.sleep(5.0)
-    survivor = subprocess.Popen(
+    survivor = spawn_logged(
         fleet_base + ["--search-role", "actor", "--host-id", "2"],
-        env=dict(env, FAA_HOST_ID="2", FAA_FSFAULT=fsf),
-        stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True)
-    out_l = learner.communicate(timeout=900)[0]
-    out_d = doomed.communicate(timeout=300)[0]
-    out_s = survivor.communicate(timeout=300)[0]
+        env=dict(env, FAA_HOST_ID="2", FAA_FSFAULT=fsf), name="survivor")
+    out_l = spawn_logged.finish(learner, 900)
+    out_d = spawn_logged.finish(doomed, 300)
+    out_s = spawn_logged.finish(survivor, 300)
     assert learner.returncode == 0, out_l[-3000:]
     assert survivor.returncode == 0, out_s[-3000:]
     assert doomed.returncode == -9, (doomed.returncode, out_d[-1500:])
@@ -459,7 +456,7 @@ def test_fleet_search_byte_identical_under_lag_skew_eio(tmp_path):
 
 
 @pytest.mark.slow
-def test_chaos_composed_fault_smoke(tmp_path):
+def test_chaos_composed_fault_smoke(tmp_path, spawn_logged):
     """``make chaos``: FAA_FAULT (sigkill) layered with FAA_FSFAULT
     (lag + eio) over a bounded fleet drill — the composed-fault smoke.
     Asserts completion and artifact integrity (the byte-identity
@@ -470,9 +467,8 @@ def test_chaos_composed_fault_smoke(tmp_path):
     tmp = str(tmp_path)
     conf = tmp_path / "conf.yaml"
     conf.write_text(_CONF_YAML)
-    cache = f"{tmp}/cc"
     tel = f"{tmp}/tel"
-    base = _fleet_cmd(conf, tmp, cache)
+    base = _fleet_cmd(conf, tmp)
     env = dict(os.environ, JAX_PLATFORMS="cpu", FAA_TELEMETRY=tel)
     env.pop("FAA_FAULT", None)
     env.pop("FAA_FSFAULT", None)
@@ -481,23 +477,20 @@ def test_chaos_composed_fault_smoke(tmp_path):
     fleet_base = base + ["--save-dir", save, "--fleet-transport", tr,
                          "--lease-ttl", "5"]
     t0 = time.monotonic()
-    learner = subprocess.Popen(
+    learner = spawn_logged(
         fleet_base + ["--search-role", "learner", "--host-id", "0"],
-        env=dict(env, FAA_HOST_ID="0", FAA_FSFAULT=fsf),
-        stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True)
-    doomed = subprocess.Popen(
+        env=dict(env, FAA_HOST_ID="0", FAA_FSFAULT=fsf), name="learner")
+    doomed = spawn_logged(
         fleet_base + ["--search-role", "actor", "--host-id", "1"],
         env=dict(env, FAA_HOST_ID="1", FAA_FSFAULT=fsf,
-                 FAA_FAULT="sigkill_trial@trial=1"),
-        stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True)
+                 FAA_FAULT="sigkill_trial@trial=1"), name="doomed")
     time.sleep(5.0)  # the doomed actor claims first, then dies
-    survivor = subprocess.Popen(
+    survivor = spawn_logged(
         fleet_base + ["--search-role", "actor", "--host-id", "2"],
-        env=dict(env, FAA_HOST_ID="2", FAA_FSFAULT=fsf),
-        stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True)
-    out_l = learner.communicate(timeout=900)[0]
-    doomed.communicate(timeout=300)
-    out_s = survivor.communicate(timeout=300)[0]
+        env=dict(env, FAA_HOST_ID="2", FAA_FSFAULT=fsf), name="survivor")
+    out_l = spawn_logged.finish(learner, 900)
+    spawn_logged.finish(doomed, 300)
+    out_s = spawn_logged.finish(survivor, 300)
     assert learner.returncode == 0, out_l[-3000:]
     assert survivor.returncode == 0, out_s[-3000:]
     assert doomed.returncode == -9

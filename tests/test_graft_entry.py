@@ -1,10 +1,10 @@
 """The driver's `dryrun_multichip` must pass without real chips.
 
-Round-1 failure mode: the dryrun inherited the ambient single-chip TPU
-environment and hung in backend init (MULTICHIP_r01.json rc=124).  The
-entry point now unconditionally re-execs into a forced-CPU subprocess;
-this test runs it exactly the way the driver does — ambient environment,
-no special setup — and must finish well inside the driver's timeout.
+The entry point unconditionally re-execs into a forced-CPU subprocess
+with n virtual devices (the device count only takes effect before the
+backend initialises); this test runs it exactly the way the driver
+does — ambient environment, no special setup — and must finish well
+inside the driver's timeout.
 """
 
 import os

@@ -457,7 +457,7 @@ def test_search_random_control_arm(tmp_path):
     result = search_policies(conf, dataroot=str(tmp_path), save_dir=save,
                              **kwargs)
     # ledger provenance: a CPU run must say so next to its device-secs
-    assert result["backend"] == "cpu"
+    assert result["platform"] == "cpu"
     assert result["device_count"] >= 1
     assert result["device_secs_phase2"] == result["tpu_secs_phase2"]
     rand = result["random_policy_set"]

@@ -149,13 +149,12 @@ def test_inflight_batch_survives_next_stage(plain, donated):
     np.testing.assert_array_equal(np.asarray(h_b.materialize()), want_b)
 
 
-def test_cpu_donation_warning_is_filtered():
-    # on backends without donation support, lowering warns-and-ignores
-    # per executable; the compile seam (core/compilecache.aot_compile)
-    # filters that spray — compiling a donating applier and serving
-    # with it must not surface a single donation warning.  The seam's
-    # filter is installed INSIDE aot_compile's catch_warnings block, so
-    # it wins over this test's "always" filter.
+def test_donating_applier_compiles_without_a_donation_warning():
+    # the installed backend implements donation (a donated input is
+    # deleted by the dispatch), so the compile seam filters nothing:
+    # compiling a donating applier and serving with it must not surface
+    # a "donation is not implemented" warning — if one appears, the
+    # backend stopped honoring the aliasing and donate=True is a no-op.
     with warnings.catch_warnings(record=True) as caught:
         warnings.simplefilter("always")
         app = AotPolicyApplier(SINGLE_SUB, image=IMG, shapes=(2,),

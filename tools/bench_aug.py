@@ -95,11 +95,10 @@ def main(argv=None):
 
     # loadavg/process provenance, shared with bench.py: a busy-host
     # capture must be visible in the output itself, and
-    # FAA_BENCH_REQUIRE_QUIET=1 refuses instead (VERDICT r5 weak 1)
+    # FAA_BENCH_REQUIRE_QUIET=1 refuses instead
     import json
 
     from bench import (
-        arm_compile_cache_from_env,
         host_contention_stamp,
         refuse_or_flag_contention,
         telemetry_stamp,
@@ -107,7 +106,9 @@ def main(argv=None):
 
     contention = refuse_or_flag_contention(host_contention_stamp())
     print(f"contention: {json.dumps(contention)}")
-    arm_compile_cache_from_env()
+    from fast_autoaugment_tpu.core.compilecache import configure_compile_cache
+
+    configure_compile_cache()
 
     import jax
     import jax.numpy as jnp
@@ -115,6 +116,7 @@ def main(argv=None):
 
     from fast_autoaugment_tpu.ops import augment as A
     from fast_autoaugment_tpu.ops.preprocess import cifar_train_batch
+    from fast_autoaugment_tpu.parallel.mesh import device_stamp
     from fast_autoaugment_tpu.policies.archive import load_policy, policy_to_tensor
 
     images = jnp.asarray(
@@ -185,7 +187,7 @@ def main(argv=None):
     print(json.dumps({
         "metric": "aug_images_per_sec",
         "unit": "images/sec",
-        "backend": jax.devices()[0].platform,
+        **device_stamp(),
         "batch": args.batch,
         "size": args.size,
         "steps": args.steps,

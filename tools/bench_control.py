@@ -26,6 +26,11 @@ verdict, the unified telemetry stamp and the ``single_core_caveat`` —
 every process here shares one core, so the goodput ratio measures
 PLUMBING overhead, not fleet behavior at scale.
 
+CPU-only: every replica and the controller are spawned with ``JAX_PLATFORMS=cpu`` — three replicas at once
+cannot share one chip (a chip has one owner process), so this is a
+host-side drill and its JSON says ``"platform": "cpu"``; it needs an
+in-process or device-pinned mode before it can measure the device.
+
     python tools/bench_control.py [--pairs 2] [--seconds-per-arm 12]
 """
 
@@ -128,7 +133,7 @@ def _drive_traffic(ports, seconds, imgs_per_request, image,
     return rows, time.monotonic() - t0
 
 
-def run_round(arm: str, args, compile_cache: str) -> dict:
+def run_round(arm: str, args) -> dict:
     from bench_router import wait_port_record, wait_ready
 
     procs: list[subprocess.Popen] = []
@@ -141,8 +146,7 @@ def run_round(arm: str, args, compile_cache: str) -> dict:
             json.dump(POLICY_A, fh)
         with open(path_b, "w") as fh:
             json.dump(POLICY_B, fh)
-        env = dict(os.environ, JAX_PLATFORMS="cpu",
-                   FAA_COMPILE_CACHE=compile_cache)
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
         env.pop("FAA_TELEMETRY", None)
         if arm == "rollover":
             env["FAA_FAULT"] = (f"drift@dispatch={DRIFT_DISPATCH},"
@@ -159,7 +163,6 @@ def run_round(arm: str, args, compile_cache: str) -> dict:
                     "--max-wait-ms", "2",
                     "--traffic-stats",
                     "--telemetry", tel_dir,
-                    "--compile-cache", compile_cache,
                     "--port", "0", "--port-dir", port_dir,
                     "--host-tag", f"replica{i}",
                 ], env=env_i, cwd=_REPO))
@@ -294,12 +297,11 @@ def main(argv=None) -> int:
     contention = refuse_or_flag_contention(host_contention_stamp())
 
     rounds = []
-    with tempfile.TemporaryDirectory(prefix="bench_control_cc_") as cc:
-        for i in range(max(1, args.pairs)):
-            order = (("steady", "rollover") if i % 2 == 0
-                     else ("rollover", "steady"))
-            for arm in order:
-                rounds.append(run_round(arm, args, cc))
+    for i in range(max(1, args.pairs)):
+        order = (("steady", "rollover") if i % 2 == 0
+                 else ("rollover", "steady"))
+        for arm in order:
+            rounds.append(run_round(arm, args))
 
     meds = {}
     for arm in ("steady", "rollover"):
@@ -317,6 +319,7 @@ def main(argv=None) -> int:
     promoted = all(r.get("promoted") for r in roll)
     out = {
         "metric": "control_detect_to_promote",
+        "platform": "cpu",  # every replica is pinned to it
         "replicas": args.replicas,
         "pairs": args.pairs,
         "seconds_per_arm": args.seconds_per_arm,

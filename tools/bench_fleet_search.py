@@ -33,6 +33,12 @@ The JSON line reports:
 Honors ``FAA_BENCH_REQUIRE_QUIET=1`` (refuses on a contended host,
 exit 3).
 
+CPU-only: the learner and the actor "hosts" are processes on this
+machine pinned to ``JAX_PLATFORMS=cpu`` — three processes cannot share
+one chip (a chip has one owner process), so this is a host-side drill
+and its JSON says ``"platform": "cpu"``; it needs device-pinned hosts
+before it can measure the device.
+
     python tools/bench_fleet_search.py --num-search 8 --actor-hosts 2
     make bench-fleet-search
 """
@@ -68,7 +74,7 @@ def _pct(xs, q):
         if xs else None
 
 
-def _base_cmd(conf, dataroot, args, cache):
+def _base_cmd(conf, dataroot, args):
     return [
         sys.executable, "-m", "fast_autoaugment_tpu.launch.search_cli",
         "-c", conf, "--dataroot", dataroot,
@@ -78,7 +84,7 @@ def _base_cmd(conf, dataroot, args, cache):
         "--num-op", str(args.num_op), "--num-top", "2",
         "--trial-batch", str(args.trial_batch),
         "--until", "2", "--fold-quality-floor", "off",
-        "--seed", str(args.seed), "--compile-cache", cache,
+        "--seed", str(args.seed),
         "--async-pipeline", "on",
         "--pipeline-actors", str(args.actor_hosts),
         "--pipeline-queue-depth", str(args.queue_depth),
@@ -142,10 +148,8 @@ def run_fleet_search_bench(args, workdir: str) -> dict:
     conf = os.path.join(workdir, "conf.yaml")
     with open(conf, "w") as fh:
         fh.write(_CONF_YAML)
-    cache = os.path.join(workdir, "compile_cache")
-    base = _base_cmd(conf, workdir, args, cache)
-    env = dict(os.environ, JAX_PLATFORMS=os.environ.get(
-        "JAX_PLATFORMS", "cpu"))
+    base = _base_cmd(conf, workdir, args)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("FAA_FAULT", None)
 
     # ---- arm 1: single host (threads); also warms the compile cache
@@ -168,30 +172,34 @@ def run_fleet_search_bench(args, workdir: str) -> dict:
                          "--telemetry", transport,
                          "--lease-ttl", str(args.lease_ttl)]
     t0 = time.time()
-    procs = [subprocess.Popen(
-        fleet_base + ["--search-role", "learner", "--host-id", "0"],
-        env=dict(env, FAA_HOST_ID="0"), stdout=subprocess.PIPE,
-        stderr=subprocess.STDOUT, text=True, cwd=_REPO)]
-    for i in range(1, args.actor_hosts + 1):
-        procs.append(subprocess.Popen(
-            fleet_base + ["--search-role", "actor",
-                          "--host-id", str(i)],
-            env=dict(env, FAA_HOST_ID=str(i)), stdout=subprocess.PIPE,
-            stderr=subprocess.STDOUT, text=True, cwd=_REPO))
-    outs = []
+    # each host logs to a FILE: hosts on PIPEs read one after the other
+    # deadlock once one fills its pipe while we block on another's
+    roles = ["learner"] + ["actor"] * args.actor_hosts
+    logs = [os.path.join(workdir, f"host{i}.log") for i in range(len(roles))]
+    procs = []
     try:
+        for i, role in enumerate(roles):
+            with open(logs[i], "w") as log:
+                procs.append(subprocess.Popen(
+                    fleet_base + ["--search-role", role,
+                                  "--host-id", str(i)],
+                    env=dict(env, FAA_HOST_ID=str(i)), stdout=log,
+                    stderr=subprocess.STDOUT, cwd=_REPO))
         for p in procs:
-            outs.append(p.communicate(timeout=args.timeout)[0])
+            p.wait(timeout=args.timeout)
     finally:
         for p in procs:
             if p.poll() is None:
                 p.kill()
     fleet_wall = time.time() - t0
     if any(p.returncode for p in procs):
+        tails = []
+        for path in logs:
+            with open(path) as fh:
+                tails.append(fh.read()[-1500:])
         raise RuntimeError(
             "fleet arm failed rcs="
-            + str([p.returncode for p in procs]) + ":\n"
-            + "\n".join(o[-1500:] for o in outs))
+            + str([p.returncode for p in procs]) + ":\n" + "\n".join(tails))
 
     # ---- byte-identity: the fleet determinism acceptance
     trials_match = (
@@ -219,6 +227,9 @@ def run_fleet_search_bench(args, workdir: str) -> dict:
                                          "search_result.json")))
     return {
         "bench": "fleet_search",
+        # the search children stamp what they ran on; both arms are
+        # pinned to the CPU (module docstring)
+        "platform": result.get("platform"),
         "actor_hosts": args.actor_hosts,
         "num_fold": args.num_fold,
         "num_search": args.num_search,

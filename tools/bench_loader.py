@@ -193,12 +193,11 @@ def main(argv=None):
     p.add_argument("--report", default=None)
     args = p.parse_args(argv)
 
-    # loadavg/process provenance, shared with bench.py (VERDICT r5
-    # weak 1); FAA_BENCH_REQUIRE_QUIET=1 refuses on a busy host
+    # loadavg/process provenance, shared with bench.py;
+    # FAA_BENCH_REQUIRE_QUIET=1 refuses on a busy host
     import json
 
     from bench import (
-        arm_compile_cache_from_env,
         host_contention_stamp,
         refuse_or_flag_contention,
         telemetry_stamp,
@@ -206,7 +205,10 @@ def main(argv=None):
 
     contention = refuse_or_flag_contention(host_contention_stamp())
     print(f"contention: {json.dumps(contention)}")
-    arm_compile_cache_from_env()
+    from fast_autoaugment_tpu.core.compilecache import configure_compile_cache
+    from fast_autoaugment_tpu.parallel.mesh import device_stamp
+
+    configure_compile_cache()
 
     from fast_autoaugment_tpu.data import native_loader
 
@@ -252,6 +254,7 @@ def main(argv=None):
     # unified provenance block (bench.telemetry_stamp): schema_version
     # + contention + compile cache + registry counters in one schema
     gather.update(telemetry_stamp(contention=contention))
+    gather.update(device_stamp())  # bench_gather device_puts batches
     print(json.dumps(gather))
 
     if args.report:

@@ -94,12 +94,10 @@ def run_pipeline_bench(args, workdir: str) -> dict:
     )
 
     conf = _conf(args.batch, 1)
-    cache_dir = os.path.join(workdir, "compile_cache")
     common = dict(
         dataroot=workdir, cv_num=1, cv_ratio=args.cv_ratio,
         num_policy=args.num_policy, num_op=args.num_op,
         num_top=5, trial_batch=args.trial_batch, seed=args.seed,
-        compile_cache=cache_dir,
     )
     devices = jax.device_count()
 
@@ -230,6 +228,9 @@ def main(argv=None):
     # unified provenance block (bench.telemetry_stamp): contention +
     # compile cache + registry counters in the shared schema
     record.update(telemetry_stamp(contention=contention))
+    from fast_autoaugment_tpu.parallel.mesh import device_stamp
+
+    record.update(device_stamp())
     # the overlap headroom the async arm hides: host ask/tell latency
     # at this bench's trial batch (same JSON line, per the bench_tpe
     # citation contract)

@@ -22,6 +22,11 @@ conditions.  The JSON line carries both arms' rps/p50/p99 medians, the
 routed/direct throughput ratio, the router's own topology + affinity
 accounting, and the unified telemetry stamp.
 
+CPU-only: every replica and the router are spawned with ``JAX_PLATFORMS=cpu`` — N replicas at once
+cannot share one chip (a chip has one owner process), so this is a
+host-side drill and its JSON says ``"platform": "cpu"``; it needs an
+in-process or device-pinned mode before it can measure the device.
+
     python tools/bench_router.py [--replicas 3] [--pairs 3]
         [--seconds-per-arm 2] [--image 8] [--shapes 1,8]
 """
@@ -247,6 +252,7 @@ def main(argv=None) -> int:
             topology = json.loads(stats_body)
             out = {
                 "metric": "serve_router_paired_rps",
+                "platform": "cpu",  # every replica is pinned to it
                 "replicas": args.replicas,
                 "pairs": args.pairs,
                 "seconds_per_arm": args.seconds_per_arm,

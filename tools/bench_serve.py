@@ -12,8 +12,8 @@ JSON line reports:
 - ``latency_ms``: p50/p90/p99/max submit-to-scatter per request;
 - ``images_per_sec``: achieved serving throughput over the run;
 - ``aot_compile_sec`` per shape + the unified ``compile_cache`` block
-  (with ``FAA_COMPILE_CACHE`` set, a re-run deserializes the
-  executables — the warm-start story applied to serving);
+  (a re-run deserializes the executables from the persistent cache —
+  the warm-start story applied to serving);
 - ``serve_robustness``: the admission/shed/breaker/reload counters
   (docs/RESILIENCE.md "Serving under overload");
 - the standard contention + shadow-watchdog stamps, plus a per-run
@@ -528,14 +528,15 @@ def main(argv=None) -> int:
     import numpy as np
 
     from fast_autoaugment_tpu.core.compilecache import configure_compile_cache
+    from fast_autoaugment_tpu.parallel.mesh import device_stamp
     from fast_autoaugment_tpu.serve.policy_server import (
         AotPolicyApplier,
         PolicyServer,
     )
 
-    # honor an inherited FAA_COMPILE_CACHE: a second bench run then
-    # deserializes the AOT executables instead of re-lowering them
-    configure_compile_cache(None)
+    # a second bench run deserializes the AOT executables from the
+    # persistent cache instead of re-lowering them
+    configure_compile_cache()
 
     if args.policy:
         from fast_autoaugment_tpu.serve.serve_cli import build_policy_tensor
@@ -571,7 +572,7 @@ def main(argv=None) -> int:
         sweep = run_overload(args, applier, pool)
         out = {
             "metric": "serve_overload_goodput",
-            "backend": jax.devices()[0].platform,
+            **device_stamp(),
             "policy": args.policy or f"synthetic_{args.num_sub}sub",
             "num_sub": int(policy.shape[0]),
             "image": args.image,
@@ -598,7 +599,7 @@ def main(argv=None) -> int:
 
     out = {
         "metric": "serve_policy_latency_ms",
-        "backend": jax.devices()[0].platform,
+        **device_stamp(),
         "policy": args.policy or f"synthetic_{args.num_sub}sub",
         "num_sub": int(policy.shape[0]),
         "image": args.image,

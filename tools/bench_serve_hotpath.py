@@ -29,6 +29,11 @@ wire formats and the four decoded results are compared bitwise — the
 acceptance gate that the zero-copy plane (and the raw format) changes
 no served byte.
 
+CPU-only: both replicas are spawned with ``JAX_PLATFORMS=cpu`` — two live replicas at once
+cannot share one chip (a chip has one owner process), so this is a
+host-side drill and its JSON says ``"platform": "cpu"``; it needs an
+in-process or device-pinned mode before it can measure the device.
+
     python tools/bench_serve_hotpath.py [--pairs 3]
         [--seconds-per-arm 2] [--image 8] [--shapes 1,4]
         [--out BENCH_r09_serve_hotpath.json]
@@ -333,6 +338,7 @@ def main(argv=None) -> int:
                      else None)
             out = {
                 "metric": "serve_hotpath_host_overhead",
+                "platform": "cpu",  # both replicas are pinned to it
                 "pairs": args.pairs,
                 "seconds_per_arm": args.seconds_per_arm,
                 "image": args.image,

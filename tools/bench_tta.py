@@ -39,9 +39,9 @@ def main(argv=None) -> int:
     p.add_argument("--out", default=None)
     args = p.parse_args(argv)
 
-    from bench import arm_compile_cache_from_env
+    from fast_autoaugment_tpu.core.compilecache import configure_compile_cache
 
-    arm_compile_cache_from_env()
+    configure_compile_cache()
 
     import jax
     import jax.numpy as jnp
@@ -52,8 +52,6 @@ def main(argv=None) -> int:
     from fast_autoaugment_tpu.search.tta import make_tta_step
     from fast_autoaugment_tpu.train.steps import create_train_state
 
-    dev = jax.devices()[0]
-    platform = dev.platform
     num_classes = num_class(args.dataset)
     model = get_model({"type": args.model, "dataset": args.dataset},
                       num_classes)
@@ -100,9 +98,10 @@ def main(argv=None) -> int:
     imgs_per_sec = args.batch * args.num_policy * args.calls / steady
     from bench import telemetry_stamp
 
+    from fast_autoaugment_tpu.parallel.mesh import device_stamp
+
     summary = {
-        "backend": platform,
-        "device_kind": getattr(dev, "device_kind", platform),
+        **device_stamp(),
         "model": args.model,
         "batch": args.batch,
         "image": args.image,
