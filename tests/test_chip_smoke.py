@@ -145,9 +145,36 @@ def test_alone_in_a_directory_fails(tmp_path):
     assert "not a checkout" in r.stderr
 
 
-def test_rehearsal_can_never_print_the_passing_line(monkeypatch, capsys):
+def _main_stdout(monkeypatch, capsys, tmp_path, device, *argv):
+    monkeypatch.setattr(chip_smoke, "OUT_DIR", str(tmp_path / "out"))
     monkeypatch.setattr(chip_smoke, "run", lambda rehearse: {
-        "device": {"platform": "cpu", "kind": "cpu", "count": 1}})
-    assert chip_smoke.main(["--rehearse"]) == 0
-    last = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert last["ok"] is False and last["rehearsal"] == "passed"
+        "device": dict(device), "steps": 8, "loss_train": 2.3})
+    assert chip_smoke.main(list(argv)) == 0
+    return [json.loads(ln) for ln
+            in capsys.readouterr().out.strip().splitlines()]
+
+
+def test_rehearsal_can_never_print_the_passing_line(
+        monkeypatch, capsys, tmp_path):
+    device = {"platform": "cpu", "kind": "cpu", "count": 1}
+    summary, last = _main_stdout(
+        monkeypatch, capsys, tmp_path, device, "--rehearse")
+    assert last == {"ok": False, "device": device}
+    assert summary["summary"]["rehearsal"] == "passed"
+
+
+def test_last_line_is_exactly_the_contracts_object(
+        monkeypatch, capsys, tmp_path):
+    """The chip check refuses any last stdout line that is not exactly
+    ``{"ok", "device": {"platform", "kind", "count"}}`` (PR 21 was
+    refused once for carrying the summary there): details go on the
+    line before it and into ``summary.json``."""
+    device = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+    summary, last = _main_stdout(monkeypatch, capsys, tmp_path, device)
+    assert last == {"ok": True, "device": device}
+    assert list(last) == ["ok", "device"]
+    assert list(last["device"]) == ["platform", "kind", "count"]
+    assert isinstance(last["device"]["count"], int)
+    assert summary["summary"]["steps"] == 8
+    with open(tmp_path / "out" / "summary.json") as fh:
+        assert json.load(fh) == summary["summary"]
