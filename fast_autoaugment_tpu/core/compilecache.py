@@ -49,13 +49,16 @@ compile grace window it no longer needs.
 
 from __future__ import annotations
 
+import collections
 import functools
 import os
+import re
 import threading
 import time
+import weakref
 from typing import Any, Callable
 
-from fast_autoaugment_tpu.core import telemetry
+from fast_autoaugment_tpu.core import scopes, telemetry
 from fast_autoaugment_tpu.utils.logging import get_logger
 
 __all__ = [
@@ -68,6 +71,9 @@ __all__ = [
     "compile_cache_stats",
     "cache_dir",
     "process_is_warm",
+    "ScopeMapError",
+    "parse_scope_map",
+    "scope_map",
 ]
 
 logger = get_logger("faa_tpu.compilecache")
@@ -99,6 +105,12 @@ _MISSES = telemetry.registry().counter(
 # {label: {"sec": float, "hit": n, "miss": n, "uncached": n, "none": n}}
 _labels: dict[str, dict] = {}
 _listener_registered = False
+# per-seam-label, the wrapped callables that have made their first call
+# (scope_map re-lowers them): weakly held, but for the newest of a
+# label, which is pinned so that a reader who asks after the trainer
+# has returned still finds the program it ran
+_called: dict[str, "weakref.WeakSet[_SeamWrapped]"] = {}
+_newest: dict[str, "_SeamWrapped"] = {}
 
 
 def _listener(event: str, **_kwargs: Any) -> None:
@@ -202,11 +214,17 @@ class _SeamWrapped:
         self._jitted = jitted
         self._seam_label = label
         self._first_done = False
+        self._first_call_specs: tuple | None = None
         functools.update_wrapper(self, jitted, updated=())
 
     def __call__(self, *args: Any, **kwargs: Any):
         if self._first_done:
             return self._jitted(*args, **kwargs)
+        # before the call: a donated argument is deleted by it
+        self._first_call_specs = _abstract((args, kwargs))
+        with _lock:
+            _called.setdefault(self._seam_label, weakref.WeakSet()).add(self)
+            _newest[self._seam_label] = self
         h0, m0 = _snapshot()
         t0 = time.perf_counter()
         out = self._jitted(*args, **kwargs)
@@ -217,6 +235,26 @@ class _SeamWrapped:
 
     def __getattr__(self, name: str):
         return getattr(self._jitted, name)
+
+
+def _abstract(tree: Any) -> Any:
+    """`tree` with every array leaf replaced by its
+    ``jax.ShapeDtypeStruct``; no array is kept.  Lowering these again
+    must give the module of the first call, so a committed array's spec
+    carries its sharding and an uncommitted one's carries none."""
+    import jax
+    import numpy as np
+
+    def spec(leaf):
+        if isinstance(leaf, jax.Array):
+            return jax.ShapeDtypeStruct(
+                leaf.shape, leaf.dtype, weak_type=leaf.weak_type,
+                sharding=leaf.sharding if leaf.committed else None)
+        if isinstance(leaf, (np.ndarray, np.generic)):
+            return jax.ShapeDtypeStruct(leaf.shape, leaf.dtype)
+        return leaf
+
+    return jax.tree.map(spec, tree)
 
 
 def instrument_jitted(jitted: Callable, *, label: str) -> Callable:
@@ -299,6 +337,133 @@ def compile_cache_stats() -> dict:
         "first_step_secs": first_step,
         "labels": labels,
     }
+
+
+class ScopeMapError(RuntimeError):
+    """No instruction of a compiled module carries a ``faa_`` scope."""
+
+
+# "  ROOT %fusion.5 = f32[8]{0} fusion(...), kind=kLoop, calls=%fused_computation.2, metadata={op_name="..."}"
+_HLO_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = ")
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{$")
+_HLO_MODULE = re.compile(r"^HloModule ([\w.\-]+)")
+_HLO_OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
+_HLO_CALLED = re.compile(
+    r"\b(?:calls|to_apply|body|condition|true_computation|false_computation|"
+    r"branch_computations|called_computations)=(?:\{([^}]*)\}|(%?[\w.\-]+))")
+
+
+def parse_scope_map(hlo_text: str) -> tuple[str, dict[str, str]]:
+    """``(module_name, {instruction_name: op_name})`` from a compiled
+    module's text, over all its computations.
+
+    Two fallbacks name what carries no ``faa_`` scope of its own.  An
+    instruction that calls computations (a fusion, a while loop) takes
+    the ``op_name`` of the commonest scope chain among the instructions
+    it calls, callees before callers.  Then an instruction that is still
+    unnamed takes the ``op_name`` of the instruction that calls its
+    computation, callers before callees: the loop XLA:TPU makes of a
+    batched ``dynamic_slice`` has a named ``while`` and a body without a
+    single ``op_name``.  What neither reaches keeps its own name, which
+    may be empty."""
+    module = ""
+    own: dict[str, str] = {}
+    members: dict[str, list[str]] = {}      # computation -> instructions
+    calls: dict[str, list[str]] = {}        # instruction -> computations
+    computation = None
+    for line in hlo_text.splitlines():
+        if computation is None:
+            found = _HLO_MODULE.match(line) if not module else None
+            if found:
+                module = found.group(1)
+                continue
+            found = _HLO_COMPUTATION.match(line)
+            if found:
+                computation = found.group(1)
+                members[computation] = []
+            continue
+        if line.startswith("}"):
+            computation = None
+            continue
+        found = _HLO_INSTRUCTION.match(line)
+        if not found:
+            continue
+        name = found.group(1)
+        members[computation].append(name)
+        op_name = _HLO_OP_NAME.search(line)
+        own[name] = op_name.group(1) if op_name else ""
+        called = [c.strip().lstrip("%")
+                  for several, one in _HLO_CALLED.findall(line)
+                  for c in (several or one).split(",")]
+        if called:
+            calls[name] = called
+
+    resolved: dict[str, str] = {}
+
+    def resolve(name: str) -> str:
+        if name in resolved:
+            return resolved[name]
+        resolved[name] = own[name]
+        if not scopes.scope_of(own[name]) and name in calls:
+            votes = collections.Counter()
+            speaker: dict[tuple, str] = {}
+            for comp in calls[name]:
+                for callee in members.get(comp, ()):
+                    chain = scopes.scope_of(resolve(callee))
+                    if chain:
+                        votes[chain] += 1
+                        speaker.setdefault(chain, resolved[callee])
+            if votes:
+                resolved[name] = speaker[votes.most_common(1)[0][0]]
+        return resolved[name]
+
+    for name in own:
+        resolve(name)
+    # callers are printed after their callees: walk them first
+    for computation in reversed(members):
+        for name in members[computation]:
+            if name in calls and scopes.scope_of(resolved[name]):
+                for comp in calls[name]:
+                    for callee in members.get(comp, ()):
+                        if not scopes.scope_of(resolved[callee]):
+                            resolved[callee] = resolved[name]
+    return module, resolved
+
+
+def scope_map(label: str) -> dict[str, dict[str, str]]:
+    """``{hlo_module_name: {instruction_name: op_name}}`` of the programs
+    compiled under seam `label` that have made their first call and are
+    still alive (the newest always is): the join between a profiler
+    trace, whose ``XLA Ops`` events are named by HLO instruction, and
+    the named scopes of ``core/scopes.py``.
+
+    On demand only.  Each program is lowered again from the abstract
+    arguments of its first call and compiled: in the process that ran
+    it JAX still holds the executable (half a second for the WRN train
+    steps on a v5e), elsewhere the persistent cache answers with a hit
+    (trace + lower + load) — never a miss, while the specs reproduce the
+    first call; a full compile where the cache is off.  Raises
+    :class:`ScopeMapError` where a module carries no scope at all: the
+    cache's key leaves metadata out, so an executable cached by a
+    checkout from before the scopes comes back on a hit with its old
+    metadata."""
+    with _lock:
+        wrapped = list(_called.get(label, ()))
+    out: dict[str, dict[str, str]] = {}
+    for fn in wrapped:
+        args, kwargs = fn._first_call_specs
+        text = fn._jitted.lower(*args, **kwargs).compile().as_text()
+        module, table = parse_scope_map(text)
+        if not any(scopes.scope_of(op_name) for op_name in table.values()):
+            raise ScopeMapError(
+                f"compile seam {label!r}: no instruction of module "
+                f"{module!r} carries a {scopes.PREFIX!r} scope.  The "
+                f"persistent compile cache ({_dir or 'off'}) answered with "
+                f"an executable cached before the scopes were added (its "
+                f"key leaves metadata out): clear that directory and run "
+                f"again")
+        out.setdefault(module, {}).update(table)
+    return out
 
 
 def _reset_stats_for_tests() -> None:

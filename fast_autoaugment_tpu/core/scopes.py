@@ -1,0 +1,89 @@
+"""The names the step program gives its work on the device.
+
+``jax.named_scope`` writes a name into the ``op_name`` metadata of every
+HLO instruction traced under it; the metadata survives XLA's
+optimisation (on fusion instructions too) and comes back out of
+``compiled.as_text()``, where ``core/compilecache.py::scope_map`` reads
+it.  A scope is compile-time metadata: it costs nothing per step,
+changes no numerics and no compile-cache key.  This module is the one
+table of those names, and the two functions that find them again in an
+``op_name`` such as::
+
+    jit(multi_fn)/vmap(faa_aug_policy)/faa_aug_op_Equalize/sort
+    jit(multi_fn)/transpose(jvp(faa_model))/layer3_1/conv1/conv_general_dilated
+
+The ``faa_`` prefix keeps them apart from the flax module paths
+(``layer3_1/conv1``) in the same string.  Which metric reads which scope
+is in docs/OBSERVABILITY.md ("Named scopes on the device").
+"""
+
+from __future__ import annotations
+
+import re
+
+__all__ = [
+    "PREFIX",
+    "BATCH_GATHER",
+    "AUG_POLICY",
+    "AUG_OP_PREFIX",
+    "AUG_FIXED",
+    "MODEL",
+    "LOSS",
+    "OPTIMIZER",
+    "EMA",
+    "METRICS",
+    "aug_op",
+    "scope_of",
+    "is_backward",
+]
+
+PREFIX = "faa_"
+
+#: ``train/steps.py``: the ``jnp.take`` of a batch from the device cache
+BATCH_GATHER = "faa_batch_gather"
+#: ``ops/preprocess.py``: sub-policy draw, gates, the switch and its select
+AUG_POLICY = "faa_aug_policy"
+#: ``ops/augment.py::_call_op``: one operation's branch, ``faa_aug_op_<Name>``
+AUG_OP_PREFIX = "faa_aug_op_"
+#: ``ops/preprocess.py``: crop, flip, normalize, cutout: what every recipe pays
+AUG_FIXED = "faa_aug_fixed"
+#: ``train/steps.py::loss_fn``: forward under ``jvp(...)``, backward
+#: under ``transpose(jvp(...))``
+MODEL = "faa_model"
+LOSS = "faa_loss"
+#: ``train/steps.py::step_fn``: update and parameter add; EMA; top-k and sums
+OPTIMIZER = "faa_optimizer"
+EMA = "faa_ema"
+METRICS = "faa_metrics"
+
+_SCOPE = re.compile(r"faa_\w+")
+# a scope that jax.grad transposed: transpose(jvp(faa_model)), and
+# transpose(jvp(vmap(faa_...))) where a batching rule sits between
+_TRANSPOSED = re.compile(r"transpose\((?:\w+\()*faa_")
+
+
+def aug_op(name: str) -> str:
+    """The scope of one augmentation operation (`name` from
+    ``ops.augment.OP_NAMES``)."""
+    return AUG_OP_PREFIX + name
+
+
+def _scoped_path(op_name: str) -> str:
+    """XLA joins the names of instructions it merged with ``;``: the
+    first of them that carries a scope speaks for the instruction."""
+    for path in op_name.split(";"):
+        if PREFIX in path:
+            return path
+    return ""
+
+
+def scope_of(op_name: str) -> tuple[str, ...]:
+    """The chain of ``faa_`` scopes in an ``op_name``, outermost first;
+    empty where the instruction was traced under none."""
+    return tuple(_SCOPE.findall(_scoped_path(op_name)))
+
+
+def is_backward(op_name: str) -> bool:
+    """True where the instruction's scope sits under ``transpose(``: the
+    backward pass of what ``jvp(<scope>)`` names in the forward one."""
+    return bool(_TRANSPOSED.search(_scoped_path(op_name)))

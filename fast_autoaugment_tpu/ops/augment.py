@@ -39,6 +39,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from fast_autoaugment_tpu.core import scopes
+
 __all__ = [
     "OP_NAMES",
     "SEARCH_OP_NAMES",
@@ -423,12 +425,15 @@ def apply_op(img: jax.Array, op_idx: jax.Array, level: jax.Array, key: jax.Array
         mirrored & (jax.random.uniform(key_mirror) > 0.5), -1.0, 1.0
     )
     value = value * sign
-    branches = [functools.partial(_call_op, fn) for fn in _OP_FNS]
+    branches = [functools.partial(_call_op, fn, scopes.aug_op(name))
+                for fn, name in zip(_OP_FNS, OP_NAMES)]
     return jax.lax.switch(op_idx, branches, img, value, key_op)
 
 
-def _call_op(fn, img, value, key):
-    return fn(img, value, key)
+def _call_op(fn, scope, img, value, key):
+    # the one place every branch passes: its device time is read by name
+    with jax.named_scope(scope):
+        return fn(img, value, key)
 
 
 def apply_subpolicy(img: jax.Array, subpolicy: jax.Array, key: jax.Array) -> jax.Array:

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from fast_autoaugment_tpu.core import scopes
 from fast_autoaugment_tpu.ops.augment import (
     apply_policy,
     apply_policy_batch_grouped,
@@ -92,17 +93,19 @@ def _cifar_train_one(img, policy, key, cutout_length, mean, std,
                      single_sub_scalar=False):
     k_policy, k_crop, k_flip, k_cutout = jax.random.split(key, 4)
     if policy is not None:
-        if single_sub_scalar:
-            # bitwise-identical to apply_policy on a [1, num_op, 3]
-            # tensor, but the op index stays scalar under the batch vmap
-            img = apply_policy_scalar_single(img, policy, k_policy)
-        else:
-            img = apply_policy(img, policy, k_policy)
-    img = random_crop_with_pad(img, k_crop, 4)
-    img = random_hflip(img, k_flip)
-    img = normalize(img, mean, std)
-    if cutout_length > 0:
-        img = cutout_default(img, k_cutout, cutout_length)
+        with jax.named_scope(scopes.AUG_POLICY):
+            if single_sub_scalar:
+                # bitwise-identical to apply_policy on a [1, num_op, 3]
+                # tensor, but the op index stays scalar under the batch vmap
+                img = apply_policy_scalar_single(img, policy, k_policy)
+            else:
+                img = apply_policy(img, policy, k_policy)
+    with jax.named_scope(scopes.AUG_FIXED):
+        img = random_crop_with_pad(img, k_crop, 4)
+        img = random_hflip(img, k_flip)
+        img = normalize(img, mean, std)
+        if cutout_length > 0:
+            img = cutout_default(img, k_cutout, cutout_length)
     return img
 
 
@@ -127,15 +130,18 @@ def cifar_train_batch(
     tensor under "grouped" takes the bitwise-exact scalar path instead
     (no selection to stratify)."""
     check_aug_dispatch(aug_dispatch)
-    images = images.astype(jnp.float32)
+    with jax.named_scope(scopes.AUG_FIXED):
+        images = images.astype(jnp.float32)
     single_sub = policy is not None and int(policy.shape[0]) == 1
     if aug_dispatch == "grouped" and policy is not None and not single_sub:
         key, key_pol = jax.random.split(key)
-        images = apply_policy_batch_grouped(images, policy, key_pol,
-                                            groups=aug_groups)
+        with jax.named_scope(scopes.AUG_POLICY):
+            images = apply_policy_batch_grouped(images, policy, key_pol,
+                                                groups=aug_groups)
         policy = None
     scalar = aug_dispatch == "grouped" and single_sub
-    keys = jax.random.split(key, images.shape[0])
+    with jax.named_scope(scopes.AUG_FIXED):
+        keys = jax.random.split(key, images.shape[0])
     return jax.vmap(
         lambda im, k: _cifar_train_one(im, policy, k, cutout_length, mean, std,
                                        single_sub_scalar=scalar)

@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 from flax import struct
 
+from fast_autoaugment_tpu.core import scopes
 from fast_autoaugment_tpu.core.compilecache import seam_jit
 from fast_autoaugment_tpu.core.metrics import (
     mixup_batch,
@@ -143,12 +144,18 @@ def _make_train_step_body(
             rngs={"shake": key_shake, "dropout": key_drop},
         )
         if mixup_alpha > 0.0:
-            mixed, targets_a, targets_b, lam = mixup_batch(key_mix, images, labels, mixup_alpha)
-            logits, mutated = apply(mixed)
-            loss = mixup_cross_entropy(logits, targets_a, targets_b, lam, lb_smooth)
+            with jax.named_scope(scopes.LOSS):
+                mixed, targets_a, targets_b, lam = mixup_batch(
+                    key_mix, images, labels, mixup_alpha)
+            with jax.named_scope(scopes.MODEL):
+                logits, mutated = apply(mixed)
+            with jax.named_scope(scopes.LOSS):
+                loss = mixup_cross_entropy(logits, targets_a, targets_b, lam, lb_smooth)
         else:
-            logits, mutated = apply(images)
-            loss = smooth_cross_entropy(logits, labels, lb_smooth)
+            with jax.named_scope(scopes.MODEL):
+                logits, mutated = apply(images)
+            with jax.named_scope(scopes.LOSS):
+                loss = smooth_cross_entropy(logits, labels, lb_smooth)
         return loss, (logits, mutated["batch_stats"])
 
     def step_fn(state: TrainState, images, labels, policy, key):
@@ -159,25 +166,30 @@ def _make_train_step_body(
         (loss, (logits, new_batch_stats)), grads = grad_fn(
             state.params, state.batch_stats, images, labels, key_model
         )
-        updates, new_opt_state = optimizer.update(grads, state.opt_state, state.params)
-        new_params = jax.tree.map(lambda p, u: p + u, state.params, updates)
+        with jax.named_scope(scopes.OPTIMIZER):
+            updates, new_opt_state = optimizer.update(
+                grads, state.opt_state, state.params)
+            new_params = jax.tree.map(lambda p, u: p + u, state.params, updates)
 
         new_ema = state.ema
         if state.ema is not None and ema_mu > 0.0:
-            new_ema = ema_update(
-                state.ema,
-                {"params": new_params, "batch_stats": new_batch_stats},
-                ema_mu,
-                state.step + 1,  # 1-based, reference train.py:70
-            )
+            with jax.named_scope(scopes.EMA):
+                new_ema = ema_update(
+                    state.ema,
+                    {"params": new_params, "batch_stats": new_batch_stats},
+                    ema_mu,
+                    state.step + 1,  # 1-based, reference train.py:70
+                )
 
         batch = labels.shape[0]
-        metrics = {
-            "loss": loss * batch,
-            "top1": top_k_correct(logits, labels, 1).astype(jnp.float32),
-            "top5": top_k_correct(logits, labels, min(5, num_classes)).astype(jnp.float32),
-            "num": jnp.float32(batch),
-        }
+        with jax.named_scope(scopes.METRICS):
+            metrics = {
+                "loss": loss * batch,
+                "top1": top_k_correct(logits, labels, 1).astype(jnp.float32),
+                "top5": top_k_correct(
+                    logits, labels, min(5, num_classes)).astype(jnp.float32),
+                "num": jnp.float32(batch),
+            }
         new_state = state.replace(
             step=state.step + 1,
             params=new_params,
@@ -283,9 +295,10 @@ def make_stacked_step_body(
                 key_pol = jax.random.fold_in(
                     jax.random.fold_in(keys[k], states.step[k]),
                     _GROUPED_AUG_TAG)
-                auged.append(apply_policy_batch_grouped(
-                    images[k].astype(jnp.float32), policy, key_pol,
-                    groups=aug_groups))
+                with jax.named_scope(scopes.AUG_POLICY):
+                    auged.append(apply_policy_batch_grouped(
+                        images[k].astype(jnp.float32), policy, key_pol,
+                        groups=aug_groups))
             images = jnp.stack(auged)
         new_states, metrics = jax.vmap(
             body, in_axes=(0, 0, 0, None, 0)
@@ -435,8 +448,9 @@ def make_multistep_train_step(
         unroll = default_dispatch_unroll(steps_per_dispatch)
 
     def gather(cache_images, cache_labels, idx_n):
-        return (jnp.take(cache_images, idx_n, axis=0),
-                jnp.take(cache_labels, idx_n, axis=0))
+        with jax.named_scope(scopes.BATCH_GATHER):
+            return (jnp.take(cache_images, idx_n, axis=0),
+                    jnp.take(cache_labels, idx_n, axis=0))
 
     if not stacked:
         def multi_fn(state, cache_images, cache_labels, idx, policy, key):

@@ -1,18 +1,17 @@
-"""Profiling, step timing, and TPU-hours accounting.
+"""TPU-hours accounting.
 
 The reference's observability is wall-clock only: total run time
 (``train.py:345-354``), per-phase stopwatches in search
 (``search.py:139-140,172,206,263``) and the per-trial "GPU-seconds"
 ``wall x device_count`` that feed its headline GPU-hours numbers
-(``search.py:132-133,251-252``).  TPU-native equivalents:
+(``search.py:132-133,251-252``).  The TPU-native equivalent:
 
-- :func:`trace` — ``jax.profiler`` trace capture around any region
-  (view in TensorBoard/XProf); the reference has no profiler at all;
-- :class:`StepTimer` — per-step wall timing with warmup skip, giving
-  steady-state images/sec;
 - :class:`PhaseStopwatch` — named phase accounting in device-seconds
   (``wall x device_count``), the reference's GPU-hours ledger
   generalized.
+
+Device time inside a step is read from a profiler trace by the named
+scopes of ``core/scopes.py`` (docs/OBSERVABILITY.md), not timed here.
 """
 
 from __future__ import annotations
@@ -22,68 +21,7 @@ import time
 
 import jax
 
-__all__ = ["trace", "StepTimer", "PhaseStopwatch"]
-
-
-@contextlib.contextmanager
-def trace(logdir: str | None):
-    """Capture a jax.profiler trace into `logdir` (no-op if None)."""
-    if not logdir:
-        yield
-        return
-    jax.profiler.start_trace(logdir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
-
-
-class StepTimer:
-    """Steady-state step timing: skips `warmup` steps (compilation),
-    then tracks mean step time and throughput.
-
-    With a `registry` (``core/telemetry.py``) and a `name`, every
-    steady-state stop mirrors ``faa_step_seconds{timer=name}`` into the
-    shared metrics registry — the same numbers any ``/metrics`` scrape
-    or bench stamp reads."""
-
-    def __init__(self, warmup: int = 3, *, name: str | None = None,
-                 registry=None):
-        self.warmup = warmup
-        self.count = 0
-        self.total = 0.0
-        self._last = None
-        self._name = name
-        self._hist = None
-        if registry is not None and name is not None:
-            self._hist = registry.histogram(
-                "faa_step_seconds", "steady-state per-step wall seconds",
-                timer=name)
-
-    def start(self):
-        self._last = time.perf_counter()
-
-    def stop(self, items: int = 0) -> float:
-        dt = time.perf_counter() - self._last
-        self.count += 1
-        if self.count > self.warmup:
-            self.total += dt
-            self._items = getattr(self, "_items", 0) + items
-            if self._hist is not None:
-                self._hist.observe(dt)
-        return dt
-
-    @property
-    def steps_timed(self) -> int:
-        return max(0, self.count - self.warmup)
-
-    @property
-    def mean_step_seconds(self) -> float:
-        return self.total / self.steps_timed if self.steps_timed else 0.0
-
-    @property
-    def items_per_second(self) -> float:
-        return getattr(self, "_items", 0) / self.total if self.total else 0.0
+__all__ = ["PhaseStopwatch"]
 
 
 class PhaseStopwatch:

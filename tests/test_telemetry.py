@@ -491,19 +491,6 @@ def test_phase1_attribution_identity_matches_stopwatch():
         sw.device_seconds("phase1_stack0"))
 
 
-def test_step_timer_mirrors_registry_histogram():
-    from fast_autoaugment_tpu.utils.profiling import StepTimer
-
-    reg = T.MetricsRegistry()
-    st = StepTimer(warmup=1, name="unit_steps", registry=reg)
-    for _ in range(3):
-        st.start()
-        time.sleep(0.002)
-        st.stop()
-    h = reg.histogram("faa_step_seconds", timer="unit_steps")
-    assert h.snapshot()["count"] == st.steps_timed == 2
-
-
 # -------------------------------------------------- export surfaces
 
 
