@@ -19,7 +19,11 @@ Semantics were pinned against PIL empirically and are exact (see
 - L (grayscale): ``(r*19595 + g*38470 + b*7471 + 0x8000) >> 16``
 - enhance ops: ``clip(trunc(deg + (img - deg) * factor), 0, 255)`` in
   float32 (PIL ``ImageEnhance`` via ``Image.blend``)
-- equalize / autocontrast: PIL's exact integer LUT constructions
+- equalize / autocontrast: PIL's exact integer LUT constructions, with no
+  sort, search, gather or scatter (what the TPU runs slowest): the
+  histogram is a compare of the pixels against the 256 levels reduced
+  over the pixels, Equalize's table is applied by a select over the
+  levels, AutoContrast's is arithmetic on each pixel
 - SMOOTH filter (sharpness degenerate): 3x3 kernel [[1,1,1],[1,5,1],
   [1,1,1]]/13, ``trunc(acc + 0.5)``, 1-pixel border copied unfiltered
 
@@ -140,15 +144,6 @@ def _blend(degenerate: jax.Array, img: jax.Array, factor: jax.Array) -> jax.Arra
     return jnp.clip(jnp.trunc(out), 0.0, 255.0)
 
 
-def _apply_lut(img: jax.Array, lut: jax.Array) -> jax.Array:
-    """Per-channel 256-entry LUT gather; lut [C, 256] or [256]."""
-    ii = _to_int(img)
-    if lut.ndim == 1:
-        return lut[ii].astype(jnp.float32)
-    out = jnp.stack([lut[c][ii[..., c]] for c in range(img.shape[-1])], axis=-1)
-    return out.astype(jnp.float32)
-
-
 def _warp_affine_nearest(img: jax.Array, mat: jax.Array) -> jax.Array:
     """PIL-exact nearest affine warp with zero fill.
 
@@ -167,20 +162,21 @@ def _warp_affine_nearest(img: jax.Array, mat: jax.Array) -> jax.Array:
     return jnp.where(valid[..., None], gathered, 0.0)
 
 
-def _histogram256(channel_int: jax.Array) -> jax.Array:
-    """256-bin histogram via sort + searchsorted.
+_LEVELS = np.arange(256, dtype=np.int32)
 
-    Scatter-adds serialize on TPU and a [N, 256] one-hot materializes
-    ~100x more intermediate data; sorting the N pixels and differencing
-    bin-edge ranks is ~9x faster (measured in tools/bench_aug.py — the
-    histogram made Equalize the single hottest augmentation op) and
-    vmaps cleanly.
+
+def _count_below(ii: jax.Array) -> jax.Array:
+    """``[256, C]`` int32: per channel, how many pixels lie below each level.
+
+    The exclusive cumulative histogram of an int32 ``[H, W, C]`` image
+    with values in [0, 255]; ``diff`` of it (with the pixel count
+    appended) is the 256-bin histogram.  One compare of every pixel
+    against every level, fused into its reduce over the pixels: linear
+    in the pixel count, no data-dependent addressing, vmaps cleanly.
     """
-    flat = channel_int.reshape(-1)
-    s = jnp.sort(flat)
-    edges = jnp.arange(257, dtype=jnp.int32)
-    ranks = jnp.searchsorted(s, edges, side="left").astype(jnp.int32)
-    return jnp.diff(ranks)
+    flat = ii.reshape(-1, ii.shape[-1])
+    return jnp.sum(flat[None, :, :] < _LEVELS[:, None, None], axis=1,
+                   dtype=jnp.int32)
 
 
 # ---------------------------------------------------------------------------
@@ -230,23 +226,21 @@ def rotate(img, v, key):
 
 
 def auto_contrast(img, v, key):
-    """PIL ImageOps.autocontrast(cutoff=0): per-channel min/max stretch LUT.
+    """PIL ImageOps.autocontrast(cutoff=0): per-channel min/max stretch.
 
-    Computed as the exact rational ``(i - lo) * 255 // (hi - lo)``.  PIL
-    evaluates the same map in double precision with truncation, which
-    lands 1 below the exact value on ~20% of images — so outputs may
-    differ from PIL by at most 1 (deliberate deviation; the exact form
-    is stable in float-free integer math on device).
+    Computed as the exact rational ``(p - lo) * 255 // (hi - lo)`` on
+    each pixel: every pixel lies in [lo, hi], so the numerator is never
+    negative and this is the entry PIL's 256-entry LUT holds for it.
+    PIL evaluates the same map in double precision with truncation,
+    which lands 1 below the exact value on ~20% of images — so outputs
+    may differ from PIL by at most 1 (deliberate deviation; the exact
+    form is stable in float-free integer math on device).
     """
     ii = _to_int(img)
     lo = ii.min(axis=(0, 1))  # [C]
     hi = ii.max(axis=(0, 1))
-    ix = jnp.arange(256, dtype=jnp.int32)
-    span = jnp.maximum(hi - lo, 1)
-    lut = jnp.clip((ix[None, :] - lo[:, None]) * 255 // span[:, None], 0, 255)
-    identity = hi <= lo
-    lut = jnp.where(identity[:, None], ix[None, :], lut)
-    return _apply_lut(img, lut)
+    stretched = jnp.clip((ii - lo) * 255 // jnp.maximum(hi - lo, 1), 0, 255)
+    return jnp.where(hi <= lo, ii, stretched).astype(jnp.float32)
 
 
 def invert(img, v, key):
@@ -254,27 +248,24 @@ def invert(img, v, key):
 
 
 def equalize(img, v, key):
-    """PIL ImageOps.equalize: per-channel integer histogram remap."""
+    """PIL ImageOps.equalize: per-channel integer histogram remap.
+
+    PIL's table is ``lut[v] = (step // 2 + #(pixels < v)) // step`` with
+    ``step = (pixels - h_last) // 255`` and ``h_last`` the count of the
+    last nonzero bin; a channel with ``step == 0`` passes unchanged.
+    """
     ii = _to_int(img)
-
-    def one_channel(ch):
-        h = _histogram256(ch)
-        total = jnp.sum(h)
-        nonzero = h > 0
-        num_nonzero = jnp.sum(nonzero)
-        # value of the last nonzero bin
-        last_idx = 255 - jnp.argmax(nonzero[::-1])
-        h_last = h[last_idx]
-        step = (total - h_last) // 255
-        csum = jnp.cumsum(h) - h  # exclusive cumsum
-        n = step // 2 + csum
-        lut = jnp.clip(n // jnp.maximum(step, 1), 0, 255)
-        ix = jnp.arange(256, dtype=jnp.int32)
-        use_identity = (num_nonzero <= 1) | (step == 0)
-        return jnp.where(use_identity, ix, lut)
-
-    lut = jnp.stack([one_channel(ii[..., c]) for c in range(img.shape[-1])])
-    return _apply_lut(img, lut)
+    below = _count_below(ii)  # [256, C]
+    # the last nonzero bin is the channel maximum's
+    h_last = jnp.sum(ii == ii.max(axis=(0, 1)), axis=(0, 1), dtype=jnp.int32)
+    step = (ii.shape[0] * ii.shape[1] - h_last) // 255
+    lut = jnp.clip((step // 2 + below) // jnp.maximum(step, 1), 0, 255)
+    # a single nonzero bin holds every pixel, so it gives step == 0 too
+    lut = jnp.where(step == 0, _LEVELS[:, None], lut)
+    # lut[p] without a gather: select each pixel's level, reduce over levels
+    picked = jnp.where(ii[None] == _LEVELS[:, None, None, None],
+                       lut[:, None, None, :], 0)
+    return jnp.sum(picked, axis=0, dtype=jnp.int32).astype(jnp.float32)
 
 
 def solarize(img, v, key):

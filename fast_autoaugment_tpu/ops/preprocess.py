@@ -59,12 +59,22 @@ def normalize(img: jax.Array, mean: Sequence[float], std: Sequence[float]) -> ja
 def random_crop_with_pad(img: jax.Array, key: jax.Array, pad: int = 4) -> jax.Array:
     """torchvision RandomCrop(size, padding=pad) with zero fill: pad all
     sides then take a random crop at the original size."""
-    h, w, c = img.shape
+    h, w = img.shape[:2]
     padded = jnp.pad(img, ((pad, pad), (pad, pad), (0, 0)))
     ky, kx = jax.random.split(key)
     oy = jax.random.randint(ky, (), 0, 2 * pad + 1)
     ox = jax.random.randint(kx, (), 0, 2 * pad + 1)
-    return jax.lax.dynamic_slice(padded, (oy, ox, 0), (h, w, c))
+    # one static slice per offset and a select, rows then columns: a
+    # dynamic_slice vmapped over per-image offsets is a gather, which
+    # XLA:TPU runs as a loop over the batch (58 ms a step at 2,048
+    # images where these selects take under one; PERF.md section 6, PR 25)
+    rows = padded[:h]
+    for k in range(1, 2 * pad + 1):
+        rows = jnp.where(oy == k, padded[k:k + h], rows)
+    out = rows[:, :w]
+    for k in range(1, 2 * pad + 1):
+        out = jnp.where(ox == k, rows[:, k:k + w], out)
+    return out
 
 
 def random_hflip(img: jax.Array, key: jax.Array) -> jax.Array:
