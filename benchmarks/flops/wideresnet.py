@@ -19,6 +19,18 @@ by this count.
 
 from __future__ import annotations
 
+import re
+
+
+def model_from_conf(conf_model: dict) -> dict:
+    """The sizes the functions below need, as the conf's ``model`` mapping
+    gives them: ``{"type": "wresnet28_10"}`` -> depth 28, widen factor 10.
+    A configuration file's ``model`` block has to agree on these keys."""
+    named = re.fullmatch(r"wresnet(\d+)_(\d+)", str(conf_model.get("type")))
+    if not named:
+        raise ValueError(f"not a Wide ResNet: model {conf_model!r}")
+    return {"depth": int(named[1]), "widen_factor": int(named[2])}
+
 
 def _convs(depth: int, widen: int, image: int, in_channels: int = 3):
     """Yield ``(name, kernel, c_in, c_out, out_size)`` per convolution."""

@@ -8,11 +8,17 @@ op_name}}``.  A trace's ``XLA Ops`` events are named by their HLO text,
 ``%fusion.2361 = s32[526336]... fusion(...)``, so the join is event ->
 instruction name -> ``op_name`` -> scope.  For every execution of the
 step program that ``step_device_ms`` uses, the self-times of the
-operations inside it are summed by innermost scope (nested time counted
-once); what carries no scope, is not in the map, or is no operation at
-all (the device waiting inside the program) is ``unscoped``, so the
-parts of an execution add up to its device time.  A metric is the median
-over executions.
+operations inside it are summed by the instruction's whole chain of
+scopes, outermost first (nested time counted once); what carries no
+scope, is not in the map, or is no operation at all (the device waiting
+inside the program) is ``unscoped``, so the parts of an execution add up
+to its device time.  A metric is the median over executions.
+
+A chain belongs to the partition family of its *outermost* scope that
+has one, so whatever a model names inside ``faa_model`` (a gate, a
+routing step, a mix with a ``custom_vjp``) stays in the model's forward
+or backward time, and :func:`scope_ms` reads such a scope by its name
+wherever it is nested: a reader file for it needs no edit here.
 
 The map is written once a run to ``<trace_dir>/scope_map.<label>.json``,
 so that
@@ -50,6 +56,8 @@ MAX_UNSCOPED_SHARE = 20.0
 HISTOGRAM_OPS = ("AutoContrast", "Equalize")
 GEOMETRIC_OPS = ("ShearX", "ShearY", "TranslateX", "TranslateY", "Rotate",
                  "TranslateXAbs", "TranslateYAbs")
+#: the families that partition the scoped time of an execution
+PARTITION = ("policy", "fixed", "forward", "backward", "optimizer", "gather")
 
 
 def program_scopes():
@@ -62,32 +70,43 @@ def program_scopes():
     return scopes
 
 
+def split_key(key: str) -> tuple[tuple[str, ...], bool]:
+    """A key of :class:`Split` as ``(chain, backward)``."""
+    backward = key.endswith(BACKWARD)
+    return tuple(key.removesuffix(BACKWARD).split("/")), backward
+
+
 def families(names) -> dict[str, callable]:
-    """``{family: predicate over a scope key}``: which scopes a metric
-    sums.  The first six partition the scoped time; the two families of
-    operations lie inside ``policy``."""
-    model = (names.MODEL, names.LOSS)
-    updates = (names.OPTIMIZER, names.EMA, names.METRICS)
-    histogram = tuple(names.aug_op(n) for n in HISTOGRAM_OPS)
-    geometric = tuple(names.aug_op(n) for n in GEOMETRIC_OPS)
+    """``{family: predicate over a key of Split}``: which time a metric
+    sums.  The six of :data:`PARTITION` divide the scoped time by the
+    outermost scope of a chain that one of them names (the model's
+    forward or backward by where the instruction sits), so what is
+    nested under a family's scope is that family's; the two families of
+    operations lie inside ``policy`` and match their operations' scopes
+    anywhere in a chain."""
+    owners = {names.AUG_POLICY: "policy", names.AUG_FIXED: "fixed",
+              names.MODEL: "model", names.LOSS: "model",
+              names.OPTIMIZER: "optimizer", names.EMA: "optimizer",
+              names.METRICS: "optimizer", names.BATCH_GATHER: "gather"}
+    histogram = {names.aug_op(n) for n in HISTOGRAM_OPS}
+    geometric = {names.aug_op(n) for n in GEOMETRIC_OPS}
 
-    def base(key):
-        return key.removesuffix(BACKWARD)
+    def owner(key):
+        chain, backward = split_key(key)
+        for scope in chain:
+            family = owners.get(scope) or (
+                "policy" if scope.startswith(names.AUG_OP_PREFIX) else None)
+            if family == "model":
+                return "backward" if backward else "forward"
+            if family:
+                return family
+        return None
 
-    return {
-        "policy": lambda k: (base(k) == names.AUG_POLICY
-                             or base(k).startswith(names.AUG_OP_PREFIX)),
-        "fixed": lambda k: base(k) == names.AUG_FIXED,
-        "forward": lambda k: k in model,
-        "backward": lambda k: k.endswith(BACKWARD) and base(k) in model,
-        "optimizer": lambda k: base(k) in updates,
-        "gather": lambda k: base(k) == names.BATCH_GATHER,
-        "histogram_ops": lambda k: base(k) in histogram,
-        "geometric_ops": lambda k: base(k) in geometric,
-    }
-
-
-PARTITION = ("policy", "fixed", "forward", "backward", "optimizer", "gather")
+    out = {family: (lambda k, family=family: owner(k) == family)
+           for family in PARTITION}
+    out["histogram_ops"] = lambda k: not histogram.isdisjoint(split_key(k)[0])
+    out["geometric_ops"] = lambda k: not geometric.isdisjoint(split_key(k)[0])
+    return out
 
 
 def instruction_name(event_name: str) -> str:
@@ -101,12 +120,14 @@ def module_name(run_name: str) -> str:
 
 
 def scope_key(op_name: str | None, names) -> str:
-    """The innermost scope of an ``op_name``, marked where it belongs to
-    the backward pass; :data:`UNSCOPED` where there is none."""
-    chain = names.scope_of(op_name) if op_name else ()
+    """The chain of scopes of an ``op_name``, outermost first and joined
+    by ``/``, marked where it belongs to the backward pass;
+    :data:`UNSCOPED` where there is none.  A scope that comes back
+    further in (a nested ``jit`` repeats the whole path) counts once."""
+    chain = dict.fromkeys(names.scope_of(op_name)) if op_name else ()
     if not chain:
         return UNSCOPED
-    return chain[-1] + (BACKWARD if names.is_backward(op_name) else "")
+    return "/".join(chain) + (BACKWARD if names.is_backward(op_name) else "")
 
 
 @dataclass
@@ -241,16 +262,35 @@ def step_unscoped_share(obs) -> float | None:
     return None if split is None else split.unscoped_share()
 
 
-def family_ms(obs, family: str) -> float | None:
-    """Median device milliseconds of one execution under `family`; None
-    where there is no split or it leaves too much unexplained."""
+def _median_ms(obs, select) -> float | None:
+    """What :func:`family_ms` and :func:`scope_ms` share: the median over
+    the keys `select` accepts."""
     split = step_split(obs)
     if split is None:
         return None
     share = split.unscoped_share()
     if share is None or share > MAX_UNSCOPED_SHARE:
         return None
-    return split.median_ms(families(program_scopes())[family])
+    return split.median_ms(select)
+
+
+def family_ms(obs, family: str) -> float | None:
+    """Median device milliseconds of one execution under one of
+    :func:`families`; None where there is no split or it leaves too much
+    unexplained."""
+    names = program_scopes()
+    return None if names is None else _median_ms(obs, families(names)[family])
+
+
+def scope_ms(obs, name: str, backward: bool | None = None) -> float | None:
+    """Median device milliseconds of one execution under the scope `name`
+    wherever it is nested, what is nested under it included; the forward
+    pass alone with ``backward=False``, the backward pass alone with
+    ``True``.  None where :func:`family_ms` would give None."""
+    def select(key):
+        chain, is_backward = split_key(key)
+        return name in chain and backward in (None, is_backward)
+    return _median_ms(obs, select)
 
 
 # ------------------------------------------------------------ the table
@@ -273,11 +313,13 @@ def format_table(label: str, split: Split, names) -> list[str]:
     for family, select in families(names).items():
         row(family, split.median_ms(select))
     row(UNSCOPED, split.median_ms(lambda k: k == UNSCOPED))
-    lines.append("  by scope:")
+    lines.append("  by scope (a nested scope under its parent, its own time only):")
     keys = sorted({k for parts in split.executions for k in parts if k != UNSCOPED}
-                  | {names.aug_op(n) for n in OP_NAMES})
+                  | {f"{names.AUG_POLICY}/{names.aug_op(n)}" for n in OP_NAMES})
     for key in keys:
-        row("  " + key, split.median_ms(lambda k, key=key: k == key))
+        chain, backward = split_key(key)
+        row("  " * len(chain) + chain[-1] + (BACKWARD if backward else ""),
+            split.median_ms(lambda k, key=key: k == key))
     lines.append("  largest unscoped operations (the rest of unscoped is "
                  "the device waiting inside the program):")
     ranked = sorted(split.unscoped_ops.items(), key=lambda kv: -kv[1])[:10]

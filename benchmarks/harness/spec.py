@@ -1,11 +1,36 @@
 """Find a cell's files by the names ``BENCHMARK.json`` gives them.
 
-Nothing here knows a configuration, a traffic mix, a program or a metric
-by name: a cell names its configuration and traffic, the traffic file
-names its program and fixture, the configuration file names its
-operations function and plain reference, and a per-layer metric is the
-reader file that carries its name.  Adding any of them is adding files
-and entries; no list in this directory has to be edited.
+Nothing here knows a configuration, a model family, a traffic mix, a
+program or a metric by name: a cell names its configuration and traffic,
+the traffic file names its program and fixture, the configuration file
+names its family's operations file and plain reference, and a per-layer
+metric is the reader file that carries its name.  Adding any of them is
+adding files and entries; no list in this directory has to be edited.
+
+What a configuration of a new model family brings, and who calls it
+(``configs/<config>.json`` names both files, as ``"flops"`` and
+``"reference"``, and hands them its ``"model"`` block of sizes):
+
+``flops/<family>.py``, operations from shapes alone
+    ``model_from_conf(conf_model) -> dict``: the sizes the functions
+    below need, derived from the conf's ``model`` mapping; the
+    configuration self-test (``tests/benchmarks/test_bench_spec.py::
+    check_config``) holds the file's ``model`` block to it on those keys.
+    ``train_flops_per_image(model) -> float`` and
+    ``forward_flops_per_image(model) -> float``: model operations of one
+    image through forward and backward, and through forward alone;
+    ``harness/readers.py::model_flops_utilization`` calls the one the
+    cell's program names in ``Observed.work["passes"]``.
+
+``references/<family>.py``, the plain float32 forward pass
+    ``forward(params, batch_stats, images_u8, model) -> logits``: no
+    code of the program, every product at ``highest``;
+    ``harness/window.py::reference_check`` calls it on the parameter
+    trees the program checkpoints, and ``correct`` rests on it.
+
+A family's own tests (its operations against a hand count, its reference
+against the program on seeded weights) come with it as a new file under
+``tests/benchmarks/``.
 """
 
 from __future__ import annotations

@@ -1,5 +1,6 @@
 """Median device time of one train step in the backward pass of the model and the
-loss: ``faa_model`` and ``faa_loss`` under ``transpose(jvp(...))``."""
+loss: ``faa_model`` and ``faa_loss`` with every scope a model nests under them,
+under ``transpose(jvp(...))``."""
 
 from benchmarks.harness.scopes import family_ms
 

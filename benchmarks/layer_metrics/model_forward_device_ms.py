@@ -1,5 +1,6 @@
 """Median device time of one train step in the model's forward pass and the loss:
-``faa_model`` and ``faa_loss`` where they do not sit under ``transpose(``."""
+``faa_model`` and ``faa_loss`` with every scope a model nests under them, where
+they do not sit under ``transpose(``."""
 
 from benchmarks.harness.scopes import family_ms
 

@@ -64,8 +64,12 @@ def build_tiny_checkout(root: str, *, chips_train: int = 1,
         # reduced_cifar10 needs the full 50,000; folds of the 400-image
         # fixture are cut from `cifar10` itself
         "conf_overrides": {"dataset": "cifar10", "batch": 4},
+        # the tests ask for a window shorter than any round (0.05 s), which
+        # `take_window` runs to the end of the round it cut into: a fixed
+        # number of rounds however slow the machine, so the watcher may wait
+        # an hour (0.05 s x 72,000) for that round before it gives up
         "warmup_trials": 2, "close_margin_seconds": 0.5,
-        "max_window_factor": 6, "trace_seconds": 1.5, "trace_min_trials": 4,
+        "max_window_factor": 72000, "trace_seconds": 1.5, "trace_min_trials": 4,
         "dispatch_labels": ["tta", "tta_batched"],
         "step_program": "^jit_(tta_step_batched|one_candidate)",
         "reward_tolerance": 0.002, "reference_images": 16})

@@ -92,7 +92,15 @@ def test_names_are_cut_from_the_trace_events():
     assert hs.instruction_name("while.2 = (s32[]) while(...)") == "while.2"
     assert hs.module_name("jit_multi_fn(9872292373413704833)") == "jit_multi_fn"
     assert hs.scope_key(MODULES["jit_multi_fn"]["fusion.9"], scopes) == "faa_model/backward"
-    assert hs.scope_key(MODULES["jit_multi_fn"]["fusion.3"], scopes) == "faa_aug_op_Equalize"
+    # the whole chain, outermost first
+    equalize = "faa_aug_policy/faa_aug_op_Equalize"
+    assert hs.scope_key(MODULES["jit_multi_fn"]["fusion.3"], scopes) == equalize
+    assert hs.split_key(equalize) == (("faa_aug_policy", "faa_aug_op_Equalize"), False)
+    assert hs.split_key("faa_model/backward") == (("faa_model",), True)
+    # a nested jit repeats the path (recorded: Equalize's searchsorted, six deep)
+    assert hs.scope_key(JIT + "vmap(faa_aug_policy)/faa_aug_op_Equalize/jit(searchsorted)/"
+                        + JIT + "vmap(faa_aug_policy)/faa_aug_op_Equalize/gather",
+                        scopes) == equalize
     assert hs.scope_key("", scopes) == hs.scope_key(None, scopes) == hs.UNSCOPED
 
 
@@ -102,8 +110,9 @@ def test_scopes_and_unscoped_add_up_to_the_steps_device_time():
     one = split.executions[0]
     assert sum(one.values()) == pytest.approx(1000.0)
     # nested time counts once: the while keeps 300 - 200 for itself
-    assert one["faa_aug_op_Equalize"] == pytest.approx(300.0)
-    assert one["faa_aug_op_Rotate"] == 150.0 and one["faa_aug_policy"] == 40.0
+    assert one["faa_aug_policy/faa_aug_op_Equalize"] == pytest.approx(300.0)
+    assert one["faa_aug_policy/faa_aug_op_Rotate"] == 150.0
+    assert one["faa_aug_policy"] == 40.0
     assert one["faa_aug_fixed"] == 60.0 and one["faa_batch_gather"] == 10.0
     # backward splits from forward
     assert one["faa_model"] == 100.0 and one["faa_model/backward"] == 180.0
@@ -131,6 +140,46 @@ def test_an_unknown_module_is_all_unscoped():
     split = hs.split_plane(_plane(), "^jit_multi_fn", {}, scopes)
     assert split.unscoped_share() == pytest.approx(100.0)
     assert hs.split_plane(_plane(), "^jit_no_such", MODULES, scopes).executions == []
+
+
+# What a model names inside ``faa_model`` (the next configuration's
+# ShakeDrop gate): the threefry becomes its forward draw, the instruction
+# the map had never seen its backward mix, the copy a scope no family names
+NESTED = {"jit_multi_fn": dict(MODULES["jit_multi_fn"], **{
+    "fusion.13": JIT + "jvp(faa_model)/PyramidNet/block3/faa_shake_drop/mul",
+    "fusion.99": JIT + "transpose(jvp(faa_model))/PyramidNet/block3/faa_shake_drop/mul",
+    "copy.12": JIT + "faa_not_of_any_family/copy"})}
+
+
+@pytest.mark.parametrize("family, flat_ns, nested_ns", [
+    ("forward", 110.0, 125.0), ("backward", 180.0, 205.0),
+    ("policy", 490.0, 490.0), ("optimizer", 25.0, 25.0)])
+def test_a_scope_nested_under_the_model_stays_the_models(family, flat_ns, nested_ns):
+    fam = hs.families(scopes)
+    flat = hs.split_plane(_plane(), "^jit_multi_fn", MODULES, scopes)
+    nested = hs.split_plane(_plane(), "^jit_multi_fn", NESTED, scopes)
+    assert flat.median_ms(fam[family]) == pytest.approx(2 * flat_ns * 1e-6)
+    assert nested.median_ms(fam[family]) == pytest.approx(2 * nested_ns * 1e-6)
+
+
+def test_the_partition_holds_with_nested_scopes():
+    nested = hs.split_plane(_plane(), "^jit_multi_fn", NESTED, scopes)
+    one = nested.executions[0]
+    assert one["faa_model/faa_shake_drop"] == 15.0
+    assert one["faa_model/faa_shake_drop/backward"] == 25.0
+    assert one["faa_model"] == 100.0 and one["faa_model/backward"] == 180.0
+    # a scope that no family names is scoped time in nobody's metric
+    assert one["faa_not_of_any_family"] == 30.0
+    assert one[hs.UNSCOPED] == pytest.approx(55.0)
+    fam = hs.families(scopes)
+    for parts, total in zip(nested.executions, nested.durations_ns):
+        six = sum(ns for k, ns in parts.items()
+                  if any(fam[f](k) for f in hs.PARTITION))
+        assert six + parts[hs.UNSCOPED] + parts["faa_not_of_any_family"] == \
+            pytest.approx(total)
+    # each key is in one family of the six at most
+    assert all(sum(fam[f](k) for f in hs.PARTITION) <= 1
+               for parts in nested.executions for k in parts)
 
 
 def _observed(plane, monkeypatch, tmp_path, modules=MODULES, raises=None):
@@ -168,6 +217,56 @@ def test_nine_readers_one_map_and_a_file_beside_the_trace(monkeypatch, tmp_path)
     held = load_json(hs.map_path(str(tmp_path), "train_dispatch"))
     assert held == {"label": "train_dispatch", "step_program": "^jit_multi_fn",
                     "modules": MODULES}
+
+
+@pytest.mark.parametrize("name, backward, ns", [
+    ("faa_shake_drop", None, 40.0), ("faa_shake_drop", False, 15.0),
+    ("faa_shake_drop", True, 25.0), ("faa_model", None, 320.0),
+    ("faa_aug_op_Equalize", None, 300.0), ("faa_aug_policy", None, 490.0),
+    ("faa_no_such_scope", None, 0.0)])
+def test_scope_ms_reads_a_scope_wherever_it_is_nested(monkeypatch, tmp_path,
+                                                      name, backward, ns):
+    """What a later reader file calls: three lines, no edit to the harness."""
+    obs, _ = _observed(_plane(), monkeypatch, tmp_path, modules=NESTED)
+    assert hs.scope_ms(obs, name, backward) == pytest.approx(2 * ns * 1e-6)
+
+
+def test_the_models_readers_hold_what_is_nested_and_the_step_adds_up(
+        monkeypatch, tmp_path):
+    obs, _ = _observed(_plane(), monkeypatch, tmp_path, modules=NESTED)
+    values = {n: load_module("layer_metrics", n).read(obs) for n in SIX}
+    assert values["model_forward_device_ms"] == pytest.approx(2 * 125e-6)
+    assert values["model_backward_device_ms"] == pytest.approx(2 * 205e-6)
+    step_ms = load_module("layer_metrics", "step_device_ms").read(obs)
+    share = load_module("layer_metrics", "step_unscoped_share").read(obs)
+    # the six, the unscoped and the one scope of no family make the step
+    assert (sum(values.values()) + share / 100 * step_ms
+            + hs.scope_ms(obs, "faa_not_of_any_family")) == pytest.approx(step_ms)
+
+
+def test_scope_ms_reports_nothing_where_the_split_does_not_hold(monkeypatch,
+                                                                tmp_path):
+    obs, _ = _observed(_plane(), monkeypatch, tmp_path)
+    obs.__dict__["trace"] = None
+    assert hs.scope_ms(obs, "faa_model") is None
+    fewer = {"jit_multi_fn": {k: v for k, v in NESTED["jit_multi_fn"].items()
+                              if k != "fusion.4"}}  # 25% left unexplained
+    obs, _ = _observed(_plane(), monkeypatch, tmp_path, modules=fewer)
+    assert hs.scope_ms(obs, "faa_shake_drop") is None
+
+
+def test_the_table_prints_a_nested_scope_under_its_parent():
+    nested = hs.split_plane(_plane(), "^jit_multi_fn", NESTED, scopes)
+    lines = hs.format_table("train_dispatch", nested, scopes)
+    at = {line.split()[0]: i for i, line in enumerate(lines)
+          if line.startswith("    ")}
+    indent = {line.split()[0]: len(line) - len(line.lstrip()) for line in lines
+              if line.startswith("    ")}
+    assert at["faa_model"] < at["faa_model/backward"] < at["faa_shake_drop"] \
+        < at["faa_shake_drop/backward"] < at["faa_optimizer"]
+    assert indent["faa_shake_drop"] == indent["faa_model"] + 2
+    assert indent["faa_aug_op_Equalize"] == indent["faa_aug_policy"] + 2
+    assert indent["faa_aug_op_Invert"] == indent["faa_aug_policy"] + 2  # reads 0
 
 
 def test_a_split_that_leaves_a_fifth_unexplained_is_no_split(monkeypatch, tmp_path):
