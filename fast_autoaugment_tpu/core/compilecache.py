@@ -73,7 +73,9 @@ __all__ = [
     "process_is_warm",
     "ScopeMapError",
     "parse_scope_map",
+    "parse_scope_members",
     "scope_map",
+    "scope_members",
 ]
 
 logger = get_logger("faa_tpu.compilecache")
@@ -353,19 +355,11 @@ _HLO_CALLED = re.compile(
     r"branch_computations|called_computations)=(?:\{([^}]*)\}|(%?[\w.\-]+))")
 
 
-def parse_scope_map(hlo_text: str) -> tuple[str, dict[str, str]]:
-    """``(module_name, {instruction_name: op_name})`` from a compiled
-    module's text, over all its computations.
-
-    Two fallbacks name what carries no ``faa_`` scope of its own.  An
-    instruction that calls computations (a fusion, a while loop) takes
-    the ``op_name`` of the commonest scope chain among the instructions
-    it calls, callees before callers.  Then an instruction that is still
-    unnamed takes the ``op_name`` of the instruction that calls its
-    computation, callers before callees: the loop XLA:TPU makes of a
-    batched ``dynamic_slice`` has a named ``while`` and a body without a
-    single ``op_name``.  What neither reaches keeps its own name, which
-    may be empty."""
+def _parse_hlo(hlo_text: str):
+    """``(module_name, own, members, calls)`` of a compiled module's text:
+    every instruction's own ``op_name`` (may be empty), the instructions
+    of every computation in the order printed, and the computations an
+    instruction calls."""
     module = ""
     own: dict[str, str] = {}
     members: dict[str, list[str]] = {}      # computation -> instructions
@@ -397,6 +391,23 @@ def parse_scope_map(hlo_text: str) -> tuple[str, dict[str, str]]:
                   for c in (several or one).split(",")]
         if called:
             calls[name] = called
+    return module, own, members, calls
+
+
+def parse_scope_map(hlo_text: str) -> tuple[str, dict[str, str]]:
+    """``(module_name, {instruction_name: op_name})`` from a compiled
+    module's text, over all its computations.
+
+    Two fallbacks name what carries no ``faa_`` scope of its own.  An
+    instruction that calls computations (a fusion, a while loop) takes
+    the ``op_name`` of the commonest scope chain among the instructions
+    it calls, callees before callers.  Then an instruction that is still
+    unnamed takes the ``op_name`` of the instruction that calls its
+    computation, callers before callees: the loop XLA:TPU makes of a
+    batched ``dynamic_slice`` has a named ``while`` and a body without a
+    single ``op_name``.  What neither reaches keeps its own name, which
+    may be empty."""
+    module, own, members, calls = _parse_hlo(hlo_text)
 
     resolved: dict[str, str] = {}
 
@@ -430,6 +441,28 @@ def parse_scope_map(hlo_text: str) -> tuple[str, dict[str, str]]:
     return module, resolved
 
 
+def parse_scope_members(hlo_text: str) -> tuple[str, dict[str, tuple[str, ...]]]:
+    """``(module_name, {instruction_name: scopes})``: every scope that an
+    instruction or anything it calls, however deep, carries in its own
+    ``op_name``; instructions that hold none are left out.  A fusion has
+    one ``op_name``, its root's, and :func:`parse_scope_map` files its
+    whole time there; this is the other reading, by membership: which
+    fusions a scope's instructions ended up in, where XLA fused them
+    into a neighbour's (an elementwise mix into the BatchNorm before
+    it)."""
+    module, own, members, calls = _parse_hlo(hlo_text)
+    held: dict[str, frozenset] = {}
+
+    def hold(name: str) -> frozenset:
+        if name not in held:
+            held[name] = frozenset(scopes.scope_of(own[name])).union(
+                *(hold(callee) for comp in calls.get(name, ())
+                  for callee in members.get(comp, ())))
+        return held[name]
+
+    return module, {name: tuple(sorted(hold(name))) for name in own if hold(name)}
+
+
 def scope_map(label: str) -> dict[str, dict[str, str]]:
     """``{hlo_module_name: {instruction_name: op_name}}`` of the programs
     compiled under seam `label` that have made their first call and are
@@ -447,12 +480,8 @@ def scope_map(label: str) -> dict[str, dict[str, str]]:
     cache's key leaves metadata out, so an executable cached by a
     checkout from before the scopes comes back on a hit with its old
     metadata."""
-    with _lock:
-        wrapped = list(_called.get(label, ()))
     out: dict[str, dict[str, str]] = {}
-    for fn in wrapped:
-        args, kwargs = fn._first_call_specs
-        text = fn._jitted.lower(*args, **kwargs).compile().as_text()
+    for text in _compiled_texts(label):
         module, table = parse_scope_map(text)
         if not any(scopes.scope_of(op_name) for op_name in table.values()):
             raise ScopeMapError(
@@ -464,6 +493,31 @@ def scope_map(label: str) -> dict[str, dict[str, str]]:
                 f"again")
         out.setdefault(module, {}).update(table)
     return out
+
+
+def scope_members(label: str) -> dict[str, dict[str, tuple[str, ...]]]:
+    """``{hlo_module_name: {instruction_name: scopes}}`` of the same
+    programs at the same cost as :func:`scope_map`: for every instruction
+    the scopes found anywhere inside it (:func:`parse_scope_members`), so
+    that a reader can sum the time of the fusions that *hold* a scope's
+    work where none is rooted in it."""
+    out: dict[str, dict[str, tuple[str, ...]]] = {}
+    for text in _compiled_texts(label):
+        module, table = parse_scope_members(text)
+        out.setdefault(module, {}).update(table)
+    return out
+
+
+def _compiled_texts(label: str) -> list[str]:
+    """The compiled text of each live program of seam `label`, lowered
+    again from the abstract arguments of its first call."""
+    with _lock:
+        wrapped = list(_called.get(label, ()))
+    texts = []
+    for fn in wrapped:
+        args, kwargs = fn._first_call_specs
+        texts.append(fn._jitted.lower(*args, **kwargs).compile().as_text())
+    return texts
 
 
 def _reset_stats_for_tests() -> None:

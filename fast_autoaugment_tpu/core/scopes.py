@@ -28,6 +28,8 @@ __all__ = [
     "AUG_OP_PREFIX",
     "AUG_FIXED",
     "MODEL",
+    "SHAKE_MIX",
+    "SHAKE_SHORTCUT",
     "LOSS",
     "OPTIMIZER",
     "EMA",
@@ -50,6 +52,12 @@ AUG_FIXED = "faa_aug_fixed"
 #: ``train/steps.py::loss_fn``: forward under ``jvp(...)``, backward
 #: under ``transpose(jvp(...))``
 MODEL = "faa_model"
+#: ``models/shake_resnet.py``, both nested under ``faa_model``: the noise
+#: draw and the mix of a block's two branches (``_ShakeMix``; its backward
+#: instructions come out of ``ops/shake.py``'s ``custom_vjp`` rule, not out
+#: of a transpose), and the two-path strided ``Shortcut``
+SHAKE_MIX = "faa_shake_mix"
+SHAKE_SHORTCUT = "faa_shake_shortcut"
 LOSS = "faa_loss"
 #: ``train/steps.py::step_fn``: update and parameter add; EMA; top-k and sums
 OPTIMIZER = "faa_optimizer"

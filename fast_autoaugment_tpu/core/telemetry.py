@@ -116,6 +116,7 @@ EVENT_TYPES = frozenset({
     "preempt",        # a preemption/hang was honored (exit-77 path)
     "phase",          # a phase window (phase-1 fold train, phase-2 fold)
     "mark",           # free-form marker (tools, tests)
+    "model",          # the trainer built its state: family, size, batch
     "round",          # fleet-search round transport: publish/claim/return/apply
     "rotation",       # a router ejected / re-admitted a serving replica
     "tenant",         # multi-policy tenancy admit/evict/warm (serve LRU)

@@ -17,9 +17,11 @@ from __future__ import annotations
 
 from typing import Any
 
+import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from fast_autoaugment_tpu.core import scopes
 from fast_autoaugment_tpu.models.layers import BatchNorm, global_avg_pool, he_normal_fanout
 from fast_autoaugment_tpu.ops.shake import (
     sample_shake_shake_noise,
@@ -58,14 +60,15 @@ class Shortcut(nn.Module):
 
     @nn.compact
     def __call__(self, x, train: bool):
-        h = nn.relu(x)
-        s = self.stride
-        h1 = h[:, ::s, ::s, :]
-        h1 = _conv(self.out_ch // 2, 1, dtype=self.dtype, name="conv1")(h1)
-        # F.pad(h, (-1, 1, -1, 1)): crop first row/col, pad one at the end
-        h2 = jnp.pad(h[:, 1:, 1:, :], ((0, 0), (0, 1), (0, 1), (0, 0)))[:, ::s, ::s, :]
-        h2 = _conv(self.out_ch // 2, 1, dtype=self.dtype, name="conv2")(h2)
-        return BatchNorm(name="bn")(jnp.concatenate([h1, h2], axis=-1), train)
+        with jax.named_scope(scopes.SHAKE_SHORTCUT):
+            h = nn.relu(x)
+            s = self.stride
+            h1 = h[:, ::s, ::s, :]
+            h1 = _conv(self.out_ch // 2, 1, dtype=self.dtype, name="conv1")(h1)
+            # F.pad(h, (-1, 1, -1, 1)): crop first row/col, pad one at the end
+            h2 = jnp.pad(h[:, 1:, 1:, :], ((0, 0), (0, 1), (0, 1), (0, 0)))[:, ::s, ::s, :]
+            h2 = _conv(self.out_ch // 2, 1, dtype=self.dtype, name="conv2")(h2)
+            return BatchNorm(name="bn")(jnp.concatenate([h1, h2], axis=-1), train)
 
 
 class _ShakeBranchBasic(nn.Module):
@@ -110,11 +113,12 @@ class _ShakeMix(nn.Module):
 
     @nn.compact
     def __call__(self, h1, h2, train: bool):
-        if train:
-            key = self.make_rng("shake")
-            alpha, beta = sample_shake_shake_noise(key, h1.shape[0], h1.dtype)
-            return shake_shake(h1, h2, alpha, beta)
-        return shake_shake_eval(h1, h2)
+        with jax.named_scope(scopes.SHAKE_MIX):
+            if train:
+                key = self.make_rng("shake")
+                alpha, beta = sample_shake_shake_noise(key, h1.shape[0], h1.dtype)
+                return shake_shake(h1, h2, alpha, beta)
+            return shake_shake_eval(h1, h2)
 
 
 class ShakeResNet(nn.Module):

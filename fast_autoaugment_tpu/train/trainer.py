@@ -401,6 +401,16 @@ def train_and_eval(
 
     optimizer = build_optimizer(optimizer_conf, lr_fn)
     state = create_train_state(model, optimizer, rng, sample, use_ema=ema_mu > 0.0)
+    # which family ran at what size, for the journal and /metrics (sizes
+    # from shapes: nothing waits for the device)
+    num_params = sum(int(p.size) for p in jax.tree.leaves(state.params))
+    model_type = str(model_conf["type"])
+    telemetry.registry().gauge(
+        "faa_model_parameters", "trainable parameters of the model a "
+        "trainer built", model=model_type).set(num_params)
+    telemetry.emit("model", model_type, parameters=num_params,
+                   batch_per_device=batch_per_device,
+                   steps_per_epoch=steps_per_epoch)
 
     policy = resolve_policy_tensor(conf.get("aug", "default"))
     use_policy = policy is not None

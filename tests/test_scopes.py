@@ -320,6 +320,42 @@ def test_parse_scope_map_reads_every_computation_and_votes_for_fusions():
     assert table["copy.7"] == "" and table["Arg_0.1"] == "x"
 
 
+def test_parse_scope_members_finds_a_scope_in_a_fusion_rooted_elsewhere():
+    """By membership, not by root: ``fused_computation.5`` is rooted in
+    ``faa_aug_fixed`` and holds two Equalize instructions, so every
+    fusion that calls it holds all three scopes, whatever it is named."""
+    module, held = cc.parse_scope_members(HLO_TEXT)
+    assert module == "jit_multi_fn"
+    inside = ("faa_aug_fixed", "faa_aug_op_Equalize", "faa_aug_policy")
+    assert held["fusion.2361"] == inside and held["fusion.11"] == inside
+    # its own name is counted with what it calls
+    assert held["fusion.9"] == tuple(sorted(inside + ("faa_optimizer",)))
+    # through a loop: body and condition, however deep
+    assert held["while.3"] == inside
+    assert held["while.58"] == ("faa_aug_fixed",)
+    assert held["reduce.1"] == ("faa_model",)
+    # a single instruction holds its own chain; one without a scope is left out
+    assert held["add.3"] == ("faa_aug_op_Equalize", "faa_aug_policy")
+    assert "copy.7" not in held and "dynamic-slice.250" not in held
+    # where the two readings part: the map files fusion.2361 under Equalize alone
+    _, table = cc.parse_scope_map(HLO_TEXT)
+    assert "faa_aug_fixed" not in scopes.scope_of(table["fusion.2361"])
+
+
+def test_scope_members_reads_the_compiled_program_of_a_label():
+    def multi_fn(x):
+        with jax.named_scope(scopes.MODEL):
+            y = jnp.sin(x)
+            with jax.named_scope(scopes.SHAKE_MIX):
+                return y * 2.0
+
+    cc.seam_jit(multi_fn, label="t_scope_members")(jnp.arange(8.0))
+    held = cc.scope_members("t_scope_members")["jit_multi_fn"]
+    assert (scopes.MODEL, scopes.SHAKE_MIX) in set(held.values())
+    assert set(held) <= set(cc.scope_map("t_scope_members")["jit_multi_fn"])
+    assert cc.scope_members("t_scope_no_such_label") == {}
+
+
 def test_parse_scope_map_takes_names_without_the_percent_sign():
     module, table = cc.parse_scope_map(HLO_TEXT.replace("%", ""))
     assert module == "jit_multi_fn"
