@@ -26,6 +26,7 @@ __all__ = [
     "BATCH_GATHER",
     "AUG_POLICY",
     "AUG_OP_PREFIX",
+    "AUG_WARP",
     "AUG_FIXED",
     "MODEL",
     "SHAKE_MIX",
@@ -47,6 +48,10 @@ BATCH_GATHER = "faa_batch_gather"
 AUG_POLICY = "faa_aug_policy"
 #: ``ops/augment.py::_call_op``: one operation's branch, ``faa_aug_op_<Name>``
 AUG_OP_PREFIX = "faa_aug_op_"
+#: ``ops/augment.py::_warp_affine_nearest``, nested in ``faa_aug_policy``:
+#: the resampling of the seven affine operations, once an op slot; their
+#: own ``faa_aug_op_<Name>`` scopes hold a 2x3 matrix each
+AUG_WARP = "faa_aug_warp"
 #: ``ops/preprocess.py``: crop, flip, normalize, cutout: what every recipe pays
 AUG_FIXED = "faa_aug_fixed"
 #: ``train/steps.py::loss_fn``: forward under ``jvp(...)``, backward

@@ -15,7 +15,9 @@ Semantics were pinned against PIL empirically and are exact (see
 
 - affine/rotate: nearest-neighbor, ``src = floor(A @ (x, y) + t + 0.5)``,
   fill 0, rotate about ``(W/2, H/2)``  (PIL ``Image.transform``
-  with ``AFFINE`` / ``Image.rotate``, reference ``augmentations.py:17-62``)
+  with ``AFFINE`` / ``Image.rotate``, reference ``augmentations.py:17-62``);
+  the seven operations are seven 2x3 matrices into ONE warp, which up
+  to 384 px addresses its source pixels by one-hot products, no gather
 - L (grayscale): ``(r*19595 + g*38470 + b*7471 + 0x8000) >> 16``
 - enhance ops: ``clip(trunc(deg + (img - deg) * factor), 0, 255)`` in
   float32 (PIL ``ImageEnhance`` via ``Image.blend``)
@@ -43,7 +45,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from fast_autoaugment_tpu.core import scopes
+from fast_autoaugment_tpu.core import scopes, telemetry
 
 __all__ = [
     "OP_NAMES",
@@ -99,7 +101,7 @@ CUTOUT_COLOR = (125.0, 123.0, 114.0)  # reference augmentations.py:140
 
 # dispatch modes for batched policy application: "exact" is the i.i.d.
 # per-image sub-policy draw (vmapped lax.switch — XLA lowers the batched
-# op index to executing ALL 19 branches per image and selecting one);
+# op index to executing ALL 13 branches per image and selecting one);
 # "grouped" keeps the switch index SCALAR inside the compiled program
 # (stratified per-chunk sub-policy draws; one branch executes).
 AUG_DISPATCH_MODES = ("exact", "grouped")
@@ -144,6 +146,74 @@ def _blend(degenerate: jax.Array, img: jax.Array, factor: jax.Array) -> jax.Arra
     return jnp.clip(jnp.trunc(out), 0.0, 255.0)
 
 
+# The warp addresses its source pixels in one of two ways, chosen by the
+# static image shape it is traced with.  Dense: two one-hot products, no
+# data-dependent addressing (a gather is the slowest thing the TPU does:
+# 9 ns a row of three floats, whatever the image).  The dense form's work
+# grows as (H*W)^2*C an image where the gather's grows as H*W, so there is
+# a size where it stops winning: alone on a v5e it takes 2.1 ms against
+# 18.9 at 2,048 x 32 px, 25.5 against 63.2 at 128 x 224 px, 38.4 against
+# 49.0 at 32 x 384 px, and loses at 448 px (PERF.md section 6, PR 29).
+# Its intermediate (the rows picked for every output pixel) grows as
+# H*W*W*C an image, 196 KB at 32 px and 67 MB at 224: the batch runs in
+# chunks whose rows fit the budget, which costs no time (PERF.md).
+_DENSE_WARP_MAX_PIXELS = 384 * 384
+_DENSE_WARP_BUDGET_BYTES = 256 << 20
+
+
+def _resample_dense_one(img, sy, sx):
+    """``img[sy, sx]`` with zero fill, as two one-hot selections through
+    the MXU and nothing addressed by data.
+
+    Rows: ``[H*W, H] x [H, W*C]`` picks the source row of every output
+    pixel.  Columns: that row masked down to the source column's C
+    lanes, then ``[H*W, W*C] x [W*C, C]`` against a constant selector
+    sums the lanes of each channel.  Exact, whatever the ambient matmul
+    precision: every sum is of one pixel value and zeros, and pixel
+    values are integers in [0, 255], which bfloat16 holds.  An index out
+    of range matches no row or lane, which is the zero fill."""
+    h, w, c = img.shape
+    contract = (((1,), (0,)), ((), ()))
+    rows = jax.lax.dot_general(
+        (sy.reshape(-1, 1) == jnp.arange(h)).astype(jnp.bfloat16),
+        img.reshape(h, w * c).astype(jnp.bfloat16),
+        contract, preferred_element_type=jnp.bfloat16)
+    # jnp.arange, not np: the batching of a switch would batch a constant
+    # this function closed over, which a custom_vmap rule refuses
+    lane = jnp.arange(w * c)
+    masked = jnp.where(sx.reshape(-1, 1) == lane // c, rows, 0)
+    channel_of = (lane[:, None] % c == jnp.arange(c)).astype(jnp.bfloat16)
+    out = jax.lax.dot_general(masked, channel_of, contract,
+                              preferred_element_type=jnp.float32)
+    return out.reshape(h, w, c)
+
+
+@jax.custom_batching.custom_vmap
+def _resample_dense(imgs, sy, sx):
+    """:func:`_resample_dense_one` over a leading axis of N images.
+
+    Its ``vmap`` rule folds every batch axis a caller adds into N, so this
+    body is traced with the whole batch in sight, which a per-image
+    function under ``vmap`` never is, and can bound what it allocates."""
+    n, h, w, c = imgs.shape
+    chunk = max(1, _DENSE_WARP_BUDGET_BYTES // (h * w * w * c * 2))  # bfloat16 rows
+    if n <= chunk:
+        return jax.vmap(_resample_dense_one)(imgs, sy, sx)
+    return jax.lax.map(lambda a: _resample_dense_one(*a), (imgs, sy, sx),
+                       batch_size=chunk)
+
+
+@_resample_dense.def_vmap
+def _resample_dense_fold(axis_size, in_batched, *args):
+    folded = []
+    for x, batched in zip(args, in_batched):  # [B, N, ...] or [N, ...]
+        if not batched:
+            x = jnp.broadcast_to(x, (axis_size,) + x.shape)
+        folded.append(x.reshape((-1,) + x.shape[2:]))
+    out = _resample_dense(*folded)
+    return out.reshape((axis_size, -1) + out.shape[1:]), True
+
+
 def _warp_affine_nearest(img: jax.Array, mat: jax.Array) -> jax.Array:
     """PIL-exact nearest affine warp with zero fill.
 
@@ -153,13 +223,22 @@ def _warp_affine_nearest(img: jax.Array, mat: jax.Array) -> jax.Array:
     empirically; the center offset matters for tie-breaking at .5).
     """
     h, w = img.shape[0], img.shape[1]
-    ys, xs = jnp.mgrid[0:h, 0:w]
-    xsf, ysf = xs.astype(jnp.float32) + 0.5, ys.astype(jnp.float32) + 0.5
-    sx = jnp.floor(mat[0, 0] * xsf + mat[0, 1] * ysf + mat[0, 2]).astype(jnp.int32)
-    sy = jnp.floor(mat[1, 0] * xsf + mat[1, 1] * ysf + mat[1, 2]).astype(jnp.int32)
-    valid = (sx >= 0) & (sx < w) & (sy >= 0) & (sy < h)
-    gathered = img[jnp.clip(sy, 0, h - 1), jnp.clip(sx, 0, w - 1)]
-    return jnp.where(valid[..., None], gathered, 0.0)
+    dense = h * w <= _DENSE_WARP_MAX_PIXELS
+    # trace time: which addressing each program that holds a warp got
+    telemetry.registry().counter(
+        "faa_aug_warp_traces_total", "affine warps traced into a program, "
+        "by how they address their source pixels",
+        form="dense" if dense else "gather", image=f"{h}x{w}").inc()
+    with jax.named_scope(scopes.AUG_WARP):
+        ys, xs = jnp.mgrid[0:h, 0:w]
+        xsf, ysf = xs.astype(jnp.float32) + 0.5, ys.astype(jnp.float32) + 0.5
+        sx = jnp.floor(mat[0, 0] * xsf + mat[0, 1] * ysf + mat[0, 2]).astype(jnp.int32)
+        sy = jnp.floor(mat[1, 0] * xsf + mat[1, 1] * ysf + mat[1, 2]).astype(jnp.int32)
+        if dense:
+            return _resample_dense(img[None], sy[None], sx[None])[0]
+        valid = (sx >= 0) & (sx < w) & (sy >= 0) & (sy < h)
+        gathered = img[jnp.clip(sy, 0, h - 1), jnp.clip(sx, 0, w - 1)]
+        return jnp.where(valid[..., None], gathered, 0.0)
 
 
 _LEVELS = np.arange(256, dtype=np.int32)
@@ -184,45 +263,85 @@ def _count_below(ii: jax.Array) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
-def shear_x(img, v, key):
-    return _warp_affine_nearest(img, jnp.array([[1.0, 0.0, 0.0], [0.0, 1.0, 0.0]]).at[0, 1].set(v))
+def _identity_matrix() -> jax.Array:
+    return jnp.array([[1.0, 0.0, 0.0], [0.0, 1.0, 0.0]])
 
 
-def shear_y(img, v, key):
-    return _warp_affine_nearest(img, jnp.array([[1.0, 0.0, 0.0], [0.0, 1.0, 0.0]]).at[1, 0].set(v))
+def shear_x_matrix(v, h, w):
+    return _identity_matrix().at[0, 1].set(v)
 
 
-def translate_x(img, v, key):
+def shear_y_matrix(v, h, w):
+    return _identity_matrix().at[1, 0].set(v)
+
+
+def translate_x_matrix(v, h, w):
     # fractional of width (reference augmentations.py:28-33)
-    shift = v * img.shape[1]
-    return _warp_affine_nearest(img, jnp.array([[1.0, 0.0, 0.0], [0.0, 1.0, 0.0]]).at[0, 2].set(shift))
+    return _identity_matrix().at[0, 2].set(v * w)
 
 
-def translate_y(img, v, key):
-    shift = v * img.shape[0]
-    return _warp_affine_nearest(img, jnp.array([[1.0, 0.0, 0.0], [0.0, 1.0, 0.0]]).at[1, 2].set(shift))
+def translate_y_matrix(v, h, w):
+    return _identity_matrix().at[1, 2].set(v * h)
 
 
-def translate_x_abs(img, v, key):
-    return _warp_affine_nearest(img, jnp.array([[1.0, 0.0, 0.0], [0.0, 1.0, 0.0]]).at[0, 2].set(v))
+def translate_x_abs_matrix(v, h, w):
+    return _identity_matrix().at[0, 2].set(v)
 
 
-def translate_y_abs(img, v, key):
-    return _warp_affine_nearest(img, jnp.array([[1.0, 0.0, 0.0], [0.0, 1.0, 0.0]]).at[1, 2].set(v))
+def translate_y_abs_matrix(v, h, w):
+    return _identity_matrix().at[1, 2].set(v)
 
 
-def rotate(img, v, key):
-    """PIL Image.rotate(v): CCW degrees about (W/2, H/2), nearest."""
-    h, w = img.shape[0], img.shape[1]
+def rotate_matrix(v, h, w):
+    """PIL Image.rotate(v): CCW degrees about (W/2, H/2)."""
     cx, cy = w / 2.0, h / 2.0
     rad = v * (np.pi / 180.0)
     ca, sa = jnp.cos(rad), jnp.sin(rad)
-    mat = jnp.array(
-        [[1.0, 0.0, 0.0], [0.0, 1.0, 0.0]]
-    )
+    mat = _identity_matrix()
     mat = mat.at[0, 0].set(ca).at[0, 1].set(-sa).at[0, 2].set(cx - ca * cx + sa * cy)
     mat = mat.at[1, 0].set(sa).at[1, 1].set(ca).at[1, 2].set(cy - sa * cx - ca * cy)
-    return _warp_affine_nearest(img, mat)
+    return mat
+
+
+# the seven affine operations differ in their matrix alone: (value, H, W)
+# -> the 2x3 inverse map :func:`_warp_affine_nearest` takes
+_AFFINE_MATRIX_FNS = {
+    "ShearX": shear_x_matrix,
+    "ShearY": shear_y_matrix,
+    "TranslateX": translate_x_matrix,
+    "TranslateY": translate_y_matrix,
+    "Rotate": rotate_matrix,
+    "TranslateXAbs": translate_x_abs_matrix,
+    "TranslateYAbs": translate_y_abs_matrix,
+}
+
+
+def shear_x(img, v, key):
+    return _warp_affine_nearest(img, shear_x_matrix(v, *img.shape[:2]))
+
+
+def shear_y(img, v, key):
+    return _warp_affine_nearest(img, shear_y_matrix(v, *img.shape[:2]))
+
+
+def translate_x(img, v, key):
+    return _warp_affine_nearest(img, translate_x_matrix(v, *img.shape[:2]))
+
+
+def translate_y(img, v, key):
+    return _warp_affine_nearest(img, translate_y_matrix(v, *img.shape[:2]))
+
+
+def translate_x_abs(img, v, key):
+    return _warp_affine_nearest(img, translate_x_abs_matrix(v, *img.shape[:2]))
+
+
+def translate_y_abs(img, v, key):
+    return _warp_affine_nearest(img, translate_y_abs_matrix(v, *img.shape[:2]))
+
+
+def rotate(img, v, key):
+    return _warp_affine_nearest(img, rotate_matrix(v, *img.shape[:2]))
 
 
 def auto_contrast(img, v, key):
@@ -385,10 +504,10 @@ def apply_augment(img: jax.Array, name: str, level, key: jax.Array) -> jax.Array
 
 @functools.lru_cache(maxsize=None)
 def _op_range_constants():
-    """Device-resident (low, high, mirror) op-range tables, built ONCE.
+    """Device-resident (low, high, mirror, branch) op tables, built ONCE.
 
     ``apply_op`` used to call ``jnp.asarray(_OP_LOW)`` (and friends) per
-    invocation, rebuilding the three constants on every trace.  Lazy
+    invocation, rebuilding the constants on every trace.  Lazy
     (not module-level) so importing this module never eagerly
     initializes a JAX backend — bench tools probe backend liveness
     before touching the device.  ``ensure_compile_time_eval`` keeps the
@@ -396,7 +515,48 @@ def _op_range_constants():
     trace (a cached tracer would escape its trace scope)."""
     with jax.ensure_compile_time_eval():
         return (jnp.asarray(_OP_LOW), jnp.asarray(_OP_HIGH),
-                jnp.asarray(_OP_MIRROR))
+                jnp.asarray(_OP_MIRROR), jnp.asarray(_BRANCH_OF))
+
+
+def op_affine_matrix(op_idx: jax.Array, value: jax.Array, h: int, w: int) -> jax.Array:
+    """The 2x3 matrix of op `op_idx` (traced scalar) at `value` on an
+    ``h x w`` image: its builder's for the seven affine operations, the
+    identity for the twelve that resample nothing."""
+    def branch(name):
+        build = _AFFINE_MATRIX_FNS.get(name)
+        if build is None:
+            return lambda v: _identity_matrix()
+
+        def scoped(v):
+            with jax.named_scope(scopes.aug_op(name)):
+                return build(v, h, w)
+        return scoped
+
+    return jax.lax.switch(op_idx, [branch(name) for name in OP_NAMES], value)
+
+
+def _warp_op(img, value, key, op_idx):
+    """The one branch of the seven affine operations: the matrix is
+    chosen by `op_idx`, the resampling runs once."""
+    mat = op_affine_matrix(op_idx, value, img.shape[0], img.shape[1])
+    return _warp_affine_nearest(img, mat)
+
+
+def _call_op(fn, scope, img, value, key, op_idx):
+    # the one place every other branch passes: its device time is read by name
+    with jax.named_scope(scope):
+        return fn(img, value, key)
+
+
+# the switch of :func:`apply_op`: a branch for each of the twelve other
+# operations, then the warp; `_BRANCH_OF` maps an op index to its branch
+_PLAIN_OPS = tuple(n for n in OP_NAMES if n not in _AFFINE_MATRIX_FNS)
+_BRANCHES = tuple(
+    functools.partial(_call_op, _OP_FNS[op_index(name)], scopes.aug_op(name))
+    for name in _PLAIN_OPS) + (_warp_op,)
+_BRANCH_OF = np.array(
+    [_PLAIN_OPS.index(name) if name in _PLAIN_OPS else len(_PLAIN_OPS)
+     for name in OP_NAMES], np.int32)
 
 
 def apply_op(img: jax.Array, op_idx: jax.Array, level: jax.Array, key: jax.Array) -> jax.Array:
@@ -405,9 +565,13 @@ def apply_op(img: jax.Array, op_idx: jax.Array, level: jax.Array, key: jax.Array
     Maps level -> value = level*(high-low)+low and flips the sign with
     prob 0.5 for mirrored (geometric) ops, then dispatches via
     ``lax.switch`` so the op id can be a runtime tensor (policy-as-data).
+    The switch has 13 branches, not 19: the seven affine operations
+    share :func:`_warp_op`, so a batched index (which executes every
+    branch) pays for one resampling, and a scalar index on any other
+    operation for none.
     """
     key_mirror, key_op = jax.random.split(key)
-    op_low, op_high, op_mirror = _op_range_constants()
+    op_low, op_high, op_mirror, branch_of = _op_range_constants()
     low = op_low[op_idx]
     high = op_high[op_idx]
     value = level * (high - low) + low
@@ -416,15 +580,7 @@ def apply_op(img: jax.Array, op_idx: jax.Array, level: jax.Array, key: jax.Array
         mirrored & (jax.random.uniform(key_mirror) > 0.5), -1.0, 1.0
     )
     value = value * sign
-    branches = [functools.partial(_call_op, fn, scopes.aug_op(name))
-                for fn, name in zip(_OP_FNS, OP_NAMES)]
-    return jax.lax.switch(op_idx, branches, img, value, key_op)
-
-
-def _call_op(fn, scope, img, value, key):
-    # the one place every branch passes: its device time is read by name
-    with jax.named_scope(scope):
-        return fn(img, value, key)
+    return jax.lax.switch(branch_of[op_idx], _BRANCHES, img, value, key_op, op_idx)
 
 
 def apply_subpolicy(img: jax.Array, subpolicy: jax.Array, key: jax.Array) -> jax.Array:
@@ -465,8 +621,9 @@ def apply_policy_batch(images: jax.Array, policy: jax.Array, key: jax.Array) -> 
 
     This is the EXACT dispatch path: every image draws its sub-policy
     i.i.d., which makes the ``lax.switch`` op index a batched tensor —
-    XLA lowers that to executing all ``NUM_OPS`` branches for every
-    image per op slot and selecting one (~19x redundant op compute).
+    XLA lowers that to executing all 13 branches of :func:`apply_op`
+    for every image per op slot and selecting one (the twelve other
+    operations and one warp, where one operation would do).
     :func:`apply_policy_batch_grouped` is the scalar-dispatch
     alternative."""
     keys = jax.random.split(key, images.shape[0])

@@ -80,7 +80,7 @@ def make_tta_step(model, *, num_policy: int = 5, cutout_length: int = 16,
     for ``num_candidates=K`` the candidate axis) is traversed with
     ``lax.map`` instead of ``vmap`` so the per-chunk sub-policy indices
     stay SCALAR — a vmapped axis would re-batch them and XLA would fall
-    back to executing all 19 op branches.  The model forward still runs
+    back to executing every op branch.  The model forward still runs
     on the full flattened batch either way.  A custom `augment_fn`
     combined with grouped dispatch owns its own internal dispatch; this
     function only serializes the outer axes for it.
