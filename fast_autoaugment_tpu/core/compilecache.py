@@ -206,8 +206,8 @@ class _SeamWrapped:
     """A jitted callable instrumented at its first invocation.
 
     Transparent otherwise: ``lower``/``_cache_size``/every other
-    attribute delegates to the wrapped jit object (``bench.py`` AOT-
-    lowers through ``.lower``; ``search/census.py`` probes
+    attribute delegates to the wrapped jit object (a caller may AOT-
+    lower through ``.lower``; ``search/census.py`` probes
     ``_cache_size``), and post-first-call invocations are a single
     attribute load + call on top of the C++ fast dispatch path.
     """
@@ -291,7 +291,7 @@ def aot_compile(fn: Callable, *, label: str, example_args: tuple,
     `donate_argnums` compiles a DONATING executable: the named input
     buffers alias the outputs, so the device never holds input and
     output live at once — the zero-allocation serving dispatch
-    (docs/BENCHMARKS.md "Serving data plane").  A donated input is
+    (docs/SERVING.md "Serving data plane").  A donated input is
     deleted by the dispatch and must never be read after it; the output
     is bitwise-identical to the undonated executable's, which the
     donation tests pin.

@@ -45,13 +45,11 @@ substrate, shared by train/search/serve/fleet:
 Defaults are bit-for-bit: the journal and every exporter sit behind
 ``--telemetry {off,DIR}`` / ``FAA_TELEMETRY`` (off = no file I/O, no
 new artifact keys, :func:`emit` is a None check), and the registry
-never touches numerics.  Overhead with telemetry fully ON is bounded
-and measured (``make bench-dispatch`` comparison row): a fixed
-~26-39 µs per DISPATCH on this host — ≤1% steps/s for any dispatch
-wall ≥ ~3 ms, i.e. every real model configuration; the conv-free
-2 kHz dispatch stress probe pays 7.6% by design
-(docs/OBSERVABILITY.md "Overhead" — rate-budgeted journal slices,
-interval-buffered flushing, cached metric fast path).
+never touches numerics.  Overhead with telemetry fully ON is a fixed
+host cost per DISPATCH (tens of microseconds on a CPU host; not
+measured on the chip), bounded by design: rate-budgeted journal
+slices, interval-buffered flushing, a cached metric fast path
+(docs/OBSERVABILITY.md "Overhead").
 
 Lint rule R8 (``tools/lint_robustness.py``) keeps raw
 ``time.time()``/``time.perf_counter()`` out of the train/search/serve

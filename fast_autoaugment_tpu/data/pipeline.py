@@ -310,7 +310,7 @@ class DeviceCache:
     Eager (in-memory) datasets only: a lazy dataset has nothing resident
     to gather from — ``resolve_device_cache`` gates it off.  HBM cost is
     the raw uint8 array (CIFAR-10 train: 50000*32*32*3 = 146 MiB; see
-    docs/BENCHMARKS.md "Step dispatch & device cache" for the budget
+    docs/PARITY.md "Step dispatch & device cache" for the budget
     math), divided across the data-axis shards.
     """
 
@@ -428,19 +428,19 @@ def num_train_steps(n_examples: int, global_batch: int) -> int:
 
 
 def default_prefetch_depth() -> int:
-    """Measured-default queue depth (docs/loader_bench.md): on a
-    single-core host the worker and consumer fight over the one CPU, so
-    any depth beyond 1 only adds queue contention (237 img/s at depth 1
-    vs ~180 at depth 2-8 on this box); with >=2 cores, 2 buffers the
-    decode burst while the consumer dispatches the previous batch."""
+    """Measured-default queue depth: on a single-core host the worker
+    and consumer fight over the one CPU, so any depth beyond 1 only
+    adds queue contention (237 img/s at depth 1 vs ~180 at depth 2-8
+    on a CPU host's decode); with >=2 cores, 2 buffers the decode
+    burst while the consumer dispatches the previous batch."""
     return 1 if (os.cpu_count() or 1) < 2 else 2
 
 
 def prefetch(iterator, depth: int | None = None, transform=None):
     """Run `iterator` in a background thread with a bounded queue —
     double-buffered host -> device feed.  `depth=None` uses
-    :func:`default_prefetch_depth` (cpu-count gated, measured in
-    docs/loader_bench.md).
+    :func:`default_prefetch_depth` (cpu-count gated; its docstring has
+    the measurement).
 
     ``depth=0`` (or ``FAA_PREFETCH_SYNC=1`` for default-depth callers —
     an explicit depth always wins) degrades to a synchronous inline

@@ -27,7 +27,7 @@ child process shares the persistent compile cache placed by
 ``JAX_COMPILATION_CACHE_DIR`` / the in-checkout default
 (``core/compilecache.py`` — the first scenario pays the AOT warm, the
 rest ride it) and the suite renders the verdict table
-docs/BENCHMARKS.md pins.
+(``make gameday`` writes it into docs/gameday.json).
 
 CPU-only: every child is spawned with ``JAX_PLATFORMS=cpu`` — several
 replicas at once cannot share one chip (one process owns it), so the

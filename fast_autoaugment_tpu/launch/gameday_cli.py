@@ -6,11 +6,11 @@ print the verdict table, exit 0 only when the SUITE is green (every
 verdict matched its spec's ``expect`` — a broken-config scenario that
 failed on cue is green; one that passed is not).
 
-The suite JSON (``--out``) carries the bench provenance stamps
-(``bench.py``: contention + ``single_core_caveat``) because a verdict
-captured on a contended host is evidence about the HOST, not the
-plane.  All filesystem work lives in the runner — this module stays
-FS-free (faalint F1 polices ``launch/``).
+The suite JSON (``--out``) carries two provenance stamps,
+``single_core_caveat`` and ``platform: cpu``: every plane process is
+spawned on the CPU, so a verdict says the plane behaved, never how
+fast a chip is.  All filesystem work lives in the runner — this module
+stays FS-free (faalint F1 polices ``launch/``).
 
 Examples::
 
@@ -25,11 +25,7 @@ Examples::
 from __future__ import annotations
 
 import argparse
-import os
 import sys
-
-_REPO = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -84,20 +80,10 @@ def main(argv=None) -> int:
               f"--list shows the registry", file=sys.stderr)
         return 2
 
-    # provenance stamps ride the suite JSON: a verdict captured on a
-    # contended host is evidence about the host, not the plane
-    # "platform": every plane process is spawned with JAX_PLATFORMS=cpu
-    # (gameday/runner.py) — a game day never touches the chip
+    # provenance stamps ride the suite JSON.  "platform": every plane
+    # process is spawned with JAX_PLATFORMS=cpu (gameday/runner.py) —
+    # a game day never touches the chip
     extra = {"single_core_caveat": True, "platform": "cpu"}
-    try:
-        if _REPO not in sys.path:
-            sys.path.insert(0, _REPO)
-        from bench import (host_contention_stamp,
-                           refuse_or_flag_contention, telemetry_stamp)
-        contention = refuse_or_flag_contention(host_contention_stamp())
-        extra.update(telemetry_stamp(contention=contention))
-    except ImportError:
-        pass  # running from an installed package without the bench kit
 
     from fast_autoaugment_tpu.gameday.runner import run_suite
     result = run_suite(names, smoke=args.smoke,

@@ -144,7 +144,7 @@ def build_parser() -> argparse.ArgumentParser:
                         "= the historical serial driver bit-for-bit; "
                         "'on' with --pipeline-actors 1 --pipeline-queue-"
                         "depth 0 reproduces the serial trial log exactly "
-                        "(docs/BENCHMARKS.md 'Search pipelining')")
+                        "(search/pipeline.py's docstring has the rule)")
     p.add_argument("--pipeline-actors", type=int, default=1,
                    help="device actor threads per fold in --async-"
                         "pipeline on (each runs one monitored TTA "
@@ -172,7 +172,7 @@ def build_parser() -> argparse.ArgumentParser:
                         "19 op branches per image); 'grouped' = scalar "
                         "dispatch (one branch executes; stratified "
                         "per-chunk sub-policy draws with identical "
-                        "per-image marginals — docs/BENCHMARKS.md "
+                        "per-image marginals — docs/PARITY.md "
                         "'Augmentation dispatch')")
     p.add_argument("--aug-groups", type=int, default=8,
                    help="chunks per batch for --aug-dispatch grouped "
@@ -194,7 +194,7 @@ def build_parser() -> argparse.ArgumentParser:
                         "(default) = on for in-memory single-host "
                         "datasets, bit-for-bit at --steps-per-dispatch 1; "
                         "lazy ImageNet datasets keep the prefetch path "
-                        "(docs/BENCHMARKS.md 'Step dispatch & device "
+                        "(docs/PARITY.md 'Step dispatch & device "
                         "cache')")
     p.add_argument("--steps-per-dispatch", type=int, default=1,
                    help="fuse N train steps into ONE dispatch (lax.scan "

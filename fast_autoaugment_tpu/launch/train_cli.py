@@ -46,7 +46,7 @@ def build_parser() -> argparse.ArgumentParser:
                         "the per-image vmapped-switch path bit-for-bit; "
                         "'grouped' keeps op dispatch scalar (one lax.switch "
                         "branch executes; stratified per-chunk sub-policy "
-                        "draws — docs/BENCHMARKS.md 'Augmentation dispatch')")
+                        "draws — docs/PARITY.md 'Augmentation dispatch')")
     p.add_argument("--aug-groups", type=int, default=8,
                    help="chunks per batch for --aug-dispatch grouped")
     p.add_argument("--device-cache", default="auto",
@@ -60,7 +60,7 @@ def build_parser() -> argparse.ArgumentParser:
                         "host feed at --steps-per-dispatch 1); lazy "
                         "ImageNet datasets keep the prefetch path; 'on' "
                         "errors where auto would fall back "
-                        "(docs/BENCHMARKS.md 'Step dispatch & device "
+                        "(docs/PARITY.md 'Step dispatch & device "
                         "cache')")
     p.add_argument("--steps-per-dispatch", type=int, default=1,
                    help="fuse N train steps into ONE dispatch (lax.scan "

@@ -683,7 +683,7 @@ def apply_policy_batch_grouped(images: jax.Array, policy: jax.Array,
     gating, mirror signs and op randomness remain exactly per-image.
 
     Distributional deviation vs the exact path (documented in
-    docs/BENCHMARKS.md "Augmentation dispatch"): sub-policy selection
+    docs/PARITY.md "Augmentation dispatch"): sub-policy selection
     is STRATIFIED — each batch sees fixed per-chunk counts instead of
     i.i.d. per-image draws.  The per-image marginal is unchanged (the
     uniform permutation makes every image's chunk — hence its

@@ -614,7 +614,7 @@ def search_policies(
     ``lax.switch`` op index scalar inside the compiled programs
     (single-branch execution; stratified per-chunk sub-policy draws in
     the multi-sub TTA step, bitwise-identical single-sub lanes in the
-    audit and the quality-gate baseline — see docs/BENCHMARKS.md
+    audit and the quality-gate baseline — see docs/PARITY.md
     "Augmentation dispatch").  Both settings are stamped into
     ``search_result.json``.  Phase-1 pretraining is policy-free, so the
     knob does not touch it.
@@ -670,9 +670,9 @@ def search_policies(
     deviate only the way a larger `trial_batch` does — pessimistic
     placeholder posteriors for in-flight rounds.  Accounting lands in
     ``search_result.json['pipeline']`` (mode, actors, queue_depth,
-    tell_reorders, device_busy_frac + the dispatch-gap histogram) —
-    ``tools/bench_pipeline.py`` / ``make bench-pipeline`` is the
-    measured serial-vs-async evidence.  Async mode is single-host:
+    tell_reorders, device_busy_frac + the dispatch-gap histogram);
+    the determinism rule is ``search/pipeline.py``'s docstring.
+    Async mode is single-host:
     `work_queue` forces it off (work units already scatter folds).
 
     `fleet_transport` (a :class:`~fast_autoaugment_tpu.search.pipeline.
@@ -1269,7 +1269,7 @@ def search_policies(
 
         # small budgets keep some TPE engagement: the hyperopt default
         # n_startup=20 leaves a 60-trial run barely out of the random
-        # phase (round-2 run; docs/tpe_benchmark.md)
+        # phase (round-2 run; docs/SEARCH_QUALITY.md)
         tpe = TPE(space, seed=seed * 1000 + fold,
                   n_startup=min(20, max(5, num_search // 4)))
         key_fold = jax.random.PRNGKey(seed * 77 + fold)

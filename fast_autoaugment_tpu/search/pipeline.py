@@ -44,10 +44,9 @@ thread and each fold is handed to phase-2 evaluation the moment its
 training (and quality gate) completes, while the remaining folds still
 train.
 
-:class:`DispatchTrace` records per-dispatch start/end timestamps so
-``tools/bench_pipeline.py`` can report the dispatch-gap histogram
-(p50/p99 inter-dispatch idle, device busy fraction) for serial vs async
-runs; ``search_result.json`` stamps the summary under ``pipeline``.
+:class:`DispatchTrace` records per-dispatch start/end timestamps: the
+dispatch-gap histogram (p50/p99 inter-dispatch idle, device busy
+fraction) that ``search_result.json`` stamps under ``pipeline``.
 """
 
 from __future__ import annotations
@@ -697,8 +696,7 @@ class FleetTransport:
     republishes byte-identical payloads onto the same units and adopts
     any results actors posted while it was down.  Journal evidence:
     typed ``round`` events (``publish``/``claim``/``return``/``apply``)
-    carry the transport latencies ``tools/bench_fleet_search.py``
-    reports."""
+    carry the transport latencies."""
 
     UNIT_PREFIX = "p2r-"
 

@@ -1,6 +1,5 @@
-"""AOT policy-application serving (docs/BENCHMARKS.md "Compile cost &
-cache"; README "Serving a found policy"; docs/RESILIENCE.md "Serving
-under overload").
+"""AOT policy-application serving (docs/SERVING.md; README "Serving a
+found policy"; docs/RESILIENCE.md "Serving under overload").
 
 The searched policies are only useful if traffic can hit them: this
 package turns a ``final_policy.json`` into a batch-coalescing

@@ -18,7 +18,7 @@ kernels from ``ops/augment.py``:
   multi-sub.  Lane i depends ONLY on (image i, key i), so padded lanes
   cannot leak into results by construction, and every served output is
   bitwise what a direct ``apply_policy(image, policy, key)`` call
-  produces — the contract ``tools/bench_serve.py`` re-verifies per run.
+  produces — the contract ``tests/test_serve.py`` holds.
 - ``grouped``: one key per dispatch,
   :func:`~fast_autoaugment_tpu.ops.augment.apply_policy_batch_grouped`
   — the PR-3 scalar-dispatch kernel for multi-sub policies (one switch
@@ -1386,7 +1386,7 @@ class PolicyServer:
             h = telemetry.registry().histogram(
                 "faa_serve_stage_seconds",
                 "serving data-plane per-stage overhead (seconds; "
-                "docs/BENCHMARKS.md 'Serving data plane')",
+                "docs/SERVING.md 'Serving data plane')",
                 buckets=_STAGE_BUCKETS, stage=stage,
                 server=self._server_id)
             self._stage_hist[stage] = h

@@ -45,9 +45,8 @@ The ``FAA_FAULT`` verbs ``replica_down@request=N`` and
 ``readyz_flap@period=P`` are consulted at the health-poll seam
 (``utils/faultinject.py``) so rotation ejection, failover and
 degraded-goodput behavior are all deterministically drillable without
-killing real processes.  ``tools/bench_router.py`` (``make
-bench-router``) measures routed-vs-direct cost and affinity hit rate;
-docs/SERVING.md documents the plane end to end.
+killing real processes.  docs/SERVING.md documents the plane end to
+end.
 """
 
 from __future__ import annotations

@@ -60,10 +60,6 @@ breaker to exit **77** ("restart me"): under
 ``launch/fleet.py --no-rank-args`` supervision the replica is
 relaunched and returns to ready (docs/RESILIENCE.md "Serving under
 overload").
-
-``tools/bench_serve.py`` (``make bench-serve`` / ``make
-bench-overload``) measures the in-process latency/throughput and
-overload envelopes of the same applier/server pair.
 """
 
 from __future__ import annotations

@@ -23,7 +23,7 @@ connection per hop.  This module holds the replacements:
   one pool per (host, port), with a retry-once-on-stale-socket rule —
   the client half of persistent-connection serving.
 
-Wire format spec (docs/BENCHMARKS.md "Serving data plane"):
+Wire format spec (docs/SERVING.md "Serving data plane"):
 
 ``FAAR1\\n{"dtype":"float32","shape":[n,H,W,C],"seeds":k}\\n`` then
 ``n*H*W*C`` elements of ``dtype`` in C order, then (if ``k > 0``)

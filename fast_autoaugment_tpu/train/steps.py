@@ -385,7 +385,7 @@ def default_dispatch_unroll(steps_per_dispatch: int) -> int:
     with N; acceptable at the small N the CPU dev/test path uses).  On
     TPU the rolled scan is the standard pjit-trainer shape and keeps
     compile time independent of N, which is what production wants at
-    N=32 on minutes-long WRN compiles.  See docs/BENCHMARKS.md "Step
+    N=32 on minutes-long WRN compiles.  See docs/PARITY.md "Step
     dispatch & device cache".
     """
     return steps_per_dispatch if jax.default_backend() == "cpu" else 1

@@ -286,7 +286,7 @@ def train_and_eval(
     one ``lax.scan`` dispatch (``make_multistep_train_step``): N=1
     (default) is bit-for-bit the host-fed path; N>1 deviates by the
     documented ~1 f32 ULP/step scan-kernel bound (the fold-stacking
-    deviation class — docs/BENCHMARKS.md "Step dispatch & device
+    deviation class — docs/PARITY.md "Step dispatch & device
     cache").
 
     Resilience (docs/RESILIENCE.md; defaults preserve the historical
@@ -944,8 +944,8 @@ def train_folds_stacked(
     (tests/test_train.py::test_train_step_single_vs_eight_devices).
     The seeded equivalence test pins the bound at short horizons and
     checks eval-metric agreement at run end
-    (tests/test_stacked_phase1.py); docs/BENCHMARKS.md records the
-    deviation rationale.
+    (tests/test_stacked_phase1.py); docs/PARITY.md "Step dispatch &
+    device cache" records the deviation class.
 
     `mesh` defaults to :func:`make_fold_mesh` over all devices — folds
     shard across device groups when the counts divide (the per-fold

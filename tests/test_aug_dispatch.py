@@ -19,7 +19,7 @@ Covers the three contracts of the dispatch split:
   change at all and must match exact numerically.
 
 Tier-1 keeps only the cheap guards (the golden exact-default pin, the
-grouped permutation-plumbing check, flag/bench units); every
+grouped permutation-plumbing check, flag units); every
 compile-heavy wiring/parity test and the statistical tests carry
 ``@pytest.mark.slow`` so the tier-1 suite stays inside its wall-clock
 budget on a 1-core host (``make test`` still runs everything).
@@ -538,61 +538,3 @@ def test_cli_dispatch_flags():
     t = train_parser()
     args = t.parse_args(["-c", "x.yaml"])
     assert args.aug_dispatch == "exact" and args.aug_groups == 8
-
-
-# ------------------------------------------------------------- bench
-
-
-def test_bench_vs_baseline_only_on_a_tpu():
-    """Only a TPU number is compared against the reference-pipeline
-    estimate; whatever else JAX ran on reports null, from the platform
-    it observed — there is no fallback flag to forget."""
-    import bench
-
-    assert bench.vs_baseline(46.4, "cpu") is None
-    assert bench.vs_baseline(65046.3, "tpu") == 43.364
-
-
-def test_bench_peak_flops_keyed_by_exact_device_kind():
-    """MFU needs the chip's peak: known by exact device_kind, None on
-    the CPU, and an unknown TPU is an error — never a null MFU."""
-    import types
-
-    import bench
-
-    dev = lambda platform, kind: types.SimpleNamespace(  # noqa: E731
-        platform=platform, device_kind=kind)
-    assert bench._chip_peak_flops(dev("tpu", "TPU v5 lite")) == 197e12
-    assert bench._chip_peak_flops(dev("cpu", "cpu")) is None
-    with pytest.raises(KeyError, match="TPU v9 imaginary"):
-        bench._chip_peak_flops(dev("tpu", "TPU v9 imaginary"))
-    with pytest.raises(KeyError):  # a substring is not a match
-        bench._chip_peak_flops(dev("tpu", "TPU v5"))
-
-
-def test_bench_fails_when_a_sub_bench_raises(monkeypatch, capsys):
-    """A failed phase fails the run: the exception leaves main() (exit
-    code 1 as a script) and no JSON line is printed — where the old
-    bench wrote a null and exited 0."""
-    import bench
-
-    monkeypatch.setattr(bench, "bench_headline",
-                        lambda: ({"metric": "x"}, [0.1]))
-    monkeypatch.setattr(bench, "bench_tta_scheduler", lambda: (_ for _ in ())
-                        .throw(RuntimeError("tta probe broke")))
-    monkeypatch.setattr(bench.sys, "argv", ["bench.py"])
-    with pytest.raises(RuntimeError, match="tta probe broke"):
-        bench.main()
-    assert capsys.readouterr().out.strip() == ""
-
-
-def test_bench_aug_full19_policy_covers_every_op():
-    import sys as _sys
-
-    _sys.path.insert(0, os.path.join(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__))), "tools"))
-    import bench_aug
-
-    pol = bench_aug.full_19op_policy()
-    assert pol.shape == (A.NUM_OPS, 2, 3)
-    assert set(pol[:, :, 0].astype(int).ravel()) == set(range(A.NUM_OPS))

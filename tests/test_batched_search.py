@@ -6,7 +6,6 @@ driver loop end-to-end."""
 
 import json
 import os
-import sys
 
 import numpy as np
 import pytest
@@ -15,9 +14,6 @@ import jax
 import jax.numpy as jnp
 
 from fast_autoaugment_tpu.search.tpe import TPE, choice, uniform
-
-sys.path.insert(0, os.path.join(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "tools"))
 
 
 # ---------------------------------------------------------------- TPE
@@ -60,16 +56,16 @@ def test_ask_batch_leaves_observations_intact():
 
 def test_batched_tpe_beats_random_on_policy_space():
     """Posterior sanity at K>1: constant-liar batches on the REAL 30-D
-    policy space (planted-policy reward, the tools/bench_tpe.py
-    methodology) must beat paired random search about as often as the
+    policy space (planted-policy reward, tests/planted_policy.py) must
+    beat paired random search about as often as the
     sequential TPE does.  Measured at this cell (60 trials, sigma=0.02,
     20 seeds): sequential 16/20, K=4 16/20, K=16 16/20 with equal or
     better mean gain — so the gates are wins >= 15/20 and gain > 0.02,
     plus non-inferiority to the sequential optimizer on the same seeds.
     (The issue's nominal ">= 17/20" traced to an 18/20 claim that the
-    committed benchmark table itself revised to 14-16/20,
-    docs/tpe_benchmark.md; fully deterministic given the seeds.)"""
-    import bench_tpe
+    committed table itself revised to 14-16/20, docs/SEARCH_QUALITY.md;
+    fully deterministic given the seeds.)"""
+    import planted_policy as planted
 
     from fast_autoaugment_tpu.search.driver import make_search_space
 
@@ -77,10 +73,10 @@ def test_batched_tpe_beats_random_on_policy_space():
 
     def run_batched(seed, k):
         rng = np.random.default_rng((seed, 1))
-        target = bench_tpe.plant_target(np.random.default_rng((seed, 2)))
-        observed_fn, true_fn = bench_tpe.make_reward(target, noise, rng)
-        opt = TPE(make_search_space(bench_tpe.NUM_POLICY, bench_tpe.NUM_OP),
-                  seed=seed, n_startup=bench_tpe.driver_n_startup(trials))
+        target = planted.plant_target(np.random.default_rng((seed, 2)))
+        observed_fn, true_fn = planted.make_reward(target, noise, rng)
+        opt = TPE(make_search_space(planted.NUM_POLICY, planted.NUM_OP),
+                  seed=seed, n_startup=planted.driver_n_startup(trials))
         best_obs, best_true, done = -np.inf, 0.0, 0
         while done < trials:
             ps = opt.ask(min(k, trials - done))
@@ -92,9 +88,9 @@ def test_batched_tpe_beats_random_on_policy_space():
             done += len(ps)
         return best_true
 
-    rand = np.array([bench_tpe.run_strategy("random", trials, s, noise)[-1]
+    rand = np.array([planted.run_strategy("random", trials, s, noise)[-1]
                      for s in range(runs)])
-    seq = np.array([bench_tpe.run_strategy("tpe", trials, s, noise)[-1]
+    seq = np.array([planted.run_strategy("tpe", trials, s, noise)[-1]
                     for s in range(runs)])
     seq_wins = int((seq > rand).sum())
     for k in (4, 16):
@@ -342,36 +338,3 @@ def test_random_arm_skip_reason():
     assert "empty after audit" in random_arm_skip_reason(partial)
     never_drawn = {}
     assert "no random policy set" in random_arm_skip_reason(never_drawn)
-
-
-# ------------------------------------------------------------- bench
-
-def test_host_contention_stamp():
-    """Every bench artifact carries loadavg + process-count provenance
-    (VERDICT r5 weak 1: a busy-host capture must be visible in the
-    artifact itself)."""
-    import bench
-
-    stamp = bench.host_contention_stamp()
-    assert stamp["cpu_count"] >= 1
-    assert stamp["loadavg_1m"] is None or stamp["loadavg_1m"] >= 0.0
-    assert stamp["process_count"] is None or stamp["process_count"] >= 1
-    assert isinstance(stamp["contended"], bool)
-
-
-def test_refuse_quiet_exits_on_contention(monkeypatch):
-    import bench
-
-    monkeypatch.setenv("FAA_BENCH_REQUIRE_QUIET", "1")
-    with pytest.raises(SystemExit) as exc:
-        bench.refuse_or_flag_contention(
-            {"contended": True, "loadavg_1m": 9.0, "cpu_count": 1,
-             "process_count": 42})
-    assert exc.value.code == 3
-    monkeypatch.delenv("FAA_BENCH_REQUIRE_QUIET")
-    flagged = bench.refuse_or_flag_contention(
-        {"contended": True, "loadavg_1m": 9.0, "cpu_count": 1,
-         "process_count": 42})
-    assert "contention" in flagged["note"]
-    quiet = bench.refuse_or_flag_contention({"contended": False})
-    assert "note" not in quiet

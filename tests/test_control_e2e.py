@@ -29,8 +29,6 @@ import numpy as np
 import pytest
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, _REPO)
-sys.path.insert(0, os.path.join(_REPO, "tools"))
 
 pytestmark = pytest.mark.slow
 
@@ -196,15 +194,13 @@ def test_research_through_fleet_learner_actor_byte_identical(tmp_path,
     assert len(log_b["0"]) == 6
     # the fleet really evaluated remotely: the actor posted rounds
     assert "fleet_transport" in res_b and "fleet_transport" not in res_a
-    import bench
-
     print("RESEARCH_FLEET " + json.dumps({
         "research_fleet": {
             "host_wall_sec": round(host_wall, 1),
             "fleet_wall_sec": round(fleet_wall, 1),
             "topup_trials": 2,
             "single_core_caveat": True,
-        }, **bench.telemetry_stamp()}))
+        }}))
 
 
 # ----------------------------------------------------------- THE drill
@@ -295,7 +291,7 @@ def test_drift_detect_research_canary_promote_drill(tmp_path):
                 "--port", "0", "--port-dir", port_dir,
                 "--host-tag", f"replica{i}",
             ], env=env_i, cwd=_REPO))
-        from bench_router import wait_port_record, wait_ready
+        from plane_helpers import wait_port_record, wait_ready
 
         ports = []
         for i in range(3):

@@ -22,8 +22,6 @@ import numpy as np
 import pytest
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, _REPO)
-sys.path.insert(0, os.path.join(_REPO, "tools"))
 
 pytestmark = pytest.mark.slow
 
@@ -109,7 +107,7 @@ def test_controller_sigkilled_mid_canary_resumes_to_promote(tmp_path):
                 "--port", "0", "--port-dir", port_dir,
                 "--host-tag", f"replica{i}",
             ], env=dict(env, FAA_HOST_ID=str(i)), cwd=_REPO))
-        from bench_router import wait_port_record, wait_ready
+        from plane_helpers import wait_port_record, wait_ready
 
         ports = []
         for i in range(3):

@@ -510,21 +510,3 @@ def test_cli_device_cache_flags():
         assert args.steps_per_dispatch == 32
         with pytest.raises(SystemExit):
             parser.parse_args(["-c", "x.yaml", "--device-cache", "maybe"])
-
-
-def test_bench_dispatch_helpers_exist():
-    """`make bench-dispatch` wiring: the bench callable and its probe
-    are importable and the Makefile target exists (the full bench run
-    is exercised out-of-band — it is a measurement, not a test)."""
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "bench", os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    assert callable(bench.bench_step_dispatch)
-    assert callable(bench._dispatch_probe_model)
-    mk = open(os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "Makefile")).read()
-    assert "bench-dispatch" in mk and "--dispatch-only" in mk

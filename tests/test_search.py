@@ -232,22 +232,17 @@ def test_tpe_beats_random_at_small_budget():
     rewards TPE must clearly beat random, and under heavy observation
     noise it must at worst match it.  Metric is the TRUE reward of the
     best-by-observed incumbent (what top-N selection consumes).  Fully
-    deterministic given the seeds; full budget x noise sweep in
-    tools/bench_tpe.py / docs/tpe_benchmark.md."""
-    import os
-    import sys
+    deterministic given the seeds; the full budget x noise grid is in
+    docs/SEARCH_QUALITY.md."""
+    import planted_policy
 
-    sys.path.insert(0, os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "tools"))
-    import bench_tpe
-
-    clean = bench_tpe.run_cell(trials=60, noise=0.02, runs=10)
+    clean = planted_policy.run_cell(trials=60, noise=0.02, runs=10)
     assert clean["wins"] >= 5, clean
     assert clean["gain"] > 0.01, clean
 
     # the regime the fold-quality gate exists to avoid: reward noise at
     # the weak-oracle spread — TPE may lose its edge but not its floor
-    noisy = bench_tpe.run_cell(trials=60, noise=0.1, runs=10)
+    noisy = planted_policy.run_cell(trials=60, noise=0.1, runs=10)
     assert noisy["gain"] > -0.02, noisy
 
 

@@ -39,8 +39,6 @@ from fast_autoaugment_tpu.serve.policy_server import (
 from fast_autoaugment_tpu.utils import faultinject
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, _REPO)
-sys.path.insert(0, os.path.join(_REPO, "tools"))
 
 IMG = 8
 
@@ -939,47 +937,3 @@ def test_http_reload_endpoint_roundtrip(tmp_path):
         httpd.shutdown()
         httpd.server_close()
         srv.stop()
-
-
-# ---------------------------------------------------------- bench hook
-
-
-@pytest.mark.slow
-def test_bench_overload_smoke(capsys):
-    """tools/bench_serve.py --overload end-to-end at a tiny shape: the
-    JSON line carries the sweep schema (goodput/shed/miss per arm,
-    shedding on AND off) and the robustness counter stamps."""
-    import bench_serve
-
-    rc = bench_serve.main([
-        "--overload", "--image", str(IMG), "--num-sub", "1",
-        "--shapes", "1,4", "--overload-imgs-per-request", "4",
-        "--multipliers", "1,4", "--overload-seconds", "0.4",
-        "--deadline-ms", "50", "--max-wait-ms", "1",
-        "--overload-queue-depth", "8"])
-    assert rc == 0
-    line = [ln for ln in capsys.readouterr().out.splitlines()
-            if ln.startswith("{")][-1]
-    out = json.loads(line)
-    assert out["metric"] == "serve_overload_goodput"
-    assert out["capacity_qps"] > 0 and out["bitwise_match"] is True
-    assert len(out["arms"]) == 4  # 2 multipliers x shedding on/off
-    sheds = {(a["shedding"], a["multiplier"]) for a in out["arms"]}
-    assert sheds == {("on", 1.0), ("on", 4.0), ("off", 1.0), ("off", 4.0)}
-    for arm in out["arms"]:
-        assert "goodput_rps" in arm and "shed_rate" in arm
-        assert "deadline_miss_rate" in arm
-        assert "p99" in arm["admitted_latency_ms"]
-        assert "breaker_fires" in arm["serve_robustness"]
-
-
-def test_bench_robustness_stamp_shape():
-    import bench_serve
-
-    srv = PolicyServer(DummyApplier(), queue_depth=1)
-    srv.submit(_images(1), _keys(1))
-    with pytest.raises(ServerOverloadedError):
-        srv.submit(_images(1), _keys(1))
-    stamp = bench_serve._robustness_stamp(srv.stats())
-    assert stamp["admitted"] == 1 and stamp["shed_overload"] == 1
-    assert stamp["breaker_state"] == "disabled" and stamp["reloads"] == 0

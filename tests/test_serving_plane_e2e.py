@@ -20,6 +20,8 @@ from http.server import ThreadingHTTPServer
 import numpy as np
 import pytest
 
+from plane_helpers import wait_port_record
+
 from fast_autoaugment_tpu.serve.router import Router
 from fast_autoaugment_tpu.serve.router_cli import make_router_handler
 
@@ -49,21 +51,6 @@ def _npz_body(imgs, seeds=None):
     else:
         np.savez(buf, images=imgs.astype(np.uint8), seeds=seeds)
     return buf.getvalue()
-
-
-def _wait_record(port_dir, tag, proc, timeout=180.0) -> int:
-    path = os.path.join(port_dir, f"{tag}.json")
-    t0 = time.monotonic()
-    while time.monotonic() - t0 < timeout:
-        if proc.poll() is not None:
-            raise AssertionError(
-                f"replica {tag} died early: rc={proc.returncode}")
-        try:
-            with open(path) as fh:
-                return int(json.load(fh)["port"])
-        except (OSError, ValueError, KeyError):
-            time.sleep(0.2)
-    raise AssertionError(f"replica {tag} never wrote its port record")
 
 
 @pytest.mark.slow
@@ -104,8 +91,8 @@ def test_serving_plane_three_replica_drill(tmp_path):
             ], env=env_i, cwd=_REPO))
         ports = {}
         for i in range(3):
-            ports[f"replica{i}"] = _wait_record(port_dir, f"replica{i}",
-                                                procs[i])
+            ports[f"replica{i}"] = wait_port_record(
+                port_dir, f"replica{i}", procs[i])
         # pre-warm policy B everywhere (mixed warm 2-policy traffic)
         for tag, port in ports.items():
             resp, data = _http(port, "POST", "/tenants/warm",

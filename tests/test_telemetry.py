@@ -20,7 +20,6 @@ import pytest
 from fast_autoaugment_tpu.core import telemetry as T
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, _REPO)
 sys.path.insert(0, os.path.join(_REPO, "tools"))
 
 
@@ -510,22 +509,6 @@ def test_metrics_http_server_scrape():
     finally:
         httpd.shutdown()
         httpd.server_close()
-
-
-def test_bench_telemetry_stamp_unified_schema():
-    import bench
-
-    stamp = bench.telemetry_stamp([0.1], label="unit_stamp")
-    assert stamp["schema_version"] == bench.TELEMETRY_STAMP_SCHEMA_VERSION
-    assert set(stamp) == {"schema_version", "contention", "watchdog",
-                          "compile_cache", "telemetry_counters"}
-    assert "loadavg_1m" in stamp["contention"]
-    assert stamp["watchdog"]["watchdog_deadline_sec"] is not None
-    assert "hits" in stamp["compile_cache"]
-    assert isinstance(stamp["telemetry_counters"], dict)
-    # a pre-built per-row watchdog stamp rides through untouched
-    wd = {"watchdog_fires": 7}
-    assert bench.telemetry_stamp(watchdog=wd)["watchdog"] is wd
 
 
 def test_faa_status_aggregates_journals_and_beats(tmp_path):

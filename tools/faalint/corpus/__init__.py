@@ -28,7 +28,7 @@ CASES = {
     "blocking_admission": (
         "fast_autoaugment_tpu/serve/policy_server.py", {"R6"},
         "robustness"),
-    # the measured dispatch pathologies (PR 4 / docs/BENCHMARKS.md)
+    # the measured dispatch pathologies (PR 4 / docs/PARITY.md)
     "mixed_commit": (
         "fast_autoaugment_tpu/train/trainer.py", {"D3"}, "dispatch"),
     "host_sync_loop": (

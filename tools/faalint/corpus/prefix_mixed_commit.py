@@ -1,6 +1,6 @@
 """Pre-fix regression snippet: mixed mesh-commitment into a jitted
 entry point — the measured 17x dispatch-overhead pathology (PR 4,
-docs/BENCHMARKS.md "Step dispatch & device cache").
+docs/PARITY.md "Step dispatch & device cache").
 
 The device cache is mesh-committed but the loop-carried TrainState is
 not: every dispatch re-resolves placement and falls off the C++ fast

@@ -63,7 +63,7 @@ TIMING_SEAM_DIRS = ("train", "search", "serve")             # R8
 EXT_BLOCKING_DIRS = ("core", "launch", "data", "utils")
 # D1–D3: the train/search/serve hot paths whose dispatch loops must
 # stay off the host-sync / recompile / mixed-commitment pathologies
-# (docs/BENCHMARKS.md "Step dispatch & device cache").
+# (docs/PARITY.md "Step dispatch & device cache").
 DISPATCH_DIRS = ("train", "search", "serve")
 # T1–T3: the artifact-writing layers (everything funneled through
 # write_json_atomic / save_checkpoint).  launch/ is deliberately out:

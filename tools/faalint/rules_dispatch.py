@@ -1,6 +1,6 @@
 """Dispatch-hazard rules D1–D3 (JAX-specific, train/search/serve hot
 paths).  Every rule pins a pathology this repo has MEASURED on this
-host (docs/BENCHMARKS.md "Step dispatch & device cache"):
+host (docs/PARITY.md "Step dispatch & device cache"):
 
 D1  **host-device sync inside a dispatch loop**: ``.item()`` anywhere
     in a loop body, or ``float()``/``int()``/``np.asarray()``/
@@ -266,7 +266,7 @@ class MixedCommitDispatch(Rule):
                         f"({', '.join(sorted(committed_args))}) with the "
                         f"uncommitted loop-carried state '{missing}' — "
                         "the measured 17x dispatch-overhead pathology "
-                        "(docs/BENCHMARKS.md): jax.device_put the "
+                        "(docs/PARITY.md): jax.device_put the "
                         "carried state onto the mesh before the loop"))
         return out
 

@@ -1,6 +1,6 @@
 """Measure the TTA reward noise the TPE optimizer actually faces.
 
-VERDICT r3, weak 3: the TPE-vs-random benchmark (docs/tpe_benchmark.md)
+VERDICT r3, weak 3: the TPE-vs-random table (docs/SEARCH_QUALITY.md)
 shows TPE's edge vanishing past reward noise sigma ~0.05, and the
 driver's defense (the fold-quality gate keeps oracles strong enough
 that sigma stays ~0.02) was validated only on glyph tasks.  This probe
@@ -14,8 +14,7 @@ conditions on.
         [--dataroot ./data] [--folds 0] [--policies 3] [--draws 8]
 
 Emits one JSON line: per-fold sigma estimates + the pooled estimate,
-ready for docs/BENCHMARKS.md and comparable against the TPE benchmark's
-noise grid.
+comparable against the noise grid of docs/SEARCH_QUALITY.md.
 """
 
 from __future__ import annotations
@@ -103,7 +102,7 @@ def main(argv=None) -> int:
         return 1
     out["sigma_pooled"] = float(np.sqrt(np.mean(np.square(sigmas))))
     out["tpe_edge_context"] = (
-        "docs/tpe_benchmark.md: TPE beats random for sigma <= 0.02, "
+        "docs/SEARCH_QUALITY.md: TPE beats random for sigma <= 0.02, "
         "parity by sigma ~0.05-0.1"
     )
     print(json.dumps(out))
