@@ -22,10 +22,14 @@ Semantics were pinned against PIL empirically and are exact (see
 - enhance ops: ``clip(trunc(deg + (img - deg) * factor), 0, 255)`` in
   float32 (PIL ``ImageEnhance`` via ``Image.blend``)
 - equalize / autocontrast: PIL's exact integer LUT constructions, with no
-  sort, search, gather or scatter (what the TPU runs slowest): the
-  histogram is a compare of the pixels against the 256 levels reduced
-  over the pixels, Equalize's table is applied by a select over the
-  levels, AutoContrast's is arithmetic on each pixel
+  sort, search, gather or scatter (what the TPU runs slowest).  Equalize
+  addresses the 256 levels as 16 x 16: a pixel ``p = 16a + b`` is two
+  16-wide one-hots, whose product over the pixels is the histogram and
+  through which the table is applied, both on the MXU.  The one-hots and
+  the table (integers in [0, 255]) are bfloat16 operands, which is exact;
+  a count is never one (it passes 256 at 32 px), so the sums are float32
+  and the table is built in int32.  AutoContrast's table is arithmetic
+  on each pixel
 - SMOOTH filter (sharpness degenerate): 3x3 kernel [[1,1,1],[1,5,1],
   [1,1,1]]/13, ``trunc(acc + 0.5)``, 1-pixel border copied unfiltered
 
@@ -241,21 +245,51 @@ def _warp_affine_nearest(img: jax.Array, mat: jax.Array) -> jax.Array:
         return jnp.where(valid[..., None], gathered, 0.0)
 
 
+# Equalize addresses the 256 levels as 16 x 16: level ``16a + b`` is entry
+# ``[a, b]`` of a ``[16, 16]`` table
 _LEVELS = np.arange(256, dtype=np.int32)
+_NIBBLES = np.arange(16, dtype=np.int32)
+_NIBBLE_BELOW = _NIBBLES[:, None] < _NIBBLES[None, :]  # [u, v]: u < v
 
 
-def _count_below(ii: jax.Array) -> jax.Array:
-    """``[256, C]`` int32: per channel, how many pixels lie below each level.
+def _nibble_one_hots(ii: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """``p = 16a + b``: the one-hots of ``a`` and of ``b``, bool ``[C, 16, P]``
+    each, for an int32 ``[H, W, C]`` image with values in [0, 255].
 
-    The exclusive cumulative histogram of an int32 ``[H, W, C]`` image
-    with values in [0, 255]; ``diff`` of it (with the pixel count
-    appended) is the 256-bin histogram.  One compare of every pixel
-    against every level, fused into its reduce over the pixels: linear
-    in the pixel count, no data-dependent addressing, vmaps cleanly.
-    """
-    flat = ii.reshape(-1, ii.shape[-1])
-    return jnp.sum(flat[None, :, :] < _LEVELS[:, None, None], axis=1,
-                   dtype=jnp.int32)
+    The ``P = H * W`` pixels lie in the last (lane) axis, so both 256-way
+    passes of :func:`equalize` are 16-row products over dense lanes."""
+    flat = ii.reshape(-1, ii.shape[-1]).T[:, None, :]  # [C, 1, P]
+    return ((flat >> 4) == _NIBBLES[:, None],
+            (flat & 15) == _NIBBLES[:, None])
+
+
+def _histogram256(high: jax.Array, low: jax.Array) -> jax.Array:
+    """``[C, 16, 16]`` int32: the 256-bin histogram of every channel, as
+    ``hist[a, b] = sum_p high[a, p] * low[b, p]`` on the MXU.
+
+    The operands are 0/1, exact in bfloat16; the sums are counts, so they
+    accumulate in float32, exact up to 2**24 pixels an image."""
+    hist = jax.lax.dot_general(
+        high.astype(jnp.bfloat16), low.astype(jnp.bfloat16),
+        (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32)
+    return hist.astype(jnp.int32)
+
+
+def _count_below(hist: jax.Array) -> jax.Array:
+    """``[C, 256]`` int32: per channel, how many pixels lie below each
+    level: the exclusive running sum of the ``[C, 16, 16]`` int32
+    histogram over its 256 bins, in two levels as the histogram is.
+
+    Below level ``16a + b`` lie the rows above row ``a`` and the entries
+    left of ``b`` in it.  Counts stay integers: two triangular
+    select-and-sums, no ``cumsum`` (a loop of slices on the TPU) and no
+    bfloat16."""
+    rows = jnp.sum(hist, axis=2, dtype=jnp.int32)  # [C, 16]
+    rows_above = jnp.sum(jnp.where(_NIBBLE_BELOW, rows[:, :, None], 0),
+                         axis=1, dtype=jnp.int32)  # [C, 16a]
+    left_of = jnp.sum(jnp.where(_NIBBLE_BELOW, hist[:, :, :, None], 0),
+                      axis=2, dtype=jnp.int32)  # [C, 16a, 16b]
+    return (rows_above[:, :, None] + left_of).reshape(-1, 256)
 
 
 # ---------------------------------------------------------------------------
@@ -372,19 +406,33 @@ def equalize(img, v, key):
     PIL's table is ``lut[v] = (step // 2 + #(pixels < v)) // step`` with
     ``step = (pixels - h_last) // 255`` and ``h_last`` the count of the
     last nonzero bin; a channel with ``step == 0`` passes unchanged.
-    """
+
+    The 256 levels are addressed as 16 x 16 (``p = 16a + b``) through
+    the two one-hots of :func:`_nibble_one_hots`.  Their product over
+    the pixels is the histogram (:func:`_histogram256`), from which the
+    table is built in int32.  The table, as ``[C, 16, 16]``, is applied
+    by one more product: ``table[:, a, :]`` against the one-hot of ``a``
+    leaves every pixel the 16 entries of its high nibble, and the
+    one-hot of ``b`` selects one.  Operands of that product are
+    bfloat16, stated in the ``dot_general`` as the warp's are, exact
+    whatever the ambient matmul precision: one is 0/1, the other an
+    integer in [0, 255], and every sum is of one entry and zeros.  A
+    count is never a bfloat16 operand: counts pass 256 at 32 px."""
     ii = _to_int(img)
-    below = _count_below(ii)  # [256, C]
+    high, low = _nibble_one_hots(ii)  # [C, 16, P]
+    below = _count_below(_histogram256(high, low))  # [C, 256]
     # the last nonzero bin is the channel maximum's
     h_last = jnp.sum(ii == ii.max(axis=(0, 1)), axis=(0, 1), dtype=jnp.int32)
-    step = (ii.shape[0] * ii.shape[1] - h_last) // 255
+    step = ((ii.shape[0] * ii.shape[1] - h_last) // 255)[:, None]  # [C, 1]
     lut = jnp.clip((step // 2 + below) // jnp.maximum(step, 1), 0, 255)
     # a single nonzero bin holds every pixel, so it gives step == 0 too
-    lut = jnp.where(step == 0, _LEVELS[:, None], lut)
-    # lut[p] without a gather: select each pixel's level, reduce over levels
-    picked = jnp.where(ii[None] == _LEVELS[:, None, None, None],
-                       lut[:, None, None, :], 0)
-    return jnp.sum(picked, axis=0, dtype=jnp.int32).astype(jnp.float32)
+    lut = jnp.where(step == 0, _LEVELS, lut)
+    # lut[p] without a gather: [C, 16a, 16b] x [C, 16a, P] -> [C, 16b, P]
+    rows = jax.lax.dot_general(
+        lut.reshape(-1, 16, 16).astype(jnp.bfloat16), high.astype(jnp.bfloat16),
+        (((1,), (1,)), ((0,), (0,))), preferred_element_type=jnp.bfloat16)
+    out = jnp.sum(jnp.where(low, rows, 0), axis=1)  # [C, P]
+    return out.T.reshape(ii.shape).astype(jnp.float32)
 
 
 def solarize(img, v, key):

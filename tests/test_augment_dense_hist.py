@@ -1,9 +1,12 @@
 """Equalize and AutoContrast in their dense forms against the plain reference.
 
-``ops/augment.py`` computes both with compares and reduces over the 256
-levels (no sort, search, gather or scatter: those are what the TPU runs
-slowest).  The reference below is the sort + searchsorted + gather
-construction they replaced, kept here verbatim; the dense forms must give
+``ops/augment.py`` computes both with no sort, search, gather or scatter
+(those are what the TPU runs slowest): AutoContrast by arithmetic on each
+pixel, Equalize by addressing the 256 levels as 16 x 16, two one-hots
+through the MXU.  The reference below is the sort + searchsorted + gather
+construction they replaced, kept here verbatim; the form between the two,
+a compare of every pixel against all 256 levels and a select, is kept
+verbatim as a second reference for Equalize.  The dense forms must give
 the same float32 pixels element for element, and their jaxprs must stay
 free of data-dependent addressing.  The random crop of the fixed
 stack (``ops/preprocess.py``) is held to the same two rules at the end.
@@ -73,6 +76,35 @@ def _ref_equalize(img, v, key):
 
     lut = jnp.stack([one_channel(ii[..., c]) for c in range(img.shape[-1])])
     return _ref_apply_lut(img, lut)
+
+
+# ---------------------------------------------------------------------------
+# the second reference: Equalize as it stood before the two levels, a compare
+# of every pixel against all 256 levels, twice (the counts, then the table)
+# ---------------------------------------------------------------------------
+
+_LEVELS = np.arange(256, dtype=np.int32)
+
+
+def _compare_select_count_below(ii):
+    flat = ii.reshape(-1, ii.shape[-1])
+    return jnp.sum(flat[None, :, :] < _LEVELS[:, None, None], axis=1,
+                   dtype=jnp.int32)
+
+
+def _compare_select_equalize(img, v, key):
+    ii = A._to_int(img)
+    below = _compare_select_count_below(ii)  # [256, C]
+    # the last nonzero bin is the channel maximum's
+    h_last = jnp.sum(ii == ii.max(axis=(0, 1)), axis=(0, 1), dtype=jnp.int32)
+    step = (ii.shape[0] * ii.shape[1] - h_last) // 255
+    lut = jnp.clip((step // 2 + below) // jnp.maximum(step, 1), 0, 255)
+    # a single nonzero bin holds every pixel, so it gives step == 0 too
+    lut = jnp.where(step == 0, _LEVELS[:, None], lut)
+    # lut[p] without a gather: select each pixel's level, reduce over levels
+    picked = jnp.where(ii[None] == _LEVELS[:, None, None, None],
+                       lut[:, None, None, :], 0)
+    return jnp.sum(picked, axis=0, dtype=jnp.int32).astype(jnp.float32)
 
 
 # ---------------------------------------------------------------------------
@@ -148,7 +180,13 @@ def _draw(case, count):
 OPS = {
     "equalize": (A.equalize, _ref_equalize),
     "auto_contrast": (A.auto_contrast, _ref_auto_contrast),
+    "equalize_vs_compare_select": (A.equalize, _compare_select_equalize),
 }
+EQUALIZE_REFS = {"sort": _ref_equalize, "compare_select": _compare_select_equalize}
+
+
+def _batched(fn):
+    return jax.jit(jax.vmap(fn, in_axes=(0, None, None)))
 
 
 def _assert_identical(got, want):
@@ -171,19 +209,70 @@ def test_dense_form_equals_reference_under_jit(op, case):
 def test_dense_form_equals_reference_under_vmap(op, case):
     new, ref = OPS[op]
     imgs = _draw(case, 16)
-    batched = lambda fn: jax.jit(jax.vmap(fn, in_axes=(0, None, None)))
-    _assert_identical(batched(new)(imgs, V, KEY), batched(ref)(imgs, V, KEY))
+    _assert_identical(_batched(new)(imgs, V, KEY), _batched(ref)(imgs, V, KEY))
+
+
+def _heavy_bin_380(rng):
+    # 380 x 380 = 144,400 pixels, 97% of them one value: a bin over 65,536,
+    # which float32 counts exactly and bfloat16 (or 16 bits) would not
+    img = rng.integers(0, 256, (380, 380, 3)).astype(np.float32)
+    heavy = rng.random((380, 380, 3)) < 0.97
+    return np.where(heavy, rng.integers(0, 256, 3).astype(np.float32), img)
+
+
+@pytest.mark.parametrize("ref", EQUALIZE_REFS)
+def test_equalize_at_380_px_with_a_bin_over_65536(ref):
+    rng = np.random.default_rng(380)
+    imgs = jnp.asarray(np.stack([_heavy_bin_380(rng), _heavy_bin_380(rng)]))
+    counts = np.bincount(np.asarray(imgs[0, ..., 0], np.int64).ravel())
+    assert counts.max() > 65536
+    _assert_identical(_batched(A.equalize)(imgs, V, KEY),
+                      _batched(EQUALIZE_REFS[ref])(imgs, V, KEY))
+    _assert_identical(jax.jit(A.equalize)(imgs[0], V, KEY),
+                      jax.jit(EQUALIZE_REFS[ref])(imgs[0], V, KEY))
+
+
+@pytest.mark.parametrize("ref", EQUALIZE_REFS)
+def test_equalize_over_a_batch_of_2048_at_32_px(ref):
+    # the batch `wrn40x2_train` augments in one step; every kind of image in it
+    kinds = sorted(set(CASES) - {"8x8", "224x224"})
+    rng = np.random.default_rng(2048)
+    imgs = jnp.asarray(np.stack(
+        [CASES[kinds[i % len(kinds)]](rng) for i in range(2048)]))
+    _assert_identical(_batched(A.equalize)(imgs, V, KEY),
+                      _batched(EQUALIZE_REFS[ref])(imgs, V, KEY))
+
+
+@pytest.mark.parametrize("case", ["random0", "skewed_low_level",
+                                  "skewed_step_zero", "224x224",
+                                  "float_out_of_range"])
+@pytest.mark.parametrize("precision", ["bfloat16", "float32", "highest"])
+def test_equalize_is_exact_under_every_ambient_matmul_precision(precision, case):
+    # the float32 check of `shake26_2x96d_train` runs the policy under
+    # "highest"; the products state their operand types, so none of it shows
+    imgs = _draw(case, 4)
+    want = _batched(_compare_select_equalize)(imgs, V, KEY)
+    with jax.default_matmul_precision(precision):
+        got = _batched(A.equalize)(imgs, V, KEY)
+        one = jax.jit(A.equalize)(imgs[0], V, KEY)
+    _assert_identical(got, want)
+    _assert_identical(one, want[0])
 
 
 @pytest.mark.parametrize("case", CASES)
 def test_count_below_differences_are_the_histogram(case):
     ii = A._to_int(_draw(case, 1)[0])
-    below = np.asarray(A._count_below(ii))  # [256, C]
-    assert below.dtype == np.int32
+    hist = A._histogram256(*A._nibble_one_hots(ii))  # [C, 16, 16]: bin 16a + b
+    below = np.asarray(A._count_below(hist))  # [C, 256]
+    hist = np.asarray(hist).reshape(3, 256)
+    assert hist.dtype == below.dtype == np.int32
     pixels = ii.shape[0] * ii.shape[1]
-    hist = np.diff(below, axis=0, append=np.full((1, 3), pixels, np.int32))
+    diffs = np.diff(below, axis=1, append=np.full((3, 1), pixels, np.int32))
+    np.testing.assert_array_equal(
+        below.T, _compare_select_count_below(ii))  # as it was counted before
     for c in range(3):
-        np.testing.assert_array_equal(hist[:, c], _ref_histogram256(ii[..., c]))
+        np.testing.assert_array_equal(hist[c], _ref_histogram256(ii[..., c]))
+        np.testing.assert_array_equal(diffs[c], hist[c])
 
 
 def test_skewed_case_really_has_step_zero():
@@ -201,26 +290,52 @@ def test_skewed_case_really_has_step_zero():
 _FORBIDDEN = ("sort", "gather", "scatter", "while", "scan")
 
 
-def _primitive_names(jaxpr):
-    """Every primitive name in `jaxpr` and the jaxprs its equations hold."""
+def _equations(jaxpr):
+    """Every equation in `jaxpr` and in the jaxprs its equations hold."""
     for eqn in jaxpr.eqns:
-        yield eqn.primitive.name
+        yield eqn
         for param in eqn.params.values():
             for sub in param if isinstance(param, (list, tuple)) else (param,):
                 sub = getattr(sub, "jaxpr", sub)  # ClosedJaxpr -> Jaxpr
                 if hasattr(sub, "eqns"):
-                    yield from _primitive_names(sub)
+                    yield from _equations(sub)
+
+
+def _primitive_names(jaxpr):
+    return (eqn.primitive.name for eqn in _equations(jaxpr))
+
+
+def _vmapped_equations(fn, imgs):
+    closed = jax.make_jaxpr(jax.vmap(fn, in_axes=(0, None, None)))(imgs, V, KEY)
+    return list(_equations(closed.jaxpr))
 
 
 def _forbidden_in(fn, imgs):
-    closed = jax.make_jaxpr(jax.vmap(fn, in_axes=(0, None, None)))(imgs, V, KEY)
-    return sorted({name for name in _primitive_names(closed.jaxpr)
-                   if any(word in name for word in _FORBIDDEN)})
+    return sorted({eqn.primitive.name for eqn in _vmapped_equations(fn, imgs)
+                   if any(word in eqn.primitive.name for word in _FORBIDDEN)})
 
 
 @pytest.mark.parametrize("op", OPS)
 def test_no_sort_gather_scatter_or_loop(op):
     assert _forbidden_in(OPS[op][0], _draw("random0", 4)) == []
+
+
+def _largest_output(fn, imgs):
+    """(primitive names, elements of the largest output) of vmapped `fn`."""
+    eqns = _vmapped_equations(fn, imgs)
+    return ({eqn.primitive.name for eqn in eqns},
+            max(int(np.prod(var.aval.shape)) for eqn in eqns for var in eqn.outvars))
+
+
+def test_equalize_is_two_products_and_nothing_256_wide_over_the_pixels():
+    imgs = _draw("random0", 4)
+    all_levels = 256 * imgs.shape[0] * imgs.shape[1] * imgs.shape[2]
+    names, largest = _largest_output(A.equalize, imgs)
+    assert "dot_general" in names
+    assert largest < all_levels  # two 16-wide one-hots a channel: 48 a pixel
+    # the same walk over the form it replaced finds what it is there to keep out
+    names, largest = _largest_output(_compare_select_equalize, imgs)
+    assert "dot_general" not in names and largest >= all_levels
 
 
 def test_the_walk_sees_what_the_reference_holds():
