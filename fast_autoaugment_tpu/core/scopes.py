@@ -28,7 +28,10 @@ __all__ = [
     "AUG_OP_PREFIX",
     "AUG_WARP",
     "AUG_FIXED",
+    "AUG_JITTER",
+    "AUG_LIGHTING",
     "MODEL",
+    "RESNET_STEM",
     "SHAKE_MIX",
     "SHAKE_SHORTCUT",
     "LOSS",
@@ -52,8 +55,15 @@ AUG_OP_PREFIX = "faa_aug_op_"
 #: the resampling of the seven affine operations, once an op slot; their
 #: own ``faa_aug_op_<Name>`` scopes hold a 2x3 matrix each
 AUG_WARP = "faa_aug_warp"
-#: ``ops/preprocess.py``: crop, flip, normalize, cutout: what every recipe pays
+#: ``ops/preprocess.py``: crop, flip, normalize, cutout: what every recipe pays;
+#: ``ops/preprocess_imagenet.py``: the ImageNet per-image stack outside the
+#: policy (flip, ColorJitter, Lighting, normalize, cutout)
 AUG_FIXED = "faa_aug_fixed"
+#: ``ops/preprocess_imagenet.py``, both nested under ``faa_aug_fixed``:
+#: ``_color_jitter`` (brightness, contrast and saturation in a drawn
+#: order) and ``_lighting`` (the PCA noise)
+AUG_JITTER = "faa_aug_jitter"
+AUG_LIGHTING = "faa_aug_lighting"
 #: ``train/steps.py::loss_fn``: forward under ``jvp(...)``, backward
 #: under ``transpose(jvp(...))``
 MODEL = "faa_model"
@@ -63,6 +73,9 @@ MODEL = "faa_model"
 #: of a transpose), and the two-path strided ``Shortcut``
 SHAKE_MIX = "faa_shake_mix"
 SHAKE_SHORTCUT = "faa_shake_shortcut"
+#: ``models/resnet.py``, nested under ``faa_model``: the ImageNet stem
+#: (7x7 stride-2 convolution, BatchNorm, ReLU, 3x3 stride-2 max-pool)
+RESNET_STEM = "faa_resnet_stem"
 LOSS = "faa_loss"
 #: ``train/steps.py::step_fn``: update and parameter add; EMA; top-k and sums
 OPTIMIZER = "faa_optimizer"

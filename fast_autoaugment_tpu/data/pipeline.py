@@ -18,13 +18,16 @@ iterate deterministically without dropping.
 
 from __future__ import annotations
 
+import functools
 import os
 import queue
 import threading
+from concurrent.futures import ThreadPoolExecutor
 from typing import Iterator
 
 import numpy as np
 
+from fast_autoaugment_tpu.core import telemetry
 from fast_autoaugment_tpu.data.datasets import ArrayDataset
 
 __all__ = ["BatchIterator", "DeviceCache", "train_batches",
@@ -75,20 +78,48 @@ class SizeCache:
         return got
 
 
-def _decode_boxed(paths, imgsize: int, box_fn, rng, size_cache: SizeCache) -> np.ndarray:
-    """Decode + crop(box_fn) + resize a batch.
-
-    Uses the native C++ loader (one threaded pass: libjpeg decode, crop,
-    triangle resample) when built; falls back to PIL (bicubic, the
-    golden-parity path).  `box_fn(rng, width, height) -> (x0, y0, x1, y1)`.
-    """
-    from fast_autoaugment_tpu.data import native_loader
-
+def _draw_boxes(paths, box_fn, rng, size_cache: SizeCache) -> np.ndarray:
+    """One crop box a path, drawn in order from `rng` (float32 [N, 4]).
+    Reads image headers (cached), never pixels: a resumed epoch replays
+    the draws of the batches it skips at this cost alone."""
     boxes = np.empty((len(paths), 4), np.float32)
     for i, p in enumerate(paths):
         w, h = size_cache.get(p)
         boxes[i] = box_fn(rng, w, h)
+    return boxes
+
+
+@functools.cache
+def _pil_threads() -> ThreadPoolExecutor:
+    """The PIL decode pool, as wide as the native loader's (made once:
+    a pool a batch would pay the thread starts 10,009 times an epoch)."""
+    return ThreadPoolExecutor(max_workers=min(16, os.cpu_count() or 1),
+                              thread_name_prefix="faa-pil-decode")
+
+
+def _pil_decode_one(path, box, imgsize: int) -> np.ndarray:
+    import PIL.Image
+
+    img = PIL.Image.open(path).convert("RGB")
+    img = img.crop(tuple(box)).resize((imgsize, imgsize), PIL.Image.BICUBIC)
+    return np.asarray(img, np.uint8)
+
+
+def _decode_boxes(paths, imgsize: int, boxes: np.ndarray) -> np.ndarray:
+    """Decode + crop(boxes) + resize a batch.
+
+    Uses the native C++ loader (one threaded pass: libjpeg decode, crop,
+    triangle resample) when built; falls back to PIL (bicubic, the
+    golden-parity path) on a thread pool — PIL releases the GIL inside
+    decode and resize, and ``map`` keeps the order, so the batch is the
+    serial loop's byte for byte.  The call's wall time and image count
+    go to ``faa_decode_seconds_total{decoder}`` / ``faa_decode_images_total``.
+    """
+    from fast_autoaugment_tpu.data import native_loader
+
+    t0 = telemetry.mono()
     if native_loader.available():
+        decoder = "native"
         batch, failures = native_loader.decode_resize_batch(paths, imgsize, boxes)
         if failures:
             import logging
@@ -97,15 +128,25 @@ def _decode_boxed(paths, imgsize: int, box_fn, rng, size_cache: SizeCache) -> np
                 "native loader: %d/%d images failed to decode (zero-filled)",
                 failures, len(paths),
             )
-        return batch
-    import PIL.Image
+    else:
+        decoder = "pil"
+        batch = np.stack(list(_pil_threads().map(
+            _pil_decode_one, paths, boxes, [imgsize] * len(paths))))
+    reg = telemetry.registry()
+    reg.counter("faa_decode_seconds_total",
+                "wall seconds the feed spent decoding, cropping and "
+                "resizing batches", decoder=decoder).inc(
+                    telemetry.mono() - t0)
+    reg.counter("faa_decode_images_total",
+                "images the feed decoded, cropped and resized").inc(len(paths))
+    return batch
 
-    out = []
-    for p, box in zip(paths, boxes):
-        img = PIL.Image.open(p).convert("RGB")
-        img = img.crop(tuple(box)).resize((imgsize, imgsize), PIL.Image.BICUBIC)
-        out.append(np.asarray(img, np.uint8))
-    return np.stack(out)
+
+def _decode_boxed(paths, imgsize: int, box_fn, rng, size_cache: SizeCache) -> np.ndarray:
+    """Draw a crop box a path (`box_fn(rng, width, height) -> (x0, y0,
+    x1, y1)`, in order), then decode, crop and resize the batch."""
+    return _decode_boxes(paths, imgsize,
+                         _draw_boxes(paths, box_fn, rng, size_cache))
 
 
 def train_index_matrix(
@@ -150,6 +191,7 @@ def train_batches(
     box_fn=None,
     imgsize: int | None = None,
     size_cache: "SizeCache | None" = None,
+    skip: int = 0,
 ) -> Iterator[tuple[np.ndarray, np.ndarray]]:
     """Shuffled, drop-last train batches for one epoch.
 
@@ -159,6 +201,12 @@ def train_batches(
     either `box_fn(rng, w, h) -> crop box` + `imgsize` (native-loader
     fast path) or `host_transform(pil_image, rng) -> uint8 array` runs
     per image.
+
+    `skip` drops the epoch's first `skip` batches (a mid-epoch resume)
+    and leaves the rest as an unbroken epoch yields them: the crop
+    boxes of a skipped batch are still drawn, from headers alone, so
+    the random stream stands where it stood; only a `host_transform`
+    feed, whose draws need the pixels, decodes what it skips.
     """
     idx = np.arange(len(dataset)) if indices is None else np.asarray(indices)
     rng = np.random.default_rng((seed, epoch))
@@ -169,14 +217,22 @@ def train_batches(
     transform = None
     if host_transform is not None:
         transform = lambda img: host_transform(img, rng)  # noqa: E731
-    for chunk in mat:
+    size_cache = size_cache or SizeCache()
+    for bi, chunk in enumerate(mat):
+        skipped = bi < skip
+        if skipped and not dataset.lazy:
+            continue
         images = dataset.images[chunk]
         if dataset.lazy:
             if box_fn is not None:
-                images = _decode_boxed(images, imgsize, box_fn, rng,
-                                       size_cache or SizeCache())
+                boxes = _draw_boxes(images, box_fn, rng, size_cache)
+                if skipped:
+                    continue
+                images = _decode_boxes(images, imgsize, boxes)
             else:
                 images = _decode(images, transform, decode_size)
+                if skipped:
+                    continue
         yield images, dataset.labels[chunk]
 
 
@@ -436,6 +492,32 @@ def default_prefetch_depth() -> int:
     return 1 if (os.cpu_count() or 1) < 2 else 2
 
 
+#: a wait on the feed shorter than this leaves no ``feed_wait`` span
+FEED_WAIT_SPAN_SEC = 1e-3
+
+
+def _feed_meter():
+    """``delivered(t0)`` for :func:`prefetch`'s consumer side: one batch
+    handed over, after being blocked since ``t0`` (:func:`telemetry.mono`)
+    — ``faa_feed_batches_total`` and ``faa_feed_wait_seconds_total``
+    always, a ``feed_wait`` span where the wait passed a millisecond."""
+    reg = telemetry.registry()
+    batches = reg.counter("faa_feed_batches_total",
+                          "batches the prefetch feed handed its consumer")
+    waited = reg.counter("faa_feed_wait_seconds_total",
+                         "seconds a consumer was blocked in next() on "
+                         "the prefetch feed")
+
+    def delivered(t0: float) -> None:
+        t1 = telemetry.mono()
+        batches.inc()
+        waited.inc(t1 - t0)
+        if t1 - t0 > FEED_WAIT_SPAN_SEC:
+            telemetry.record_dispatch("feed_wait", t0, t1)
+
+    return delivered
+
+
 def prefetch(iterator, depth: int | None = None, transform=None):
     """Run `iterator` in a background thread with a bounded queue —
     double-buffered host -> device feed.  `depth=None` uses
@@ -463,6 +545,12 @@ def prefetch(iterator, depth: int | None = None, transform=None):
     bounded slices against a stop event, so the thread never blocks
     forever holding buffered batches — with a device-put transform
     those would be TPU HBM, not just host arrays.
+
+    Every batch handed over counts in ``faa_feed_batches_total``, and
+    the time the consumer was blocked in ``next()`` for it in
+    ``faa_feed_wait_seconds_total`` (:func:`_feed_meter`): what a step
+    waits on the host, where the device's idle time says only that it
+    waited.
     """
     if depth is None:
         # env override applies only to default-depth callers — an
@@ -471,13 +559,22 @@ def prefetch(iterator, depth: int | None = None, transform=None):
             depth = 0
         else:
             depth = default_prefetch_depth()
+    delivered = _feed_meter()
     if depth == 0:
         # NOTE prefetch is a generator function (the async path below
         # yields): the sync path must yield inline, not return a
         # sub-generator
-        for item in iterator:
-            yield (transform(item) if transform is not None else item)
-        return
+        source = iter(iterator)
+        while True:
+            t0 = telemetry.mono()
+            try:
+                item = next(source)
+            except StopIteration:
+                return
+            if transform is not None:
+                item = transform(item)
+            delivered(t0)
+            yield item
     q: queue.Queue = queue.Queue(maxsize=depth)
     _END = object()
     stop = threading.Event()
@@ -516,22 +613,26 @@ def prefetch(iterator, depth: int | None = None, transform=None):
     t.start()
     try:
         while True:
-            try:
-                item = q.get(timeout=5.0)
-            except queue.Empty:
-                # bounded wait (lint R9): if the worker died without
-                # delivering its _END sentinel (e.g. killed hard), an
-                # untimed get would park the consumer forever; drained
-                # items always win over the liveness verdict
-                if t.is_alive():
-                    continue
-                if err:
-                    raise err[0]
-                return
+            t0 = telemetry.mono()
+            while True:
+                try:
+                    item = q.get(timeout=5.0)
+                    break
+                except queue.Empty:
+                    # bounded wait (lint R9): if the worker died without
+                    # delivering its _END sentinel (e.g. killed hard), an
+                    # untimed get would park the consumer forever; drained
+                    # items always win over the liveness verdict
+                    if t.is_alive():
+                        continue
+                    if err:
+                        raise err[0]
+                    return
             if item is _END:
                 if err:
                     raise err[0]
                 return
+            delivered(t0)
             yield item
     finally:
         stop.set()

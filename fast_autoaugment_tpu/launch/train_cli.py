@@ -83,7 +83,7 @@ def build_parser() -> argparse.ArgumentParser:
                         "run.  1 = the pre-chain overwrite-in-place")
     p.add_argument("--ckpt-every-dispatch", type=int, default=0,
                    help="checkpoint every M dispatch chunks MID-epoch "
-                        "(device-cache path only; resumable bit-identically "
+                        "(resumable bit-identically "
                         "from the exact dispatch boundary).  0 (default) = "
                         "checkpoint at evaluation epochs only")
     p.add_argument("--watchdog", default="off",

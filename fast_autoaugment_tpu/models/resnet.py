@@ -12,9 +12,11 @@ from __future__ import annotations
 
 from typing import Any
 
+import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from fast_autoaugment_tpu.core import scopes
 from fast_autoaugment_tpu.models.layers import BatchNorm, global_avg_pool, he_normal_fanout
 
 __all__ = ["ResNet", "IMAGENET_LAYERS"]
@@ -114,13 +116,15 @@ class ResNet(nn.Module):
         elif self.dataset == "imagenet":
             kind, counts = IMAGENET_LAYERS[self.depth]
             block = BasicBlock if kind == "basic" else Bottleneck
-            out = nn.Conv(
-                64, (7, 7), strides=(2, 2), padding=[(3, 3), (3, 3)],
-                use_bias=False, kernel_init=he_normal_fanout, dtype=self.dtype,
-                name="conv1",
-            )(x)
-            out = nn.relu(BatchNorm(name="bn1")(out, train))
-            out = nn.max_pool(out, (3, 3), strides=(2, 2), padding=[(1, 1), (1, 1)])
+            with jax.named_scope(scopes.RESNET_STEM):
+                out = nn.Conv(
+                    64, (7, 7), strides=(2, 2), padding=[(3, 3), (3, 3)],
+                    use_bias=False, kernel_init=he_normal_fanout,
+                    dtype=self.dtype, name="conv1",
+                )(x)
+                out = nn.relu(BatchNorm(name="bn1")(out, train))
+                out = nn.max_pool(out, (3, 3), strides=(2, 2),
+                                  padding=[(1, 1), (1, 1)])
             for stage, (width, count) in enumerate(zip((64, 128, 256, 512), counts)):
                 for i in range(count):
                     stride = 2 if (stage > 0 and i == 0) else 1

@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from fast_autoaugment_tpu.core import scopes
 from fast_autoaugment_tpu.ops.augment import (
     apply_policy,
     apply_policy_batch_grouped,
@@ -170,21 +171,25 @@ def _train_one(img, policy, key, cutout_length, single_sub_scalar=False):
 
     k_pol, k_flip, k_jit, k_light, k_cut = jax.random.split(key, 5)
     if policy is not None:
-        if single_sub_scalar:
-            img = apply_policy_scalar_single(img, policy, k_pol)
-        else:
-            img = apply_policy(img, policy, k_pol)
-    img = jnp.where(jax.random.uniform(k_flip) < 0.5, img[:, ::-1], img)
-    img = _color_jitter(img, k_jit)
-    img01 = img / 255.0
-    img01 = _lighting(img01, k_light)
-    mean = jnp.asarray(IMAGENET_MEAN, img01.dtype)
-    std = jnp.asarray(IMAGENET_STD, img01.dtype)
-    out = (img01 - mean) / std
-    if cutout_length > 0:
-        # CutoutDefault applies post-normalize on every dataset family
-        # when conf cutout > 0 (reference data.py:111-112)
-        out = cutout_default(out, k_cut, cutout_length)
+        with jax.named_scope(scopes.AUG_POLICY):
+            if single_sub_scalar:
+                img = apply_policy_scalar_single(img, policy, k_pol)
+            else:
+                img = apply_policy(img, policy, k_pol)
+    with jax.named_scope(scopes.AUG_FIXED):
+        img = jnp.where(jax.random.uniform(k_flip) < 0.5, img[:, ::-1], img)
+        with jax.named_scope(scopes.AUG_JITTER):
+            img = _color_jitter(img, k_jit)
+        img01 = img / 255.0
+        with jax.named_scope(scopes.AUG_LIGHTING):
+            img01 = _lighting(img01, k_light)
+        mean = jnp.asarray(IMAGENET_MEAN, img01.dtype)
+        std = jnp.asarray(IMAGENET_STD, img01.dtype)
+        out = (img01 - mean) / std
+        if cutout_length > 0:
+            # CutoutDefault applies post-normalize on every dataset family
+            # when conf cutout > 0 (reference data.py:111-112)
+            out = cutout_default(out, k_cut, cutout_length)
     return out
 
 
@@ -201,15 +206,18 @@ def imagenet_train_batch(images: jax.Array, key: jax.Array,
     "grouped" applies the policy with scalar op dispatch (stratified
     per-chunk sub-policy draws) before the per-image jitter stack."""
     check_aug_dispatch(aug_dispatch)
-    images = images.astype(jnp.float32)
+    with jax.named_scope(scopes.AUG_FIXED):
+        images = images.astype(jnp.float32)
     single_sub = policy is not None and int(policy.shape[0]) == 1
     if aug_dispatch == "grouped" and policy is not None and not single_sub:
         key, key_pol = jax.random.split(key)
-        images = apply_policy_batch_grouped(images, policy, key_pol,
-                                            groups=aug_groups)
+        with jax.named_scope(scopes.AUG_POLICY):
+            images = apply_policy_batch_grouped(images, policy, key_pol,
+                                                groups=aug_groups)
         policy = None
     scalar = aug_dispatch == "grouped" and single_sub
-    keys = jax.random.split(key, images.shape[0])
+    with jax.named_scope(scopes.AUG_FIXED):
+        keys = jax.random.split(key, images.shape[0])
     return jax.vmap(lambda im, k: _train_one(im, policy, k, cutout_length,
                                              single_sub_scalar=scalar))(images, keys)
 

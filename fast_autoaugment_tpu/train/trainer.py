@@ -291,25 +291,27 @@ def train_and_eval(
 
     Resilience (docs/RESILIENCE.md; defaults preserve the historical
     behavior bit-for-bit): SIGTERM/SIGUSR1 requests a graceful stop —
-    the loop checkpoints at the next dispatch-chunk (cache path) or
-    epoch boundary with ``preempted: true`` metadata and raises
+    the loop checkpoints at the next dispatch boundary (either feed:
+    a scan chunk on the cache path, a batch on the host-fed one) with
+    ``preempted: true`` metadata and the position in the epoch, and raises
     :class:`PreemptedError` (exit-code contract 77 = "resume me").
     ``divergence_retries`` (R, default 0 = raise as before) rolls a
     non-finite epoch loss back to the newest intact epoch-boundary
     checkpoint up to R times, folding the retry counter into the PRNG
     and shuffle seeds so the replay draws fresh randomness.
     ``ckpt_keep`` bounds the rollback chain (``path``, ``path.prev``,
-    …).  ``checkpoint_every_dispatch`` (M, cache path only) adds a
-    mid-epoch snapshot every M dispatches — resumable from the exact
-    dispatch boundary, bit-identically.
+    …).  ``checkpoint_every_dispatch`` (M) adds a mid-epoch snapshot
+    every M dispatches — resumable from the exact dispatch boundary,
+    bit-identically (the host-fed resume skips the batches already
+    trained without decoding them).
 
     ``watchdog`` ("off" default / "auto" / seconds, or a shared
     :class:`~fast_autoaugment_tpu.core.watchdog.DispatchWatchdog`)
     deadline-guards every train dispatch and eval replay; a wedged
     dispatch raises the typed ``DispatchHungError`` (exit-77 restart
     recovery) instead of blocking forever.  ``heartbeat`` (callable,
-    e.g. a work-queue lease renewal) is invoked at every dispatch-chunk
-    boundary (cache path) and epoch boundary — a raised
+    e.g. a work-queue lease renewal) is invoked after every dispatch
+    and at every epoch boundary — a raised
     ``LeaseLostError`` propagates and aborts the unit.
 
     The persistent compilation cache is always armed where
@@ -480,13 +482,11 @@ def train_and_eval(
     if save_path:
         # lenient when the file came from the torch importer (no opt_state)
         lenient = bool((read_metadata(save_path) or {}).get("imported_from"))
-        # restore from the NEWEST intact chain link; mid-epoch
-        # (preempted) snapshots are only restorable where the dispatch
-        # position can be fast-forwarded — the device-cache index feed.
-        # The host path walks back to an epoch-boundary link instead.
+        # restore from the NEWEST intact chain link; a mid-epoch
+        # (preempted) snapshot fast-forwards its epoch to the dispatch
+        # position it names, on either feed
         restored = load_checkpoint_chain(
-            save_path, state, lenient=lenient, keep=ckpt_keep,
-            accept=None if use_cache else (lambda m: "in_epoch" not in m))
+            save_path, state, lenient=lenient, keep=ckpt_keep)
         if restored is not None and "in_epoch" in restored[1]:
             rec = restored[1]["in_epoch"] or {}
             if int(rec.get("epoch", -1)) != int(restored[1].get("epoch", 0)) + 1:
@@ -534,14 +534,18 @@ def train_and_eval(
     elif only_eval and save_path:
         raise FileNotFoundError(f"--only-eval requires a checkpoint at {save_path}")
 
-    if use_cache:
-        # commit the carried state to the mesh BEFORE the first dispatch
-        # or eval: an uncommitted state compiled against the
-        # mesh-committed cache knocks every later call off the C++ fast
-        # dispatch path (make_multistep_train_step note), and an
-        # --only-eval restore must lower the SAME replay_eval program
-        # the training run cached, not an uncommitted variant of it
-        state = jax.device_put(state, replicated(mesh))
+    # commit the carried state to the mesh BEFORE the first dispatch or
+    # eval, on either feed.  Cached: an uncommitted state compiled
+    # against the mesh-committed cache knocks every later call off the
+    # C++ fast dispatch path (make_multistep_train_step note), and an
+    # --only-eval restore must lower the SAME replay_eval program the
+    # training run cached, not an uncommitted variant of it.  Host-fed:
+    # the batches arrive committed, so the first step returns a
+    # committed state and the SECOND call no longer matches what the
+    # first compiled — the step program was lowered and compiled (or
+    # loaded) twice a process (ResNet-50 on one v5e: 49 s of a cold
+    # run's first epoch, 7 s of a warm one's; my chip runs, PR 32)
+    state = jax.device_put(state, replicated(mesh))
 
     result: dict = {"epoch": epoch_start - 1}
     best_metric = -1e9
@@ -675,6 +679,28 @@ def train_and_eval(
                     f"\r[epoch {epoch} batch {bi + 1}] loss_ema={loss_ema:.4f} ")
                 sys.stderr.flush()
 
+        def snapshot_in_epoch(pos: int, sums: dict, epoch=epoch):
+            """Mid-epoch checkpoint at a dispatch boundary: the exact
+            position and the epoch's metric sums so far (either feed)."""
+            save_checkpoint(
+                save_path, state,
+                {"epoch": epoch - 1,
+                 "step": (epoch - 1) * steps_per_epoch + pos,
+                 "preempted": preemption_requested(),
+                 "in_epoch": {
+                     "epoch": epoch, "pos": pos,
+                     "sums": {k: float(v) for k, v in sums.items()},
+                     "retries": retries_done}},
+                keep=ckpt_keep)
+
+        def preempted_in_epoch(pos: int, total: int, epoch=epoch):
+            logger.warning(
+                "preempted at epoch %d dispatch boundary (position %d/%d) "
+                "— checkpointed, exit %d means 'resume me'", epoch, pos,
+                total, PREEMPTED_EXIT_CODE)
+            return PreemptedError(
+                f"preempted mid-epoch {epoch} at dispatch position {pos}")
+
         if train_cache is not None:
             # device-resident feed: the per-epoch shuffle is the
             # IDENTICAL host permutation; only the index matrix is
@@ -723,41 +749,35 @@ def train_and_eval(
                 if pos < len(mat) and (preemption_requested() or periodic):
                     if save_path and is_master:
                         sums = _sum_metric_dicts(dispatch_metrics)
-                        save_checkpoint(
-                            save_path, state,
-                            {"epoch": epoch - 1,
-                             "step": (epoch - 1) * steps_per_epoch + pos,
-                             "preempted": preemption_requested(),
-                             "in_epoch": {
-                                 "epoch": epoch, "pos": pos,
-                                 "sums": {k: float(v)
-                                          for k, v in sums.items()},
-                                 "retries": retries_done}},
-                            keep=ckpt_keep)
+                        snapshot_in_epoch(pos, sums)
                         # saved sums replace the pending handles — the
                         # continued f32 chain is identical either way
                         dispatch_metrics = [
                             {k: np.float32(v) for k, v in sums.items()}]
                     if preemption_requested():
-                        logger.warning(
-                            "preempted at epoch %d dispatch boundary "
-                            "(position %d/%d) — checkpointed, exit %d "
-                            "means 'resume me'", epoch, pos, len(mat),
-                            PREEMPTED_EXIT_CODE)
-                        raise PreemptedError(
-                            f"preempted mid-epoch {epoch} at dispatch "
-                            f"position {pos}")
+                        raise preempted_in_epoch(pos, len(mat))
             acc.add_dict(_sum_metric_dicts(dispatch_metrics))
         else:
+            # host feed: the same resume points as the device-resident
+            # feed, one batch a dispatch.  A resumed epoch skips the
+            # batches already trained without decoding them (their crop
+            # boxes are still drawn, so the rest of the epoch is the
+            # unbroken run's) and continues the saved metric sums.
+            pos = 0
+            if resume_pos and epoch == epoch_start:
+                pos = resume_pos
+                if resume_sums:
+                    acc.add_dict(resume_sums)
             batches = prefetch(
                 train_it.train_epoch(
                     global_batch, epoch, seed=seed_epoch,
                     process_index=jax.process_index(),
                     process_count=jax.process_count(),
+                    skip=pos,
                 ),
                 transform=shard_transform(mesh),
             )
-            for bi, batch in enumerate(batches):
+            for bi, batch in enumerate(batches, start=pos):
                 state, metrics = _monitored_dispatch(
                     wd, "train_step", fi,
                     (epoch - 1) * steps_per_epoch + bi + 1,
@@ -765,8 +785,18 @@ def train_and_eval(
                     pol, rng_epoch)
                 acc.add_dict(metrics)
                 progress(bi, metrics)
+                pos = bi + 1
+                _beat(heartbeat)
                 if fi is not None:
-                    fi.maybe_signal((epoch - 1) * steps_per_epoch + bi + 1)
+                    fi.maybe_signal((epoch - 1) * steps_per_epoch + pos)
+                periodic = (checkpoint_every_dispatch > 0
+                            and pos % checkpoint_every_dispatch == 0)
+                if pos < steps_per_epoch and (preemption_requested()
+                                              or periodic):
+                    if save_path and is_master:
+                        snapshot_in_epoch(pos, dict(acc.items()))
+                    if preemption_requested():
+                        raise preempted_in_epoch(pos, steps_per_epoch)
         _beat(heartbeat)
         resume_pos, resume_sums = 0, None  # consumed by the first epoch
         if is_master and progress_every and loss_ema is not None:
@@ -877,10 +907,11 @@ def train_and_eval(
                     epoch=epoch,
                 )
 
-        # graceful preemption at the epoch boundary (the host path's
-        # only safe point; the cache path usually caught the flag at a
-        # dispatch boundary already): checkpoint the COMPLETED epoch
-        # with preempted metadata and exit via the 77 contract
+        # graceful preemption at the epoch boundary (both feeds usually
+        # caught the flag at a dispatch boundary already; this is the
+        # request that arrived with the epoch's last dispatch or during
+        # its evaluation): checkpoint the COMPLETED epoch with preempted
+        # metadata and exit via the 77 contract
         if preemption_requested():
             if save_path and is_master:
                 save_checkpoint(
@@ -1320,9 +1351,9 @@ def train_folds_stacked(
                     stacked, batch["x"], batch["y"], pol, keys, active)
                 epoch_sums = metrics if epoch_sums is None else {
                     kk: epoch_sums[kk] + metrics[kk] for kk in epoch_sums}
+                _beat(heartbeat)
                 if fi is not None:
                     fi.maybe_signal((epoch - 1) * steps_per_epoch + bi + 1)
-            _beat(heartbeat)
         host_sums = {kk: np.asarray(v)
                      for kk, v in (epoch_sums or {}).items()}
 

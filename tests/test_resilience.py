@@ -382,7 +382,12 @@ def test_sigterm_preemption_checkpoints_and_resumes_bit_identical(tmp_path):
 
 
 @pytest.mark.slow
-def test_sigterm_on_host_path_preempts_at_epoch_boundary(tmp_path):
+def test_sigterm_on_host_path_preempts_at_dispatch_boundary(tmp_path):
+    """The host-fed path gives the device-cache path's guarantee (PR 32;
+    the fast form is tests/test_hostfed_preemption.py): SIGTERM mid-epoch-1
+    -> a checkpoint with the exact position -> the rerun skips the batches
+    already trained and lands a checkpoint bit-identical to an unbroken
+    run's."""
     from fast_autoaugment_tpu.core.resilience import PreemptedError
     from fast_autoaugment_tpu.train.trainer import train_and_eval
 
@@ -395,9 +400,9 @@ def test_sigterm_on_host_path_preempts_at_epoch_boundary(tmp_path):
         train_and_eval(conf, tmp, save_path=part, device_cache="off",
                        **_TRAIN_KW)
     meta = read_metadata(part)
-    # host path: honored at the epoch boundary, no mid-epoch record
-    assert meta["preempted"] is True and meta["epoch"] == 1
-    assert "in_epoch" not in meta
+    assert meta["preempted"] is True and meta["epoch"] == 0
+    assert meta["step"] == 2
+    assert (meta["in_epoch"]["epoch"], meta["in_epoch"]["pos"]) == (1, 2)
 
     os.environ.pop("FAA_FAULT")
     faultinject.reset()

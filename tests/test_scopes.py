@@ -367,3 +367,58 @@ def test_parse_scope_map_takes_names_without_the_percent_sign():
     assert module == "jit_multi_fn"
     assert scopes.scope_of(table["fusion.2361"])[-1] == "faa_aug_op_Equalize"
     assert scopes.scope_of(table["fusion.9"]) == ("faa_optimizer",)
+
+
+# ------------------------------------------- the host-fed (ImageNet) step
+
+HOSTFED_TABLE = (scopes.AUG_POLICY, scopes.AUG_FIXED, scopes.AUG_JITTER,
+                 scopes.AUG_LIGHTING, scopes.MODEL, scopes.RESNET_STEM,
+                 scopes.LOSS, scopes.OPTIMIZER, scopes.METRICS)
+
+
+@pytest.fixture(scope="module")
+def lowered_hostfed_step():
+    """The single-step program ``train_and_eval`` builds for a lazy data
+    set (``make_train_step`` with ``imagenet_train_batch`` as its
+    ``augment_fn``), lowered on a ResNet-50 at 32 px with a two-row policy."""
+    from fast_autoaugment_tpu.models import get_model
+    from fast_autoaugment_tpu.ops.optim import build_optimizer
+    from fast_autoaugment_tpu.ops.preprocess_imagenet import imagenet_train_batch
+    from fast_autoaugment_tpu.train.steps import create_train_state, make_train_step
+
+    model = get_model({"type": "resnet50", "dataset": "imagenet"}, 1000)
+    opt = build_optimizer({"type": "sgd", "decay": 1e-4, "nesterov": True},
+                          lambda s: 0.05)
+    step = make_train_step(
+        model, opt, num_classes=1000, cutout_length=0, use_policy=True,
+        augment_fn=lambda images, policy, key: imagenet_train_batch(
+            images, key, policy, cutout_length=0))
+    state = jax.eval_shape(lambda: create_train_state(
+        model, opt, jax.random.PRNGKey(0), jnp.zeros((2, 32, 32, 3)), False))
+    return step.lower(
+        state, jax.ShapeDtypeStruct((4, 32, 32, 3), jnp.uint8),
+        jax.ShapeDtypeStruct((4,), jnp.int32),
+        jax.ShapeDtypeStruct((2, 2, 3), jnp.float32),
+        jax.ShapeDtypeStruct((2,), jnp.uint32)).as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("name", HOSTFED_TABLE)
+def test_a_scope_of_the_hostfed_step_reaches_its_lowering(
+        lowered_hostfed_step, name):
+    assert name in lowered_hostfed_step
+
+
+def test_the_new_scopes_nest_where_the_readers_look(lowered_hostfed_step):
+    """Jitter and Lighting inside ``faa_aug_fixed`` (so ``aug_fixed_device_ms``
+    holds them), the stem inside ``faa_model``, forward and backward, and
+    the policy's operations inside ``faa_aug_policy`` as on the CIFAR step."""
+    text = lowered_hostfed_step
+    assert f"vmap({scopes.AUG_FIXED})/{scopes.AUG_JITTER}/" in text
+    assert f"vmap({scopes.AUG_FIXED})/{scopes.AUG_LIGHTING}/" in text
+    assert f"jvp({scopes.MODEL})/ResNet/{scopes.RESNET_STEM}/" in text
+    assert f"transpose(jvp({scopes.MODEL}))/ResNet/{scopes.RESNET_STEM}/" in text
+    assert f"vmap({scopes.AUG_POLICY})/" in text
+    for name in (scopes.AUG_JITTER, scopes.AUG_LIGHTING, scopes.RESNET_STEM):
+        assert name.startswith(scopes.PREFIX)
+        chain = scopes.scope_of(f"jit(step_fn)/vmap(faa_aug_fixed)/{name}/add")
+        assert chain == ("faa_aug_fixed", name)
