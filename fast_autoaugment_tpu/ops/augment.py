@@ -16,8 +16,10 @@ Semantics were pinned against PIL empirically and are exact (see
 - affine/rotate: nearest-neighbor, ``src = floor(A @ (x, y) + t + 0.5)``,
   fill 0, rotate about ``(W/2, H/2)``  (PIL ``Image.transform``
   with ``AFFINE`` / ``Image.rotate``, reference ``augmentations.py:17-62``);
-  the seven operations are seven 2x3 matrices into ONE warp, which up
-  to 384 px addresses its source pixels by one-hot products, no gather
+  the seven operations are seven 2x3 matrices into ONE warp, which
+  addresses its source pixels by one-hot products, no gather: over the
+  whole image at CIFAR size, over a bounded source window per output
+  tile at ImageNet size
 - L (grayscale): ``(r*19595 + g*38470 + b*7471 + 0x8000) >> 16``
 - enhance ops: ``clip(trunc(deg + (img - deg) * factor), 0, 255)`` in
   float32 (PIL ``ImageEnhance`` via ``Image.blend``)
@@ -150,33 +152,88 @@ def _blend(degenerate: jax.Array, img: jax.Array, factor: jax.Array) -> jax.Arra
     return jnp.clip(jnp.trunc(out), 0.0, 255.0)
 
 
-# The warp addresses its source pixels in one of two ways, chosen by the
-# static image shape it is traced with.  Dense: two one-hot products, no
-# data-dependent addressing (a gather is the slowest thing the TPU does:
-# 9 ns a row of three floats, whatever the image).  The dense form's work
-# grows as (H*W)^2*C an image where the gather's grows as H*W, so there is
-# a size where it stops winning: alone on a v5e it takes 2.1 ms against
-# 18.9 at 2,048 x 32 px, 25.5 against 63.2 at 128 x 224 px, 38.4 against
-# 49.0 at 32 x 384 px, and loses at 448 px (PERF.md section 6, PR 29).
-# Its intermediate (the rows picked for every output pixel) grows as
-# H*W*W*C an image, 196 KB at 32 px and 67 MB at 224: the batch runs in
-# chunks whose rows fit the budget, which costs no time (PERF.md).
-_DENSE_WARP_MAX_PIXELS = 384 * 384
+# The warp addresses its source pixels by one-hot products, nothing
+# addressed by data (a gather is the slowest thing the TPU does: 9 ns a
+# row of three floats, whatever the image).  An output pixel pays for the
+# window its source pixel is looked up in.  An image of fewer pixels than
+# the window of a `_WARP_TILE` tile is one tile and its own window: 3.1
+# MMAC an image at 32 px and nothing, where 224 px would be 7.55 GMAC.
+# A larger one is cut into tiles of at most `_WARP_TILE` output pixels a
+# side, and a tile looks its pixels up in a window that the seven
+# operations' matrices cannot leave while their values stay inside
+# `_OP_TABLE`'s ranges (:func:`_warp_window_side`): 1.26 GMAC an image at
+# 224 px, the windows' fetch included, and linear in the pixels but for
+# the rows picked for the windows (H*W*H*W*C*S/T**2: a sixth of the work
+# at 224 px, most of it at 600, where the tiled form still takes a
+# quarter of the gather's time).  The intermediates (the rows picked for
+# every output pixel) run in chunks of the batch that fit
+# `_DENSE_WARP_BUDGET_BYTES`, which costs no time.  Every form timed
+# alone on a v5e at 32 to 600 px, and why 56: PERF.md section 6, PR 33.
+_WARP_TILE = 56
 _DENSE_WARP_BUDGET_BYTES = 256 << 20
 
 
-def _resample_dense_one(img, sy, sx):
+def _warp_window_side(tile: int) -> int:
+    """Source pixels a side that a `tile` x `tile` block of output pixels
+    can reach under any of the seven operations inside `_OP_TABLE`'s
+    ranges, rounded up to whole sublanes.
+
+    A source coordinate is ``floor(a*x + b*y + t)``: over the block it
+    takes at most ``floor((tile - 1) * (|a| + |b|)) + 2`` values, and
+    ``|a| + |b|`` is at most ``1 + |shear|`` or ``cos + sin`` of the
+    largest rotation (a translation moves the window, not its size).
+    The hundredth covers float32 rounding of coordinates under 2**13."""
+    def reach(name):  # the sign flip reaches both ends of a range
+        return float(max(abs(_OP_LOW[op_index(name)]), abs(_OP_HIGH[op_index(name)])))
+
+    turn = np.deg2rad(min(reach("Rotate"), 45.0))
+    gain = max(1.0 + reach("ShearX"), 1.0 + reach("ShearY"),
+               float(np.cos(turn) + np.sin(turn)))
+    return -(-(int((tile - 1) * gain + 0.01) + 2) // 8) * 8
+
+
+def _warp_tiling(h: int, w: int):
+    """``((tile rows, tile columns), window rows, window columns)`` of an
+    ``h x w`` image, or None where the image is one tile and its own
+    window.
+
+    A side is cut into as few tiles as `_WARP_TILE` allows, of equal size:
+    240 px is 5 x 48, not 5 x 56 with a third of the last tile empty.  A
+    side shorter than the window is the window."""
+    if h * w < _warp_window_side(_WARP_TILE) ** 2:
+        return None
+    th, tw = (-(-n // -(-n // _WARP_TILE)) for n in (h, w))
+    side = _warp_window_side(max(th, tw))
+    return (th, tw), min(side, h), min(side, w)
+
+
+def _warp_bytes_an_image(h: int, w: int, c: int) -> int:
+    """What the resampling of one image keeps at a time, in bfloat16: the
+    rows picked for every output pixel and, where it is tiled, those
+    picked for every window."""
+    tiling = _warp_tiling(h, w)
+    if tiling is None:
+        return h * w * w * c * 2
+    (th, tw), sh, sw = tiling
+    tiles = -(-h // th) * -(-w // tw)
+    return tiles * (th * tw * sw + sh * w) * c * 2
+
+
+def _resample_dense_one(img, sy, sx, planar=False):
     """``img[sy, sx]`` with zero fill, as two one-hot selections through
     the MXU and nothing addressed by data.
 
-    Rows: ``[H*W, H] x [H, W*C]`` picks the source row of every output
+    Rows: ``[P, H] x [H, W*C]`` picks the source row of every output
     pixel.  Columns: that row masked down to the source column's C
-    lanes, then ``[H*W, W*C] x [W*C, C]`` against a constant selector
+    lanes, then ``[P, W*C] x [W*C, C]`` against a constant selector
     sums the lanes of each channel.  Exact, whatever the ambient matmul
     precision: every sum is of one pixel value and zeros, and pixel
     values are integers in [0, 255], which bfloat16 holds.  An index out
-    of range matches no row or lane, which is the zero fill."""
-    h, w, c = img.shape
+    of range matches no row or lane, which is the zero fill.
+
+    `img` is ``[H, W, C]``, or ``[H, C, W]`` where `planar` (a window of
+    :func:`_fetch_windows`); the result has `sy`'s shape and then C."""
+    h, w, c = (img.shape[0], img.shape[2], img.shape[1]) if planar else img.shape
     contract = (((1,), (0,)), ((), ()))
     rows = jax.lax.dot_general(
         (sy.reshape(-1, 1) == jnp.arange(h)).astype(jnp.bfloat16),
@@ -185,26 +242,130 @@ def _resample_dense_one(img, sy, sx):
     # jnp.arange, not np: the batching of a switch would batch a constant
     # this function closed over, which a custom_vmap rule refuses
     lane = jnp.arange(w * c)
-    masked = jnp.where(sx.reshape(-1, 1) == lane // c, rows, 0)
-    channel_of = (lane[:, None] % c == jnp.arange(c)).astype(jnp.bfloat16)
+    masked = jnp.where(sx.reshape(-1, 1) == (lane % w if planar else lane // c), rows, 0)
+    lane = lane[:, None]
+    channel_of = ((lane // w if planar else lane % c) == jnp.arange(c)).astype(jnp.bfloat16)
     out = jax.lax.dot_general(masked, channel_of, contract,
                               preferred_element_type=jnp.float32)
-    return out.reshape(h, w, c)
+    return out.reshape(sy.shape + (c,))
+
+
+def _in_chunks(one, bytes_an_image, *args):
+    """``vmap(one)`` over the leading axis, in chunks of the batch whose
+    intermediates fit `_DENSE_WARP_BUDGET_BYTES`; never of one image,
+    which runs at 0.4 of a larger chunk's rate (from 528 px a side:
+    PERF.md section 6, PR 33)."""
+    chunk = max(2, _DENSE_WARP_BUDGET_BYTES // bytes_an_image)
+    if args[0].shape[0] <= chunk:
+        return jax.vmap(one)(*args)
+    return jax.lax.map(lambda a: one(*a), args, batch_size=chunk)
+
+
+def _fetch_windows(img, oy, ox, sh, sw):
+    """The ``sh x sw`` windows of `img` ``[H, W, C]`` at the origins
+    `oy`, `ox` (``[tiles]`` each, inside the image), as ``[tiles, sh, C,
+    sw]``: the one-hot of a window's rows picks them out of the image,
+    channels apart (one product for every tile), and the one-hot of its
+    columns picks those out of the rows (a product a tile)."""
+    h, w, c = img.shape
+    tiles = oy.shape[0]
+    pick_rows = (oy[:, None] + jnp.arange(sh)).reshape(-1, 1) == jnp.arange(h)
+    planes = img.astype(jnp.bfloat16).transpose(0, 2, 1).reshape(h, c * w)
+    rows = jax.lax.dot_general(
+        pick_rows.astype(jnp.bfloat16), planes, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.bfloat16)  # [tiles * sh, C * W]
+    pick_cols = jnp.arange(w)[:, None] == ox[:, None, None] + jnp.arange(sw)
+    wins = jax.lax.dot_general(
+        rows.reshape(tiles, sh * c, w), pick_cols.astype(jnp.bfloat16),
+        (((2,), (1,)), ((0,), (0,))), preferred_element_type=jnp.bfloat16)
+    return wins.reshape(tiles, sh, c, sw)
+
+
+def _tiles_of(grid, th, tw):
+    """``[H, W]`` -> ``[tiles, th * tw]``; the last tiles of a side no
+    tile divides repeat its last row or column."""
+    h, w = grid.shape
+    rows, cols = -(-h // th), -(-w // tw)
+    grid = jnp.pad(grid, ((0, rows * th - h), (0, cols * tw - w)), mode="edge")
+    return grid.reshape(rows, th, cols, tw).transpose(0, 2, 1, 3).reshape(
+        rows * cols, th * tw)
+
+
+def _window_origins(tiles, size, side):
+    """Where the windows of `tiles` (``[..., tiles, pixels]`` source
+    indices along an axis of `size` pixels) start: at the smallest index
+    of a tile, clamped so that the `side` pixels lie inside the image."""
+    return jnp.clip(tiles.min(axis=-1), 0, size - side)
+
+
+def _resample_tiled_one(img, sy, sx):
+    """:func:`_resample_dense_one` of an image of many tiles: every tile
+    looks its pixels up in a window of its own (:func:`_warp_tiling`).
+
+    Every index inside the image that a tile needs lies in its window if
+    :func:`_fits_windows` says so, and one outside the image lies outside
+    the window too (the window is inside the image), which is the zero
+    fill."""
+    h, w, c = img.shape
+    (th, tw), sh, sw = _warp_tiling(h, w)
+    ty, tx = _tiles_of(sy, th, tw), _tiles_of(sx, th, tw)
+    oy, ox = _window_origins(ty, h, sh), _window_origins(tx, w, sw)
+    out = jax.vmap(functools.partial(_resample_dense_one, planar=True))(
+        _fetch_windows(img, oy, ox, sh, sw), ty - oy[:, None], tx - ox[:, None])
+    rows, cols = -(-h // th), -(-w // tw)
+    out = out.reshape(rows, cols, th, tw, c).transpose(0, 2, 1, 3, 4)
+    return out.reshape(rows * th, cols * tw, c)[:h, :w]
+
+
+def _fits_windows(sy, sx, tile, sh, sw):
+    """Whether, in every tile of every image, the largest source row and
+    column inside the image lie in the tile's window (``[N, H, W]`` each
+    -> a scalar).  The smallest do by where a window starts."""
+    def fits(grid, size, side):
+        tiles = jax.vmap(lambda g: _tiles_of(g, *tile))(grid)
+        last = _window_origins(tiles, size, side) + side
+        return jnp.all(jnp.minimum(tiles.max(axis=-1), size - 1) < last)
+    return fits(sy, sy.shape[1], sh) & fits(sx, sx.shape[2], sw)
+
+
+def _resample_gather_one(img, sy, sx):
+    """``img[sy, sx]`` with zero fill as a gather: slow, and right for
+    any indices."""
+    h, w = img.shape[0], img.shape[1]
+    valid = (sx >= 0) & (sx < w) & (sy >= 0) & (sy < h)
+    gathered = img[jnp.clip(sy, 0, h - 1), jnp.clip(sx, 0, w - 1)]
+    return jnp.where(valid[..., None], gathered, 0.0)
+
+
+def _tile_label(tile, sh, sw) -> str:
+    """``TxS``: output pixels and window pixels a side; all four where
+    the sides differ."""
+    th, tw = tile
+    return f"{th}x{sh}" if (th, sh) == (tw, sw) else f"{th}x{tw}x{sh}x{sw}"
 
 
 @jax.custom_batching.custom_vmap
 def _resample_dense(imgs, sy, sx):
-    """:func:`_resample_dense_one` over a leading axis of N images.
+    """``imgs[n, sy[n], sx[n]]`` with zero fill over a leading axis of N
+    images, by the form :func:`_warp_tiling` gives their shape.
 
     Its ``vmap`` rule folds every batch axis a caller adds into N, so this
     body is traced with the whole batch in sight, which a per-image
-    function under ``vmap`` never is, and can bound what it allocates."""
-    n, h, w, c = imgs.shape
-    chunk = max(1, _DENSE_WARP_BUDGET_BYTES // (h * w * w * c * 2))  # bfloat16 rows
-    if n <= chunk:
-        return jax.vmap(_resample_dense_one)(imgs, sy, sx)
-    return jax.lax.map(lambda a: _resample_dense_one(*a), (imgs, sy, sx),
-                       batch_size=chunk)
+    function under ``vmap`` never is: it can bound what it allocates,
+    and it can ask of the whole batch at once, at run time, whether the
+    indices stay inside the tiles' windows.  They do for every matrix of
+    the seven operations at a level in [0, 1]; where one does not (the
+    named functions take any value, a policy tensor any level), the
+    batch is gathered: never a wrong pixel, and no value is clamped."""
+    h, w, c = imgs.shape[1:]
+    tiling = _warp_tiling(h, w)
+    bytes_an_image = _warp_bytes_an_image(h, w, c)
+    if tiling is None:
+        return _in_chunks(_resample_dense_one, bytes_an_image, imgs, sy, sx)
+    return jax.lax.cond(
+        _fits_windows(sy, sx, *tiling),
+        functools.partial(_in_chunks, _resample_tiled_one, bytes_an_image),
+        jax.vmap(_resample_gather_one), imgs, sy, sx)
 
 
 @_resample_dense.def_vmap
@@ -225,24 +386,23 @@ def _warp_affine_nearest(img: jax.Array, mat: jax.Array) -> jax.Array:
     from output to source coords.  PIL samples at pixel centers with a
     plain floor: ``src = floor(A @ (x+0.5, y+0.5) + t)`` (pinned
     empirically; the center offset matters for tie-breaking at .5).
+    Any `mat` gives the right pixels; one of the seven operations inside
+    `_OP_TABLE`'s ranges gives them fast (:func:`_resample_dense`).
     """
     h, w = img.shape[0], img.shape[1]
-    dense = h * w <= _DENSE_WARP_MAX_PIXELS
+    tiling = _warp_tiling(h, w)
     # trace time: which addressing each program that holds a warp got
     telemetry.registry().counter(
         "faa_aug_warp_traces_total", "affine warps traced into a program, "
         "by how they address their source pixels",
-        form="dense" if dense else "gather", image=f"{h}x{w}").inc()
+        form="dense" if tiling is None else "tiled", image=f"{h}x{w}",
+        tile=f"{h}x{w}" if tiling is None else _tile_label(*tiling)).inc()
     with jax.named_scope(scopes.AUG_WARP):
         ys, xs = jnp.mgrid[0:h, 0:w]
         xsf, ysf = xs.astype(jnp.float32) + 0.5, ys.astype(jnp.float32) + 0.5
         sx = jnp.floor(mat[0, 0] * xsf + mat[0, 1] * ysf + mat[0, 2]).astype(jnp.int32)
         sy = jnp.floor(mat[1, 0] * xsf + mat[1, 1] * ysf + mat[1, 2]).astype(jnp.int32)
-        if dense:
-            return _resample_dense(img[None], sy[None], sx[None])[0]
-        valid = (sx >= 0) & (sx < w) & (sy >= 0) & (sy < h)
-        gathered = img[jnp.clip(sy, 0, h - 1), jnp.clip(sx, 0, w - 1)]
-        return jnp.where(valid[..., None], gathered, 0.0)
+        return _resample_dense(img[None], sy[None], sx[None])[0]
 
 
 # Equalize addresses the 256 levels as 16 x 16: level ``16a + b`` is entry

@@ -77,6 +77,34 @@ def test_rotate(h, w, v):
     _check(A.rotate(jnp.float32(img), jnp.float32(v), KEY), pim.rotate(v))
 
 
+def _pil_affine(pim, coeffs):
+    return pim.transform(pim.size, PIL.Image.AFFINE, coeffs)
+
+
+#: the ends of the five ranges, where a tile's window is fullest
+AFFINE_ENDS_224 = {
+    "Rotate": (A.rotate, 30.0, lambda pim, v: pim.rotate(v)),
+    "ShearX": (A.shear_x, 0.3, lambda pim, v: _pil_affine(pim, (1, v, 0, 0, 1, 0))),
+    "ShearY": (A.shear_y, 0.3, lambda pim, v: _pil_affine(pim, (1, 0, 0, v, 1, 0))),
+    "TranslateX": (A.translate_x, 0.45, lambda pim, v: _pil_affine(
+        pim, (1, 0, v * pim.size[0], 0, 1, 0))),
+    "TranslateY": (A.translate_y, 0.45, lambda pim, v: _pil_affine(
+        pim, (1, 0, 0, 0, 1, v * pim.size[1]))),
+}
+
+
+@pytest.mark.parametrize("h,w", [(224, 224), (160, 224)])
+@pytest.mark.parametrize("sign", [-1, 1])
+@pytest.mark.parametrize("name", AFFINE_ENDS_224)
+def test_affine_ends_at_224_px(name, sign, h, w):
+    """ImageNet size, where the warp goes tile by tile (``_warp_tiling``)."""
+    assert A._warp_tiling(h, w) is not None
+    fn, end, pil = AFFINE_ENDS_224[name]
+    img = _rand_img(8, h, w)
+    _check(fn(jnp.float32(img), jnp.float32(sign * end), KEY),
+           pil(PIL.Image.fromarray(img), sign * end))
+
+
 @pytest.mark.parametrize("seed", range(4))
 def test_autocontrast(seed):
     img = _rand_img(seed)
