@@ -83,13 +83,17 @@ def families(names) -> dict[str, callable]:
     forward or backward by where the instruction sits), so what is
     nested under a family's scope is that family's; the two families of
     operations lie inside ``policy`` and match their operations' scopes
-    anywhere in a chain."""
+    anywhere in a chain.  ``geometric_ops`` is the seven operations'
+    scopes (a 2x3 matrix each since PR 29) and the one resampling an op
+    slot those matrices drive, ``names.AUG_WARP``, which stays the
+    policy's in the partition."""
     owners = {names.AUG_POLICY: "policy", names.AUG_FIXED: "fixed",
               names.MODEL: "model", names.LOSS: "model",
               names.OPTIMIZER: "optimizer", names.EMA: "optimizer",
               names.METRICS: "optimizer", names.BATCH_GATHER: "gather"}
     histogram = {names.aug_op(n) for n in HISTOGRAM_OPS}
-    geometric = {names.aug_op(n) for n in GEOMETRIC_OPS}
+    geometric = {names.aug_op(n) for n in GEOMETRIC_OPS} | (
+        {names.AUG_WARP} if hasattr(names, "AUG_WARP") else set())
 
     def owner(key):
         chain, backward = split_key(key)

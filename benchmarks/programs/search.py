@@ -276,14 +276,16 @@ def run(cell: Cell, devices: list, start_wall: float) -> Observed:
     checks["first_trial_reproduced"] = {
         "ok": abs(again - logged_reward) <= float(traffic["reward_tolerance"]),
         "logged": logged_reward, "re_evaluated": again,
-        "tolerance": float(traffic["reward_tolerance"])}
+        "tolerance": float(traffic["reward_tolerance"]),
+        "compared": win.compared(abs(again - logged_reward), "<=",
+                                 float(traffic["reward_tolerance"]))}
     census = executable_census(evaluator.tta_step)
-    checks["one_tta_executable"] = {"ok": census == 1, "executables": census}
+    checks["one_tta_executable"] = {"ok": census == 1, "executables": census,
+                                    "compared": win.compared(census, "==", 1)}
 
     images = load_dataset(conf["dataset"], dataroot)[1].images[
         :int(traffic["reference_images"])]
-    checks["reference_logits"] = win.reference_check(
-        cell, conf, params, batch_stats, images)
+    checks.update(win.reference_check(cell, conf, params, batch_stats, images))
 
     # a trial is the whole held-out fold under every draw
     forwards = float(replayed["cnt"]) * int(entry_args["num_policy"])

@@ -167,6 +167,37 @@ def training_top1(meta: dict) -> float | None:
     return None if value is None else float(value)
 
 
+def no_compile_check(beat: _Beat) -> dict:
+    """Nothing asked the compiler or the cache for a program in the window."""
+    requests = beat.compiles1 - beat.compiles0
+    return {"ok": requests == 0, "compile_requests": requests,
+            "compared": win.compared(requests, "==", 0)}
+
+
+def step_counter_check(meta: dict, counted: int) -> dict:
+    """The preemption checkpoint's step counter is the dispatches counted."""
+    return {"ok": meta.get("step") == counted,
+            "checkpoint_step": meta.get("step"), "steps_counted": counted,
+            "compared": win.compared(meta.get("step"), "==", counted)}
+
+
+def learned_check(meta: dict, evaluated: dict, floor: float) -> dict:
+    """That the steps counted were training: a finite test loss through the
+    ``only_eval`` restore, and top-1 at `floor` or over in the better of
+    two readings (the evaluation's, the trainer's own at the checkpoint)."""
+    top1_train = training_top1(meta)
+    loss_test = float(evaluated.get("loss_test", float("nan")))
+    top1_test = float(evaluated.get("top1_test", float("nan")))
+    best = max((v for v in (top1_test, top1_train)
+                if v is not None and math.isfinite(v)), default=float("nan"))
+    return {"ok": math.isfinite(loss_test) and best >= floor,
+            "top1_train": top1_train, "top1_test": top1_test,
+            "top1_must_reach": floor, "loss_test": loss_test,
+            "num_test": evaluated.get("num_test"),
+            "restored_steps": evaluated.get("steps"),
+            "compared": win.compared(best, ">=", floor)}
+
+
 def run(cell: Cell, devices: list, start_wall: float) -> Observed:
     from flax import serialization
 
@@ -234,16 +265,12 @@ def run(cell: Cell, devices: list, start_wall: float) -> Observed:
     steps = (beat.d1 - beat.d0) * steps_per_dispatch
     rate = steps * global_batch / window_s / len(devices)
     checks["finite_loss"] = {"ok": True}
-    checks["no_compile_in_window"] = {
-        "ok": beat.compiles1 == beat.compiles0,
-        "compile_requests": beat.compiles1 - beat.compiles0}
+    checks["no_compile_in_window"] = no_compile_check(beat)
 
     # -- outside the window: the weights the window ended on ------------
     meta = read_metadata(save_path) or {}
     counted = (beat.last_count - beat.first_count) * steps_per_dispatch
-    checks["step_counter"] = {"ok": meta.get("step") == counted,
-                              "checkpoint_step": meta.get("step"),
-                              "steps_counted": counted}
+    checks["step_counter"] = step_counter_check(meta, counted)
     evaluated = train_and_eval(conf, dataroot, save_path=save_path, mesh=mesh,
                                seed=cell.seed, only_eval=True)
     # that the steps counted were training: top-1 over chance by the
@@ -253,25 +280,16 @@ def run(cell: Cell, devices: list, start_wall: float) -> Observed:
     # steps, once with a loss of 7.05; 14 seeds, my chip runs, PR 22);
     # the trainer's own training top-1 at the checkpoint is steady.  A
     # collapsed model reads chance in both.
-    floor = 1.0 / num_class(conf["dataset"]) + float(traffic["accuracy_margin"])
-    top1_train = training_top1(meta)
-    loss_test = float(evaluated.get("loss_test", float("nan")))
-    top1_test = float(evaluated.get("top1_test", float("nan")))
-    checks["learned"] = {
-        "ok": math.isfinite(loss_test) and (
-            top1_test >= floor
-            or (top1_train is not None and top1_train >= floor)),
-        "top1_train": top1_train, "top1_test": top1_test,
-        "top1_must_reach": floor, "loss_test": loss_test,
-        "num_test": evaluated.get("num_test"),
-        "restored_steps": evaluated.get("steps")}
+    checks["learned"] = learned_check(
+        meta, evaluated,
+        1.0 / num_class(conf["dataset"]) + float(traffic["accuracy_margin"]))
 
     with open(save_path, "rb") as fh:
         saved = serialization.msgpack_restore(fh.read())
     images = load_dataset(conf["dataset"], dataroot)[1].images[
         :int(traffic["reference_images"])]
-    checks["reference_logits"] = win.reference_check(
-        cell, conf, saved["params"], saved["batch_stats"], images)
+    checks.update(win.reference_check(
+        cell, conf, saved["params"], saved["batch_stats"], images))
 
     return Observed(
         cell=cell, devices=devices,

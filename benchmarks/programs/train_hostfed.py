@@ -15,27 +15,23 @@ unchanged; what differs is what that file cannot do for this path:
   270 epochs: the first beat that finds more than ``steps_per_dispatch``
   dispatches since the beat before raises :class:`BeatsTooRarely`, which
   ``train_and_eval`` lets through, and the run exits non-zero there;
-- a traced run leaves the host tracer off (:class:`DeviceOnlyTracer` says
-  what it costs on a path that copies a batch to the chip every step);
+- a traced run leaves the profiler's host tracer off: the traffic file
+  gives ``host_tracer_level`` 0 and says what the host plane costs on a
+  path that copies a batch to the chip every step;
 - the feed's counters (``faa_feed_*``, ``faa_decode_*``: the program's
   registry) are read when the window opens and when it closes, and what
-  they say of the window goes into the ``feed`` check of every run;
-- the comparison with the plain reference goes through
-  ``imagenet_eval_batch`` on centre-cropped validation files, twice: the
-  system as deployed (``reference_logits``, the configuration's
-  ``logit_tolerance``) and under ``jax.default_matmul_precision(
-  "highest")`` (``reference_logits_float32``, its
-  ``logit_tolerance_float32``): what ``train_float32_check.py`` says of
-  the two holds here.
+  they say of the window goes into the ``feed`` check of every run and,
+  through ``Observed.work``, to the readers ``feed_wait_ms`` and
+  ``host_decode_images_per_s``;
+- ``harness/window.py::reference_check``'s two comparisons go through
+  ``imagenet_eval_batch`` on centre-cropped validation files.
 """
 
 from __future__ import annotations
 
 import inspect
-import math
 import os
 import shutil
-import time
 
 import numpy as np
 
@@ -62,29 +58,6 @@ def feed_counters() -> dict[str, float]:
     return {key: value
             for key, value in telemetry.registry().counters_snapshot().items()
             if key.startswith(FEED_COUNTERS)}
-
-
-class DeviceOnlyTracer(win.Tracer):
-    """``harness/window.py``'s tracer with the host tracer off (its level 2
-    is the only difference; ``Tracer`` has no argument for it).  On this
-    path the runtime's transfer threads copy 19 MB to the chip every step,
-    and with the host tracer on, at level 2 and at level 1 alike, each of
-    their inner calls is an event: 12-14 million beside 0.2 million on the
-    device in a 5-7 s stretch, a 420-500 MB file that took 58-74 s to stop
-    and some 40 s to read, and a device idle 19-46% of the traced stretch
-    where no untraced window shows it (my chip runs, PR 32).  The clock
-    marker goes with the host plane, so this cell's traced idle gaps come
-    out ``unattributed``; every per-layer metric reads the device plane."""
-
-    def start(self) -> None:
-        import jax
-
-        shutil.rmtree(self.directory, ignore_errors=True)
-        options = jax.profiler.ProfileOptions()
-        options.python_tracer_level = 0
-        options.host_tracer_level = 0
-        jax.profiler.start_trace(self.directory, profiler_options=options)
-        self.started_perf = self.marker_perf = time.perf_counter()
 
 
 class OneBeatADispatch:
@@ -134,39 +107,6 @@ class OneBeatADispatch:
         return out
 
 
-def reference_checks(cell: Cell, conf, params, batch_stats, images) -> dict:
-    """``{"reference_logits", "reference_logits_float32"}``: the system's
-    model behind ``imagenet_eval_batch``, jitted as the evaluation step
-    runs them, against the configuration's plain reference on `images`
-    (uint8, centre-cropped): at the deployed matmul precision and under
-    ``highest``, each with its own limit."""
-    import jax
-
-    from fast_autoaugment_tpu.models import get_model, num_class
-    from fast_autoaugment_tpu.ops.preprocess_imagenet import imagenet_eval_batch
-
-    model_conf = dict(conf["model"], dataset=conf["dataset"])
-    model_conf.setdefault("precision", conf.get("precision", "f32"))
-    model = get_model(model_conf, num_class(conf["dataset"]))
-
-    def system():
-        return np.asarray(jax.jit(lambda p, s, x: model.apply(
-            {"params": p, "batch_stats": s}, imagenet_eval_batch(x),
-            train=False))(params, batch_stats, images))
-
-    plain = cell.module("references", cell.config["reference"]).forward(
-        jax.device_get(params), jax.device_get(batch_stats), images,
-        cell.config["model"])
-    deployed = system()
-    with jax.default_matmul_precision("highest"):
-        highest = system()
-    return {
-        "reference_logits": win.logits_agreement(
-            deployed, plain, float(cell.config["logit_tolerance"])),
-        "reference_logits_float32": win.logits_agreement(
-            highest, plain, float(cell.config["logit_tolerance_float32"]))}
-
-
 def validation_images(conf, dataroot: str, count: int) -> np.ndarray:
     """The first `count` validation files as the trainer's evaluation
     feed hands them over: decoded, centre-cropped, resized, uint8."""
@@ -197,6 +137,7 @@ def run(cell: Cell, devices: list, start_wall: float) -> Observed:
     )
     from fast_autoaugment_tpu.data import native_loader
     from fast_autoaugment_tpu.models import num_class
+    from fast_autoaugment_tpu.ops.preprocess_imagenet import imagenet_eval_batch
     from fast_autoaugment_tpu.parallel.mesh import make_mesh
     from fast_autoaugment_tpu.train.trainer import train_and_eval
 
@@ -218,8 +159,6 @@ def run(cell: Cell, devices: list, start_wall: float) -> Observed:
         inspect.signature(train_and_eval).parameters["steps_per_dispatch"].default))
 
     beat = train._Beat(cell, devices, mesh, start_wall)
-    if beat.tracer is not None:
-        beat.tracer = DeviceOnlyTracer(beat.tracer.directory)
     guarded = OneBeatADispatch(beat, steps_per_dispatch)
     diverged = None
     clear_preemption()
@@ -246,13 +185,15 @@ def run(cell: Cell, devices: list, start_wall: float) -> Observed:
     low, high = cell.fixture["mean_file_bytes_must_lie_in"]
     # the files have ImageNet's size, and the decoder is the install's: PIL
     # threads pass only where there is nothing to build the loader with
+    feed = guarded.feed_over_the_window()
     checks: dict[str, dict] = {"feed": dict(
-        guarded.feed_over_the_window(),
+        feed,
         ok=low <= wrote["mean_file_bytes"] <= high and (
             decoder == "native" or not (shutil.which("make")
                                         and shutil.which("g++"))),
         decoder=decoder, cpu_count=os.cpu_count(), fixture=wrote,
-        mean_file_bytes_must_lie_in=[low, high])}
+        mean_file_bytes_must_lie_in=[low, high],
+        compared=win.compared(wrote["mean_file_bytes"], "in", [low, high]))}
     if diverged or beat.state != "closed":
         steps = 0 if beat.d0 is None else (beat.last_count - beat.d0) * steps_per_dispatch
         checks["finite_loss"] = {"ok": False, "why": diverged or
@@ -267,39 +208,27 @@ def run(cell: Cell, devices: list, start_wall: float) -> Observed:
     steps = (beat.d1 - beat.d0) * steps_per_dispatch
     rate = steps * global_batch / window_s / len(devices)
     checks["finite_loss"] = {"ok": True}
-    checks["no_compile_in_window"] = {
-        "ok": beat.compiles1 == beat.compiles0,
-        "compile_requests": beat.compiles1 - beat.compiles0}
+    checks["no_compile_in_window"] = train.no_compile_check(beat)
 
     # -- outside the window: the weights the window ended on ------------
     meta = read_metadata(save_path) or {}
     counted = (beat.last_count - beat.first_count) * steps_per_dispatch
-    checks["step_counter"] = {"ok": meta.get("step") == counted,
-                              "checkpoint_step": meta.get("step"),
-                              "steps_counted": counted}
+    checks["step_counter"] = train.step_counter_check(meta, counted)
     evaluated = train_and_eval(conf, dataroot, save_path=save_path, mesh=mesh,
                                seed=cell.seed, only_eval=True)
     # that the steps counted were training: top-1 over chance (one in the
     # head's 1,000 outputs) by the traffic file's margin, in either of
     # programs/train.py's two readings
-    floor = 1.0 / num_class(conf["dataset"]) + float(traffic["accuracy_margin"])
-    top1_train = train.training_top1(meta)
-    loss_test = float(evaluated.get("loss_test", float("nan")))
-    top1_test = float(evaluated.get("top1_test", float("nan")))
-    checks["learned"] = {
-        "ok": math.isfinite(loss_test) and (
-            top1_test >= floor
-            or (top1_train is not None and top1_train >= floor)),
-        "top1_train": top1_train, "top1_test": top1_test,
-        "top1_must_reach": floor, "loss_test": loss_test,
-        "num_test": evaluated.get("num_test"),
-        "restored_steps": evaluated.get("steps")}
+    checks["learned"] = train.learned_check(
+        meta, evaluated,
+        1.0 / num_class(conf["dataset"]) + float(traffic["accuracy_margin"]))
 
     with open(save_path, "rb") as fh:
         saved = serialization.msgpack_restore(fh.read())
-    checks.update(reference_checks(
+    checks.update(win.reference_check(
         cell, conf, saved["params"], saved["batch_stats"],
-        validation_images(conf, dataroot, int(traffic["reference_images"]))))
+        validation_images(conf, dataroot, int(traffic["reference_images"])),
+        preprocess=imagenet_eval_batch))
 
     return Observed(
         cell=cell, devices=devices,
@@ -307,8 +236,8 @@ def run(cell: Cell, devices: list, start_wall: float) -> Observed:
         window_s=window_s, attempted=steps, failed=0, checks=checks,
         compile_stats=beat.compile_stats,
         memory_peak_bytes=beat.memory_peak,
-        work={"images_per_s_per_chip": rate, "passes": "train",
-              "steps": steps},
+        work=dict(feed, images_per_s_per_chip=rate, passes="train",
+                  steps=steps),
         step_program=traffic["step_program"],
         trace_dir=beat.tracer.directory if beat.tracer else None,
         host_spans=beat.host_spans,

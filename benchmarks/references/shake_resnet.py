@@ -3,8 +3,9 @@
 Written from Gastaldi, "Shake-Shake regularization" (arXiv:1705.07485,
 CIFAR form, the "Shake-Shake-Image" variant: one coefficient per image
 and block, drawn anew for the backward pass) in ``jax.numpy`` and
-``lax.conv_general_dilated`` only: no flax module, no jit, no
-``custom_vjp``, no code of the program.  It reads the parameter tree the
+``lax.conv_general_dilated`` only: no flax module, no ``custom_vjp``, no
+code of the program (the evaluation pass is jitted as it stands, so that
+a cold run pays one compilation and not one an operation).  It reads the parameter tree the
 system checkpoints (flax naming: ``c_in``, ``s<stage>_<i>_branch{1,2}/
 {conv1, bn1, conv2, bn2}``, ``s<stage>_<i>_shortcut/{conv1, conv2, bn}``,
 ``fc_out``; a BatchNorm's leaves sit under ``BatchNorm_0``), every
@@ -136,9 +137,12 @@ def forward(params: dict, batch_stats: dict, images_u8: np.ndarray,
             model: dict) -> np.ndarray:
     """Evaluation logits ``[n, num_classes]`` (float32, on the host) for
     uint8 images under the given parameter and running-statistics trees."""
+    @jax.jit
+    def logits(p, s, images):
+        return _net(p, s, preprocess(images), model, None)[0]
+
     with jax.default_matmul_precision("highest"):
-        logits, _ = _net(params, batch_stats, preprocess(images_u8), model, None)
-    return np.asarray(logits)
+        return np.asarray(logits(params, batch_stats, images_u8))
 
 
 def loss_and_grads(params: dict, batch_stats: dict, images, labels,

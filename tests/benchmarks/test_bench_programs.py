@@ -11,7 +11,7 @@ import pytest
 from benchmarks import run as runner
 from benchmarks.harness import device, spec
 
-LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device", "compared"}
 DEVICE_KEYS = {"platform", "kind", "count", "memory_peak_bytes"}
 
 
@@ -54,6 +54,15 @@ def test_train_window(make_tiny_checkout, chips):
     assert 0.0 <= obs.checks["learned"]["top1_train"] <= 1.0
     assert obs.checks["learned"]["restored_steps"] == counted["steps_counted"]
     assert obs.checks["reference_logits"]["images"] == 16
+    # the tiny configuration states both limits, as the file it copies
+    assert obs.checks["reference_logits_float32"]["tolerance"] == 1e-5
+    # every number compared, beside its limit, last in the line
+    assert list(line)[-1] == "compared" and set(line["compared"]) == {
+        "no_compile_in_window", "step_counter", "learned", "reference_logits",
+        "reference_logits_float32"}
+    assert line["compared"]["step_counter"] == {
+        "value": counted["steps_counted"], "must": "==",
+        "limit": counted["steps_counted"]}
     # rate = steps x global batch / window / chips
     assert line["metrics"]["train_images_per_s"]["value"] == pytest.approx(
         obs.attempted * 8 / obs.window_s)
@@ -170,7 +179,7 @@ def test_main_prints_no_result_without_a_tpu(capsys):
     assert code == 3 and out.out == "" and "no result" in out.err
 
 
-def test_last_line_has_the_contracts_keys_and_no_other(make_tiny_checkout):
+def test_last_line_has_the_contracts_keys_and_what_was_compared(make_tiny_checkout):
     from benchmarks.harness.observed import Observed
 
     root = make_tiny_checkout()
@@ -179,7 +188,9 @@ def test_last_line_has_the_contracts_keys_and_no_other(make_tiny_checkout):
                    end_to_end={"train_images_per_s": 10.0, "setup_s": 3.0,
                                "not_a_metric_of_this_cell": 1.0},
                    attempted=5, failed=0, compile_stats={},
-                   checks={"a": {"ok": True}, "b": {"ok": False}},
+                   checks={"a": {"ok": True}, "b": {
+                       "ok": False, "compared": {"value": float("nan"),
+                                                 "must": "<=", "limit": 0.02}}},
                    memory_peak_bytes=123)
     line = json.loads(json.dumps(runner.result_line(obs)))
     assert line == {
@@ -187,6 +198,8 @@ def test_last_line_has_the_contracts_keys_and_no_other(make_tiny_checkout):
         "metrics": {"train_images_per_s": {"value": 10.0, "unit": "images/s/chip"},
                     "setup_s": {"value": 3.0, "unit": "s"}},
         "device": {"platform": "cpu", "kind": "cpu",
-                   "count": len(jax.devices()), "memory_peak_bytes": 123}}
+                   "count": len(jax.devices()), "memory_peak_bytes": 123},
+        # a reading that is no number goes by its name: the line stays JSON
+        "compared": {"b": {"value": "nan", "must": "<=", "limit": 0.02}}}
     obs.checks = {}
     assert not obs.correct  # no check made is not a pass

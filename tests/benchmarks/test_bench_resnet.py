@@ -57,6 +57,9 @@ SIZES = {"depth": 50, "blocks": [3, 4, 6, 3], "widths": [64, 128, 256, 512],
 LOGIT_TOL, LOSS_TOL, GRAD_TOL, STATS_TOL = 1e-5, 1e-5, 5e-2, 1e-5
 #: the configuration's limits (their readings are in the file)
 DEPLOYED_LIMIT, FLOAT32_LIMIT = 0.05, 1e-5
+#: the per-layer metrics that list this cell alone (PR 34; PERF.md section 3)
+OWN_READERS = ("feed_wait_ms", "host_decode_images_per_s",
+               "aug_jitter_device_ms", "resnet_stem_device_ms")
 
 
 def _gap(system, plain) -> float:
@@ -326,8 +329,10 @@ def test_the_cell_runs_the_conf_at_the_entry_points_defaults():
     assert {m["name"] for m in cell.end_to_end} == {"train_images_per_s", "setup_s"}
     named = {m["name"] for m in cell.per_layer}
     wrn = {m["name"] for m in spec.resolve_cell("wrn28x10_train").per_layer}
-    assert len(named) == 15 and named < wrn
     assert wrn - named == {"batch_gather_device_ms"}   # nothing is gathered
+    # what only the host-fed 224-px program has to read (PR 34)
+    assert named - wrn == set(OWN_READERS) and len(named) == 19
+    assert traffic["host_tracer_level"] == 0 and traffic["host_tracer_level_because"]
 
 
 def test_every_metric_lists_cells_that_exist_and_move_what_it_moves():
@@ -342,6 +347,21 @@ def test_every_metric_lists_cells_that_exist_and_move_what_it_moves():
         assert listed and len(set(listed)) == len(listed), metric["name"]
         assert set(listed) <= cells & reports[metric["moves"]], metric["name"]
         spec.load_module("layer_metrics", metric["name"])   # a reader is there
+
+
+@pytest.mark.parametrize("cell_name", [
+    w["name"] for w in spec.load_benchmark()["workloads"]])
+def test_every_metric_a_cell_lists_has_a_reader_that_agrees_with_its_entry(cell_name):
+    """The converse, cell by cell: what ``run.py::read_layer_metrics`` would
+    raise on in a traced run on the chip is caught here.  A metric lists
+    the cells whose programs have what it reads; no test names a cell that
+    every metric must list."""
+    cell = spec.resolve_cell(cell_name, trace=True)
+    assert cell.per_layer
+    for entry in cell.per_layer:
+        reader = runner.reader_for(cell, entry)
+        assert callable(reader.read) and reader.__doc__, entry["name"]
+        assert cell_name in entry["workloads"]
 
 
 # --------------------------------------------------------- the JPEG fixture
@@ -525,6 +545,38 @@ def test_a_window_without_two_ends_or_counters_says_nothing_of_the_feed(counters
     assert _guard_with(counters).feed_over_the_window() == {}
 
 
+@pytest.mark.parametrize("metric, value", [
+    ("feed_wait_ms", 5.0), ("host_decode_images_per_s", 2560.0),
+    ("aug_jitter_device_ms", 45e-6), ("resnet_stem_device_ms", 90e-6)])
+def test_a_reader_of_this_cells_own_reads_its_counter_or_scope(traced, metric, value):
+    """On the synthetic run: the counters' two quotients as the program
+    hands them over in ``Observed.work``, jitter 40 + lighting 5 ns, and
+    the stem's 30 forward + 60 backward."""
+    traced.work.update(_guard_with(COUNTERS).feed_over_the_window())
+    assert _read(traced, metric) == pytest.approx(value)
+    traced.end_to_end = {"train_images_per_s": 1.0, "setup_s": 1.0}
+    assert runner.read_layer_metrics(traced)[metric]["value"] == pytest.approx(value)
+
+
+@pytest.mark.parametrize("metric, absent", [
+    ("feed_wait_ms", None), ("host_decode_images_per_s", None),
+    ("aug_jitter_device_ms", "AUG_LIGHTING"), ("resnet_stem_device_ms", "RESNET_STEM")])
+def test_a_program_from_before_pr_32_leaves_the_readers_own_out(
+        traced, monkeypatch, metric, absent):
+    """The benchmark's files laid over a parent whose registry has no feed
+    counters (``feed_over_the_window`` then hands over nothing) and whose
+    table of scopes lacks the name: nothing is read, nothing fails."""
+    reader = spec.load_module("layer_metrics", metric)
+    if absent is not None:
+        before = types.SimpleNamespace(**{
+            name: getattr(scopes, name) for name in dir(scopes)
+            if not name.startswith("_") and name != absent})
+        monkeypatch.setattr(reader, "program_scopes", lambda: before)
+    assert reader.read(traced) is None
+    traced.end_to_end = {"train_images_per_s": 1.0, "setup_s": 1.0}
+    assert metric not in runner.read_layer_metrics(traced)
+
+
 def test_the_references_nesterov_step_is_the_programs_optimizer(seeded):
     """``ops/optim.py``'s chain (masked decay, trace, the learning rate)
     from an empty momentum buffer against the reference's plain formula:
@@ -657,6 +709,9 @@ def test_train_window_on_a_small_resnet50(make_tiny_checkout):
     assert exact["ok"] and exact["relative_gap"] < FLOAT32_LIMIT
     # what the feed's counters said of the window
     assert feed["wait_ms_a_step"] >= 0 and feed["decode_images_per_s"] > 0
+    # and hands them to the two readers
+    assert (obs.work["wait_ms_a_step"], obs.work["decode_images_per_s"]) == (
+        feed["wait_ms_a_step"], feed["decode_images_per_s"])
     # the control: the nearest precision below the configuration's, from the
     # weights the window ended on: 2% lets it through, the float32 limit not
     loaded = spec.load_module("programs", "train_hostfed",
@@ -666,9 +721,9 @@ def test_train_window_on_a_small_resnet50(make_tiny_checkout):
     conf = cell.conf_dict()
     images = loaded.validation_images(conf, os.path.join(cell.work, "data"), 8)
     assert images.shape == (8, 32, 32, 3) and images.dtype == np.uint8
-    control = loaded.reference_checks(
+    control = window.reference_check(
         cell, dict(conf, precision="bf16"), saved["params"],
-        saved["batch_stats"], images)
+        saved["batch_stats"], images, preprocess=imagenet_eval_batch)
     assert control["reference_logits"]["ok"]
     lower = control["reference_logits_float32"]
     assert not lower["ok"] and lower["relative_gap"] > 10 * FLOAT32_LIMIT

@@ -287,41 +287,48 @@ def test_the_configuration_file_states_its_cut():
 
 def test_the_cell_runs_the_conf_at_the_entry_points_defaults():
     cell = spec.resolve_cell(CELL)
-    assert cell.chips == 1 and cell.traffic["program"] == "train_float32_check"
+    assert cell.chips == 1 and cell.traffic["program"] == "train"
     assert cell.traffic["conf_overrides"] == {} and cell.traffic["entry_args"] == {}
     assert cell.conf_dict() == cell.config["conf"]
     assert cell.conf_dict()["model"] == {"type": "shakeshake26_2x96d"}
     assert 50_000 // cell.conf_dict()["batch"] == 97   # steps an epoch
     reported = {m["name"] for m in cell.end_to_end}
     assert reported == {"train_images_per_s", "setup_s"}
-    named = {m["name"] for m in cell.per_layer}
-    assert len(named) == 18
-    wrn = {m["name"] for m in spec.resolve_cell("wrn28x10_train").per_layer}
-    assert named - wrn == {"shake_mix_fusions_device_ms", "shake_shortcut_device_ms"}
 
 
-def test_the_cells_traffic_is_train_epochs_under_one_more_check():
-    """Two files, one traffic: every parameter the harness reads is equal,
-    so the cell's window is ``wrn28x10_train``'s and the two cannot drift."""
+def test_the_cells_traffic_is_train_epochs_under_the_name_the_ledger_knows():
+    """Two files, one traffic and one program: every parameter the harness
+    reads is equal, so the cell's window is ``wrn28x10_train``'s and the two
+    cannot drift.  The second comparison the file was added for is
+    ``reference_check``'s since PR 34, for every configuration that states
+    its limit; the program that made it is gone."""
     ours = spec.load_json(os.path.join(
         spec.BENCH_DIR, "traffic", "train_epochs_float32_check.json"))
     theirs = spec.load_json(os.path.join(spec.BENCH_DIR, "traffic", "train_epochs.json"))
-    words = {"describes", "program", "entry_defaults_in_force", "reduced",
+    words = {"describes", "entry_defaults_in_force", "reduced",
              "accuracy_margin_because"}
     assert set(ours) == set(theirs)
     assert {k: v for k, v in ours.items() if k not in words} == \
         {k: v for k, v in theirs.items() if k not in words}
     assert set(ours["reduced"]) == set(theirs["reduced"])
-    assert (ours["program"], theirs["program"]) == ("train_float32_check", "train")
-    program = spec.load_module("programs", ours["program"])
-    assert program.train.run.__code__.co_filename.endswith("programs/train.py")
+    assert ours["program"] == theirs["program"] == "train"
+    assert not os.path.exists(os.path.join(
+        spec.BENCH_DIR, "programs", "train_float32_check.py"))
+    entry = next(w for w in spec.load_benchmark()["workloads"] if w["name"] == CELL)
+    assert (entry["traffic"], entry["config"]) == ("train_epochs_float32_check", CONFIG)
 
 
-def test_every_metric_of_the_benchmark_lists_its_cells():
-    """The three that moved ``setup_s`` without a list have one now: a
-    later cell says by itself which of them it reports."""
-    for metric in spec.load_benchmark()["per_layer"]:
-        assert CELL in metric["workloads"], metric["name"]
+def test_the_cell_reports_what_wrn28x10_train_reports_and_its_two_own():
+    """The set, by rule: the same policy at the same batch from the same
+    device cache, so every metric ``wrn28x10_train`` lists this cell lists
+    too, and the two that read what only a Shake-Shake program has.  A later
+    configuration's own metrics list neither cell and change nothing here;
+    that every list is sound is ``test_bench_resnet.py::
+    test_every_metric_lists_cells_that_exist_and_move_what_it_moves``."""
+    named = {m["name"] for m in spec.resolve_cell(CELL).per_layer}
+    wrn = {m["name"] for m in spec.resolve_cell("wrn28x10_train").per_layer}
+    assert named == wrn | {"shake_mix_fusions_device_ms", "shake_shortcut_device_ms"}
+    assert len(named) == len(wrn) + 2 == 18
 
 
 # ------------------------------------------------- the reader of the scope
@@ -443,13 +450,12 @@ def test_membership_reads_nothing_where_the_program_cannot_say(traced, monkeypat
 
 
 def test_train_window_on_a_tiny_shake_shake(make_tiny_checkout):
-    """The cell's program (``programs/train.py`` as it is, and the
-    float32 comparison after it) on Shake-Shake-26 2x8d over the
-    400-image fixture: the window opens and closes, the checkpoint
+    """The cell's program (``programs/train.py``) on Shake-Shake-26 2x8d
+    over the 400-image fixture: the window opens and closes, the checkpoint
     restores through ``only_eval``, ``reference_check`` compares the
-    system's evaluation path with this family's reference, and the second
-    comparison fails a model with bfloat16 activations that the first
-    lets through.  Counts, never times — a CPU run measures nothing."""
+    system's evaluation path with this family's reference twice, and the
+    second comparison fails a model with bfloat16 activations that the
+    first lets through.  Counts, never times — a CPU run measures nothing."""
     root = make_tiny_checkout()
     bench_dir = os.path.join(root, "benchmarks")
     held = spec.load_json(os.path.join(bench_dir, "configs", f"{CONFIG}.json"))
@@ -459,8 +465,9 @@ def test_train_window_on_a_tiny_shake_shake(make_tiny_checkout):
     with open(os.path.join(bench_dir, "configs", "tiny_shake.json"), "w") as fh:
         json.dump(held, fh)
     tiny = spec.load_json(os.path.join(bench_dir, "traffic", "tiny_train.json"))
+    assert tiny["program"] == "train"
     with open(os.path.join(bench_dir, "traffic", "tiny_shake_train.json"), "w") as fh:
-        json.dump(dict(tiny, program="train_float32_check"), fh)
+        json.dump(tiny, fh)
     bench = spec.load_benchmark(root)
     bench["configs"].append({
         "name": "tiny_shake", "source": "test", "reduced": ["model", "batch"],
@@ -498,18 +505,21 @@ def test_train_window_on_a_tiny_shake_shake(make_tiny_checkout):
     # the control: the nearest precision below the configuration's, from
     # the weights the window ended on.  2% lets it through; the float32
     # limit does not, and ``correct`` is the conjunction
-    program = spec.load_module("programs", "train_float32_check",
-                               os.path.join(root, "benchmarks"))
     with open(os.path.join(cell.work, "ckpt", "model.msgpack"), "rb") as fh:
         saved = serialization.msgpack_restore(fh.read())
     images = load_dataset("cifar10", os.path.join(cell.work, "data"))[1].images[:16]
     lower = dict(cell.conf_dict(), precision="bf16")
     args = (cell, lower, saved["params"], saved["batch_stats"], images)
-    assert window.reference_check(*args)["ok"]
-    control = program.float32_check(*args)
-    assert not control["ok"] and control["relative_gap"] > 100 * FLOAT32_LIMIT
-    obs.checks["reference_logits_float32"] = control
+    control = window.reference_check(*args)
+    assert control["reference_logits"]["ok"]
+    lower = control["reference_logits_float32"]
+    assert not lower["ok"] and lower["relative_gap"] > 100 * FLOAT32_LIMIT
+    assert lower["compared"] == {"value": lower["relative_gap"], "must": "<=",
+                                 "limit": FLOAT32_LIMIT}
+    obs.checks.update(control)
     assert not obs.correct
+    assert runner.result_line(obs)["compared"]["reference_logits_float32"][
+        "value"] == lower["relative_gap"]
     # the gauge says which family ran at what size
     from fast_autoaugment_tpu.core import telemetry
     assert telemetry.registry().gauge(
