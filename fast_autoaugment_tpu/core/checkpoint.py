@@ -27,8 +27,12 @@ from __future__ import annotations
 import hashlib
 import json
 import os
+import struct
 from typing import Any, Callable
 
+import jax
+import msgpack
+import numpy as np
 from flax import serialization
 
 from fast_autoaugment_tpu.core.resilience import CheckpointCorruptError
@@ -67,6 +71,59 @@ def _digest(payload: bytes) -> str:
     return hashlib.sha256(payload).hexdigest()
 
 
+#: array leaves of at least this many bytes are streamed, not packed
+_STREAMED_FROM = 1 << 16
+
+
+def _stream_payload(state: Any, fh) -> tuple[str, int]:
+    """Write ``flax.serialization.to_bytes(state)`` to `fh` without
+    building it, and return its ``(sha256 digest, size)``: byte for byte
+    the same payload (tests), but a large array leaf goes from the
+    device's copy on the host to the file and the digest as it is,
+    where ``to_bytes`` copies it four times into one ``bytes`` of the
+    whole state — 62 s for a 7.2 GB state, most of a minute of a
+    preempted run's exit (PERF.md section 6, PR 35).
+
+    msgpack is written front to back, a container's header and then its
+    members, so the map headers and the small leaves come from the
+    packer flax uses and a large leaf's ``ext(ndarray, [shape, dtype,
+    bin])`` header is spelled out here.  A leaf over flax's chunking
+    limit (1 GiB) falls back to ``to_bytes`` of the whole state."""
+    digest, size = hashlib.sha256(), 0
+
+    def emit(data) -> None:
+        nonlocal size
+        fh.write(data)
+        digest.update(data)
+        size += len(data)
+
+    def walk(node) -> None:
+        if isinstance(node, dict):
+            emit(msgpack.Packer().pack_map_header(len(node)))
+            for key, value in node.items():
+                emit(msgpack.packb(key, strict_types=True))
+                walk(value)
+        elif (isinstance(node, (np.ndarray, jax.Array))
+              and _STREAMED_FROM <= node.nbytes <= serialization.MAX_CHUNK_SIZE):
+            array = np.ascontiguousarray(node)
+            body = array.reshape(-1).view(np.uint8).data
+            head = (b"\x93" + msgpack.packb(array.shape)
+                    + msgpack.packb(array.dtype.name)
+                    + b"\xc6" + struct.pack(">I", len(body)))
+            emit(b"\xc9" + struct.pack(">Ib", len(head) + len(body), 1) + head)
+            emit(body)
+        else:
+            emit(serialization.msgpack_serialize(node))
+
+    tree = serialization.to_state_dict(state)
+    if any(getattr(leaf, "nbytes", 0) > serialization.MAX_CHUNK_SIZE
+           for leaf in jax.tree.leaves(tree)):
+        emit(serialization.to_bytes(state))
+    else:
+        walk(tree)
+    return digest.hexdigest(), size
+
+
 def _rotate_chain(path: str, keep: int) -> None:
     """Shift ``path`` -> ``path.prev`` -> … before a new save lands.
 
@@ -98,13 +155,18 @@ def save_checkpoint(path: str, state: Any, metadata: dict | None = None,
     from fast_autoaugment_tpu.utils import faultinject
 
     os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
-    payload = serialization.to_bytes(state)
     meta = dict(metadata or {})
-    meta["digest"] = _digest(payload)
-    meta["nbytes"] = len(payload)
+    tmp = path + ".tmp"
 
     fi = faultinject.active_plan()
-    if fi is not None:
+    if fi is None:
+        with open(tmp, "wb") as fh:
+            meta["digest"], meta["nbytes"] = _stream_payload(state, fh)
+    else:
+        # a fault plan tears or corrupts the payload: it needs it whole
+        payload = serialization.to_bytes(state)
+        meta["digest"] = _digest(payload)
+        meta["nbytes"] = len(payload)
         save_n = fi.next_save()
         if fi.torn_at(save_n):
             # simulate a torn non-atomic write: half the payload lands
@@ -123,11 +185,11 @@ def save_checkpoint(path: str, state: Any, metadata: dict | None = None,
             corrupted[len(corrupted) // 2] ^= 0xFF
             payload = bytes(corrupted)
 
+        with open(tmp, "wb") as fh:
+            fh.write(payload)
+
     if keep >= 2:
         _rotate_chain(path, keep)
-    tmp = path + ".tmp"
-    with open(tmp, "wb") as fh:
-        fh.write(payload)
     os.replace(tmp, path)
     tmp_meta = _meta_path(path) + ".tmp"
     with open(tmp_meta, "w") as fh:
@@ -140,7 +202,7 @@ def save_checkpoint(path: str, state: Any, metadata: dict | None = None,
     telemetry.registry().counter(
         "faa_checkpoints_saved_total", "checkpoint chain saves").inc()
     telemetry.emit("checkpoint", os.path.basename(path), action="save",
-                   nbytes=len(payload), epoch=meta.get("epoch"))
+                   nbytes=meta["nbytes"], epoch=meta.get("epoch"))
 
 
 def _read_payload(path: str) -> bytes:

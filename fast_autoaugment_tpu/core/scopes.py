@@ -34,6 +34,13 @@ __all__ = [
     "RESNET_STEM",
     "SHAKE_MIX",
     "SHAKE_SHORTCUT",
+    "KDA",
+    "KDA_SCAN",
+    "MLA",
+    "MOE",
+    "MOE_ROUTER",
+    "MOE_EXPERTS",
+    "LM_HEAD",
     "LOSS",
     "OPTIMIZER",
     "EMA",
@@ -76,6 +83,21 @@ SHAKE_SHORTCUT = "faa_shake_shortcut"
 #: ``models/resnet.py``, nested under ``faa_model``: the ImageNet stem
 #: (7x7 stride-2 convolution, BatchNorm, ReLU, 3x3 stride-2 max-pool)
 RESNET_STEM = "faa_resnet_stem"
+#: ``models/kimi_linear.py``, all nested under ``faa_model``: the KDA mixer
+#: (projections, short convolutions, gates, output norm and gate) with the
+#: chunked delta-rule recurrence alone inside it (``ops/kda.py``, forward
+#: and backward); the latent-attention mixer; an expert layer (its shared
+#: expert included) with the router (scores, top-k, every expert's load)
+#: and the held experts' part (the assignments sorted by expert, a loop over
+#: the blocks of rows the routing filled: gather, three products, weighted
+#: scatter-add; forward and backward) inside it; the output head's product
+KDA = "faa_kda"
+KDA_SCAN = "faa_kda_scan"
+MLA = "faa_mla"
+MOE = "faa_moe"
+MOE_ROUTER = "faa_moe_router"
+MOE_EXPERTS = "faa_moe_experts"
+LM_HEAD = "faa_lm_head"
 LOSS = "faa_loss"
 #: ``train/steps.py::step_fn``: update and parameter add; EMA; top-k and sums
 OPTIMIZER = "faa_optimizer"

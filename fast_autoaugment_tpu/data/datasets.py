@@ -25,7 +25,8 @@ from dataclasses import dataclass, replace
 
 import numpy as np
 
-__all__ = ["ArrayDataset", "load_dataset", "cv_split", "IDX120"]
+__all__ = ["ArrayDataset", "load_dataset", "cv_split", "is_token_dataset",
+           "IDX120"]
 
 # reference data.py:154 — the fixed 120 ImageNet classes of reduced_imagenet
 IDX120 = [
@@ -48,12 +49,20 @@ class ArrayDataset:
     For datasets too large for RAM (ImageNet), ``images`` may instead be
     an object array of file paths with ``lazy=True``; the pipeline then
     decodes per batch.
+
+    A token data set (``tokens=True``) rides in the same two arrays, so
+    that the batch iterators, the index matrices and the device cache
+    take it as they stand: ``images`` is int32 ``[N, T + 1]`` ids (a
+    sequence and the id that follows its last token), ``labels`` one
+    unused zero a sequence (the targets are the ids shifted by one), and
+    ``num_classes`` one more than the largest id present.
     """
 
     images: np.ndarray
     labels: np.ndarray
     num_classes: int
     lazy: bool = False
+    tokens: bool = False
 
     def __len__(self):
         return len(self.labels)
@@ -258,6 +267,46 @@ def _synthetic_shapes(n_train: int = 600, n_test: int = 2000, size: int = 32,
     return render(n_train, 1), render(n_test, 2)
 
 
+def is_token_dataset(dataset: str) -> bool:
+    """Token data sets are named ``tokens`` (``<dataroot>/tokens/*.npy``)
+    or ``synthetic_tokens`` (seeded, for tests)."""
+    return dataset in ("tokens", "synthetic_tokens")
+
+
+def _token_dataset(ids: np.ndarray) -> ArrayDataset:
+    ids = np.ascontiguousarray(ids, np.int32)
+    if ids.ndim != 2 or ids.shape[1] < 2 or ids.min() < 0:
+        raise ValueError(f"a token data set is non-negative ids [N, T + 1] "
+                         f"with T >= 1; got {ids.shape}")
+    return ArrayDataset(ids, np.zeros(len(ids), np.int32),
+                        int(ids.max()) + 1, tokens=True)
+
+
+def _load_tokens(dataroot: str):
+    """``<dataroot>/tokens/train.npy`` and ``test.npy``: int32 ids
+    ``[N, T + 1]``, as a tokenizer's packed output would be saved."""
+    base = os.path.join(dataroot, "tokens")
+    return tuple(_token_dataset(np.load(os.path.join(base, f"{split}.npy")))
+                 for split in ("train", "test"))
+
+
+def _synthetic_tokens(vocab: int = 64, length: int = 32, n_train: int = 32,
+                      n_test: int = 8):
+    """A first-order Markov chain in which an id is followed by one of
+    four fixed ids: learnable, so a few steps bring the loss down."""
+    rng = np.random.default_rng(0)
+    successors = rng.integers(0, vocab, (vocab, 4))
+
+    def draw(n):
+        ids = np.empty((n, length + 1), np.int32)
+        ids[:, 0] = rng.integers(0, vocab, n)
+        for t in range(length):
+            ids[:, t + 1] = successors[ids[:, t], rng.integers(0, 4, n)]
+        return _token_dataset(ids)
+
+    return draw(n_train), draw(n_test)
+
+
 def _synthetic(num_classes: int, n_train: int = 512, n_test: int = 256, size: int = 32):
     rng = np.random.default_rng(0)
     mk = lambda n: ArrayDataset(
@@ -276,6 +325,10 @@ def _synthetic(num_classes: int, n_train: int = 512, n_test: int = 256, size: in
 def load_dataset(dataset: str, dataroot: str):
     """Return (total_trainset, testset) for a dataset name, applying the
     reference's reduction rules."""
+    if dataset == "tokens":
+        return _load_tokens(dataroot)
+    if dataset == "synthetic_tokens":
+        return _synthetic_tokens()
     if dataset == "cifar10":
         return _load_cifar(dataroot, "cifar10")
     if dataset == "cifar100":

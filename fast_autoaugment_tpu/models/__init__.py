@@ -8,6 +8,7 @@ are the train step's concern — a module is pure structure.
 Supported types (reference parity): resnet50, resnet200, wresnet40_2,
 wresnet28_10, shakeshake26_2x32d / 2x64d / 2x96d / 2x112d,
 shakeshake26_2x96d_next, pyramid, efficientnet-b0..b7 (+condconv).
+Beyond the reference: kimi_linear, a token model (``models/kimi_linear.py``).
 """
 
 from __future__ import annotations
@@ -21,11 +22,30 @@ from fast_autoaugment_tpu.models.resnet import ResNet
 from fast_autoaugment_tpu.models.shake_resnet import ShakeResNet, ShakeResNeXt
 from fast_autoaugment_tpu.models.wideresnet import WideResNet
 
-__all__ = ["get_model", "num_class", "input_image_size"]
+__all__ = ["get_model", "model_conf_of", "num_class", "input_image_size"]
+
+
+def model_conf_of(conf: Any) -> dict:
+    """The mapping :func:`get_model` takes, from a whole conf: its
+    ``model`` block with the data set, the precision, and — for a model
+    that can hold a share of itself — what this chip holds
+    (``models/kimi_linear.py::CUT_KEYS``, top-level conf keys)."""
+    from fast_autoaugment_tpu.models.kimi_linear import CUT_KEYS
+
+    model_conf = dict(conf["model"], dataset=conf["dataset"])
+    model_conf.setdefault("precision", conf.get("precision", "f32"))
+    for key in CUT_KEYS:
+        if conf.get(key) is not None:
+            model_conf[key] = conf[key]
+    return model_conf
 
 
 def num_class(dataset: str) -> int:
     """Class count per dataset (reference ``networks/__init__.py:93-103``)."""
+    if dataset.endswith("tokens"):
+        raise ValueError(
+            f"{dataset!r} is a token data set: its classes are the ids the "
+            "model holds (conf ids_held, else model.vocab_size)")
     if dataset.startswith("synthetic_shapes"):
         return 10  # glyph task is always 10-class (any _nN train size)
     if dataset.startswith("synthetic"):
@@ -107,6 +127,10 @@ def get_model(conf: Any, num_classes: int) -> nn.Module:
             bottleneck=bool(conf.get("bottleneck", True)),
             dtype=dtype,
         )
+    if name == "kimi_linear":
+        from fast_autoaugment_tpu.models.kimi_linear import kimi_linear_from_conf
+
+        return kimi_linear_from_conf(conf, dtype=dtype)
     if name.startswith("efficientnet"):
         from fast_autoaugment_tpu.models.efficientnet import EfficientNet
 

@@ -18,6 +18,15 @@ name-based (``'_bn' in name or '.bn' in name``) and therefore silently
 *decays* BN params inside the shake-net branches (which are indexed, not
 named ``bn*``).  Here BN params are never decayed, in every model.
 
+Beyond the reference: ``optimizer.type: adamw`` (a token model's
+optimizer), Adam's two float32 moments and a *decoupled* decay added
+after them, ``p <- p - lr (m_hat / (sqrt(v_hat) + eps) + decay p)``, the
+global-norm clip first; its default mask ``matrices`` decays what has two
+or more dimensions (projections, expert stacks, embeddings, convolution
+taps) and not norms, biases, ``A_log``, ``dt_bias`` or a router's
+correction bias.  ``optimizer.decay_mask`` names a mask of
+:data:`DECAY_MASKS`; an unknown name raises.
+
 EMA (reference ``common.py:28-51``, applied ``train.py:69-70``): shadow
 of params+batch_stats with TF-style warmup ``mu_t = min(mu,
 (1+step)/(10+step))``, as a pure pytree lerp inside the jitted step —
@@ -34,6 +43,8 @@ import optax
 
 __all__ = [
     "non_bn_mask",
+    "matrices_mask",
+    "DECAY_MASKS",
     "build_optimizer",
     "rmsprop_tf",
     "ema_update",
@@ -53,6 +64,26 @@ def non_bn_mask(params) -> Any:
         return any("bn" in str(getattr(k, "key", k)).lower() for k in path)
 
     return jax.tree_util.tree_map_with_path(lambda p, _: not is_bn_path(p), params)
+
+
+#: leaves :func:`matrices_mask` never decays whatever their shape
+NEVER_DECAYED = ("A_log", "dt_bias", "e_score_correction_bias")
+
+
+def matrices_mask(params) -> Any:
+    """Pytree mask: True for leaves of two or more dimensions, but for
+    the names of :data:`NEVER_DECAYED`.  A callable mask, as
+    :func:`non_bn_mask`."""
+
+    def decayed(path, leaf) -> bool:
+        name = str(getattr(path[-1], "key", path[-1]))
+        return leaf.ndim >= 2 and name not in NEVER_DECAYED
+
+    return jax.tree_util.tree_map_with_path(decayed, params)
+
+
+#: ``optimizer.decay_mask`` -> mask
+DECAY_MASKS = {"non_bn": non_bn_mask, "matrices": matrices_mask}
 
 
 class RmspropTFState(NamedTuple):
@@ -102,17 +133,35 @@ def build_optimizer(
     learning_rate: Callable[[jax.Array], jax.Array],
 ) -> optax.GradientTransformation:
     """Weight-decay -> clip -> core optimizer chain, from the conf schema
-    ``optimizer{type, decay, (momentum), (nesterov), (clip)}``.
+    ``optimizer{type, decay, (momentum), (nesterov), (clip), (decay_mask),
+    (beta1, beta2, eps)}``; for ``adamw`` clip -> Adam -> decoupled decay.
 
-    The non-BN mask is a callable, so no parameters are needed up front.
+    The decay mask is a callable, so no parameters are needed up front.
     """
     kind = optimizer_conf["type"]
     decay = float(optimizer_conf.get("decay", 0.0))
     clip = float(optimizer_conf.get("clip", 5.0))
+    mask_name = optimizer_conf.get("decay_mask") or (
+        "matrices" if kind == "adamw" else "non_bn")
+    if mask_name not in DECAY_MASKS:
+        raise ValueError(f"invalid optimizer decay_mask {mask_name!r}: "
+                         f"use one of {sorted(DECAY_MASKS)}")
+    mask = DECAY_MASKS[mask_name]
+
+    if kind == "adamw":
+        chain = [optax.clip_by_global_norm(clip)] if clip > 0 else []
+        chain.append(optax.scale_by_adam(
+            b1=float(optimizer_conf.get("beta1", 0.9)),
+            b2=float(optimizer_conf.get("beta2", 0.95)),
+            eps=float(optimizer_conf.get("eps", 1e-8))))
+        if decay > 0:
+            chain.append(optax.add_decayed_weights(decay, mask=mask))
+        chain.append(optax.scale_by_learning_rate(learning_rate))
+        return optax.chain(*chain)
 
     chain = []
     if decay > 0:
-        chain.append(optax.add_decayed_weights(decay, mask=non_bn_mask))
+        chain.append(optax.add_decayed_weights(decay, mask=mask))
     if clip > 0:
         chain.append(optax.clip_by_global_norm(clip))
 

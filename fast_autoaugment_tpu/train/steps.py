@@ -49,6 +49,9 @@ __all__ = [
     "create_train_state",
     "make_train_step",
     "make_train_step_body",
+    "make_token_step_body",
+    "make_token_train_step",
+    "COUNT_PREFIX",
     "make_stacked_train_step",
     "make_stacked_step_body",
     "make_multistep_train_step",
@@ -75,10 +78,25 @@ class TrainState(struct.PyTreeNode):
     ema: Any  # {'params', 'batch_stats'} shadow, or None
 
 
-def create_train_state(model, optimizer, rng, sample_input, use_ema: bool) -> TrainState:
-    variables = model.init(
-        {"params": rng, "shake": jax.random.fold_in(rng, 1)}, sample_input, train=False
-    )
+def create_train_state(model, optimizer, rng, sample_input, use_ema: bool,
+                       jit_init: bool = False) -> TrainState:
+    """`jit_init` runs ``model.init`` as one program instead of operation
+    by operation (a token model's hundreds of operations would each be
+    compiled: 73 s of a cold run on the chip's host with the forward pass
+    kept, my chip run, PR 35).  The image models keep the eager
+    construction: as one program XLA fuses a draw with its scaling, and
+    the seeded weights of four of six presets then differ in the last
+    bit (Shake-Shake-26 2x96d, ResNet-50, PyramidNet, EfficientNet-B0:
+    up to 2.4e-7 on the CPU; WRN-40-2 and WRN-28-10 bit-equal; PR 35),
+    which the pinned numeric tests of the image path would see."""
+    def init(rngs, sample):
+        variables = model.init(rngs, sample, train=False)
+        # nothing else comes out, so under jit the forward pass that shaped
+        # the parameters is dead code and only their draws are compiled
+        return {k: v for k, v in variables.items() if k in ("params", "batch_stats")}
+
+    variables = (seam_jit(init, label="state_init") if jit_init else init)(
+        {"params": rng, "shake": jax.random.fold_in(rng, 1)}, sample_input)
     params = variables["params"]
     batch_stats = variables.get("batch_stats", {})
     # the EMA shadow must be a DISTINCT set of buffers: the train step
@@ -166,21 +184,7 @@ def _make_train_step_body(
         (loss, (logits, new_batch_stats)), grads = grad_fn(
             state.params, state.batch_stats, images, labels, key_model
         )
-        with jax.named_scope(scopes.OPTIMIZER):
-            updates, new_opt_state = optimizer.update(
-                grads, state.opt_state, state.params)
-            new_params = jax.tree.map(lambda p, u: p + u, state.params, updates)
-
-        new_ema = state.ema
-        if state.ema is not None and ema_mu > 0.0:
-            with jax.named_scope(scopes.EMA):
-                new_ema = ema_update(
-                    state.ema,
-                    {"params": new_params, "batch_stats": new_batch_stats},
-                    ema_mu,
-                    state.step + 1,  # 1-based, reference train.py:70
-                )
-
+        new_state = _advance(state, optimizer, grads, new_batch_stats, ema_mu)
         batch = labels.shape[0]
         with jax.named_scope(scopes.METRICS):
             metrics = {
@@ -190,13 +194,104 @@ def _make_train_step_body(
                     logits, labels, min(5, num_classes)).astype(jnp.float32),
                 "num": jnp.float32(batch),
             }
-        new_state = state.replace(
-            step=state.step + 1,
-            params=new_params,
-            batch_stats=new_batch_stats,
-            opt_state=new_opt_state,
-            ema=new_ema,
-        )
+        return new_state, metrics
+
+    return step_fn
+
+
+def _advance(state: TrainState, optimizer, grads, new_batch_stats,
+             ema_mu: float) -> TrainState:
+    """The update every step body ends on: optimizer, parameter add, EMA."""
+    with jax.named_scope(scopes.OPTIMIZER):
+        updates, new_opt_state = optimizer.update(
+            grads, state.opt_state, state.params)
+        new_params = jax.tree.map(lambda p, u: p + u, state.params, updates)
+
+    new_ema = state.ema
+    if state.ema is not None and ema_mu > 0.0:
+        with jax.named_scope(scopes.EMA):
+            new_ema = ema_update(
+                state.ema,
+                {"params": new_params, "batch_stats": new_batch_stats},
+                ema_mu,
+                state.step + 1,  # 1-based, reference train.py:70
+            )
+    return state.replace(
+        step=state.step + 1,
+        params=new_params,
+        batch_stats=new_batch_stats,
+        opt_state=new_opt_state,
+        ema=new_ema,
+    )
+
+
+#: metric sums under this prefix are counts: the trainer publishes them
+#: as counters where it syncs the epoch's sums and never divides them
+COUNT_PREFIX = "n_"
+
+
+def _next_token_sums(logits, targets):
+    """``(nll [B], correct [B])``: a sequence's mean next-token
+    cross-entropy and the share of its targets the largest logit hits."""
+    logits = logits.astype(jnp.float32)
+    picked = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+    nll = jax.nn.logsumexp(logits, axis=-1) - picked
+    correct = jnp.argmax(logits, axis=-1) == targets
+    return nll.mean(axis=-1), correct.astype(jnp.float32).mean(axis=-1)
+
+
+def make_token_step_body(model, optimizer, *, ema_mu: float = 0.0) -> Callable:
+    """The UNJITTED train-step body of a token model, with the image
+    body's signature so that :func:`make_train_step` 's jit and
+    :func:`make_multistep_train_step` 's gather take it as they stand:
+    ``(state, ids [B, T + 1], labels, policy, key) -> (state, sums)``.
+
+    Inputs are ``ids[:, :-1]``, targets ``ids[:, 1:]``; no augmentation
+    stage, no policy (`labels`, `policy` and `key` are not read: the model
+    draws nothing), the loss the mean next-token cross-entropy over
+    ``[B, T, V]``.  The sums: ``loss`` and ``top1`` (next-token accuracy)
+    a sequence, ``num`` sequences, and counts under :data:`COUNT_PREFIX`
+    — tokens, and what the model counted.
+
+    A model may keep a rule that runs between steps and outside the
+    gradient (a router's load balancing): it names a collection
+    (``model.step_collection``) that its forward pass ``sow``s into, and
+    ``model.after_step(params, sown) -> (params, counts)`` is applied to
+    the parameters the optimizer left; `counts` (scalars by name) join the
+    step's count sums.  A model without the two names has no such rule.
+    """
+    collection = getattr(model, "step_collection", None)
+    mutable = ["batch_stats"] + ([collection] if collection else [])
+
+    def loss_fn(params, batch_stats, ids):
+        inputs, targets = ids[:, :-1], ids[:, 1:]
+        with jax.named_scope(scopes.MODEL):
+            logits, mutated = model.apply(
+                {"params": params, "batch_stats": batch_stats}, inputs,
+                train=True, mutable=mutable)
+        with jax.named_scope(scopes.LOSS):
+            nll, correct = _next_token_sums(logits, targets)
+        return nll.mean(), (nll.sum(), correct.sum(),
+                            mutated.get("batch_stats", batch_stats),
+                            mutated.get(collection, {}))
+
+    def step_fn(state: TrainState, ids, labels, policy, key):
+        del labels, policy, key
+        (_, (nll, correct, new_batch_stats, stats)), grads = jax.value_and_grad(
+            loss_fn, has_aux=True)(state.params, state.batch_stats, ids)
+        new_state = _advance(state, optimizer, grads, new_batch_stats, ema_mu)
+        batch, length = ids.shape[0], ids.shape[1] - 1
+        counts = {"tokens": batch * length}
+        if collection:
+            with jax.named_scope(scopes.OPTIMIZER):
+                params, counted = model.after_step(
+                    new_state.params, jax.lax.stop_gradient(stats))
+            new_state = new_state.replace(params=params)
+            counts.update(counted)
+        with jax.named_scope(scopes.METRICS):
+            metrics = {"loss": nll, "top1": correct, "num": jnp.float32(batch),
+                       **{f"{COUNT_PREFIX}{name}": jnp.float32(value)
+                          for name, value in counts.items()}}
         return new_state, metrics
 
     return step_fn
@@ -243,6 +338,14 @@ def make_train_step(
     # compile seam (core/compilecache.py): first-call compile is timed
     # and classified hit/miss against the persistent cache.
     return seam_jit(body, label="train_step", donate_argnums=(0,))
+
+
+def make_token_train_step(model, optimizer, *, ema_mu: float = 0.0) -> Callable:
+    """:func:`make_token_step_body` jitted as :func:`make_train_step` jits
+    the image body (the host-fed feed's step; the device cache wraps the
+    body in :func:`make_multistep_train_step`)."""
+    return seam_jit(make_token_step_body(model, optimizer, ema_mu=ema_mu),
+                    label="train_step", donate_argnums=(0,))
 
 
 def make_stacked_step_body(
@@ -525,17 +628,41 @@ def _make_eval_body(model, *, num_classes: int, lb_smooth: float = 0.0,
     return eval_fn
 
 
+def _make_token_eval_body(model) -> Callable:
+    """The eval body of a token model, with :func:`_make_eval_body` 's
+    signature: loss and next-token accuracy a sequence, padding masked."""
+
+    def eval_fn(params, batch_stats, ids, labels, mask):
+        del labels
+        logits = model.apply(
+            {"params": params, "batch_stats": batch_stats}, ids[:, :-1],
+            train=False)
+        nll, correct = _next_token_sums(logits, ids[:, 1:])
+        return {
+            "loss": (nll * mask).sum(),
+            "top1": (correct * mask).sum(),
+            "num": mask.sum().astype(jnp.float32),
+        }
+
+    return eval_fn
+
+
 def make_eval_step(model, *, num_classes: int, lb_smooth: float = 0.0,
-                   preprocess_fn: Callable | None = None) -> Callable:
+                   preprocess_fn: Callable | None = None,
+                   tokens: bool = False) -> Callable:
     """Build the jitted eval step: ``fn(params, batch_stats, images_u8,
-    labels, mask) -> metric_sums`` (loss/top1/top5/num as sums)."""
-    return seam_jit(_make_eval_body(
+    labels, mask) -> metric_sums`` (loss/top1/top5/num as sums); with
+    `tokens` the batch is ids ``[B, T + 1]`` and the sums are the
+    next-token loss and accuracy."""
+    body = _make_token_eval_body(model) if tokens else _make_eval_body(
         model, num_classes=num_classes, lb_smooth=lb_smooth,
-        preprocess_fn=preprocess_fn), label="eval_step")
+        preprocess_fn=preprocess_fn)
+    return seam_jit(body, label="eval_step")
 
 
 def make_replay_eval_step(model, *, num_classes: int, lb_smooth: float = 0.0,
-                          preprocess_fn: Callable | None = None) -> Callable:
+                          preprocess_fn: Callable | None = None,
+                          tokens: bool = False) -> Callable:
     """Whole-split evaluation in ONE dispatch: ``fn(params, batch_stats,
     images [S, B, H, W, C], labels [S, B], masks [S, B]) -> metric_sums``
     — a ``lax.scan`` of the eval body over a device-resident stack of
@@ -555,8 +682,9 @@ def make_replay_eval_step(model, *, num_classes: int, lb_smooth: float = 0.0,
     conv-backward-in-while pathology (`default_dispatch_unroll`) does
     not apply — the rolled scan is fast on every backend.
     """
-    body = _make_eval_body(model, num_classes=num_classes,
-                           lb_smooth=lb_smooth, preprocess_fn=preprocess_fn)
+    body = _make_token_eval_body(model) if tokens else _make_eval_body(
+        model, num_classes=num_classes, lb_smooth=lb_smooth,
+        preprocess_fn=preprocess_fn)
 
     def replay_fn(params, batch_stats, images, labels, masks):
         def one(carry, batch):

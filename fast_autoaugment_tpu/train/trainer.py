@@ -48,7 +48,11 @@ from fast_autoaugment_tpu.core.watchdog import (
     dispatch_enqueue_guard,
     resolve_watchdog,
 )
-from fast_autoaugment_tpu.data.datasets import cv_split, load_dataset
+from fast_autoaugment_tpu.data.datasets import (
+    cv_split,
+    is_token_dataset,
+    load_dataset,
+)
 from fast_autoaugment_tpu.data.pipeline import (
     BatchIterator,
     DeviceCache,
@@ -59,7 +63,7 @@ from fast_autoaugment_tpu.data.pipeline import (
     stacked_train_batches,
     train_index_matrix,
 )
-from fast_autoaugment_tpu.models import get_model, num_class
+from fast_autoaugment_tpu.models import get_model, model_conf_of, num_class
 from fast_autoaugment_tpu.ops.optim import build_optimizer
 from fast_autoaugment_tpu.ops.schedules import build_schedule
 from fast_autoaugment_tpu.parallel.mesh import (
@@ -74,12 +78,15 @@ from fast_autoaugment_tpu.parallel.mesh import (
 )
 from fast_autoaugment_tpu.policies.archive import load_policy, policy_to_tensor
 from fast_autoaugment_tpu.train.steps import (
+    COUNT_PREFIX,
     create_train_state,
     make_eval_step,
     make_multistep_train_step,
     make_replay_eval_step,
     make_stacked_step_body,
     make_stacked_train_step,
+    make_token_step_body,
+    make_token_train_step,
     make_train_step,
     make_train_step_body,
     slice_state,
@@ -241,6 +248,41 @@ def _sum_metric_dicts(metric_dicts: list) -> dict:
     return sums
 
 
+def _split_counts(sums: dict) -> dict:
+    """Take the count sums (``steps.COUNT_PREFIX``) out of `sums` and hand
+    them back: they are published as counters and never divided."""
+    return {k: sums.pop(k) for k in [k for k in sums
+                                     if k.startswith(COUNT_PREFIX)]}
+
+
+class _CountPublisher:
+    """Publishes an epoch's count sums (``steps.make_token_step_body``)
+    where the trainer has synced them anyway — the epoch boundary, a
+    mid-epoch snapshot: ``tokens`` as ``faa_tokens_total``, and what the
+    model counted through the model's own ``publish_counts(rise,
+    registry)``, where it has one.  Counters rise by what is new since
+    the last publication of the same epoch."""
+
+    def __init__(self, model):
+        self.publish_model = getattr(model, "publish_counts", None)
+        self.seen: dict = {}
+
+    def new_epoch(self, carried: dict | None = None) -> None:
+        # a resumed epoch's saved sums were published by the run that saved them
+        self.seen = dict(carried or {})
+
+    def publish(self, counts: dict) -> None:
+        reg = telemetry.registry()
+        rise = {k[len(COUNT_PREFIX):]: float(v) - float(self.seen.get(k, 0.0))
+                for k, v in counts.items()}
+        self.seen = {k: float(v) for k, v in counts.items()}
+        reg.counter("faa_tokens_total",
+                    "tokens the trainer's steps trained on").inc(
+                        rise.pop("tokens", 0.0))
+        if self.publish_model is not None:
+            self.publish_model(rise, reg)
+
+
 def train_and_eval(
     conf,
     dataroot: str,
@@ -329,8 +371,25 @@ def train_and_eval(
     is_master = jax.process_index() == 0
 
     dataset_name = conf["dataset"]
-    num_classes = num_class(dataset_name)
+    tokens = is_token_dataset(dataset_name)
+    model_conf = model_conf_of(conf)
     total_train, testset = load_dataset(dataset_name, dataroot)
+    if tokens:
+        # ids in, next-token loss: the classes are the ids the model holds
+        if conf.get("aug", "default") not in (None, "default"):
+            raise ValueError(
+                f"dataset {dataset_name!r} is a token data set and conf aug="
+                f"{conf['aug']!r} names an augmentation policy: policies "
+                "are image operations; use aug: default")
+        num_classes = int(model_conf.get("ids_held")
+                          or model_conf.get("vocab_size") or 0)
+        if not 0 < max(total_train.num_classes, testset.num_classes) <= num_classes:
+            raise ValueError(
+                f"the data set holds ids up to "
+                f"{max(total_train.num_classes, testset.num_classes) - 1}, the "
+                f"model {num_classes} ids (conf ids_held, else model.vocab_size)")
+    else:
+        num_classes = num_class(dataset_name)
 
     if test_ratio > 0.0:
         train_idx, valid_idx = cv_split(total_train.labels, test_ratio, cv_fold)
@@ -346,7 +405,7 @@ def train_and_eval(
 
     # conf['imgsize'] overrides the native resolution (the reference
     # evaluates ResNet-200 at 320px, README.md:44-46)
-    image = int(conf.get("imgsize", 0) or 0) or input_image_size(
+    image = None if tokens else int(conf.get("imgsize", 0) or 0) or input_image_size(
         dataset_name, conf["model"]["type"]
     )
     if is_imagenet:
@@ -391,18 +450,23 @@ def train_and_eval(
     steps_per_epoch = max(1, len(train_idx) // global_batch)
     epochs = int(conf["epoch"])
 
-    model_conf = dict(conf["model"], dataset=dataset_name)
-    model_conf.setdefault("precision", conf.get("precision", "f32"))
     model = get_model(model_conf, num_classes)
     lr_fn = build_schedule(conf, steps_per_epoch, world_lr_scale=float(mesh.size))
     optimizer_conf = conf["optimizer"]
     ema_mu = float(optimizer_conf.get("ema", 0.0) or 0.0)
 
-    sample = jnp.zeros((2, image, image, 3), jnp.float32)
+    if tokens:
+        # parameter shapes do not depend on the length: a short sample
+        sample = jnp.zeros((1, min(total_train.images.shape[1] - 1, 64)), jnp.int32)
+    else:
+        sample = jnp.zeros((2, image, image, 3), jnp.float32)
     rng = jax.random.PRNGKey(seed)
 
     optimizer = build_optimizer(optimizer_conf, lr_fn)
-    state = create_train_state(model, optimizer, rng, sample, use_ema=ema_mu > 0.0)
+    # one program for a token model's init; the image models' seeded
+    # weights are pinned under the eager one (create_train_state)
+    state = create_train_state(model, optimizer, rng, sample,
+                               use_ema=ema_mu > 0.0, jit_init=tokens)
     # which family ran at what size, for the journal and /metrics (sizes
     # from shapes: nothing waits for the device)
     num_params = sum(int(p.size) for p in jax.tree.leaves(state.params))
@@ -437,11 +501,14 @@ def train_and_eval(
         aug_dispatch=aug_dispatch,
         aug_groups=aug_groups,
     )
+    token_counters = _CountPublisher(model) if tokens else None
     if use_cache:
         # device-resident path: the body is dispatched through the
         # multi-step gather program; at most two chunk shapes per epoch
         # (N and the clamped remainder), each compiled once and reused
-        step_body = make_train_step_body(model, optimizer, **step_kw)
+        step_body = (make_token_step_body(model, optimizer, ema_mu=ema_mu)
+                     if tokens else
+                     make_train_step_body(model, optimizer, **step_kw))
         multi_fns: dict[int, Callable] = {}
 
         def get_multi_step(n: int) -> Callable:
@@ -450,14 +517,16 @@ def train_and_eval(
                     step_body, steps_per_dispatch=n)
             return multi_fns[n]
     else:
-        train_step = make_train_step(model, optimizer, **step_kw)
+        train_step = (make_token_train_step(model, optimizer, ema_mu=ema_mu)
+                      if tokens else
+                      make_train_step(model, optimizer, **step_kw))
     eval_step = make_eval_step(model, num_classes=num_classes,
                                lb_smooth=float(conf.get("lb_smooth", 0.0) or 0.0),
-                               preprocess_fn=eval_preprocess)
+                               preprocess_fn=eval_preprocess, tokens=tokens)
     replay_eval = make_replay_eval_step(
         model, num_classes=num_classes,
         lb_smooth=float(conf.get("lb_smooth", 0.0) or 0.0),
-        preprocess_fn=eval_preprocess) if use_cache else None
+        preprocess_fn=eval_preprocess, tokens=tokens) if use_cache else None
 
     writers = make_writers(
         os.path.dirname(save_path) if save_path else None,
@@ -712,6 +781,8 @@ def train_and_eval(
             )
             pos = 0
             dispatch_metrics: list = []
+            if token_counters is not None:
+                token_counters.new_epoch()
             if resume_pos and epoch == epoch_start:
                 # preempted mid-epoch: skip the dispatches already done
                 # and seed the metric chain with the saved partial sums
@@ -721,6 +792,9 @@ def train_and_eval(
                 pos = resume_pos
                 if resume_sums:
                     dispatch_metrics.append(dict(resume_sums))
+                    if token_counters is not None:
+                        token_counters.new_epoch(
+                            _split_counts(dict(resume_sums)))
             for di, n in enumerate(split_dispatch_chunks(
                     len(mat) - pos, steps_per_dispatch)):
                 idx_dev = place_index_matrix(mesh, mat[pos:pos + n])
@@ -750,13 +824,19 @@ def train_and_eval(
                     if save_path and is_master:
                         sums = _sum_metric_dicts(dispatch_metrics)
                         snapshot_in_epoch(pos, sums)
+                        if token_counters is not None:
+                            token_counters.publish(_split_counts(dict(sums)))
                         # saved sums replace the pending handles — the
                         # continued f32 chain is identical either way
                         dispatch_metrics = [
                             {k: np.float32(v) for k, v in sums.items()}]
                     if preemption_requested():
                         raise preempted_in_epoch(pos, len(mat))
-            acc.add_dict(_sum_metric_dicts(dispatch_metrics))
+            sums = _sum_metric_dicts(dispatch_metrics)
+            counts = _split_counts(sums)
+            if token_counters is not None:
+                token_counters.publish(counts)
+            acc.add_dict(sums)
         else:
             # host feed: the same resume points as the device-resident
             # feed, one batch a dispatch.  A resumed epoch skips the
@@ -801,6 +881,10 @@ def train_and_eval(
         resume_pos, resume_sums = 0, None  # consumed by the first epoch
         if is_master and progress_every and loss_ema is not None:
             sys.stderr.write("\n")
+        if token_counters is not None and train_cache is None:
+            # host feed: the sums sat on the device until here
+            token_counters.new_epoch()
+            token_counters.publish(_split_counts(acc.metrics))
         train_metrics = acc.normalize()
         if not train_metrics:
             raise RuntimeError(
@@ -849,7 +933,8 @@ def train_and_eval(
                 batch_stats=jax.tree.map(jnp.copy, state.ema["batch_stats"]),
             )
         for k in ("loss", "top1", "top5"):
-            writers[0].add_scalar(k, train_metrics[k], epoch)
+            if k in train_metrics:  # a token model reports no top-5
+                writers[0].add_scalar(k, train_metrics[k], epoch)
         logger.info(
             "[%s %3d/%3d] loss=%.4f top1=%.4f lr=%.5f",
             "train", epoch, epochs, train_metrics["loss"], train_metrics["top1"],

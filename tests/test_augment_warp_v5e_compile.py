@@ -53,3 +53,74 @@ def test_tiled_warp_compiles_for_a_v5e_inside_its_budget(one_chip, no_compile_ca
     assert "conditional" in text  # the run-time choice is a branch, not a select
     assert A._warp_bytes_an_image(size, size, 3) * n > A._DENSE_WARP_BUDGET_BYTES
     assert "while" in text  # so the batch runs in chunks
+
+
+# --- the token model's two kernels at the timed sizes (PR 35), in this file
+# because one worker may load the TPU's library and this file's fixture does
+
+
+def test_chunked_kda_scan_and_its_backward_compile_for_a_v5e(one_chip, no_compile_cache):
+    """One KDA layer of `kimi_linear_48b_a3b_train`: 8,192 tokens, 32
+    heads of 128.  The sub-block sums of ``_decayed_gram`` must stay
+    inside their fusions (2.1 GB each if they were written out)."""
+    from fast_autoaugment_tpu.ops.kda import chunk_kda
+
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
+
+    args = (shape(1, 8192, 32, 128),) * 4 + (shape(1, 8192, 32),)
+
+    def scalar(*a):
+        out, state = chunk_kda(*a)
+        return jnp.sum(out) + jnp.sum(state)
+
+    compiled = jax.jit(jax.grad(scalar, argnums=(0, 1, 2, 3, 4))).lower(*args).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 6e9
+    assert "while" in compiled.as_text()  # the scan over chunks is a loop
+
+
+def test_blocked_attention_keeps_one_blocks_scores_live(one_chip, no_compile_cache):
+    """The MLA layer's softmax at 8,192 tokens and 32 heads: whole, the
+    scores are 8.6 GB; blocked in loops, forward and backward stay under
+    a quarter of that."""
+    from fast_autoaugment_tpu.ops.attention import blocked_causal_attention
+
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
+
+    q, k, v = shape(1, 8192, 32, 128), shape(1, 8192, 32, 128), shape(1, 8192, 32, 128)
+    q_pe, k_pe = shape(1, 8192, 32, 64), shape(1, 8192, 64)
+
+    def scalar(q, k, v, q_pe, k_pe):
+        return jnp.sum(blocked_causal_attention(
+            q, k, v, q_shared=q_pe, k_shared=k_pe, scale=192 ** -0.5))
+
+    compiled = jax.jit(jax.grad(scalar, argnums=(0, 1, 2, 3, 4))).lower(
+        q, k, v, q_pe, k_pe).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.25 * 8.6e9
+
+
+def test_grouped_experts_and_their_backward_compile_for_a_v5e(one_chip, no_compile_cache):
+    """One expert layer's held share of `kimi_linear_48b_a3b_train`: 8,192
+    tokens, top-8 of 256, 8 experts of 2,304 x 1,024 held.  Both loops
+    over the blocks the routing filled (forward, and the backward pass
+    ``ops/moe.py`` writes itself) stay loops, and nothing the size of the
+    worst case (every token through every held expert: 65,536 rows) is
+    written out."""
+    from fast_autoaugment_tpu.ops import moe
+
+    def shape(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    x, chosen, weights = shape((8192, 2304)), shape((8192, 8), jnp.int32), shape((8192, 8))
+    gate, up, down = shape((8, 2304, 1024)), shape((8, 2304, 1024)), shape((8, 1024, 2304))
+
+    def scalar(x, weights, gate, up, down, chosen):
+        return jnp.sum(moe.held_experts(x, chosen, weights, gate, up, down, first=0))
+
+    compiled = jax.jit(jax.value_and_grad(scalar, argnums=(0, 1, 2, 3, 4))).lower(
+        x, weights, gate, up, down, chosen).compile()
+    assert compiled.as_text().count(" while(") >= 2
+    # inputs, their gradients and one block's rows: far under the 604 MB
+    # that 65,536 gathered rows alone would take
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9

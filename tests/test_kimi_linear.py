@@ -1,0 +1,505 @@
+"""Kimi Linear's three operations against their plain forms, at a small
+size on the CPU with seeded random inputs: the chunked delta-rule scan
+against the recurrence (output, final state and the gradients of ``q, k,
+v, g, beta``), the blocked causal softmax against the whole one, the
+expert layer's shares adding up to the uncut layer — and the AdamW the
+model trains with against the plain formula."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from fast_autoaugment_tpu.models import get_model, model_conf_of
+from fast_autoaugment_tpu.ops import moe
+from fast_autoaugment_tpu.ops.attention import blocked_causal_attention
+from fast_autoaugment_tpu.ops.kda import chunk_kda, recurrent_kda
+from fast_autoaugment_tpu.ops.optim import (
+    DECAY_MASKS,
+    build_optimizer,
+    matrices_mask,
+)
+
+pytestmark = pytest.mark.usefixtures("highest")
+
+
+@pytest.fixture()
+def highest():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+def _close(a, b, rel):
+    scale = float(jnp.abs(b).max())
+    assert float(jnp.abs(a - b).max()) <= rel * scale, (
+        float(jnp.abs(a - b).max()), scale)
+
+
+# ------------------------------------------------------------ the KDA scan
+
+
+def _kda_inputs(length, decay_spread, seed=0, batch=2, heads=3, kdim=16, vdim=8):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 5)
+
+    def unit(a):
+        return a / jnp.linalg.norm(a, axis=-1, keepdims=True)
+
+    q = unit(jax.random.normal(keys[0], (batch, length, heads, kdim))) * kdim ** -0.5
+    k = unit(jax.random.normal(keys[1], (batch, length, heads, kdim)))
+    v = jax.random.normal(keys[2], (batch, length, heads, vdim))
+    g = -jnp.exp(decay_spread * jax.random.normal(keys[3], (batch, length, heads, kdim)))
+    beta = jax.nn.sigmoid(jax.random.normal(keys[4], (batch, length, heads)))
+    return q, k, v, g, beta
+
+
+@pytest.mark.parametrize("length, chunk", [(128, 64), (96, 32), (48, 16), (24, 64)])
+def test_chunked_scan_gives_the_recurrences_output_and_state(length, chunk):
+    args = _kda_inputs(length, 1.0)
+    out, state = recurrent_kda(*args)
+    chunked, chunked_state = jax.jit(
+        lambda *a: chunk_kda(*a, chunk=chunk))(*args)
+    _close(chunked, out, 2e-5)
+    _close(chunked_state, state, 2e-5)
+
+
+def test_chunked_scan_takes_an_initial_state():
+    args = _kda_inputs(128, 1.0)
+    first = tuple(a[:, :64] for a in args)
+    second = tuple(a[:, 64:] for a in args)
+    _, carried = chunk_kda(*first)
+    out, state = chunk_kda(*second, initial_state=carried)
+    whole, whole_state = recurrent_kda(*args)
+    _close(out, whole[:, 64:], 2e-5)
+    _close(state, whole_state, 2e-5)
+
+
+def test_a_decay_that_would_overflow_a_factorised_form_does_not():
+    """A channel that decays by e^-1500 a token: exp(-G) inside a chunk
+    is far beyond float32, the recurrence itself is tame."""
+    q, k, v, g, beta = _kda_inputs(128, 2.5, seed=3)
+    assert float(g.min()) < -200
+    out, state = recurrent_kda(q, k, v, g, beta)
+    chunked, chunked_state = chunk_kda(q, k, v, g, beta)
+    assert bool(jnp.isfinite(chunked).all())
+    _close(chunked, out, 3e-4)
+    _close(chunked_state, state, 3e-4)
+
+
+@pytest.mark.parametrize("argnum, name", list(enumerate(["q", "k", "v", "g", "beta"])))
+def test_chunked_scans_gradient_is_the_recurrences(argnum, name):
+    args = _kda_inputs(128, 1.0, seed=1)
+
+    def scalar(fn):
+        def f(*a):
+            out, state = fn(*a)
+            return jnp.sum(out * jnp.cos(out)) + jnp.sum(state ** 2)
+        return f
+
+    plain = jax.grad(scalar(recurrent_kda), argnums=argnum)(*args)
+    chunked = jax.jit(jax.grad(scalar(chunk_kda), argnums=argnum))(*args)
+    _close(chunked, plain, 2e-4)
+
+
+def test_a_length_that_is_no_multiple_of_the_chunk_is_refused():
+    with pytest.raises(ValueError, match="no multiple"):
+        chunk_kda(*_kda_inputs(100, 1.0))
+
+
+# ------------------------------------------------- the blocked softmax
+
+
+def _whole_softmax(q, k, v, q_shared, k_shared, scale):
+    length = q.shape[1]
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k)
+    if q_shared is not None:
+        scores = scores + jnp.einsum("bqhd,bkd->bhqk", q_shared, k_shared)
+    seen = jnp.tril(jnp.ones((length, length), bool))
+    weights = jax.nn.softmax(jnp.where(seen, scores * scale, -jnp.inf), -1)
+    return jnp.einsum("bhqk,bkhd->bqhd", weights, v)
+
+
+@pytest.mark.parametrize("block, spans, shared", [
+    (8, 4, True), (8, 2, False), (16, 4, True), (64, 4, True), (32, 1, False)])
+def test_blocked_attention_is_the_whole_causal_softmax(block, spans, shared):
+    keys = jax.random.split(jax.random.PRNGKey(2), 5)
+    q = jax.random.normal(keys[0], (2, 64, 3, 8))
+    k = jax.random.normal(keys[1], (2, 64, 3, 8))
+    v = jax.random.normal(keys[2], (2, 64, 3, 4))
+    q_shared = jax.random.normal(keys[3], (2, 64, 3, 5)) if shared else None
+    k_shared = jax.random.normal(keys[4], (2, 64, 5)) if shared else None
+
+    def blocked(q, k, v):
+        return blocked_causal_attention(q, k, v, scale=0.3, q_shared=q_shared,
+                                        k_shared=k_shared, block=block, spans=spans)
+
+    whole = _whole_softmax(q, k, v, q_shared, k_shared, 0.3)
+    _close(jax.jit(blocked)(q, k, v), whole, 1e-5)
+    plain = jax.grad(lambda *a: jnp.sum(jnp.sin(_whole_softmax(
+        *a, q_shared, k_shared, 0.3))), argnums=(0, 1, 2))(q, k, v)
+    ours = jax.grad(lambda *a: jnp.sum(jnp.sin(blocked(*a))),
+                    argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(ours, plain):
+        _close(a, b, 1e-4)
+
+
+# ----------------------------------------------------- the expert layer
+
+
+def _moe_inputs(tokens=64, dim=16, width=8, experts=16, top_k=4, seed=4):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 5)
+    x = jax.random.normal(keys[0], (tokens, dim))
+    router = jax.random.normal(keys[1], (dim, experts))
+    w_gate = jax.random.normal(keys[2], (experts, dim, width)) * dim ** -0.5
+    w_up = jax.random.normal(keys[3], (experts, dim, width)) * dim ** -0.5
+    w_down = jax.random.normal(keys[4], (experts, width, dim)) * width ** -0.5
+    chosen, weights = moe.route(x, router, jnp.zeros(experts), top_k=top_k,
+                                scale=2.446)
+    return x, chosen, weights, w_gate, w_up, w_down
+
+
+def _every_expert_in_a_loop(x, chosen, weights, w_gate, w_up, w_down):
+    out = jnp.zeros_like(x)
+    for e in range(w_gate.shape[0]):
+        weight = jnp.sum(jnp.where(chosen == e, weights, 0.0), -1)
+        out = out + weight[:, None] * (
+            (jax.nn.silu(x @ w_gate[e]) * (x @ w_up[e])) @ w_down[e])
+    return out
+
+
+def test_router_chooses_top_k_of_all_experts_and_renormalises():
+    x, chosen, weights, *_ = _moe_inputs()
+    assert chosen.shape == weights.shape == (64, 4)
+    assert int(chosen.min()) >= 0 and int(chosen.max()) < 16
+    assert all(len(set(row)) == 4 for row in np.asarray(chosen))  # distinct
+    np.testing.assert_allclose(np.asarray(weights.sum(-1)), 2.446, rtol=1e-5)
+    # the bias moves the choice and never the weight
+    router = jax.random.normal(jax.random.split(jax.random.PRNGKey(4), 5)[1], (16, 16))
+    pushed = jnp.zeros(16).at[7].set(10.0)
+    chosen_b, weights_b = moe.route(x, router, pushed, top_k=4, scale=1.0)
+    assert bool((chosen_b == 7).any(axis=-1).all())
+    scores = jax.nn.sigmoid(x @ router)
+    picked = jnp.take_along_axis(scores, chosen_b, -1)
+    np.testing.assert_allclose(np.asarray(weights_b),
+                               np.asarray(picked / picked.sum(-1, keepdims=True)),
+                               rtol=1e-5)
+
+
+def test_the_shares_add_up_to_the_uncut_layer():
+    """16 experts over 4 shares of 4: the four routed parts are the whole
+    layer's routed part."""
+    x, chosen, weights, w_gate, w_up, w_down = _moe_inputs()
+    whole = _every_expert_in_a_loop(x, chosen, weights, w_gate, w_up, w_down)
+    shares = [moe.held_experts(
+        x, chosen, weights, w_gate[s:s + 4], w_up[s:s + 4], w_down[s:s + 4],
+        first=s) for s in range(0, 16, 4)]
+    _close(sum(shares), whole, 1e-5)
+    assert all(float(jnp.abs(share).max()) > 0 for share in shares)
+    uncut = moe.held_experts(x, chosen, weights, w_gate, w_up, w_down, first=0)
+    _close(uncut, whole, 1e-5)
+    counts = [moe.assignment_counts(chosen, s, 4) for s in range(0, 16, 4)]
+    assert int(sum(c.sum() for c in counts)) == 64 * 4   # no token dropped
+
+
+@pytest.mark.parametrize("rows", [3, 16, 64, 512])
+def test_the_grouped_blocks_size_changes_no_result(rows):
+    """An expert's assignments in blocks of 3 rows (most blocks whole,
+    the last in part), of 16, of every token, of more than there are."""
+    x, chosen, weights, w_gate, w_up, w_down = _moe_inputs()
+    held = jnp.where((chosen >= 4) & (chosen < 12), weights, 0.0)
+    plain = _every_expert_in_a_loop(x, chosen - 4, held, w_gate[4:12],
+                                    w_up[4:12], w_down[4:12])
+    ours = jax.jit(lambda *a: moe.held_experts(*a, first=4, block_rows=rows))(
+        x, chosen, weights, w_gate[4:12], w_up[4:12], w_down[4:12])
+    _close(ours, plain, 1e-5)
+
+
+def test_every_token_to_one_expert_is_the_same_sum():
+    """The routing a model trained from scratch without the balancing
+    rule falls into (PERF.md section 6, PR 35): all tokens choose the
+    same experts.  No shape and no result depends on it, and no token is
+    dropped: the loop over blocks then runs over every token."""
+    x, _, _, w_gate, w_up, w_down = _moe_inputs()
+    chosen = jnp.tile(jnp.asarray([[5, 1, 9, 14]], jnp.int32), (64, 1))
+    weights = jnp.full((64, 4), 2.446 / 4)
+    whole = _every_expert_in_a_loop(x, chosen, weights, w_gate, w_up, w_down)
+    parts = [moe.held_experts(x, chosen, weights, w_gate[s:s + 4], w_up[s:s + 4],
+                              w_down[s:s + 4], first=s) for s in range(0, 16, 4)]
+    _close(sum(parts), whole, 1e-5)
+    assert [int(moe.assignment_counts(chosen, s, 4).max()) for s in range(0, 16, 4)] == [
+        64, 64, 64, 64]
+
+
+@pytest.mark.parametrize("rows", [5, 512])
+def test_a_held_shares_gradients_are_the_loops(rows):
+    """The backward pass ``ops/moe.py`` writes itself (a loop over the
+    same blocks) against JAX's own of the plain loop over experts."""
+    x, chosen, weights, w_gate, w_up, w_down = _moe_inputs()
+
+    def ours(x, weights, w_gate, w_up, w_down):
+        return jnp.sum(jnp.sin(moe.held_experts(
+            x, chosen, weights, w_gate, w_up, w_down, first=4, block_rows=rows)))
+
+    def plain(x, weights, w_gate, w_up, w_down):
+        held = jnp.where((chosen >= 4) & (chosen < 8), weights, 0.0)
+        return jnp.sum(jnp.sin(_every_expert_in_a_loop(
+            x, chosen - 4, held, w_gate, w_up, w_down)))
+
+    args = (x, weights, w_gate[4:8], w_up[4:8], w_down[4:8])
+    for a, b in zip(jax.jit(jax.grad(ours, argnums=range(5)))(*args),
+                    jax.grad(plain, argnums=range(5))(*args)):
+        _close(a, b, 1e-4)
+
+
+def test_the_bias_moves_towards_the_mean_load_and_balances_a_skewed_router():
+    """``b_e += rate * sign(mean load - load_e)``: one step by hand, and
+    a router whose scores favour four experts for every token spreads
+    its tokens once the rule has run (no gradient involved)."""
+    load = jnp.asarray([10, 0, 4, 2], jnp.int32)          # mean 4
+    np.testing.assert_allclose(
+        np.asarray(moe.balance_bias(jnp.asarray([0.5, 0.0, -1.0, 0.0]), load, 0.1)),
+        [0.4, 0.1, -1.0, 0.1], rtol=1e-6)
+    keys = jax.random.split(jax.random.PRNGKey(11), 3)
+    x = jax.random.normal(keys[0], (256, 16))
+    # a component every token shares, as a freshly initialised model's
+    # residual stream has, decides the choice until the bias answers it
+    x = x * 0.3 + jax.random.normal(keys[1], (1, 16))
+    router = jax.random.normal(keys[2], (16, 16)) * 0.5
+    bias = jnp.zeros(16)
+
+    def loads(bias):
+        chosen, _ = moe.route(x, router, bias, top_k=4, scale=1.0)
+        return moe.assignment_counts(chosen, 0, 16)
+
+    before = loads(bias)
+    for _ in range(200):
+        bias = moe.balance_bias(bias, loads(bias), 0.01)
+    after = loads(bias)
+    mean = 256 * 4 / 16
+    assert int(before.max()) > 3 * mean and int(before.min()) < mean / 8
+    assert int(after.max()) < 2 * mean and int(after.min()) > mean / 2
+    assert int(after.sum()) == int(before.sum()) == 256 * 4
+
+
+def _tiny_layer_conf(held, share):
+    return {
+        "model": {
+            "type": "kimi_linear", "remat": False, "first_k_dense_replace": 1,
+            "hidden_size": 32, "intermediate_size": 48, "kv_lora_rank": 8,
+            "linear_attn_config": {"full_attn_layers": [4], "head_dim": 8,
+                                   "kda_layers": [1, 2, 3], "num_heads": 2,
+                                   "short_conv_kernel_size": 4},
+            "mla_use_nope": True, "moe_intermediate_size": 16, "moe_layer_freq": 1,
+            "moe_renormalize": True, "moe_router_activation_func": "sigmoid",
+            "num_attention_heads": 2, "num_expert_group": 1, "num_experts": 16,
+            "num_experts_per_token": 4, "num_hidden_layers": 2,
+            "num_shared_experts": 1, "q_lora_rank": None, "qk_nope_head_dim": 8,
+            "qk_rope_head_dim": 4, "rms_norm_eps": 1e-5,
+            "routed_scaling_factor": 2.446, "topk_group": 1, "v_head_dim": 8,
+            "vocab_size": 32, "expert_share": share},
+        "dataset": "synthetic_tokens", "experts_held": held}
+
+
+def test_the_models_expert_layer_shares_add_up_with_the_shared_expert_once():
+    """Through the module: layer 2 of a two-layer model holding 4 of 16
+    experts, one share after another with the uncut model's weights; the
+    four routed parts plus the shared expert's, counted once, are the
+    uncut layer's output."""
+    from fast_autoaugment_tpu.models.kimi_linear import ExpertLayer
+
+    whole = get_model(model_conf_of(_tiny_layer_conf(16, 0)), 32)
+    ids = jax.random.randint(jax.random.PRNGKey(5), (2, 16), 0, 32)
+    params = whole.init({"params": jax.random.PRNGKey(6)}, ids)["params"]
+    layer = params["layer2"]["moe"]
+    x = jax.random.normal(jax.random.PRNGKey(7), (2, 16, 32))
+
+    def apply(held, share, shared, p):
+        module = ExpertLayer(experts=16, held=held, share=share, top_k=4, width=16,
+                             shared=shared, scale=2.446, renormalize=True)
+        return module.apply({"params": p}, x)
+
+    uncut = apply(16, 0, 1, layer)
+    routed = dict(layer)
+    shared_expert = routed.pop("shared_experts")
+    parts = []
+    for share in range(4):
+        own = dict(routed, **{k: routed[k][4 * share:4 * share + 4]
+                              for k in ("experts_gate", "experts_up", "experts_down")})
+        parts.append(apply(4, share, 0, own))
+    from fast_autoaugment_tpu.models.kimi_linear import SwiGLU
+    once = SwiGLU(16).apply({"params": shared_expert}, x)
+    _close(sum(parts) + once, uncut, 1e-5)
+    # and the model is told which experts it holds: a share's parameters
+    held = get_model(model_conf_of(_tiny_layer_conf(4, 2)), 32)
+    shapes = jax.eval_shape(lambda: held.init({"params": jax.random.PRNGKey(6)}, ids))
+    assert shapes["params"]["layer2"]["moe"]["experts_gate"].shape == (4, 32, 16)
+    assert shapes["params"]["layer2"]["moe"]["router"].shape == (32, 16)
+
+
+@pytest.mark.parametrize("bad", [
+    {"experts_held": 5}, {"experts_held": 4, "expert_share": 4},
+    {"layers_held": 3}, {"ids_held": 64}])
+def test_a_share_the_model_cannot_hold_is_refused(bad):
+    conf = _tiny_layer_conf(16, 0)
+    conf.pop("experts_held")
+    conf["model"]["expert_share"] = bad.pop("expert_share", 0)
+    conf.update(bad)
+    with pytest.raises(ValueError):
+        get_model(model_conf_of(conf), 32)
+
+
+# ------------------------------------------------------------------ AdamW
+
+ADAMW = {"type": "adamw", "beta1": 0.9, "beta2": 0.95, "eps": 1e-8,
+         "decay": 0.1, "clip": 1.0}
+
+
+def _params_and_grads():
+    keys = jax.random.split(jax.random.PRNGKey(8), 8)
+    shapes = {"proj": {"kernel": (6, 5)}, "experts_up": (3, 5, 4),
+              "conv": {"kernel": (4, 6)}, "norm": {"weight": (6,)},
+              "A_log": (3,), "dt_bias": (2, 3),
+              "e_score_correction_bias": (8,), "embed_tokens": (9, 6)}
+    leaves, treedef = jax.tree.flatten(shapes, is_leaf=lambda s: isinstance(s, tuple))
+    params = jax.tree.unflatten(treedef, [
+        jax.random.normal(k, s) for k, s in zip(keys, leaves)])
+    grads = jax.tree.map(lambda p: 3.0 * jnp.cos(p), params)
+    return params, grads
+
+
+def _plain_adamw(params, grads, steps, lr, *, decay=True, second_moment=True):
+    """AdamW by the formula, the global-norm clip first, the decay
+    decoupled and on leaves of two or more dimensions but for ``dt_bias``."""
+    decayed = matrices_mask(params)
+    m = jax.tree.map(jnp.zeros_like, params)
+    v = jax.tree.map(jnp.zeros_like, params)
+    for t in range(1, steps + 1):
+        norm = jnp.sqrt(sum(jnp.sum(g * g) for g in jax.tree.leaves(grads)))
+        clipped = jax.tree.map(lambda g: g * jnp.minimum(1.0, 1.0 / norm), grads)
+        m = jax.tree.map(lambda m, g: 0.9 * m + 0.1 * g, m, clipped)
+        v = jax.tree.map(lambda v, g: 0.95 * v + 0.05 * g * g, v, clipped)
+
+        def update(p, m, v, d):
+            m_hat = m / (1 - 0.9 ** t)
+            v_hat = v / (1 - 0.95 ** t) if second_moment else jnp.ones_like(v)
+            step = m_hat / (jnp.sqrt(v_hat) + 1e-8)
+            return p - lr * (step + (0.1 * p if decay and d else 0.0))
+
+        params = jax.tree.map(update, params, m, v, decayed)
+    return params
+
+
+def _optimizer_steps(params, grads, steps, lr):
+    optimizer = build_optimizer(ADAMW, lambda step: lr)
+    state = optimizer.init(params)
+    for _ in range(steps):
+        updates, state = optimizer.update(grads, state, params)
+        params = optax.apply_updates(params, updates)
+    return params
+
+
+def test_adamw_step_is_the_plain_formula_with_the_decay_mask():
+    params, grads = _params_and_grads()
+    ours = _optimizer_steps(params, grads, 3, 1e-2)
+    plain = _plain_adamw(params, grads, 3, 1e-2)
+    for a, b, p in zip(jax.tree.leaves(ours), jax.tree.leaves(plain),
+                       jax.tree.leaves(params)):
+        _close(a - p, b - p, 1e-5)
+
+
+@pytest.mark.parametrize("left_out", ["decay", "second_moment"])
+def test_adamw_with_a_part_left_out_is_told_apart(left_out):
+    params, grads = _params_and_grads()
+    ours = _optimizer_steps(params, grads, 3, 1e-2)
+    wrong = _plain_adamw(params, grads, 3, 1e-2, **{left_out: False})
+    gaps = [float(jnp.abs(a - b).max() / jnp.abs(a - p).max())
+            for a, b, p in zip(jax.tree.leaves(ours), jax.tree.leaves(wrong),
+                               jax.tree.leaves(params))]
+    assert max(gaps) > 0.05, gaps
+
+
+def test_decay_mask_takes_matrices_and_leaves_the_named_vectors():
+    params, _ = _params_and_grads()
+    mask = matrices_mask(params)
+    assert mask == {"proj": {"kernel": True}, "experts_up": True,
+                    "conv": {"kernel": True}, "norm": {"weight": False},
+                    "A_log": False, "dt_bias": False,
+                    "e_score_correction_bias": False, "embed_tokens": True}
+    assert set(DECAY_MASKS) == {"non_bn", "matrices"}
+
+
+def test_unknown_decay_mask_and_optimizer_type_are_refused():
+    with pytest.raises(ValueError, match="decay_mask"):
+        build_optimizer(dict(ADAMW, decay_mask="everything"), lambda s: 1e-3)
+    with pytest.raises(ValueError, match="decay_mask"):
+        build_optimizer({"type": "sgd", "decay": 1e-4, "decay_mask": "bn"},
+                        lambda s: 1e-3)
+    with pytest.raises(ValueError, match="optimizer type"):
+        build_optimizer({"type": "lion"}, lambda s: 1e-3)
+
+
+def test_sgd_keeps_its_non_bn_mask_by_default():
+    """The image confs name no mask: the chain they get is the one they had."""
+    params = {"conv": {"kernel": jnp.ones((3, 3))}, "bn1": {"scale": jnp.ones((3,))}}
+    optimizer = build_optimizer({"type": "sgd", "decay": 0.5, "clip": 0, "momentum": 0.0,
+                                 "nesterov": False}, lambda s: 1.0)
+    zeros = jax.tree.map(jnp.zeros_like, params)
+    updates, _ = optimizer.update(zeros, optimizer.init(params), params)
+    assert float(jnp.abs(updates["bn1"]["scale"]).max()) == 0.0
+    np.testing.assert_allclose(np.asarray(updates["conv"]["kernel"]), -0.5)
+
+
+# ------------------------------------------- the checkpoint written in pieces
+
+
+@pytest.mark.parametrize("case", ["train_state", "mixed_leaves", "small_only"])
+def test_streamed_checkpoint_payload_is_flax_to_bytes_byte_for_byte(case, tmp_path):
+    """``core/checkpoint.py::_stream_payload`` against
+    ``flax.serialization.to_bytes``: the same bytes, digest and size, for a
+    train state with AdamW's moments (large float32 leaves, a step
+    counter, empty collections), for leaves of other kinds and sizes
+    around the streaming threshold, and for a state with no large leaf."""
+    import hashlib
+    import io
+
+    from flax import serialization
+
+    from fast_autoaugment_tpu.core import checkpoint
+    from fast_autoaugment_tpu.train.steps import create_train_state
+
+    if case == "train_state":
+        conf = _tiny_layer_conf(4, 1)
+        conf["model"].update(hidden_size=128, vocab_size=256)
+        model = get_model(model_conf_of(conf), 256)
+        state = create_train_state(
+            model, build_optimizer(ADAMW, lambda step: 1e-3),
+            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32), use_ema=True,
+            jit_init=True)
+        assert max(leaf.nbytes for leaf in jax.tree.leaves(state)) >= 1 << 16
+    elif case == "mixed_leaves":
+        rng = np.random.default_rng(0)
+        state = {"f32": jnp.asarray(rng.normal(size=(300, 70)), jnp.float32),
+                 "bf16": jnp.asarray(rng.normal(size=(40000,)), jnp.bfloat16),
+                 "i8_on_the_threshold": np.arange(1 << 16, dtype=np.int8),
+                 "one_under": np.zeros((1 << 16) - 1, np.uint8),
+                 "strided": np.arange(90000, dtype=np.int32).reshape(300, 300).T,
+                 "nested": {"empty": {}, "scalar": np.float32(2.5), "int": 7,
+                            "none": None, "text": "ids", "zero_d": jnp.int32(3),
+                            "no_elements": np.zeros((0, 4), np.float32)},
+                 "tuple_of_states": (optax.EmptyState(), {"mu": jnp.ones((200, 200))})}
+    else:
+        state = {"w": jnp.ones((4, 4)), "step": jnp.int32(9)}
+    want = serialization.to_bytes(state)
+    got = io.BytesIO()
+    digest, size = checkpoint._stream_payload(state, got)
+    assert got.getvalue() == want
+    assert (digest, size) == (hashlib.sha256(want).hexdigest(), len(want))
+    # and through the public pair: what is saved restores to the same leaves
+    path = str(tmp_path / "state.msgpack")
+    checkpoint.save_checkpoint(path, state, {"epoch": 1})
+    with open(path, "rb") as fh:
+        assert fh.read() == want
+    assert checkpoint.read_metadata(path)["digest"] == digest
+    restored = checkpoint.load_checkpoint(path, state)
+    for a, b in zip(jax.tree.leaves(restored), jax.tree.leaves(state)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

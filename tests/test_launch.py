@@ -60,7 +60,8 @@ def test_train_cli_overrides(tmp_path):
 
 def test_all_conf_presets_parse():
     from fast_autoaugment_tpu.core.config import load_config
-    from fast_autoaugment_tpu.models import get_model, num_class
+    from fast_autoaugment_tpu.data.datasets import is_token_dataset
+    from fast_autoaugment_tpu.models import get_model, model_conf_of, num_class
 
     confdir = os.path.join(os.path.dirname(__file__), "..", "confs")
     presets = sorted(os.listdir(confdir))
@@ -80,7 +81,9 @@ def test_all_conf_presets_parse():
     for name in presets:
         conf = load_config(os.path.join(confdir, name))
         assert conf["model"]["type"]
-        # every preset's model must be constructible
-        model_conf = dict(conf["model"], dataset=conf["dataset"])
-        get_model(model_conf, num_class(conf["dataset"]))
-        assert conf["optimizer"]["type"] in ("sgd", "rmsprop")
+        # every preset's model must be constructible; a token model's
+        # classes are its ids (PR 35)
+        tokens = is_token_dataset(conf["dataset"])
+        get_model(model_conf_of(conf), int(conf["model"]["vocab_size"]) if tokens
+                  else num_class(conf["dataset"]))
+        assert conf["optimizer"]["type"] in ("sgd", "rmsprop", "adamw")
