@@ -52,7 +52,8 @@ __all__ = [
 
 PREFIX = "faa_"
 
-#: ``train/steps.py``: the ``jnp.take`` of a batch from the device cache
+#: ``train/steps.py``: a batch taken from the device cache's stored rows
+#: (``data.pipeline.StoredRows.take``) and its labels
 BATCH_GATHER = "faa_batch_gather"
 #: ``ops/preprocess.py``: sub-policy draw, gates, the switch and its select
 AUG_POLICY = "faa_aug_policy"

@@ -25,12 +25,14 @@ import threading
 from concurrent.futures import ThreadPoolExecutor
 from typing import Iterator
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from fast_autoaugment_tpu.core import telemetry
 from fast_autoaugment_tpu.data.datasets import ArrayDataset
 
-__all__ = ["BatchIterator", "DeviceCache", "train_batches",
+__all__ = ["BatchIterator", "DeviceCache", "StoredRows", "train_batches",
            "stacked_train_batches", "eval_batches", "prefetch",
            "train_index_matrix", "stacked_index_matrix",
            "resolve_device_cache", "split_dispatch_chunks"]
@@ -348,9 +350,58 @@ def stacked_train_batches(
         yield images, dataset.labels[chunks], active
 
 
+@jax.tree_util.register_pytree_node_class
+class StoredRows:
+    """A data set's examples as the device cache keeps them: one
+    contiguous row an example, ``[N, row]``, and the shape a taken
+    example is given back.
+
+    The example axis stays first (and stays the sharded axis).  An array
+    of rank above 2 is flattened behind it, because a batch gather reads
+    whole rows: ``uint8 [N, 32, 32, 3]`` as the chip tiles it (the
+    example axis minor-most, a last dimension of 3 being no lane's
+    worth) cannot be gathered from, and XLA:TPU laid all ``N`` examples
+    out again in front of every step's gather to hand ``B`` of them on
+    (docs/PARITY.md "Step dispatch & device cache").  Rank 1 and 2
+    (labels, token ids ``[N, T+1]``) are rows already and stay as they
+    are.  A pytree whose one leaf is ``rows``: it crosses ``jit`` as the
+    array does, and the program is keyed on the example shape.
+    """
+
+    def __init__(self, rows, example_shape: tuple[int, ...]):
+        self.rows = rows
+        self.example_shape = tuple(example_shape)
+
+    @classmethod
+    def of(cls, examples: np.ndarray) -> "StoredRows":
+        """The stored form of a host array ``[N, ...]``: a view, no copy."""
+        examples = np.ascontiguousarray(examples)
+        rows = examples if examples.ndim <= 2 else examples.reshape(
+            examples.shape[0], -1)
+        return cls(rows, examples.shape[1:])
+
+    shape = property(lambda self: self.rows.shape)
+    dtype = property(lambda self: self.rows.dtype)
+
+    def take(self, idx):
+        """``examples[idx]`` exactly: ``idx [...]`` ->
+        ``[..., *example_shape]``.  Only the rows taken are read, and
+        only they are given a shape again."""
+        taken = jnp.take(self.rows, idx, axis=0)
+        return taken.reshape(idx.shape + self.example_shape)
+
+    def tree_flatten(self):
+        return (self.rows,), self.example_shape
+
+    @classmethod
+    def tree_unflatten(cls, example_shape, children):
+        return cls(children[0], example_shape)
+
+
 class DeviceCache:
-    """Device-resident dataset: the whole uint8 NHWC image array plus
-    labels uploaded ONCE, example axis sharded over the mesh's data axis
+    """Device-resident dataset: the whole uint8 image array (as
+    :class:`StoredRows`, one row an image) plus labels uploaded ONCE,
+    example axis sharded over the mesh's data axis
     (``parallel.mesh.place_dataset``).
 
     The training inner loop then never ships images: the per-epoch
@@ -358,10 +409,10 @@ class DeviceCache:
     ``default_rng((seed, epoch))`` permutation (:func:`train_index_matrix`
     / :func:`stacked_index_matrix` — the same matrices the host iterators
     walk), but only the int32 index matrix crosses to the device, and the
-    compiled train program gathers each batch from the resident copy
-    (``train.steps.make_multistep_train_step``).  This is the training-
-    side twin of the search path's upload-once/replay-batches discipline
-    (``search/tta.py::eval_tta``).
+    compiled train program takes each batch from the resident copy
+    (``images.take`` inside ``train.steps.make_multistep_train_step``).
+    This is the training-side twin of the search path's
+    upload-once/replay-batches discipline (``search/tta.py::eval_tta``).
 
     Eager (in-memory) datasets only: a lazy dataset has nothing resident
     to gather from — ``resolve_device_cache`` gates it off.  HBM cost is
@@ -377,12 +428,12 @@ class DeviceCache:
                 "datasets keep the host prefetch path")
         from fast_autoaugment_tpu.parallel.mesh import place_dataset
 
-        images = np.ascontiguousarray(dataset.images)
+        stored = StoredRows.of(dataset.images)
         labels = np.ascontiguousarray(dataset.labels)
         self.num_examples = len(dataset)
-        self.nbytes = int(images.nbytes + labels.nbytes)
-        self.images, self.labels = place_dataset(
-            mesh, images, labels, axis_name)
+        self.nbytes = int(stored.rows.nbytes + labels.nbytes)
+        rows, self.labels = place_dataset(mesh, stored.rows, labels, axis_name)
+        self.images = StoredRows(rows, stored.example_shape)
         self.mesh = mesh
 
 

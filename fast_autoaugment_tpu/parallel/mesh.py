@@ -172,7 +172,8 @@ def place_dataset(mesh: Mesh, images: np.ndarray, labels: np.ndarray,
                   axis_name: str = "data"):
     """Upload a whole eager dataset ONCE, example axis sharded over the
     mesh's data axis — the storage placement behind
-    ``data.pipeline.DeviceCache``.
+    ``data.pipeline.DeviceCache``, which hands `images` over in its
+    stored form (``StoredRows``: one row an example, ``[N, row]``).
 
     The example count is padded up to a multiple of the data-axis shard
     count with zero rows so every device holds an equal slab; pad rows

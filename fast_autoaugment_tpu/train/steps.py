@@ -510,6 +510,12 @@ def make_multistep_train_step(
     (fancy-gather + H2D image copy + dispatch) x N to shipping one int32
     index matrix and dispatching once.
 
+    ``cache_images`` is the cache's stored form
+    (``data.pipeline.StoredRows``, what ``DeviceCache.images`` is): the
+    cache owns how its examples are laid out and how a batch is taken
+    from them, so this program never sees the layout; labels are a plain
+    ``[N]`` array.
+
     `body` is an UNJITTED step body:
 
     - sequential (``stacked=False``): :func:`make_train_step_body`'s
@@ -552,7 +558,7 @@ def make_multistep_train_step(
 
     def gather(cache_images, cache_labels, idx_n):
         with jax.named_scope(scopes.BATCH_GATHER):
-            return (jnp.take(cache_images, idx_n, axis=0),
+            return (cache_images.take(idx_n),
                     jnp.take(cache_labels, idx_n, axis=0))
 
     if not stacked:

@@ -697,9 +697,10 @@ def train_and_eval(
     train_cache = DeviceCache(total_train, mesh) if use_cache else None
     if train_cache is not None:
         logger.info(
-            "device cache: %d examples (%.1f MiB uint8) resident, "
+            "device cache: %d examples (%.1f MiB) resident as %s %s, "
             "steps_per_dispatch=%d", train_cache.num_examples,
-            train_cache.nbytes / 2**20, steps_per_dispatch)
+            train_cache.nbytes / 2**20, train_cache.images.dtype,
+            list(train_cache.images.shape), steps_per_dispatch)
         # replicated inputs join the committed state on the mesh
         rng = jax.device_put(rng, replicated(mesh))
 
@@ -1327,9 +1328,10 @@ def train_folds_stacked(
     train_cache = DeviceCache(total_train, mesh) if use_cache else None
     if train_cache is not None:
         logger.info(
-            "stacked device cache: %d examples (%.1f MiB uint8) resident, "
-            "steps_per_dispatch=%d", train_cache.num_examples,
-            train_cache.nbytes / 2**20, steps_per_dispatch)
+            "stacked device cache: %d examples (%.1f MiB) resident as "
+            "%s %s, steps_per_dispatch=%d", train_cache.num_examples,
+            train_cache.nbytes / 2**20, train_cache.images.dtype,
+            list(train_cache.images.shape), steps_per_dispatch)
         # the stacked state/keys are already mesh-committed (fold
         # placement above); the policy tensor must be too, or the first
         # compile pins a mixed-commitment signature that knocks later

@@ -124,3 +124,38 @@ def test_grouped_experts_and_their_backward_compile_for_a_v5e(one_chip, no_compi
     # inputs, their gradients and one block's rows: far under the 604 MB
     # that 65,536 gathered rows alone would take
     assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
+
+
+# --- the device cache's batch gather at CIFAR's size (PR 37), in this file
+# for the same reason
+
+
+@pytest.mark.parametrize("index_shape", [(2048,), (5, 512)], ids=["batch", "stacked"])
+def test_batch_taken_from_the_stored_form_copies_no_whole_cache(
+        one_chip, no_compile_cache, index_shape):
+    """``StoredRows.take`` on 50,000 32-px images: the chip keeps a rank-4
+    ``uint8`` argument with the example axis minor-most and copied all of
+    it in front of every gather (PERF.md section 6, PR 37); from rows the
+    compiled program touches the whole cache in the gather alone, and
+    needs no temporary of its size."""
+    import re
+
+    import numpy as np
+
+    from fast_autoaugment_tpu.data.pipeline import StoredRows
+
+    cache = jax.tree.map(
+        lambda rows: jax.ShapeDtypeStruct(
+            (50000,) + rows.shape[1:], rows.dtype, sharding=one_chip),
+        StoredRows.of(np.zeros((1, 32, 32, 3), np.uint8)))
+    idx = jax.ShapeDtypeStruct(index_shape, jnp.int32, sharding=one_chip)
+
+    def first_consumer(stored, i):  # the step reads its batch as float
+        return stored.take(i).astype(jnp.float32) * (1.0 / 255.0)
+
+    compiled = jax.jit(first_consumer).lower(cache, idx).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 50000 * 3072 // 4
+    whole = re.compile(r"= \w+\[50000,[\d,]*\]\S* (\w[\w-]*)\(")
+    makers = {m.group(1) for line in compiled.as_text().splitlines()
+              if (m := whole.search(line))}
+    assert makers <= {"parameter"}, makers  # nothing makes an array that size

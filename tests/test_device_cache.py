@@ -107,15 +107,130 @@ def test_device_cache_contents_and_padding(devices8):
     ds = _dataset(n=13)  # not a multiple of 8 devices -> padded
     cache = DeviceCache(ds, make_mesh(devices8))
     assert cache.num_examples == 13
-    assert cache.images.shape[0] == 16 and cache.labels.shape[0] == 16
-    np.testing.assert_array_equal(np.asarray(cache.images)[:13], ds.images)
+    # stored as one row an image, the example axis first and padded
+    assert cache.images.shape == (16, 4 * 4 * 3) and cache.labels.shape[0] == 16
+    assert cache.images.example_shape == (4, 4, 3)
+    np.testing.assert_array_equal(
+        np.asarray(cache.images.take(np.arange(13))), ds.images)
     np.testing.assert_array_equal(np.asarray(cache.labels)[:13], ds.labels)
-    assert not np.any(np.asarray(cache.images)[13:])  # zero pad rows
+    assert not np.any(np.asarray(cache.images.rows)[13:])  # zero pad rows
     assert cache.nbytes == ds.images.nbytes + ds.labels.nbytes
     lazy = ArrayDataset(np.asarray(["a.jpg"] * 4, object),
                         np.zeros(4, np.int32), 10, lazy=True)
     with pytest.raises(ValueError, match="in-memory"):
         DeviceCache(lazy, make_mesh(devices8))
+
+
+#: name -> (examples' shape, dtype, index shape): what the trainers store
+#: and how they ask for it.  ``N`` = 203 divides by no mesh of 2, 4 or 8
+#: devices, so every mesh but a single device pads it.
+STORED_FORM_CASES = {
+    "cifar_uint8": ((203, 32, 32, 3), np.uint8, (16,)),
+    "row_4_does_not_divide_uint8": ((203, 5, 7, 3), np.uint8, (16,)),
+    "token_ids_int32": ((203, 33), np.int32, (8,)),
+    "stacked_index": ((203, 32, 32, 3), np.uint8, (3, 8)),
+    "padded_n": ((13, 4, 4, 3), np.uint8, (2, 4)),
+}
+
+
+def check_taken_batch_is_images_idx(case: str, devices=None) -> None:
+    """A batch taken inside a compiled program from the cache's stored
+    form is ``images[idx]`` bit for bit: same bytes in the same order,
+    same shape, same dtype.  On whatever backend ``jax.devices()``
+    gives (the chip runs it once by importing this module: it is the
+    only check of byte order there — `learned` and the logits
+    comparison would pass permuted bytes)."""
+    from fast_autoaugment_tpu.data.pipeline import DeviceCache
+    from fast_autoaugment_tpu.parallel.mesh import make_mesh
+
+    shape, dtype, idx_shape = STORED_FORM_CASES[case]
+    rng = np.random.default_rng(len(case))
+    high = 256 if dtype == np.uint8 else 20480
+    images = rng.integers(0, high, shape).astype(dtype)
+    # a batch in no order, the last example (next to the pad rows) and
+    # the first among it
+    idx = rng.integers(0, shape[0], idx_shape).astype(np.int32)
+    idx.flat[:2] = (shape[0] - 1, 0)
+    ds = ArrayDataset(images, rng.integers(0, 10, shape[:1]).astype(np.int32), 10)
+    cache = DeviceCache(ds, make_mesh(devices or jax.devices()))
+    # the rule: rank above 2 is flattened behind the example axis, rank
+    # 2 stays as it is; no dtype changes
+    assert cache.images.rows.ndim == 2 and cache.images.dtype == dtype
+    assert cache.images.shape[1] == int(np.prod(shape[1:]))
+    assert cache.images.example_shape == shape[1:]
+    taken = jax.jit(lambda stored, i: stored.take(i))(cache.images, idx)
+    assert taken.shape == idx_shape + shape[1:] and taken.dtype == dtype
+    np.testing.assert_array_equal(np.asarray(taken), images[idx])
+
+
+@pytest.mark.parametrize("case", sorted(STORED_FORM_CASES))
+def test_taken_batch_is_images_idx(case, devices8):
+    check_taken_batch_is_images_idx(case, devices8)
+
+
+def test_stored_rows_is_a_view_and_crosses_jit_as_its_rows():
+    from fast_autoaugment_tpu.data.pipeline import StoredRows
+
+    images = _dataset(n=6).images
+    stored = StoredRows.of(images)
+    assert np.shares_memory(stored.rows, images)  # a reshape, no copy
+    ids = np.arange(12, dtype=np.int32).reshape(4, 3)
+    assert StoredRows.of(ids).rows is ids and StoredRows.of(ids).example_shape == (3,)
+    leaves, treedef = jax.tree.flatten(stored)
+    assert len(leaves) == 1 and leaves[0] is stored.rows
+    again = jax.tree.unflatten(treedef, leaves)
+    assert again.example_shape == (4, 4, 3) and again.shape == (6, 48)
+
+
+def test_train_dispatch_lays_out_nothing_but_the_batch():
+    """The lowered ``train_dispatch`` program at CIFAR's shapes: the
+    cache argument is consumed by the gather alone, and no tensor of the
+    whole cache's element count has a rank above 2 — what the CPU can pin
+    of "only batch-sized arrays are laid out again" (on the v5e a rank-4
+    ``uint8 [50000, 32, 32, 3]`` argument drew a ``copy`` of all of it in
+    front of every step's gather: PERF.md section 6, PR 37)."""
+    import re
+
+    from fast_autoaugment_tpu.data.pipeline import StoredRows
+    from fast_autoaugment_tpu.models import get_model
+    from fast_autoaugment_tpu.ops.optim import build_optimizer
+    from fast_autoaugment_tpu.train.steps import (
+        create_train_state,
+        make_multistep_train_step,
+        make_train_step_body,
+    )
+
+    n, image, batch = 50000, (32, 32, 3), 8
+    model = get_model({"type": "wresnet10_1"}, 10)
+    opt = build_optimizer(dict(_conf()["optimizer"]), lambda s: 0.05)
+    body = make_train_step_body(model, opt, num_classes=10, cutout_length=16,
+                                use_policy=False)
+    state = jax.eval_shape(lambda: create_train_state(
+        model, opt, jax.random.PRNGKey(0),
+        jnp.zeros((2,) + image, jnp.float32), use_ema=False))
+    spec = jax.ShapeDtypeStruct
+    cache = jax.tree.map(lambda rows: spec((n,) + rows.shape[1:], rows.dtype),
+                         StoredRows.of(np.zeros((1,) + image, np.uint8)))
+    text = make_multistep_train_step(body, steps_per_dispatch=1).lower(
+        state, cache, spec((n,), jnp.int32), spec((1, batch), jnp.int32),
+        spec((1, 1, 3), jnp.float32), spec((2,), jnp.uint32)).as_text()
+
+    whole = n * int(np.prod(image))
+    seen = set()
+    for dims in re.findall(r"tensor<((?:\d+x)+)[a-z]+\d+>", text):
+        shape = tuple(int(d) for d in dims[:-1].split("x"))
+        if int(np.prod(shape)) == whole:
+            seen.add(shape)
+    assert seen == {(n, 3072)}, seen
+    cache_type = "tensor<50000x3072xui8>"
+    users = [line.strip() for line in text.splitlines()
+             if cache_type in line and "func.func" not in line]
+    assert users, "the cache argument is not in the program"
+    for line in users:
+        takes = re.search(r"= call @_take\w*\(%arg\d+,", line)
+        gathers = re.search(r'= "stablehlo.gather"\(%arg\d+,', line)
+        assert takes or gathers, line
+    assert sum("stablehlo.gather" in line for line in users) == 1
 
 
 def test_resolve_device_cache_gates():

@@ -77,6 +77,7 @@ def _tiny_train_dispatch():
     """``(multi_fn, args)``: the train_dispatch program on a tiny model
     with a 2-sub-policy tensor under the exact dispatch, its state
     committed to a one-device mesh as the trainer commits it."""
+    from fast_autoaugment_tpu.data.pipeline import StoredRows
     from fast_autoaugment_tpu.models import get_model
     from fast_autoaugment_tpu.ops.optim import build_optimizer
     from fast_autoaugment_tpu.parallel.mesh import make_mesh, replicated
@@ -101,7 +102,8 @@ def _tiny_train_dispatch():
     policy = np.array([[[7, 0.5, 0.5], [0, 0.5, 0.3]],
                        [[5, 0.9, 0.1], [14, 0.2, 0.8]]], np.float32)
     args = (jax.device_put(state, rep),
-            jax.device_put(rng.integers(0, 256, (16, 8, 8, 3), dtype=np.uint8), rep),
+            jax.device_put(StoredRows.of(
+                rng.integers(0, 256, (16, 8, 8, 3), dtype=np.uint8)), rep),
             jax.device_put(rng.integers(0, 10, (16,), np.int32), rep),
             jax.device_put(np.arange(4, dtype=np.int32)[None], rep),
             jax.device_put(policy, rep),
