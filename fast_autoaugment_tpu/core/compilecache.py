@@ -228,9 +228,12 @@ class _SeamWrapped:
             _called.setdefault(self._seam_label, weakref.WeakSet()).add(self)
             _newest[self._seam_label] = self
         h0, m0 = _snapshot()
-        t0 = time.perf_counter()
-        out = self._jitted(*args, **kwargs)
-        sec = time.perf_counter() - t0
+        # a stage of its own, nested in whatever stage is open: the
+        # parent's self-time is then its own work, not this compile or load
+        with telemetry.stage(f"first_call:{self._seam_label}"):
+            t0 = time.perf_counter()
+            out = self._jitted(*args, **kwargs)
+            sec = time.perf_counter() - t0
         self._first_done = True
         _record(self._seam_label, sec, _classify(h0, m0))
         return out

@@ -22,12 +22,20 @@ substrate, shared by train/search/serve/fleet:
    (``serve_cli`` and ``--telemetry-port`` on the train/search CLIs).
 
 2. **Span seam** (:func:`span` / :func:`record_dispatch`): ONE way to
-   time a device dispatch window.  The trainer's dispatch chunks, eval
-   replays, TTA/audit rounds and serve dispatches all route here — the
-   registry gets a ``faa_dispatch_seconds`` histogram observation, the
-   journal (when armed) gets a typed ``dispatch`` event, and the async
-   pipeline's ``DispatchTrace`` keeps receiving the same ``(t0, t1)``
-   windows it always did (its gap/busy math is unchanged).
+   time a device dispatch window.  The trainer's dispatch chunks and
+   eval replays run inside :func:`span` (which also enters a
+   ``jax.profiler.TraceAnnotation``, so they lie on a profiler trace's
+   host line); TTA/audit rounds, serve dispatches and the feed's wait
+   hand their ``(t0, t1)`` to :func:`record_dispatch` after the fact.
+   Either way the registry gets a ``faa_dispatch_seconds`` histogram
+   observation and the journal (when armed) a typed ``dispatch`` event.
+
+   **Stages** (:func:`stage` / :func:`stage_trees`): the same seam for
+   the host's own work — a nestable ``with`` context that knows its
+   parent (a per-thread stack) and records itself as
+   ``faa_phase_seconds_total{label=<dotted path>}``, a ``phase`` journal
+   event (``lane: "trainer"``), a ``TraceAnnotation`` and a bounded
+   in-memory tree.  Always on, like the registry.
 
 3. **Flight-recorder journal** (:class:`FlightRecorder`): an append-only
    JSONL stream of typed events (:data:`EVENT_TYPES` — ``dispatch``,
@@ -46,10 +54,11 @@ Defaults are bit-for-bit: the journal and every exporter sit behind
 ``--telemetry {off,DIR}`` / ``FAA_TELEMETRY`` (off = no file I/O, no
 new artifact keys, :func:`emit` is a None check), and the registry
 never touches numerics.  Overhead with telemetry fully ON is a fixed
-host cost per DISPATCH (tens of microseconds on a CPU host; not
-measured on the chip), bounded by design: rate-budgeted journal
-slices, interval-buffered flushing, a cached metric fast path
-(docs/OBSERVABILITY.md "Overhead").
+host cost per DISPATCH (tens of microseconds on a CPU host) and about
+a millisecond per epoch boundary (a dozen ``phase`` events); on the
+chip it read 0.01-0.08% of the benchmark's shortest step
+(docs/OBSERVABILITY.md "Overhead"), bounded by design: rate-budgeted
+journal slices, interval-buffered flushing, a cached metric fast path.
 
 Lint rule R8 (``tools/lint_robustness.py``) keeps raw
 ``time.time()``/``time.perf_counter()`` out of the train/search/serve
@@ -60,6 +69,8 @@ windows from :func:`span`, so every measurement stays recordable here.
 from __future__ import annotations
 
 import bisect
+import collections
+import contextlib
 import json
 import os
 import re
@@ -81,6 +92,8 @@ __all__ = [
     "wall",
     "mono",
     "span",
+    "stage",
+    "stage_trees",
     "record_dispatch",
     "emit",
     "resolve_telemetry",
@@ -838,39 +851,58 @@ def record_dispatch(label: str, t0_mono: float, t1_mono: float, *,
             meter.suppressed.inc()
 
 
+#: ``jax.profiler.TraceAnnotation``, looked up at the first span or
+#: stage (this module stays importable, and the journal's readers stay
+#: runnable, without JAX); a context that does nothing where JAX cannot
+#: be imported
+_TRACE_ANNOTATION = None
+
+
+def _annotation(name: str):
+    """An un-entered ``TraceAnnotation(name)``: inside a profiler session
+    it lies on the calling thread's host line, on the clock the device
+    lines use; outside one it is a flag check."""
+    global _TRACE_ANNOTATION
+    cls = _TRACE_ANNOTATION
+    if cls is None:
+        try:
+            from jax.profiler import TraceAnnotation as cls
+        except ImportError:
+            cls = contextlib.nullcontext
+        _TRACE_ANNOTATION = cls
+    return cls(name)
+
+
 class _Span:
     """Class-based context manager (a generator CM costs ~3x more per
     entry, and the span seam runs once per device dispatch)."""
 
-    __slots__ = ("label", "etype", "trace", "fields", "t0")
+    __slots__ = ("label", "etype", "fields", "t0", "_annotation")
 
-    def __init__(self, label, etype, trace, fields):
+    def __init__(self, label, etype, fields):
         self.label = label
         self.etype = etype
-        self.trace = trace
         self.fields = fields
 
     def __enter__(self):
+        self._annotation = _annotation(self.label)
+        self._annotation.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         t1 = time.perf_counter()
-        if self.trace is not None:
-            self.trace(self.t0, t1)
+        self._annotation.__exit__(exc_type, exc, tb)
         record_dispatch(self.label, self.t0, t1, etype=self.etype,
                         **self.fields)
         return False
 
 
-def span(label: str, *, etype: str = "dispatch", trace=None, **fields):
-    """Time one dispatch window through the seam (a ``with`` context).
-
-    `trace` (optional ``(t0, t1)`` callable) keeps feeding the async
-    pipeline's :class:`~fast_autoaugment_tpu.search.pipeline.
-    DispatchTrace` the exact windows it always consumed — the span seam
-    GENERALIZES that recorder instead of replacing it."""
-    return _Span(label, etype, trace, fields)
+def span(label: str, *, etype: str = "dispatch", **fields):
+    """Time one dispatch window through the seam (a ``with`` context):
+    :func:`record_dispatch`'s records, and a ``TraceAnnotation(label)``
+    around the window for a profiler session to pick up."""
+    return _Span(label, etype, fields)
 
 
 def phase_event(label: str, t0_mono: float, t1_mono: float,
@@ -884,6 +916,125 @@ def phase_event(label: str, t0_mono: float, t1_mono: float,
     if _recorder is not None:
         emit("phase", label, t_mono_start=float(t0_mono),
              t_mono_end=float(t1_mono), dur_sec=round(dur, 9), **fields)
+
+
+# --------------------------------------------------------------------------
+# stages: the host's own work as a tree
+# --------------------------------------------------------------------------
+
+#: the journal lane of every stage's ``phase`` event
+STAGE_LANE = "trainer"
+#: finished root stages :func:`stage_trees` keeps (the newest)
+STAGE_ROOTS_KEPT = 64
+#: children of one name kept under one parent (the newest): a root keeps
+#: every set-up stage, and its ``epoch`` children for this many epochs
+STAGE_SIBLINGS_KEPT = 256
+
+_stage_local = threading.local()
+_stage_roots: collections.deque = collections.deque(maxlen=STAGE_ROOTS_KEPT)
+
+
+def _stage_stack() -> list:
+    stack = getattr(_stage_local, "stack", None)
+    if stack is None:
+        stack = _stage_local.stack = []
+    return stack
+
+
+class _Stage:
+    """One open stage; :func:`stage` makes them."""
+
+    __slots__ = ("name", "fields", "path", "parent", "depth", "t_wall_start",
+                 "t_mono_start", "dur", "children", "_totals", "_annotation")
+
+    def __init__(self, name: str, fields: dict):
+        self.name = name
+        self.fields = fields
+        self.dur = None
+        self.children: list[dict] = []
+
+    def __enter__(self):
+        stack = _stage_stack()
+        self.parent = stack[-1] if stack else None
+        self.depth = len(stack)
+        if self.parent is None:
+            self.path = self.name
+            self._totals: dict[str, list] = {}   # path -> [count, seconds]
+        else:
+            self.path = f"{self.parent.path}.{self.name}"
+            self._totals = self.parent._totals
+        self._annotation = _annotation(self.path)
+        self._annotation.__enter__()
+        stack.append(self)
+        self.t_wall_start = wall()
+        self.t_mono_start = mono()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        t1 = mono()
+        self.dur = max(0.0, t1 - self.t_mono_start)
+        _stage_stack().pop()
+        self._annotation.__exit__(exc_type, exc, tb)
+        total = self._totals.setdefault(self.path, [0, 0.0])
+        total[0] += 1
+        total[1] += self.dur
+        record = {"name": self.name, "fields": self.fields,
+                  "t_wall_start": self.t_wall_start,
+                  "t_mono_start": self.t_mono_start, "dur": self.dur,
+                  "children": self.children}
+        if self.parent is None:
+            _stage_roots.append(record)
+        else:
+            _keep_child(self.parent.children, record)
+        phase_event(self.path, self.t_mono_start, t1, lane=STAGE_LANE,
+                    parent=None if self.parent is None else self.parent.path,
+                    depth=self.depth, **self.fields)
+        return False
+
+    def summary(self) -> dict:
+        """``{dotted path: {"n", "sec"}}`` over this stage's root so far:
+        every closed stage by its path, nothing dropped, and this stage
+        itself up to now while it is still open."""
+        out = {path: {"n": n, "sec": round(sec, 6)}
+               for path, (n, sec) in self._totals.items()}
+        if self.dur is None:
+            mine = out.setdefault(self.path, {"n": 0, "sec": 0.0})
+            mine["n"] += 1
+            mine["sec"] = round(mine["sec"] + mono() - self.t_mono_start, 6)
+        return out
+
+
+def _keep_child(children: list, record: dict) -> None:
+    children.append(record)
+    name = record["name"]
+    if sum(1 for c in children if c["name"] == name) > STAGE_SIBLINGS_KEPT:
+        del children[next(i for i, c in enumerate(children)
+                          if c["name"] == name)]
+
+
+def stage(name: str, **fields) -> _Stage:
+    """One named stretch of the host's work (a ``with`` context), nested
+    in whatever stage this thread has open.
+
+    On exit — on an exception too — it adds its seconds to
+    ``faa_phase_seconds_total{label=<path>}`` (`path` is the dotted chain
+    of names from the root, ``train_and_eval.epoch.epoch_boundary``),
+    emits one ``phase`` journal event when the journal is armed (``lane:
+    "trainer"``, ``parent``, ``depth``, `fields`), and joins its
+    parent's ``children`` in the tree :func:`stage_trees` returns.  While
+    open it holds a ``TraceAnnotation(<path>)``.  `fields` are small
+    values that name the instance (``epoch=3``); they may not shadow the
+    journal record's own keys."""
+    return _Stage(name, fields)
+
+
+def stage_trees() -> list[dict]:
+    """The finished root stages of this process, oldest first (the newest
+    :data:`STAGE_ROOTS_KEPT`), each ``{"name", "fields", "t_wall_start",
+    "t_mono_start", "dur", "children"}`` with `children` the same, in the
+    order they closed.  Under one parent at most
+    :data:`STAGE_SIBLINGS_KEPT` children of one name are kept."""
+    return list(_stage_roots)
 
 
 # --------------------------------------------------------------------------

@@ -170,15 +170,14 @@ def _run_replay_eval(replay_step, params, batch_stats, groups,
     deadlock was first observed exactly here, in eval)."""
     acc = Accumulator()
     for g in groups:
-        t0 = telemetry.mono()
-        if wd is not None and wd.enabled:
-            out = wd.run("replay_eval", replay_step, params, batch_stats,
-                         g["x"], g["y"], g["m"])
-        else:
-            with dispatch_enqueue_guard():
-                out = replay_step(params, batch_stats, g["x"], g["y"],
-                                  g["m"])
-        telemetry.record_dispatch("replay_eval", t0, telemetry.mono())
+        with telemetry.span("replay_eval"):
+            if wd is not None and wd.enabled:
+                out = wd.run("replay_eval", replay_step, params, batch_stats,
+                             g["x"], g["y"], g["m"])
+            else:
+                with dispatch_enqueue_guard():
+                    out = replay_step(params, batch_stats, g["x"], g["y"],
+                                      g["m"])
         acc.add_dict(out)
     return acc.normalize()
 
@@ -195,30 +194,25 @@ def _monitored_dispatch(wd, label: str, fi, step: int, fn, *args):
     on completion; that serializes the dispatch pipeline (wall only —
     values are unchanged), which is why ``--watchdog`` defaults off.
     A fired deadline raises the typed ``DispatchHungError`` (exit-77
-    recovery — core/watchdog.py).  Every path records the window
-    through :func:`~fast_autoaugment_tpu.core.telemetry.record_dispatch`
-    — the same span seam the TTA/audit and serve dispatches use."""
+    recovery — core/watchdog.py).  Every path times the window inside
+    :func:`~fast_autoaugment_tpu.core.telemetry.span` — the records of
+    the seam the TTA/audit and serve dispatches use, and an annotation
+    of the same name on a profiler trace's host line."""
     inject = fi.dispatch_delay(step) if fi is not None else None
     if inject is None and not wd.enabled:
         # enqueue-order serialization (async pipeline only; no-op
         # otherwise) — completion stays async, the historical path
-        t0 = telemetry.mono()
-        with dispatch_enqueue_guard():
-            out = fn(*args)
-        telemetry.record_dispatch(label, t0, telemetry.mono(), step=step,
-                                  blocking=False)
-        return out
+        with telemetry.span(label, step=step, blocking=False):
+            with dispatch_enqueue_guard():
+                return fn(*args)
     delay = 0.0
     if inject is not None:
         kind, val = inject
         # slow = straggler at F x the label's observed EMA (F seconds
         # before any observation); hang = forever
         delay = val if kind == "hang" else val * (wd.ema(label) or 1.0)
-    t0 = telemetry.mono()
-    out = wd.run(label, fn, *args, inject_delay=delay)
-    telemetry.record_dispatch(label, t0, telemetry.mono(), step=step,
-                              blocking=True)
-    return out
+    with telemetry.span(label, step=step, blocking=True):
+        return wd.run(label, fn, *args, inject_delay=delay)
 
 
 def _beat(heartbeat) -> None:
@@ -364,664 +358,696 @@ def train_and_eval(
     ``result['compile_cache']``.  The result also names the device that
     ran it (``platform``/``device_kind``/``device_count``) and the
     optimizer ``steps`` taken.
+
+    The call is the root of a stage tree (``core/telemetry.py::stage``;
+    docs/OBSERVABILITY.md "Stages" names the stages): where its seconds
+    went, by dotted path, rides under ``result['stages']``.
     """
-    cache_dir_active = configure_compile_cache()
-    if mesh is None:
-        mesh = make_mesh()
-    is_master = jax.process_index() == 0
+    with telemetry.stage("train_and_eval", only_eval=bool(only_eval)) as root:
+        cache_dir_active = configure_compile_cache()
+        if mesh is None:
+            mesh = make_mesh()
+        is_master = jax.process_index() == 0
 
-    dataset_name = conf["dataset"]
-    tokens = is_token_dataset(dataset_name)
-    model_conf = model_conf_of(conf)
-    total_train, testset = load_dataset(dataset_name, dataroot)
-    if tokens:
-        # ids in, next-token loss: the classes are the ids the model holds
-        if conf.get("aug", "default") not in (None, "default"):
-            raise ValueError(
-                f"dataset {dataset_name!r} is a token data set and conf aug="
-                f"{conf['aug']!r} names an augmentation policy: policies "
-                "are image operations; use aug: default")
-        num_classes = int(model_conf.get("ids_held")
-                          or model_conf.get("vocab_size") or 0)
-        if not 0 < max(total_train.num_classes, testset.num_classes) <= num_classes:
-            raise ValueError(
-                f"the data set holds ids up to "
-                f"{max(total_train.num_classes, testset.num_classes) - 1}, the "
-                f"model {num_classes} ids (conf ids_held, else model.vocab_size)")
-    else:
-        num_classes = num_class(dataset_name)
+        dataset_name = conf["dataset"]
+        tokens = is_token_dataset(dataset_name)
+        model_conf = model_conf_of(conf)
+        with telemetry.stage("load_dataset"):
+            total_train, testset = load_dataset(dataset_name, dataroot)
+        if tokens:
+            # ids in, next-token loss: the classes are the ids the model holds
+            if conf.get("aug", "default") not in (None, "default"):
+                raise ValueError(
+                    f"dataset {dataset_name!r} is a token data set and conf aug="
+                    f"{conf['aug']!r} names an augmentation policy: policies "
+                    "are image operations; use aug: default")
+            num_classes = int(model_conf.get("ids_held")
+                              or model_conf.get("vocab_size") or 0)
+            if not 0 < max(total_train.num_classes, testset.num_classes) <= num_classes:
+                raise ValueError(
+                    f"the data set holds ids up to "
+                    f"{max(total_train.num_classes, testset.num_classes) - 1}, the "
+                    f"model {num_classes} ids (conf ids_held, else model.vocab_size)")
+        else:
+            num_classes = num_class(dataset_name)
 
-    if test_ratio > 0.0:
-        train_idx, valid_idx = cv_split(total_train.labels, test_ratio, cv_fold)
-        if target_lb >= 0:
-            # single-class restriction (reference data.py:199-201)
-            train_idx = train_idx[total_train.labels[train_idx] == target_lb]
-            valid_idx = valid_idx[total_train.labels[valid_idx] == target_lb]
-    else:
-        train_idx, valid_idx = np.arange(len(total_train)), np.array([], np.int64)
-
-    is_imagenet = dataset_name.endswith("imagenet")
-    from fast_autoaugment_tpu.models import input_image_size
-
-    # conf['imgsize'] overrides the native resolution (the reference
-    # evaluates ResNet-200 at 320px, README.md:44-46)
-    image = None if tokens else int(conf.get("imgsize", 0) or 0) or input_image_size(
-        dataset_name, conf["model"]["type"]
-    )
-    if is_imagenet:
-        from fast_autoaugment_tpu.ops.preprocess_imagenet import (
-            center_crop_box,
-            imagenet_eval_batch,
-            imagenet_train_batch,
-            random_crop_box,
-        )
-
-        train_box = lambda rng, w, h: random_crop_box(rng, w, h, image)  # noqa: E731
-        eval_box = lambda rng, w, h: center_crop_box(w, h, image)  # noqa: E731
-    else:
-        train_box = eval_box = None
-    it_kw = dict(train_box_fn=train_box, eval_box_fn=eval_box, imgsize=image)
-    train_it = BatchIterator(total_train, train_idx, **it_kw)
-    valid_it = BatchIterator(total_train, valid_idx, **it_kw)
-    test_it = BatchIterator(testset, **it_kw)
-
-    use_cache = resolve_device_cache(device_cache, total_train,
-                                     process_count=jax.process_count())
-    steps_per_dispatch = int(steps_per_dispatch)
-    if steps_per_dispatch > 1 and not use_cache:
-        raise ValueError(
-            f"steps_per_dispatch={steps_per_dispatch} needs the device "
-            "cache (in-program batch gather); it is "
-            f"{'off' if device_cache == 'off' else 'unavailable (lazy dataset or multi-host)'} "
-            "here — use --device-cache auto/on with an eager dataset")
-
-    batch_per_device = int(conf["batch"])
-    global_batch = batch_per_device * mesh.size
-    logger.info("mesh %s over %d %s device(s); global batch %d",
-                dict(mesh.shape), mesh.size,
-                mesh.devices.flat[0].platform, global_batch)
-    if not only_eval and len(train_idx) < global_batch:
-        raise ValueError(
-            f"training set has {len(train_idx)} examples < global batch "
-            f"{global_batch} ({batch_per_device}/device x {mesh.size} devices); "
-            "every epoch would be empty (train batches drop the last partial "
-            "batch, reference data.py:215)"
-        )
-    steps_per_epoch = max(1, len(train_idx) // global_batch)
-    epochs = int(conf["epoch"])
-
-    model = get_model(model_conf, num_classes)
-    lr_fn = build_schedule(conf, steps_per_epoch, world_lr_scale=float(mesh.size))
-    optimizer_conf = conf["optimizer"]
-    ema_mu = float(optimizer_conf.get("ema", 0.0) or 0.0)
-
-    if tokens:
-        # parameter shapes do not depend on the length: a short sample
-        sample = jnp.zeros((1, min(total_train.images.shape[1] - 1, 64)), jnp.int32)
-    else:
-        sample = jnp.zeros((2, image, image, 3), jnp.float32)
-    rng = jax.random.PRNGKey(seed)
-
-    optimizer = build_optimizer(optimizer_conf, lr_fn)
-    # one program for a token model's init; the image models' seeded
-    # weights are pinned under the eager one (create_train_state)
-    state = create_train_state(model, optimizer, rng, sample,
-                               use_ema=ema_mu > 0.0, jit_init=tokens)
-    # which family ran at what size, for the journal and /metrics (sizes
-    # from shapes: nothing waits for the device)
-    num_params = sum(int(p.size) for p in jax.tree.leaves(state.params))
-    model_type = str(model_conf["type"])
-    telemetry.registry().gauge(
-        "faa_model_parameters", "trainable parameters of the model a "
-        "trainer built", model=model_type).set(num_params)
-    telemetry.emit("model", model_type, parameters=num_params,
-                   batch_per_device=batch_per_device,
-                   steps_per_epoch=steps_per_epoch)
-
-    policy = resolve_policy_tensor(conf.get("aug", "default"))
-    use_policy = policy is not None
-    if is_imagenet:
-        cutout_len = int(conf.get("cutout", 0) or 0)
-        augment_fn = lambda images, pol, key: imagenet_train_batch(  # noqa: E731
-            images, key, pol if use_policy else None, cutout_length=cutout_len,
-            aug_dispatch=aug_dispatch, aug_groups=aug_groups,
-        )
-        eval_preprocess = imagenet_eval_batch
-    else:
-        augment_fn = None
-        eval_preprocess = None
-    step_kw = dict(
-        num_classes=num_classes,
-        mixup_alpha=float(conf.get("mixup", 0.0) or 0.0),
-        lb_smooth=float(conf.get("lb_smooth", 0.0) or 0.0),
-        ema_mu=ema_mu,
-        cutout_length=int(conf.get("cutout", 0) or 0),
-        use_policy=use_policy,
-        augment_fn=augment_fn,
-        aug_dispatch=aug_dispatch,
-        aug_groups=aug_groups,
-    )
-    token_counters = _CountPublisher(model) if tokens else None
-    if use_cache:
-        # device-resident path: the body is dispatched through the
-        # multi-step gather program; at most two chunk shapes per epoch
-        # (N and the clamped remainder), each compiled once and reused
-        step_body = (make_token_step_body(model, optimizer, ema_mu=ema_mu)
-                     if tokens else
-                     make_train_step_body(model, optimizer, **step_kw))
-        multi_fns: dict[int, Callable] = {}
-
-        def get_multi_step(n: int) -> Callable:
-            if n not in multi_fns:
-                multi_fns[n] = make_multistep_train_step(
-                    step_body, steps_per_dispatch=n)
-            return multi_fns[n]
-    else:
-        train_step = (make_token_train_step(model, optimizer, ema_mu=ema_mu)
-                      if tokens else
-                      make_train_step(model, optimizer, **step_kw))
-    eval_step = make_eval_step(model, num_classes=num_classes,
-                               lb_smooth=float(conf.get("lb_smooth", 0.0) or 0.0),
-                               preprocess_fn=eval_preprocess, tokens=tokens)
-    replay_eval = make_replay_eval_step(
-        model, num_classes=num_classes,
-        lb_smooth=float(conf.get("lb_smooth", 0.0) or 0.0),
-        preprocess_fn=eval_preprocess, tokens=tokens) if use_cache else None
-
-    writers = make_writers(
-        os.path.dirname(save_path) if save_path else None,
-        os.path.basename(save_path or "run"),
-        is_master,
-    )
-
-    ckpt_keep = max(1, int(ckpt_keep))
-    divergence_retries = max(0, int(divergence_retries))
-    checkpoint_every_dispatch = max(0, int(checkpoint_every_dispatch))
-    wd = resolve_watchdog(watchdog)
-    # flag-setting SIGTERM/SIGUSR1 handlers (idempotent, main thread
-    # only): the epoch/dispatch loops below poll the flag at safe
-    # boundaries — see core/resilience.py and docs/RESILIENCE.md
-    install_signal_handlers()
-
-    epoch_start = 1
-    resume_pos = 0          # mid-epoch fast-forward (preempted snapshot)
-    resume_sums: dict | None = None
-    retries_done = 0        # divergence-retry counter (folds the PRNG)
-    restored = None
-    if save_path:
-        # lenient when the file came from the torch importer (no opt_state)
-        lenient = bool((read_metadata(save_path) or {}).get("imported_from"))
-        # restore from the NEWEST intact chain link; a mid-epoch
-        # (preempted) snapshot fast-forwards its epoch to the dispatch
-        # position it names, on either feed
-        restored = load_checkpoint_chain(
-            save_path, state, lenient=lenient, keep=ckpt_keep)
-        if restored is not None and "in_epoch" in restored[1]:
-            rec = restored[1]["in_epoch"] or {}
-            if int(rec.get("epoch", -1)) != int(restored[1].get("epoch", 0)) + 1:
-                logger.warning(
-                    "inconsistent mid-epoch record in %s — falling back "
-                    "to an epoch-boundary chain link", restored[2])
-                restored = load_checkpoint_chain(
-                    save_path, state, lenient=lenient, keep=ckpt_keep,
-                    accept=lambda m: "in_epoch" not in m)
-    if restored is not None:
-        state, meta, used_path = restored
-        lenient = bool(meta.get("imported_from"))
-        epoch_start = int(meta.get("epoch", 0)) + 1
-        in_epoch = meta.get("in_epoch")
-        if in_epoch:
-            resume_pos = int(in_epoch["pos"])
-            resume_sums = {k: np.float32(v)
-                           for k, v in (in_epoch.get("sums") or {}).items()}
-            retries_done = int(in_epoch.get("retries", 0))
-            logger.info(
-                "resuming MID-EPOCH: epoch %d from dispatch position %d "
-                "(preempted snapshot %s)", epoch_start, resume_pos,
-                used_path)
-        if lenient:
-            fixes = {}
-            # the schedule is a pure fn of step: place it at the resume
-            # epoch, not back at warmup
-            fixes["step"] = jnp.int32((epoch_start - 1) * steps_per_epoch)
-            if state.ema is not None and not meta.get("has_ema"):
-                # no EMA in the imported file: seed the shadow from the
-                # imported weights, never from random init
-                fixes["ema"] = jax.tree.map(
-                    jnp.copy,
-                    {"params": state.params, "batch_stats": state.batch_stats},
-                )
-            state = state.replace(**fixes)
-        # resume-cost provenance: whether this resumed process will
-        # deserialize its executables (warm cache) or re-pay the full
-        # compile tax — the final compile_cache stamp carries the proof
-        logger.info("resumed %s at epoch %d (compile cache: %s)",
-                    used_path, epoch_start - 1,
-                    cache_dir_active or "off — full recompile ahead")
-        if epoch_start > epochs:
-            only_eval = True
-    elif only_eval and save_path:
-        raise FileNotFoundError(f"--only-eval requires a checkpoint at {save_path}")
-
-    # commit the carried state to the mesh BEFORE the first dispatch or
-    # eval, on either feed.  Cached: an uncommitted state compiled
-    # against the mesh-committed cache knocks every later call off the
-    # C++ fast dispatch path (make_multistep_train_step note), and an
-    # --only-eval restore must lower the SAME replay_eval program the
-    # training run cached, not an uncommitted variant of it.  Host-fed:
-    # the batches arrive committed, so the first step returns a
-    # committed state and the SECOND call no longer matches what the
-    # first compiled — the step program was lowered and compiled (or
-    # loaded) twice a process (ResNet-50 on one v5e: 49 s of a cold
-    # run's first epoch, 7 s of a warm one's; my chip runs, PR 32)
-    state = jax.device_put(state, replicated(mesh))
-
-    result: dict = {"epoch": epoch_start - 1}
-    best_metric = -1e9
-    # device-cache eval replay: each split is placed once on first
-    # evaluation and reused for every later one (and for the EMA pass,
-    # which previously re-fed the split within the SAME evaluation)
-    eval_replay: dict[str, list] = {}
-
-    def evaluate(tag_prefix: str, epoch: int) -> dict:
-        # empty splits are SKIPPED, not reported as zeros: with
-        # test_ratio=0 (every phase-3 search retrain) a zero-row per
-        # interval is pure noise, and `metric="valid"` would silently
-        # track a best of 0.0 (the reference only ever evaluates real
-        # splits, train.py:272-280)
-        out = {}
-        splits = [("valid", valid_it), ("test", test_it)]
-        for split, it in splits:
-            if len(it) == 0:
-                continue
-            eval_kw = dict(
-                process_index=jax.process_index(),
-                process_count=jax.process_count(),
-                pad_multiple=mesh.size,
-            )
-            if use_cache:
-                if split not in eval_replay:
-                    eval_replay[split] = _stacked_eval_splits(
-                        it, global_batch, mesh, eval_kw)
-                norm = _run_replay_eval(
-                    replay_eval, state.params, state.batch_stats,
-                    eval_replay[split], wd=wd)
+        with telemetry.stage("split"):
+            if test_ratio > 0.0:
+                train_idx, valid_idx = cv_split(total_train.labels, test_ratio, cv_fold)
+                if target_lb >= 0:
+                    # single-class restriction (reference data.py:199-201)
+                    train_idx = train_idx[total_train.labels[train_idx] == target_lb]
+                    valid_idx = valid_idx[total_train.labels[valid_idx] == target_lb]
             else:
-                norm = _run_eval(
-                    eval_step, state.params, state.batch_stats,
-                    it.eval_epoch(global_batch, **eval_kw), mesh,
-                )
-            out[split] = norm
-            if state.ema is not None:
-                if use_cache:
-                    norm_ema = _run_replay_eval(
-                        replay_eval, state.ema["params"],
-                        state.ema["batch_stats"], eval_replay[split], wd=wd)
-                else:
-                    norm_ema = _run_eval(
-                        eval_step, state.ema["params"],
-                        state.ema["batch_stats"],
-                        it.eval_epoch(global_batch, **eval_kw), mesh,
-                    )
-                # with EMA on, the REPORTED valid/test numbers are the
-                # EMA model's (reference train.py:277-280 overwrites
-                # rs['valid']/rs['test']); raw weights kept under _raw
-                out[split + "_raw"] = norm
-                out[split + "_ema"] = norm_ema
-                out[split] = norm_ema
-        return out
+                train_idx, valid_idx = np.arange(len(total_train)), np.array([], np.int64)
 
-    if only_eval:
-        evals = evaluate("only_eval", epoch_start)
-        for split, m in evals.items():
-            for k, v in m.items():
-                result[f"{k}_{split}"] = v
-        result["epoch"] = epoch_start - 1
-        result.update(steps=int(state.step), **device_stamp())
-        result["compile_cache"] = compile_cache_stats()
-        return result
+            is_imagenet = dataset_name.endswith("imagenet")
+            from fast_autoaugment_tpu.models import input_image_size
 
-    # best-metric guards live AFTER the only_eval return (eval-only runs
-    # never consult `metric`, including resumes that auto-flip only_eval)
-    if metric not in ("last", "train", "valid", "test"):
-        raise ValueError(f"unknown metric {metric!r}: use last/train/valid/test")
-    if metric == "valid" and len(valid_it) == 0:
-        raise ValueError(
-            "metric='valid' with an empty validation split (test_ratio=0): "
-            "the best-checkpoint tracker would silently follow a constant "
-            "0.0 — pass metric='last'/'train'/'test' or a test_ratio > 0"
-        )
-    if metric == "test" and len(test_it) == 0:
-        raise ValueError("metric='test' with an empty test split")
-
-    train_cache = DeviceCache(total_train, mesh) if use_cache else None
-    if train_cache is not None:
-        logger.info(
-            "device cache: %d examples (%.1f MiB) resident as %s %s, "
-            "steps_per_dispatch=%d", train_cache.num_examples,
-            train_cache.nbytes / 2**20, train_cache.images.dtype,
-            list(train_cache.images.shape), steps_per_dispatch)
-        # replicated inputs join the committed state on the mesh
-        rng = jax.device_put(rng, replicated(mesh))
-
-    t_start = wall()
-    pol = policy if policy is not None else jnp.zeros((1, 1, 3), jnp.float32)
-    if train_cache is not None:
-        pol = jax.device_put(pol, replicated(mesh))
-    # while (not for): divergence recovery rolls `epoch` BACK to the
-    # last good checkpoint's successor and replays with fresh randomness
-    epoch = epoch_start
-    while epoch <= epochs:
-        fi = faultinject.active_plan()
-        # divergence-retry randomness: after any rollback every epoch
-        # draws retry-folded augmentation keys and shuffle seeds;
-        # retries_done == 0 is bit-for-bit the historical stream
-        if retries_done:
-            rng_epoch = jax.random.fold_in(rng, 1_000_003 * retries_done)
-            seed_epoch = seed + 1_000_003 * retries_done
-            if train_cache is not None:
-                rng_epoch = jax.device_put(rng_epoch, replicated(mesh))
-        else:
-            rng_epoch, seed_epoch = rng, seed
-        acc = Accumulator()
-        # live per-batch progress (the reference's tqdm postfix,
-        # train.py:79-88): FAA_PROGRESS=N prints a loss-EMA line every N
-        # batches (dispatches on the cache path).  Off by default —
-        # reading metrics per batch forces a device sync and stalls the
-        # dispatch pipeline, which is why the epoch loop otherwise never
-        # touches metric values mid-epoch.
-        try:
-            progress_every = int(os.environ.get("FAA_PROGRESS", "0") or 0)
-        except ValueError:  # cosmetic knob must never kill a run — but
-            # the misconfiguration must be VISIBLE, not silently eaten
-            logger.warning(
-                "FAA_PROGRESS=%r is not an integer — live progress "
-                "line disabled", os.environ.get("FAA_PROGRESS"))
-            progress_every = 0
-        loss_ema = None
-
-        def progress(bi: int, metrics, epoch=epoch):
-            nonlocal loss_ema
-            if is_master and progress_every and (bi + 1) % progress_every == 0:
-                cur = float(metrics["loss"]) / max(float(metrics["num"]), 1.0)
-                loss_ema = cur if loss_ema is None else 0.9 * loss_ema + 0.1 * cur
-                sys.stderr.write(
-                    f"\r[epoch {epoch} batch {bi + 1}] loss_ema={loss_ema:.4f} ")
-                sys.stderr.flush()
-
-        def snapshot_in_epoch(pos: int, sums: dict, epoch=epoch):
-            """Mid-epoch checkpoint at a dispatch boundary: the exact
-            position and the epoch's metric sums so far (either feed)."""
-            save_checkpoint(
-                save_path, state,
-                {"epoch": epoch - 1,
-                 "step": (epoch - 1) * steps_per_epoch + pos,
-                 "preempted": preemption_requested(),
-                 "in_epoch": {
-                     "epoch": epoch, "pos": pos,
-                     "sums": {k: float(v) for k, v in sums.items()},
-                     "retries": retries_done}},
-                keep=ckpt_keep)
-
-        def preempted_in_epoch(pos: int, total: int, epoch=epoch):
-            logger.warning(
-                "preempted at epoch %d dispatch boundary (position %d/%d) "
-                "— checkpointed, exit %d means 'resume me'", epoch, pos,
-                total, PREEMPTED_EXIT_CODE)
-            return PreemptedError(
-                f"preempted mid-epoch {epoch} at dispatch position {pos}")
-
-        if train_cache is not None:
-            # device-resident feed: the per-epoch shuffle is the
-            # IDENTICAL host permutation; only the index matrix is
-            # shipped, and each dispatch advances a whole scan chunk
-            mat = train_index_matrix(
-                train_idx, global_batch, epoch, seed=seed_epoch,
-                process_index=jax.process_index(),
-                process_count=jax.process_count(),
+            # conf['imgsize'] overrides the native resolution (the reference
+            # evaluates ResNet-200 at 320px, README.md:44-46)
+            image = None if tokens else int(conf.get("imgsize", 0) or 0) or input_image_size(
+                dataset_name, conf["model"]["type"]
             )
-            pos = 0
-            dispatch_metrics: list = []
-            if token_counters is not None:
-                token_counters.new_epoch()
-            if resume_pos and epoch == epoch_start:
-                # preempted mid-epoch: skip the dispatches already done
-                # and seed the metric chain with the saved partial sums
-                # — the host additions below continue the SAME
-                # sequential f32 chain, so the epoch's reported metrics
-                # are bit-identical to the uninterrupted run
-                pos = resume_pos
-                if resume_sums:
-                    dispatch_metrics.append(dict(resume_sums))
-                    if token_counters is not None:
-                        token_counters.new_epoch(
-                            _split_counts(dict(resume_sums)))
-            for di, n in enumerate(split_dispatch_chunks(
-                    len(mat) - pos, steps_per_dispatch)):
-                idx_dev = place_index_matrix(mesh, mat[pos:pos + n])
-                state, metrics = _monitored_dispatch(
-                    wd, "train_dispatch", fi,
-                    (epoch - 1) * steps_per_epoch + pos + n,
-                    get_multi_step(n),
-                    state, train_cache.images, train_cache.labels,
-                    idx_dev, pol, rng_epoch)
-                # per-dispatch sums are kept as ASYNC device handles and
-                # summed on host at epoch end (_sum_metric_dicts): with
-                # the committed state a per-dispatch jnp add would queue
-                # one tiny all-participant collective per metric, and
-                # long unsynced chains of those wedge the CPU backend
-                dispatch_metrics.append(metrics)
-                progress(di, metrics)
-                pos += n
-                _beat(heartbeat)
-                if fi is not None:
-                    fi.maybe_signal((epoch - 1) * steps_per_epoch + pos)
-                # resilience boundary: the PR-4 dispatch boundaries are
-                # exact resume points — honor a preemption request (or
-                # the periodic snapshot knob) here, mid-epoch
-                periodic = (checkpoint_every_dispatch > 0
-                            and (di + 1) % checkpoint_every_dispatch == 0)
-                if pos < len(mat) and (preemption_requested() or periodic):
-                    if save_path and is_master:
-                        sums = _sum_metric_dicts(dispatch_metrics)
-                        snapshot_in_epoch(pos, sums)
-                        if token_counters is not None:
-                            token_counters.publish(_split_counts(dict(sums)))
-                        # saved sums replace the pending handles — the
-                        # continued f32 chain is identical either way
-                        dispatch_metrics = [
-                            {k: np.float32(v) for k, v in sums.items()}]
-                    if preemption_requested():
-                        raise preempted_in_epoch(pos, len(mat))
-            sums = _sum_metric_dicts(dispatch_metrics)
-            counts = _split_counts(sums)
-            if token_counters is not None:
-                token_counters.publish(counts)
-            acc.add_dict(sums)
-        else:
-            # host feed: the same resume points as the device-resident
-            # feed, one batch a dispatch.  A resumed epoch skips the
-            # batches already trained without decoding them (their crop
-            # boxes are still drawn, so the rest of the epoch is the
-            # unbroken run's) and continues the saved metric sums.
-            pos = 0
-            if resume_pos and epoch == epoch_start:
-                pos = resume_pos
-                if resume_sums:
-                    acc.add_dict(resume_sums)
-            batches = prefetch(
-                train_it.train_epoch(
-                    global_batch, epoch, seed=seed_epoch,
+            if is_imagenet:
+                from fast_autoaugment_tpu.ops.preprocess_imagenet import (
+                    center_crop_box,
+                    imagenet_eval_batch,
+                    imagenet_train_batch,
+                    random_crop_box,
+                )
+
+                train_box = lambda rng, w, h: random_crop_box(rng, w, h, image)  # noqa: E731
+                eval_box = lambda rng, w, h: center_crop_box(w, h, image)  # noqa: E731
+            else:
+                train_box = eval_box = None
+            it_kw = dict(train_box_fn=train_box, eval_box_fn=eval_box, imgsize=image)
+            train_it = BatchIterator(total_train, train_idx, **it_kw)
+            valid_it = BatchIterator(total_train, valid_idx, **it_kw)
+            test_it = BatchIterator(testset, **it_kw)
+
+        use_cache = resolve_device_cache(device_cache, total_train,
+                                         process_count=jax.process_count())
+        steps_per_dispatch = int(steps_per_dispatch)
+        if steps_per_dispatch > 1 and not use_cache:
+            raise ValueError(
+                f"steps_per_dispatch={steps_per_dispatch} needs the device "
+                "cache (in-program batch gather); it is "
+                f"{'off' if device_cache == 'off' else 'unavailable (lazy dataset or multi-host)'} "
+                "here — use --device-cache auto/on with an eager dataset")
+
+        batch_per_device = int(conf["batch"])
+        global_batch = batch_per_device * mesh.size
+        logger.info("mesh %s over %d %s device(s); global batch %d",
+                    dict(mesh.shape), mesh.size,
+                    mesh.devices.flat[0].platform, global_batch)
+        if not only_eval and len(train_idx) < global_batch:
+            raise ValueError(
+                f"training set has {len(train_idx)} examples < global batch "
+                f"{global_batch} ({batch_per_device}/device x {mesh.size} devices); "
+                "every epoch would be empty (train batches drop the last partial "
+                "batch, reference data.py:215)"
+            )
+        steps_per_epoch = max(1, len(train_idx) // global_batch)
+        epochs = int(conf["epoch"])
+
+        with telemetry.stage("build"):
+            model = get_model(model_conf, num_classes)
+            lr_fn = build_schedule(conf, steps_per_epoch, world_lr_scale=float(mesh.size))
+            optimizer_conf = conf["optimizer"]
+            ema_mu = float(optimizer_conf.get("ema", 0.0) or 0.0)
+
+            if tokens:
+                # parameter shapes do not depend on the length: a short sample
+                sample = jnp.zeros((1, min(total_train.images.shape[1] - 1, 64)), jnp.int32)
+            else:
+                sample = jnp.zeros((2, image, image, 3), jnp.float32)
+            rng = jax.random.PRNGKey(seed)
+
+            optimizer = build_optimizer(optimizer_conf, lr_fn)
+        with telemetry.stage("state_init"):
+            # one program for a token model's init; the image models' seeded
+            # weights are pinned under the eager one (create_train_state)
+            state = create_train_state(model, optimizer, rng, sample,
+                                       use_ema=ema_mu > 0.0, jit_init=tokens)
+        # which family ran at what size, for the journal and /metrics (sizes
+        # from shapes: nothing waits for the device)
+        num_params = sum(int(p.size) for p in jax.tree.leaves(state.params))
+        model_type = str(model_conf["type"])
+        telemetry.registry().gauge(
+            "faa_model_parameters", "trainable parameters of the model a "
+            "trainer built", model=model_type).set(num_params)
+        telemetry.emit("model", model_type, parameters=num_params,
+                       batch_per_device=batch_per_device,
+                       steps_per_epoch=steps_per_epoch)
+
+        with telemetry.stage("build"):
+            policy = resolve_policy_tensor(conf.get("aug", "default"))
+            use_policy = policy is not None
+            if is_imagenet:
+                cutout_len = int(conf.get("cutout", 0) or 0)
+                augment_fn = lambda images, pol, key: imagenet_train_batch(  # noqa: E731
+                    images, key, pol if use_policy else None, cutout_length=cutout_len,
+                    aug_dispatch=aug_dispatch, aug_groups=aug_groups,
+                )
+                eval_preprocess = imagenet_eval_batch
+            else:
+                augment_fn = None
+                eval_preprocess = None
+            step_kw = dict(
+                num_classes=num_classes,
+                mixup_alpha=float(conf.get("mixup", 0.0) or 0.0),
+                lb_smooth=float(conf.get("lb_smooth", 0.0) or 0.0),
+                ema_mu=ema_mu,
+                cutout_length=int(conf.get("cutout", 0) or 0),
+                use_policy=use_policy,
+                augment_fn=augment_fn,
+                aug_dispatch=aug_dispatch,
+                aug_groups=aug_groups,
+            )
+            token_counters = _CountPublisher(model) if tokens else None
+            if use_cache:
+                # device-resident path: the body is dispatched through the
+                # multi-step gather program; at most two chunk shapes per epoch
+                # (N and the clamped remainder), each compiled once and reused
+                step_body = (make_token_step_body(model, optimizer, ema_mu=ema_mu)
+                             if tokens else
+                             make_train_step_body(model, optimizer, **step_kw))
+                multi_fns: dict[int, Callable] = {}
+
+                def get_multi_step(n: int) -> Callable:
+                    if n not in multi_fns:
+                        multi_fns[n] = make_multistep_train_step(
+                            step_body, steps_per_dispatch=n)
+                    return multi_fns[n]
+            else:
+                train_step = (make_token_train_step(model, optimizer, ema_mu=ema_mu)
+                              if tokens else
+                              make_train_step(model, optimizer, **step_kw))
+            eval_step = make_eval_step(model, num_classes=num_classes,
+                                       lb_smooth=float(conf.get("lb_smooth", 0.0) or 0.0),
+                                       preprocess_fn=eval_preprocess, tokens=tokens)
+            replay_eval = make_replay_eval_step(
+                model, num_classes=num_classes,
+                lb_smooth=float(conf.get("lb_smooth", 0.0) or 0.0),
+                preprocess_fn=eval_preprocess, tokens=tokens) if use_cache else None
+
+            writers = make_writers(
+                os.path.dirname(save_path) if save_path else None,
+                os.path.basename(save_path or "run"),
+                is_master,
+            )
+
+            ckpt_keep = max(1, int(ckpt_keep))
+            divergence_retries = max(0, int(divergence_retries))
+            checkpoint_every_dispatch = max(0, int(checkpoint_every_dispatch))
+            wd = resolve_watchdog(watchdog)
+            # flag-setting SIGTERM/SIGUSR1 handlers (idempotent, main thread
+            # only): the epoch/dispatch loops below poll the flag at safe
+            # boundaries — see core/resilience.py and docs/RESILIENCE.md
+            install_signal_handlers()
+
+        epoch_start = 1
+        resume_pos = 0          # mid-epoch fast-forward (preempted snapshot)
+        resume_sums: dict | None = None
+        retries_done = 0        # divergence-retry counter (folds the PRNG)
+        restored = None
+        with telemetry.stage("restore"):
+            if save_path:
+                # lenient when the file came from the torch importer (no opt_state)
+                lenient = bool((read_metadata(save_path) or {}).get("imported_from"))
+                # restore from the NEWEST intact chain link; a mid-epoch
+                # (preempted) snapshot fast-forwards its epoch to the dispatch
+                # position it names, on either feed
+                restored = load_checkpoint_chain(
+                    save_path, state, lenient=lenient, keep=ckpt_keep)
+                if restored is not None and "in_epoch" in restored[1]:
+                    rec = restored[1]["in_epoch"] or {}
+                    if int(rec.get("epoch", -1)) != int(restored[1].get("epoch", 0)) + 1:
+                        logger.warning(
+                            "inconsistent mid-epoch record in %s — falling back "
+                            "to an epoch-boundary chain link", restored[2])
+                        restored = load_checkpoint_chain(
+                            save_path, state, lenient=lenient, keep=ckpt_keep,
+                            accept=lambda m: "in_epoch" not in m)
+            if restored is not None:
+                state, meta, used_path = restored
+                lenient = bool(meta.get("imported_from"))
+                epoch_start = int(meta.get("epoch", 0)) + 1
+                in_epoch = meta.get("in_epoch")
+                if in_epoch:
+                    resume_pos = int(in_epoch["pos"])
+                    resume_sums = {k: np.float32(v)
+                                   for k, v in (in_epoch.get("sums") or {}).items()}
+                    retries_done = int(in_epoch.get("retries", 0))
+                    logger.info(
+                        "resuming MID-EPOCH: epoch %d from dispatch position %d "
+                        "(preempted snapshot %s)", epoch_start, resume_pos,
+                        used_path)
+                if lenient:
+                    fixes = {}
+                    # the schedule is a pure fn of step: place it at the resume
+                    # epoch, not back at warmup
+                    fixes["step"] = jnp.int32((epoch_start - 1) * steps_per_epoch)
+                    if state.ema is not None and not meta.get("has_ema"):
+                        # no EMA in the imported file: seed the shadow from the
+                        # imported weights, never from random init
+                        fixes["ema"] = jax.tree.map(
+                            jnp.copy,
+                            {"params": state.params, "batch_stats": state.batch_stats},
+                        )
+                    state = state.replace(**fixes)
+                # resume-cost provenance: whether this resumed process will
+                # deserialize its executables (warm cache) or re-pay the full
+                # compile tax — the final compile_cache stamp carries the proof
+                logger.info("resumed %s at epoch %d (compile cache: %s)",
+                            used_path, epoch_start - 1,
+                            cache_dir_active or "off — full recompile ahead")
+                if epoch_start > epochs:
+                    only_eval = True
+            elif only_eval and save_path:
+                raise FileNotFoundError(f"--only-eval requires a checkpoint at {save_path}")
+
+        # commit the carried state to the mesh BEFORE the first dispatch or
+        # eval, on either feed.  Cached: an uncommitted state compiled
+        # against the mesh-committed cache knocks every later call off the
+        # C++ fast dispatch path (make_multistep_train_step note), and an
+        # --only-eval restore must lower the SAME replay_eval program the
+        # training run cached, not an uncommitted variant of it.  Host-fed:
+        # the batches arrive committed, so the first step returns a
+        # committed state and the SECOND call no longer matches what the
+        # first compiled — the step program was lowered and compiled (or
+        # loaded) twice a process (ResNet-50 on one v5e: 49 s of a cold
+        # run's first epoch, 7 s of a warm one's; my chip runs, PR 32)
+        with telemetry.stage("place_state"):
+            state = jax.device_put(state, replicated(mesh))
+
+        result: dict = {"epoch": epoch_start - 1}
+        best_metric = -1e9
+        # device-cache eval replay: each split is placed once on first
+        # evaluation and reused for every later one (and for the EMA pass,
+        # which previously re-fed the split within the SAME evaluation)
+        eval_replay: dict[str, list] = {}
+
+        def evaluate(tag_prefix: str, epoch: int) -> dict:
+            # empty splits are SKIPPED, not reported as zeros: with
+            # test_ratio=0 (every phase-3 search retrain) a zero-row per
+            # interval is pure noise, and `metric="valid"` would silently
+            # track a best of 0.0 (the reference only ever evaluates real
+            # splits, train.py:272-280)
+            out = {}
+            splits = [("valid", valid_it), ("test", test_it)]
+            for split, it in splits:
+                if len(it) == 0:
+                    continue
+                eval_kw = dict(
                     process_index=jax.process_index(),
                     process_count=jax.process_count(),
-                    skip=pos,
-                ),
-                transform=shard_transform(mesh),
-            )
-            for bi, batch in enumerate(batches, start=pos):
-                state, metrics = _monitored_dispatch(
-                    wd, "train_step", fi,
-                    (epoch - 1) * steps_per_epoch + bi + 1,
-                    train_step, state, batch["x"], batch["y"],
-                    pol, rng_epoch)
-                acc.add_dict(metrics)
-                progress(bi, metrics)
-                pos = bi + 1
-                _beat(heartbeat)
-                if fi is not None:
-                    fi.maybe_signal((epoch - 1) * steps_per_epoch + pos)
-                periodic = (checkpoint_every_dispatch > 0
-                            and pos % checkpoint_every_dispatch == 0)
-                if pos < steps_per_epoch and (preemption_requested()
-                                              or periodic):
-                    if save_path and is_master:
-                        snapshot_in_epoch(pos, dict(acc.items()))
-                    if preemption_requested():
-                        raise preempted_in_epoch(pos, steps_per_epoch)
-        _beat(heartbeat)
-        resume_pos, resume_sums = 0, None  # consumed by the first epoch
-        if is_master and progress_every and loss_ema is not None:
-            sys.stderr.write("\n")
-        if token_counters is not None and train_cache is None:
-            # host feed: the sums sat on the device until here
-            token_counters.new_epoch()
-            token_counters.publish(_split_counts(acc.metrics))
-        train_metrics = acc.normalize()
-        if not train_metrics:
-            raise RuntimeError(
-                f"epoch {epoch} produced zero train batches "
-                f"({len(train_idx)} examples, global batch {global_batch}) — "
-                "feed pipeline bug or dataset/batch mismatch"
-            )
-        if fi is not None and fi.nan_loss_in((epoch - 1) * steps_per_epoch,
-                                             epoch * steps_per_epoch):
-            train_metrics["loss"] = float("nan")  # injected at the seam
-        if not np.isfinite(train_metrics["loss"]):
-            # divergence recovery (--divergence-retries R, default 0 =
-            # the historical raise): roll back to the newest intact
-            # EPOCH-BOUNDARY chain link and replay with retry-folded
-            # randomness; re-raise only after R failed rollbacks
-            if retries_done < divergence_retries and save_path:
-                rolled = load_checkpoint_chain(
-                    save_path, state, keep=ckpt_keep,
-                    accept=lambda m: "in_epoch" not in m)
-                if rolled is not None:
-                    retries_done += 1
-                    state, meta_rb, used_rb = rolled
-                    if train_cache is not None:
-                        state = jax.device_put(state, replicated(mesh))
-                    rollback_epoch = int(meta_rb.get("epoch", 0)) + 1
-                    logger.warning(
-                        "divergence: non-finite loss at epoch %d — rolled "
-                        "back to %s (replaying from epoch %d), retry %d/%d "
-                        "with retry-folded PRNG/shuffle streams",
-                        epoch, used_rb, rollback_epoch, retries_done,
-                        divergence_retries)
-                    epoch = rollback_epoch
-                    continue
-                logger.error(
-                    "divergence: retries remain but NO intact rollback "
-                    "checkpoint under %s — re-raising", save_path)
-            raise RuntimeError("loss is NaN — training diverged (reference train.py:259)")
-
-        # periodic EMA -> model weight restore (reference train.py:262-270)
-        ema_interval = int(optimizer_conf.get("ema_interval", -1) or -1)
-        if state.ema is not None and ema_interval > 0 and epoch % ema_interval == 0:
-            logger.info("ema synced into model at epoch %d", epoch)
-            # copy: params must not alias the EMA shadow (donated buffers)
-            state = state.replace(
-                params=jax.tree.map(jnp.copy, state.ema["params"]),
-                batch_stats=jax.tree.map(jnp.copy, state.ema["batch_stats"]),
-            )
-        for k in ("loss", "top1", "top5"):
-            if k in train_metrics:  # a token model reports no top-5
-                writers[0].add_scalar(k, train_metrics[k], epoch)
-        logger.info(
-            "[%s %3d/%3d] loss=%.4f top1=%.4f lr=%.5f",
-            "train", epoch, epochs, train_metrics["loss"], train_metrics["top1"],
-            float(lr_fn(int(state.step) - 1)),
-        )
-
-        result.update({f"{k}_train": v for k, v in train_metrics.items() if k != "num"})
-        result["epoch"] = epoch
-
-        if epoch % evaluation_interval == 0 or epoch == epochs:
-            evals = evaluate("eval", epoch)
-            for split, m in evals.items():
-                widx = 1 if split.startswith("valid") else 2
-                if split.endswith("_ema"):
-                    tag_suffix = "_ema"
-                elif split.endswith("_raw"):
-                    tag_suffix = "_raw"
+                    pad_multiple=mesh.size,
+                )
+                if use_cache:
+                    if split not in eval_replay:
+                        eval_replay[split] = _stacked_eval_splits(
+                            it, global_batch, mesh, eval_kw)
+                    norm = _run_replay_eval(
+                        replay_eval, state.params, state.batch_stats,
+                        eval_replay[split], wd=wd)
                 else:
-                    tag_suffix = ""
-                for k in ("loss", "top1", "top5"):
-                    writers[widx].add_scalar(f"{k}{tag_suffix}", m.get(k, 0.0), epoch)
+                    norm = _run_eval(
+                        eval_step, state.params, state.batch_stats,
+                        it.eval_epoch(global_batch, **eval_kw), mesh,
+                    )
+                out[split] = norm
+                if state.ema is not None:
+                    if use_cache:
+                        norm_ema = _run_replay_eval(
+                            replay_eval, state.ema["params"],
+                            state.ema["batch_stats"], eval_replay[split], wd=wd)
+                    else:
+                        norm_ema = _run_eval(
+                            eval_step, state.ema["params"],
+                            state.ema["batch_stats"],
+                            it.eval_epoch(global_batch, **eval_kw), mesh,
+                        )
+                    # with EMA on, the REPORTED valid/test numbers are the
+                    # EMA model's (reference train.py:277-280 overwrites
+                    # rs['valid']/rs['test']); raw weights kept under _raw
+                    out[split + "_raw"] = norm
+                    out[split + "_ema"] = norm_ema
+                    out[split] = norm_ema
+            return out
+
+        if only_eval:
+            with telemetry.stage("evaluate"):
+                evals = evaluate("only_eval", epoch_start)
+            for split, m in evals.items():
                 for k, v in m.items():
                     result[f"{k}_{split}"] = v
-                logger.info("[%s %3d/%3d] %s", split, epoch, epochs,
-                            {k: round(float(v), 4) for k, v in m.items()})
+            result["epoch"] = epoch_start - 1
+            result.update(steps=int(state.step), **device_stamp())
+            result["compile_cache"] = compile_cache_stats()
+            result["stages"] = root.summary()
+            return result
 
-            if metric == "last":
-                cur = float(epoch)
-            elif metric == "train":
-                cur = train_metrics["top1"]
-            else:
-                cur = evals.get(metric, {}).get("top1", 0.0)
-            if cur >= best_metric:
-                best_metric = cur
-                result["best_valid_top1"] = evals.get("valid", {}).get("top1", 0.0)
-                result["best_test_top1"] = evals.get("test", {}).get("top1", 0.0)
-                if save_path and is_master:
-                    save_checkpoint(
-                        save_path,
-                        state,
-                        {
-                            "epoch": epoch,
-                            "step": int(state.step),
-                            "metrics": {k: float(v) for k, v in result.items()
-                                        if isinstance(v, (int, float))},
-                        },
-                        keep=ckpt_keep,
-                    )
-            if reporter is not None:
-                reporter(
-                    loss_valid=evals.get("valid", {}).get("loss", 0.0),
-                    top1_valid=evals.get("valid", {}).get("top1", 0.0),
-                    loss_train=train_metrics["loss"],
-                    top1_train=train_metrics["top1"],
-                    epoch=epoch,
-                )
+        # best-metric guards live AFTER the only_eval return (eval-only runs
+        # never consult `metric`, including resumes that auto-flip only_eval)
+        if metric not in ("last", "train", "valid", "test"):
+            raise ValueError(f"unknown metric {metric!r}: use last/train/valid/test")
+        if metric == "valid" and len(valid_it) == 0:
+            raise ValueError(
+                "metric='valid' with an empty validation split (test_ratio=0): "
+                "the best-checkpoint tracker would silently follow a constant "
+                "0.0 — pass metric='last'/'train'/'test' or a test_ratio > 0"
+            )
+        if metric == "test" and len(test_it) == 0:
+            raise ValueError("metric='test' with an empty test split")
 
-        # graceful preemption at the epoch boundary (both feeds usually
-        # caught the flag at a dispatch boundary already; this is the
-        # request that arrived with the epoch's last dispatch or during
-        # its evaluation): checkpoint the COMPLETED epoch with preempted
-        # metadata and exit via the 77 contract
-        if preemption_requested():
-            if save_path and is_master:
-                save_checkpoint(
-                    save_path, state,
-                    {"epoch": epoch, "step": int(state.step),
-                     "preempted": True,
-                     "metrics": {k: float(v) for k, v in result.items()
-                                 if isinstance(v, (int, float))}},
-                    keep=ckpt_keep)
-            logger.warning(
-                "preempted at epoch %d boundary — checkpointed, exit %d "
-                "means 'resume me'", epoch, PREEMPTED_EXIT_CODE)
-            raise PreemptedError(f"preempted after epoch {epoch}")
-        epoch += 1
+        with telemetry.stage("cache_upload"):
+            train_cache = DeviceCache(total_train, mesh) if use_cache else None
+            if train_cache is not None:
+                logger.info(
+                    "device cache: %d examples (%.1f MiB) resident as %s %s, "
+                    "steps_per_dispatch=%d", train_cache.num_examples,
+                    train_cache.nbytes / 2**20, train_cache.images.dtype,
+                    list(train_cache.images.shape), steps_per_dispatch)
+                # replicated inputs join the committed state on the mesh
+                rng = jax.device_put(rng, replicated(mesh))
 
-    result["elapsed_sec"] = wall() - t_start
-    result.update(steps=int(state.step), **device_stamp())
-    # compile-tax evidence (hit/miss counts + per-label first-call
-    # seconds through the seam): a resumed/warm process proves here
-    # that it reached its first step in seconds, not minutes
-    result["compile_cache"] = compile_cache_stats()
-    for w in writers:
-        w.close()
-    return result
+        t_start = wall()
+        pol = policy if policy is not None else jnp.zeros((1, 1, 3), jnp.float32)
+        if train_cache is not None:
+            pol = jax.device_put(pol, replicated(mesh))
+        # while (not for): divergence recovery rolls `epoch` BACK to the
+        # last good checkpoint's successor and replays with fresh randomness
+        epoch = epoch_start
+        while epoch <= epochs:
+            with telemetry.stage("epoch", epoch=epoch):
+                fi = faultinject.active_plan()
+                # divergence-retry randomness: after any rollback every epoch
+                # draws retry-folded augmentation keys and shuffle seeds;
+                # retries_done == 0 is bit-for-bit the historical stream
+                if retries_done:
+                    rng_epoch = jax.random.fold_in(rng, 1_000_003 * retries_done)
+                    seed_epoch = seed + 1_000_003 * retries_done
+                    if train_cache is not None:
+                        rng_epoch = jax.device_put(rng_epoch, replicated(mesh))
+                else:
+                    rng_epoch, seed_epoch = rng, seed
+                acc = Accumulator()
+                # live per-batch progress (the reference's tqdm postfix,
+                # train.py:79-88): FAA_PROGRESS=N prints a loss-EMA line every N
+                # batches (dispatches on the cache path).  Off by default —
+                # reading metrics per batch forces a device sync and stalls the
+                # dispatch pipeline, which is why the epoch loop otherwise never
+                # touches metric values mid-epoch.
+                try:
+                    progress_every = int(os.environ.get("FAA_PROGRESS", "0") or 0)
+                except ValueError:  # cosmetic knob must never kill a run — but
+                    # the misconfiguration must be VISIBLE, not silently eaten
+                    logger.warning(
+                        "FAA_PROGRESS=%r is not an integer — live progress "
+                        "line disabled", os.environ.get("FAA_PROGRESS"))
+                    progress_every = 0
+                loss_ema = None
+
+                def progress(bi: int, metrics, epoch=epoch):
+                    nonlocal loss_ema
+                    if is_master and progress_every and (bi + 1) % progress_every == 0:
+                        cur = float(metrics["loss"]) / max(float(metrics["num"]), 1.0)
+                        loss_ema = cur if loss_ema is None else 0.9 * loss_ema + 0.1 * cur
+                        sys.stderr.write(
+                            f"\r[epoch {epoch} batch {bi + 1}] loss_ema={loss_ema:.4f} ")
+                        sys.stderr.flush()
+
+                def snapshot_in_epoch(pos: int, sums: dict, epoch=epoch):
+                    """Mid-epoch checkpoint at a dispatch boundary: the exact
+                    position and the epoch's metric sums so far (either feed)."""
+                    with telemetry.stage("checkpoint"):
+                        save_checkpoint(
+                            save_path, state,
+                            {"epoch": epoch - 1,
+                             "step": (epoch - 1) * steps_per_epoch + pos,
+                             "preempted": preemption_requested(),
+                             "in_epoch": {
+                                 "epoch": epoch, "pos": pos,
+                                 "sums": {k: float(v) for k, v in sums.items()},
+                                 "retries": retries_done}},
+                            keep=ckpt_keep)
+
+                def preempted_in_epoch(pos: int, total: int, epoch=epoch):
+                    logger.warning(
+                        "preempted at epoch %d dispatch boundary (position %d/%d) "
+                        "— checkpointed, exit %d means 'resume me'", epoch, pos,
+                        total, PREEMPTED_EXIT_CODE)
+                    return PreemptedError(
+                        f"preempted mid-epoch {epoch} at dispatch position {pos}")
+
+                if train_cache is not None:
+                    # device-resident feed: the per-epoch shuffle is the
+                    # IDENTICAL host permutation; only the index matrix is
+                    # shipped, and each dispatch advances a whole scan chunk
+                    with telemetry.stage("index_matrix"):
+                        mat = train_index_matrix(
+                            train_idx, global_batch, epoch, seed=seed_epoch,
+                            process_index=jax.process_index(),
+                            process_count=jax.process_count(),
+                        )
+                    with telemetry.stage("dispatch_loop"):
+                        pos = 0
+                        dispatch_metrics: list = []
+                        if token_counters is not None:
+                            token_counters.new_epoch()
+                        if resume_pos and epoch == epoch_start:
+                            # preempted mid-epoch: skip the dispatches already done
+                            # and seed the metric chain with the saved partial sums
+                            # — the host additions below continue the SAME
+                            # sequential f32 chain, so the epoch's reported metrics
+                            # are bit-identical to the uninterrupted run
+                            pos = resume_pos
+                            if resume_sums:
+                                dispatch_metrics.append(dict(resume_sums))
+                                if token_counters is not None:
+                                    token_counters.new_epoch(
+                                        _split_counts(dict(resume_sums)))
+                        for di, n in enumerate(split_dispatch_chunks(
+                                len(mat) - pos, steps_per_dispatch)):
+                            idx_dev = place_index_matrix(mesh, mat[pos:pos + n])
+                            state, metrics = _monitored_dispatch(
+                                wd, "train_dispatch", fi,
+                                (epoch - 1) * steps_per_epoch + pos + n,
+                                get_multi_step(n),
+                                state, train_cache.images, train_cache.labels,
+                                idx_dev, pol, rng_epoch)
+                            # per-dispatch sums are kept as ASYNC device handles and
+                            # summed on host at epoch end (_sum_metric_dicts): with
+                            # the committed state a per-dispatch jnp add would queue
+                            # one tiny all-participant collective per metric, and
+                            # long unsynced chains of those wedge the CPU backend
+                            dispatch_metrics.append(metrics)
+                            progress(di, metrics)
+                            pos += n
+                            _beat(heartbeat)
+                            if fi is not None:
+                                fi.maybe_signal((epoch - 1) * steps_per_epoch + pos)
+                            # resilience boundary: the PR-4 dispatch boundaries are
+                            # exact resume points — honor a preemption request (or
+                            # the periodic snapshot knob) here, mid-epoch
+                            periodic = (checkpoint_every_dispatch > 0
+                                        and (di + 1) % checkpoint_every_dispatch == 0)
+                            if pos < len(mat) and (preemption_requested() or periodic):
+                                if save_path and is_master:
+                                    sums = _sum_metric_dicts(dispatch_metrics)
+                                    snapshot_in_epoch(pos, sums)
+                                    if token_counters is not None:
+                                        token_counters.publish(_split_counts(dict(sums)))
+                                    # saved sums replace the pending handles — the
+                                    # continued f32 chain is identical either way
+                                    dispatch_metrics = [
+                                        {k: np.float32(v) for k, v in sums.items()}]
+                                if preemption_requested():
+                                    raise preempted_in_epoch(pos, len(mat))
+                else:
+                    # host feed: the same resume points as the device-resident
+                    # feed, one batch a dispatch.  A resumed epoch skips the
+                    # batches already trained without decoding them (their crop
+                    # boxes are still drawn, so the rest of the epoch is the
+                    # unbroken run's) and continues the saved metric sums.
+                    with telemetry.stage("dispatch_loop"):
+                        pos = 0
+                        if resume_pos and epoch == epoch_start:
+                            pos = resume_pos
+                            if resume_sums:
+                                acc.add_dict(resume_sums)
+                        batches = prefetch(
+                            train_it.train_epoch(
+                                global_batch, epoch, seed=seed_epoch,
+                                process_index=jax.process_index(),
+                                process_count=jax.process_count(),
+                                skip=pos,
+                            ),
+                            transform=shard_transform(mesh),
+                        )
+                        for bi, batch in enumerate(batches, start=pos):
+                            state, metrics = _monitored_dispatch(
+                                wd, "train_step", fi,
+                                (epoch - 1) * steps_per_epoch + bi + 1,
+                                train_step, state, batch["x"], batch["y"],
+                                pol, rng_epoch)
+                            acc.add_dict(metrics)
+                            progress(bi, metrics)
+                            pos = bi + 1
+                            _beat(heartbeat)
+                            if fi is not None:
+                                fi.maybe_signal((epoch - 1) * steps_per_epoch + pos)
+                            periodic = (checkpoint_every_dispatch > 0
+                                        and pos % checkpoint_every_dispatch == 0)
+                            if pos < steps_per_epoch and (preemption_requested()
+                                                          or periodic):
+                                if save_path and is_master:
+                                    snapshot_in_epoch(pos, dict(acc.items()))
+                                if preemption_requested():
+                                    raise preempted_in_epoch(pos, steps_per_epoch)
+                with telemetry.stage("epoch_boundary"):
+                    if train_cache is not None:
+                        # the cached feed's sums: the host waits here for the
+                        # epoch's last dispatches
+                        with telemetry.stage("metric_sync"):
+                            sums = _sum_metric_dicts(dispatch_metrics)
+                            counts = _split_counts(sums)
+                            if token_counters is not None:
+                                token_counters.publish(counts)
+                            acc.add_dict(sums)
+                    with telemetry.stage("heartbeat"):
+                        _beat(heartbeat)
+                    resume_pos, resume_sums = 0, None  # consumed by the first epoch
+                    if is_master and progress_every and loss_ema is not None:
+                        sys.stderr.write("\n")
+                    with telemetry.stage("metric_sync"):
+                        if token_counters is not None and train_cache is None:
+                            # host feed: the sums sat on the device until here
+                            token_counters.new_epoch()
+                            token_counters.publish(_split_counts(acc.metrics))
+                        train_metrics = acc.normalize()
+                    if not train_metrics:
+                        raise RuntimeError(
+                            f"epoch {epoch} produced zero train batches "
+                            f"({len(train_idx)} examples, global batch {global_batch}) — "
+                            "feed pipeline bug or dataset/batch mismatch"
+                        )
+                    if fi is not None and fi.nan_loss_in((epoch - 1) * steps_per_epoch,
+                                                         epoch * steps_per_epoch):
+                        train_metrics["loss"] = float("nan")  # injected at the seam
+                    if not np.isfinite(train_metrics["loss"]):
+                        # divergence recovery (--divergence-retries R, default 0 =
+                        # the historical raise): roll back to the newest intact
+                        # EPOCH-BOUNDARY chain link and replay with retry-folded
+                        # randomness; re-raise only after R failed rollbacks
+                        if retries_done < divergence_retries and save_path:
+                            rolled = load_checkpoint_chain(
+                                save_path, state, keep=ckpt_keep,
+                                accept=lambda m: "in_epoch" not in m)
+                            if rolled is not None:
+                                retries_done += 1
+                                state, meta_rb, used_rb = rolled
+                                if train_cache is not None:
+                                    state = jax.device_put(state, replicated(mesh))
+                                rollback_epoch = int(meta_rb.get("epoch", 0)) + 1
+                                logger.warning(
+                                    "divergence: non-finite loss at epoch %d — rolled "
+                                    "back to %s (replaying from epoch %d), retry %d/%d "
+                                    "with retry-folded PRNG/shuffle streams",
+                                    epoch, used_rb, rollback_epoch, retries_done,
+                                    divergence_retries)
+                                epoch = rollback_epoch
+                                continue
+                            logger.error(
+                                "divergence: retries remain but NO intact rollback "
+                                "checkpoint under %s — re-raising", save_path)
+                        raise RuntimeError("loss is NaN — training diverged (reference train.py:259)")
+
+                    # periodic EMA -> model weight restore (reference train.py:262-270)
+                    ema_interval = int(optimizer_conf.get("ema_interval", -1) or -1)
+                    if state.ema is not None and ema_interval > 0 and epoch % ema_interval == 0:
+                        logger.info("ema synced into model at epoch %d", epoch)
+                        # copy: params must not alias the EMA shadow (donated buffers)
+                        state = state.replace(
+                            params=jax.tree.map(jnp.copy, state.ema["params"]),
+                            batch_stats=jax.tree.map(jnp.copy, state.ema["batch_stats"]),
+                        )
+                    with telemetry.stage("log"):
+                        for k in ("loss", "top1", "top5"):
+                            if k in train_metrics:  # a token model reports no top-5
+                                writers[0].add_scalar(k, train_metrics[k], epoch)
+                        logger.info(
+                            "[%s %3d/%3d] loss=%.4f top1=%.4f lr=%.5f",
+                            "train", epoch, epochs, train_metrics["loss"], train_metrics["top1"],
+                            float(lr_fn(int(state.step) - 1)),
+                        )
+
+                        result.update({f"{k}_train": v for k, v in train_metrics.items() if k != "num"})
+                        result["epoch"] = epoch
+
+                    if epoch % evaluation_interval == 0 or epoch == epochs:
+                        with telemetry.stage("evaluate"):
+                            evals = evaluate("eval", epoch)
+                            for split, m in evals.items():
+                                widx = 1 if split.startswith("valid") else 2
+                                if split.endswith("_ema"):
+                                    tag_suffix = "_ema"
+                                elif split.endswith("_raw"):
+                                    tag_suffix = "_raw"
+                                else:
+                                    tag_suffix = ""
+                                for k in ("loss", "top1", "top5"):
+                                    writers[widx].add_scalar(f"{k}{tag_suffix}", m.get(k, 0.0), epoch)
+                                for k, v in m.items():
+                                    result[f"{k}_{split}"] = v
+                                logger.info("[%s %3d/%3d] %s", split, epoch, epochs,
+                                            {k: round(float(v), 4) for k, v in m.items()})
+
+                        if metric == "last":
+                            cur = float(epoch)
+                        elif metric == "train":
+                            cur = train_metrics["top1"]
+                        else:
+                            cur = evals.get(metric, {}).get("top1", 0.0)
+                        if cur >= best_metric:
+                            best_metric = cur
+                            result["best_valid_top1"] = evals.get("valid", {}).get("top1", 0.0)
+                            result["best_test_top1"] = evals.get("test", {}).get("top1", 0.0)
+                            if save_path and is_master:
+                                with telemetry.stage("checkpoint"):
+                                    save_checkpoint(
+                                        save_path,
+                                        state,
+                                        {
+                                            "epoch": epoch,
+                                            "step": int(state.step),
+                                            "metrics": {k: float(v) for k, v in result.items()
+                                                        if isinstance(v, (int, float))},
+                                        },
+                                        keep=ckpt_keep,
+                                    )
+                        if reporter is not None:
+                            reporter(
+                                loss_valid=evals.get("valid", {}).get("loss", 0.0),
+                                top1_valid=evals.get("valid", {}).get("top1", 0.0),
+                                loss_train=train_metrics["loss"],
+                                top1_train=train_metrics["top1"],
+                                epoch=epoch,
+                            )
+
+                    # graceful preemption at the epoch boundary (both feeds usually
+                    # caught the flag at a dispatch boundary already; this is the
+                    # request that arrived with the epoch's last dispatch or during
+                    # its evaluation): checkpoint the COMPLETED epoch with preempted
+                    # metadata and exit via the 77 contract
+                    if preemption_requested():
+                        if save_path and is_master:
+                            with telemetry.stage("checkpoint"):
+                                save_checkpoint(
+                                    save_path, state,
+                                    {"epoch": epoch, "step": int(state.step),
+                                     "preempted": True,
+                                     "metrics": {k: float(v) for k, v in result.items()
+                                                 if isinstance(v, (int, float))}},
+                                    keep=ckpt_keep)
+                        logger.warning(
+                            "preempted at epoch %d boundary — checkpointed, exit %d "
+                            "means 'resume me'", epoch, PREEMPTED_EXIT_CODE)
+                        raise PreemptedError(f"preempted after epoch {epoch}")
+                    epoch += 1
+
+        result["elapsed_sec"] = wall() - t_start
+        result.update(steps=int(state.step), **device_stamp())
+        # compile-tax evidence (hit/miss counts + per-label first-call
+        # seconds through the seam): a resumed/warm process proves here
+        # that it reached its first step in seconds, not minutes
+        result["compile_cache"] = compile_cache_stats()
+        for w in writers:
+            w.close()
+        result["stages"] = root.summary()
+        return result
 
 
 def train_folds_stacked(

@@ -205,36 +205,12 @@ def _parse_value(buf: bytes) -> dict:
     return out
 
 
-def read_events(path: str, verify_crc: bool = True) -> list[dict]:
-    """Parse a tfevents file back into dicts
-    ``{"wall_time", "step"?, "file_version"?, "tag"?, "value"?}``.
-
-    A truncated trailing record (killed writer mid-flush) ends the
-    parse gracefully: the complete prefix is returned."""
-    events = []
-    with open(path, "rb") as fh:
-        data = fh.read()
-    i = 0
-    while i < len(data):
-        if i + 12 > len(data):
-            break  # truncated header
-        header = data[i:i + 8]
-        (ln,) = struct.unpack("<Q", header)
-        (hcrc,) = struct.unpack("<I", data[i + 8:i + 12])
-        if i + 16 + ln > len(data):
-            break  # truncated payload/footer
-        payload = data[i + 12:i + 12 + ln]
-        (pcrc,) = struct.unpack("<I", data[i + 12 + ln:i + 16 + ln])
-        if verify_crc:
-            # explicit raises, not asserts: `python -O` strips asserts,
-            # which would silently void the verify_crc=True contract
-            if _masked_crc(header) != hcrc:
-                raise ValueError(f"header crc mismatch @ {i} in {path}")
-            if _masked_crc(payload) != pcrc:
-                raise ValueError(f"payload crc mismatch @ {i} in {path}")
-        i += 16 + ln
-
-        ev: dict = {}
+def _parse_event(payload: bytes) -> dict:
+    """One frame's payload -> event dict; ``ValueError`` where it is not
+    an ``Event`` message (a field that runs past the payload's end, a
+    string that is not UTF-8)."""
+    ev: dict = {}
+    try:
         j = 0
         while j < len(payload):
             key, j = _read_varint(payload, j)
@@ -265,5 +241,48 @@ def read_events(path: str, verify_crc: bool = True) -> list[dict]:
                         k = _skip_field(summ, k, skey & 7)
             else:
                 j = _skip_field(payload, j, wire)
-        events.append(ev)
+    except (IndexError, struct.error, UnicodeDecodeError) as e:
+        raise ValueError(f"not an Event message: {e!r}") from e
+    return ev
+
+
+def read_events(path: str, verify_crc: bool = True) -> list[dict]:
+    """Parse a tfevents file back into dicts
+    ``{"wall_time", "step"?, "file_version"?, "tag"?, "value"?}``.
+
+    A truncated trailing record (killed writer mid-flush) ends the
+    parse gracefully: the complete prefix is returned.  Any other
+    malformed frame raises ``ValueError``: a CRC that does not match, a
+    payload that is no ``Event`` message.  With ``verify_crc=False`` the
+    caller has opted out of both: a frame whose payload does not parse
+    is kept as an empty event and the parse goes on with the next frame
+    (the frames' boundaries come from their length headers)."""
+    events = []
+    with open(path, "rb") as fh:
+        data = fh.read()
+    i = 0
+    while i < len(data):
+        if i + 12 > len(data):
+            break  # truncated header
+        header = data[i:i + 8]
+        (ln,) = struct.unpack("<Q", header)
+        (hcrc,) = struct.unpack("<I", data[i + 8:i + 12])
+        if i + 16 + ln > len(data):
+            break  # truncated payload/footer
+        payload = data[i + 12:i + 12 + ln]
+        (pcrc,) = struct.unpack("<I", data[i + 12 + ln:i + 16 + ln])
+        if verify_crc:
+            # explicit raises, not asserts: `python -O` strips asserts,
+            # which would silently void the verify_crc=True contract
+            if _masked_crc(header) != hcrc:
+                raise ValueError(f"header crc mismatch @ {i} in {path}")
+            if _masked_crc(payload) != pcrc:
+                raise ValueError(f"payload crc mismatch @ {i} in {path}")
+        try:
+            events.append(_parse_event(payload))
+        except ValueError as e:
+            if verify_crc:
+                raise ValueError(f"malformed frame @ {i} in {path}: {e}") from e
+            events.append({})
+        i += 16 + ln
     return events

@@ -88,3 +88,42 @@ def test_reader_crc_mismatch_raises_value_error(tmp_path):
         read_events(w.path)
     # opting out of verification still parses the frames
     assert read_events(w.path, verify_crc=False)
+
+
+def test_reader_raises_value_error_on_any_malformed_frame(tmp_path):
+    """Whatever bytes a damaged frame holds (a payload read as fields runs
+    by the bytes of the writer's clock), the reader raises ``ValueError``
+    and nothing else; without verification it keeps the frame's place and
+    goes on."""
+    import pytest
+
+    from fast_autoaugment_tpu.utils.tb_events import _event_bytes, _record
+
+    good = _record(_event_bytes(1.0, step=3, scalar=("loss", 2.0)))
+    # intact CRCs around payloads that are no Event message: a key whose
+    # varint never ends, a string that is not UTF-8, a double cut short
+    for i, payload in enumerate((b"\xf6\xff\xff", b"\x1a\x02\xff\xfe",
+                                 b"\x09\x00")):
+        path = str(tmp_path / f"events.{i}")
+        with open(path, "wb") as fh:
+            fh.write(_record(payload) + good)
+        with pytest.raises(ValueError, match="malformed frame @ 0"):
+            read_events(path)
+        events = read_events(path, verify_crc=False)
+        assert events[0] == {} and events[1]["tag"] == "loss"
+    # every damaged byte of a real file: ValueError or a clean parse
+    w = TBEventWriter(str(tmp_path / "real"), "train")
+    w.add_scalar("loss", 1.5, step=1)
+    w.close()
+    data = open(w.path, "rb").read()
+    for pos in range(len(data)):
+        for flip in (0xFF, 0x80, 0x01):
+            broken = bytearray(data)
+            broken[pos] ^= flip
+            with open(w.path, "wb") as fh:
+                fh.write(bytes(broken))
+            for verify in (True, False):
+                try:
+                    read_events(w.path, verify_crc=verify)
+                except ValueError:
+                    pass

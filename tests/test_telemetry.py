@@ -201,23 +201,186 @@ def test_env_handoff_and_resolve(monkeypatch, tmp_path):
 # ----------------------------------------------------------- span seam
 
 
-def test_span_feeds_registry_trace_and_journal(journal_dir):
+def test_span_inside_nested_stages_feeds_registry_and_journal(journal_dir):
+    """A dispatch span keeps its records wherever it runs, and the stages
+    around it nest: each knows its parent, its depth and its dotted path."""
     reg = T.registry()
     c0 = reg.counter("faa_dispatches_total",
                      label="train_dispatch").value
-    windows = []
-    with T.span("train_dispatch", trace=lambda t0, t1: windows.append(
-            (t0, t1)), step=4):
-        time.sleep(0.01)
-    assert len(windows) == 1 and windows[0][1] > windows[0][0]
+    with T.stage("nest_root", run=7) as root:
+        with T.stage("outer") as outer:
+            with T.stage("inner") as inner:
+                with T.span("train_dispatch", step=4):
+                    time.sleep(0.01)
+    assert (root.path, outer.path, inner.path) == (
+        "nest_root", "nest_root.outer", "nest_root.outer.inner")
+    assert (root.depth, outer.depth, inner.depth) == (0, 1, 2)
+    assert root.parent is None and inner.parent is outer
     assert reg.counter("faa_dispatches_total",
                        label="train_dispatch").value == c0 + 1
-    rec = [r for r in _read_records(journal_dir)
-           if r["type"] == "dispatch"][-1]
+    records = _read_records(journal_dir)
+    rec = [r for r in records if r["type"] == "dispatch"][-1]
     assert rec["label"] == "train_dispatch" and rec["step"] == 4
     assert rec["t_mono_end"] >= rec["t_mono_start"]
     assert abs(rec["dur_sec"]
                - (rec["t_mono_end"] - rec["t_mono_start"])) < 1e-6
+    phases = {r["label"]: r for r in records if r["type"] == "phase"}
+    assert phases["nest_root.outer.inner"]["parent"] == "nest_root.outer"
+    assert phases["nest_root.outer.inner"]["depth"] == 2
+    assert phases["nest_root"]["parent"] is None
+    assert phases["nest_root"]["run"] == 7
+    # the dispatch lies inside the innermost stage's window
+    inner_rec = phases["nest_root.outer.inner"]
+    assert inner_rec["t_mono_start"] <= rec["t_mono_start"]
+    assert rec["t_mono_end"] <= inner_rec["t_mono_end"]
+
+
+# --------------------------------------------------------------- stages
+
+
+def test_stage_tree_records_children_in_order_inside_their_parent():
+    with T.stage("tree_root", kind="t"):
+        with T.stage("a"):
+            time.sleep(0.002)
+        with T.stage("b"):
+            with T.stage("c"):
+                pass
+    root = T.stage_trees()[-1]
+    assert root["name"] == "tree_root" and root["fields"] == {"kind": "t"}
+    assert set(root) == {"name", "fields", "t_wall_start", "t_mono_start",
+                         "dur", "children"}
+    assert [c["name"] for c in root["children"]] == ["a", "b"]
+    assert [c["name"] for c in root["children"][1]["children"]] == ["c"]
+    a, b = root["children"]
+    assert root["t_mono_start"] <= a["t_mono_start"]
+    assert a["t_mono_start"] + a["dur"] <= b["t_mono_start"]
+    assert b["t_mono_start"] + b["dur"] <= root["t_mono_start"] + root["dur"]
+    assert abs(root["t_wall_start"] - time.time()) < 60.0
+
+
+def test_stage_closes_on_exception_and_reraises(journal_dir):
+    n0 = len(T.stage_trees())
+
+    class Boom(Exception):
+        pass
+
+    with pytest.raises(Boom):
+        with T.stage("exc_root"):
+            with T.stage("child"):
+                raise Boom("x")
+    root = T.stage_trees()[-1]
+    assert len(T.stage_trees()) <= n0 + 1
+    assert root["name"] == "exc_root"
+    assert [c["name"] for c in root["children"]] == ["child"]
+    assert root["dur"] >= root["children"][0]["dur"] >= 0.0
+    labels = [r["label"] for r in _read_records(journal_dir)
+              if r["type"] == "phase"]
+    assert labels[-2:] == ["exc_root.child", "exc_root"]
+    # the thread's stack is empty again: the next stage is a root
+    with T.stage("after_exc") as st:
+        pass
+    assert st.parent is None and st.depth == 0
+
+
+def test_stage_on_a_second_thread_has_its_own_stack():
+    seen = {}
+    inside = threading.Event()
+    release = threading.Event()
+
+    def worker():
+        with T.stage("thread_root") as st:
+            seen["parent"], seen["path"] = st.parent, st.path
+            inside.set()
+            assert release.wait(10.0)
+
+    with T.stage("main_root") as main:
+        th = threading.Thread(target=worker)
+        th.start()
+        assert inside.wait(10.0)
+        with T.stage("main_child") as child:
+            pass
+        release.set()
+        th.join(10.0)
+        assert not th.is_alive()
+    assert seen == {"parent": None, "path": "thread_root"}
+    assert child.parent is main and child.path == "main_root.main_child"
+    names = [r["name"] for r in T.stage_trees()[-2:]]
+    assert names == ["thread_root", "main_root"]
+    assert [c["name"] for c in T.stage_trees()[-1]["children"]] == [
+        "main_child"]
+
+
+def test_stage_adds_its_duration_to_the_phase_counter():
+    counter = T.registry().counter("faa_phase_seconds_total",
+                                   label="count_root.work")
+    v0 = counter.value
+    for _ in range(2):
+        with T.stage("count_root") as root:
+            with T.stage("work"):
+                time.sleep(0.005)
+    work = T.stage_trees()[-1]["children"][0]
+    prev = T.stage_trees()[-2]["children"][0]
+    assert counter.value - v0 == pytest.approx(work["dur"] + prev["dur"])
+    assert root.summary()["count_root.work"]["n"] == 1
+    assert root.summary()["count_root.work"]["sec"] == pytest.approx(
+        work["dur"], abs=1e-5)
+
+
+def test_armed_journal_gets_one_phase_event_per_stage(journal_dir):
+    with T.stage("journal_root"):
+        with T.stage("only", epoch=3):
+            pass
+    phases = [r for r in _read_records(journal_dir) if r["type"] == "phase"]
+    mine = [r for r in phases if r["label"] == "journal_root.only"]
+    assert len(mine) == 1
+    rec = mine[0]
+    assert rec["lane"] == "trainer" and rec["epoch"] == 3
+    assert rec["parent"] == "journal_root" and rec["depth"] == 1
+    assert rec["t_mono_end"] >= rec["t_mono_start"]
+    assert "phase" in T.EVENT_TYPES  # no event type of its own
+
+
+def test_stage_trees_is_bounded():
+    with T.stage("bound_root"):
+        with T.stage("setup_a"):
+            pass
+        for i in range(T.STAGE_SIBLINGS_KEPT + 40):
+            with T.stage("epoch", epoch=i):
+                pass
+        with T.stage("setup_b"):
+            pass
+    children = T.stage_trees()[-1]["children"]
+    epochs = [c["fields"]["epoch"] for c in children if c["name"] == "epoch"]
+    # the newest are kept, and every stage of another name
+    assert epochs == list(range(40, T.STAGE_SIBLINGS_KEPT + 40))
+    assert children[0]["name"] == "setup_a" and children[-1]["name"] == "setup_b"
+    for i in range(T.STAGE_ROOTS_KEPT + 5):
+        with T.stage("many_roots", i=i):
+            pass
+    trees = T.stage_trees()
+    assert len(trees) == T.STAGE_ROOTS_KEPT
+    assert trees[-1]["fields"] == {"i": T.STAGE_ROOTS_KEPT + 4}
+
+
+def test_import_and_stage_initialise_no_backend():
+    """``tools/faa_status.py`` and the journal's readers import this module
+    where no accelerator may be touched."""
+    import subprocess
+
+    code = (
+        "import sys\n"
+        "from fast_autoaugment_tpu.core import telemetry as T\n"
+        "assert 'jax' not in sys.modules, 'import pulled jax in'\n"
+        "with T.stage('r'):\n"
+        "    with T.span('d'):\n"
+        "        pass\n"
+        "from jax._src import xla_bridge\n"
+        "assert not xla_bridge.backends_are_initialized()\n"
+        "print(T.stage_trees()[-1]['name'])\n")
+    out = subprocess.run([sys.executable, "-c", code], cwd=_REPO,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip() == "r"
 
 
 def test_dispatch_journal_rate_bound_registry_stays_exact(tmp_path):

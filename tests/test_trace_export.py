@@ -71,6 +71,36 @@ def test_roundtrip_validates_against_chrome_schema(journal_dir):
     assert shed["args"]["reason"] == "overload" and shed["args"]["n"] == 2
 
 
+def test_stage_events_nest_on_their_real_thread_lane(journal_dir):
+    """A stage's ``phase`` event (``lane: "trainer"``) is a slice on the
+    thread that ran it, inside its parent's and around the dispatches it
+    made: not on a synthetic phase lane."""
+    import threading
+
+    with T.stage("train_and_eval"):
+        with T.stage("epoch", epoch=1):
+            with T.stage("dispatch_loop"):
+                with T.span("train_dispatch", step=1):
+                    time.sleep(0.002)
+    T.phase_event("phase2-fold0", 1.0, 2.0, fold=0)
+    T.journal_flush()
+    trace = journal_to_trace(read_journal(journal_dir))
+    assert validate_trace(trace) == []
+    by_name = {s["name"]: s for s in _slices(trace)}
+    stages = [by_name[n] for n in (
+        "train_and_eval", "train_and_eval.epoch",
+        "train_and_eval.epoch.dispatch_loop", "train_dispatch")]
+    tid = threading.get_native_id()
+    assert [s["tid"] for s in stages] == [tid] * 4
+    for outer, inner in zip(stages, stages[1:]):
+        assert outer["ts"] <= inner["ts"]
+        assert inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"] + 1e-3
+    assert by_name["train_and_eval.epoch"]["args"]["epoch"] == 1
+    assert by_name["train_and_eval.epoch"]["args"]["parent"] == "train_and_eval"
+    # any other lane keeps its synthetic track
+    assert by_name["phase2-fold0"]["tid"] == PHASE_LANES["phase2"]
+
+
 def test_validate_trace_catches_schema_violations():
     assert validate_trace({"traceEvents": "nope"})
     bad = {"traceEvents": [{"ph": "X", "name": "x", "pid": 1, "tid": 1,

@@ -265,3 +265,124 @@ def test_train_step_single_vs_eight_devices(devices8):
     # would indicate a real semantic difference.
     for a, b in zip(l1, l8):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-3, atol=1e-4)
+
+
+# ------------------------------------------------ the stage tree (PR 38)
+
+
+def _assert_nested(node):
+    """Every child inside its parent, no child overlapping a sibling:
+    structure only, whatever the machine's speed."""
+    end = node["t_mono_start"] + node["dur"]
+    cursor = node["t_mono_start"]
+    for child in node["children"]:
+        assert cursor <= child["t_mono_start"], (node["name"], child["name"])
+        cursor = child["t_mono_start"] + child["dur"]
+        assert cursor <= end + 1e-9, (node["name"], child["name"])
+        _assert_nested(child)
+
+
+def _names(node):
+    return [c["name"] for c in node["children"]
+            if not c["name"].startswith("first_call:")]
+
+
+@pytest.fixture(scope="module")
+def staged_runs(tmp_path_factory):
+    """One tiny cached run to its end, an ``only_eval`` restore of it and a
+    run that a heartbeat preempts: the trees and results they left."""
+    from fast_autoaugment_tpu.core import telemetry
+    from fast_autoaugment_tpu.core.resilience import (
+        PreemptedError,
+        clear_preemption,
+        request_preemption,
+    )
+    from fast_autoaugment_tpu.train.trainer import train_and_eval
+
+    tmp = str(tmp_path_factory.mktemp("stages"))
+    save = os.path.join(tmp, "ckpt", "model.msgpack")
+    kw = dict(dataroot=tmp, save_path=save, evaluation_interval=1)
+    full = train_and_eval(_smoke_conf(), **kw)
+    evaluated = train_and_eval(_smoke_conf(), only_eval=True, **kw)
+    trees = [t for t in telemetry.stage_trees()
+             if t["name"] == "train_and_eval"][-2:]
+
+    beats = []
+
+    def heartbeat():
+        beats.append(1)
+        if len(beats) == 3:
+            request_preemption()
+
+    clear_preemption()
+    try:
+        with pytest.raises(PreemptedError):
+            train_and_eval(_smoke_conf(epoch=4), dataroot=tmp,
+                           save_path=os.path.join(tmp, "pre", "model.msgpack"),
+                           heartbeat=heartbeat)
+    finally:
+        clear_preemption()
+    preempted = telemetry.stage_trees()[-1]
+    with telemetry.stage("after_preemption") as probe:
+        pass
+    return {"full": full, "evaluated": evaluated, "trees": trees,
+            "preempted": preempted, "probe_depth": probe.depth}
+
+
+def test_train_and_eval_leaves_the_named_stages_in_order(staged_runs):
+    root = staged_runs["trees"][0]
+    assert root["name"] == "train_and_eval"
+    assert root["fields"] == {"only_eval": False}
+    assert _names(root) == [
+        "load_dataset", "split", "build", "state_init", "build", "restore",
+        "place_state", "cache_upload", "epoch", "epoch"]
+    epochs = [c for c in root["children"] if c["name"] == "epoch"]
+    assert [e["fields"] for e in epochs] == [{"epoch": 1}, {"epoch": 2}]
+    for epoch in epochs:
+        assert _names(epoch) == ["index_matrix", "dispatch_loop",
+                                 "epoch_boundary"]
+        assert _names(epoch["children"][-1]) == [
+            "metric_sync", "heartbeat", "metric_sync", "log", "evaluate",
+            "checkpoint"]
+    # the step's first call nests in the loop that made it, the
+    # evaluation's in the boundary's evaluation
+    loop = epochs[0]["children"][1]
+    assert "first_call:train_dispatch" in [c["name"] for c in loop["children"]]
+    evaluate = [c for c in epochs[0]["children"][-1]["children"]
+                if c["name"] == "evaluate"][0]
+    assert "first_call:replay_eval" in [c["name"] for c in evaluate["children"]]
+    _assert_nested(root)
+
+
+def test_only_eval_call_leaves_a_second_root(staged_runs):
+    first, second = staged_runs["trees"]
+    assert second["fields"] == {"only_eval": True}
+    assert second["t_mono_start"] >= first["t_mono_start"] + first["dur"]
+    assert _names(second) == ["load_dataset", "split", "build", "state_init",
+                              "build", "restore", "place_state", "evaluate"]
+    _assert_nested(second)
+
+
+def test_preempted_run_leaves_a_closed_tree(staged_runs):
+    root = staged_runs["preempted"]
+    assert root["name"] == "train_and_eval" and root["dur"] > 0.0
+    last_epoch = root["children"][-1]
+    assert last_epoch["name"] == "epoch"
+    # the stop fell in the dispatch loop, after the snapshot it wrote
+    assert _names(last_epoch) == ["index_matrix", "dispatch_loop"]
+    assert _names(last_epoch["children"][-1]) == ["checkpoint"]
+    _assert_nested(root)
+    assert staged_runs["probe_depth"] == 0  # nothing left open
+
+
+def test_result_carries_the_flat_stage_summary(staged_runs):
+    stages = staged_runs["full"]["stages"]
+    assert stages["train_and_eval"]["n"] == 1
+    assert stages["train_and_eval.epoch"]["n"] == 2
+    assert stages["train_and_eval.epoch.epoch_boundary.metric_sync"]["n"] == 4
+    assert stages["train_and_eval.build"]["n"] == 2
+    assert all(v["sec"] >= 0.0 for v in stages.values())
+    assert "compile_cache" in staged_runs["full"]
+    only = staged_runs["evaluated"]["stages"]
+    assert "train_and_eval.evaluate" in only
+    assert "train_and_eval.epoch" not in only

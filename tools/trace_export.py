@@ -18,6 +18,9 @@ chrome://tracing both load):
 - ``phase`` events become slices on two synthetic per-process lanes —
   "phase-1 (train)" and "phase-2 (search)" — so a PR-9 overlapped run
   renders fold k's search visibly overlapping fold k+1's training;
+  a stage's (``lane: "trainer"``, ``telemetry.stage``) stays on its real
+  thread lane instead, nested in its parent's slice, the trainer's
+  dispatches inside ``dispatch_loop``;
 - everything else (``shed``, ``breaker_fire``, ``watchdog_fire``,
   ``lease``, ``trial``, ``checkpoint``, ``reload``, ``preempt``,
   ``scenario``, ``verdict``, ``mark``) becomes an INSTANT ("i")
@@ -50,6 +53,10 @@ import sys
 PHASE_LANES = {"phase1": 10_000_001, "phase2": 10_000_002}
 PHASE_LANE_NAMES = {"phase1": "phase-1 (train)",
                     "phase2": "phase-2 (search)"}
+
+#: the lane of ``telemetry.stage``'s ``phase`` events: slices on the thread
+#: that ran them, a child inside its parent (docs/OBSERVABILITY.md "Stages")
+STAGE_LANE = "trainer"
 
 #: journal event types rendered as duration slices when they carry a
 #: mono window; everything else becomes an instant marker
@@ -165,7 +172,7 @@ def journal_to_trace(records: list[dict]) -> dict:
         if etype in _SLICE_TYPES and has_window:
             t0 = aligned_wall(rec, float(rec["t_mono_start"]))
             t1 = aligned_wall(rec, float(rec["t_mono_end"]))
-            if etype == "phase":
+            if etype == "phase" and rec.get("lane") != STAGE_LANE:
                 lane = rec.get("lane")
                 if lane not in PHASE_LANES:
                     lane = "phase1" if str(label).startswith("phase1") \
