@@ -55,15 +55,22 @@ def test_tiled_warp_compiles_for_a_v5e_inside_its_budget(one_chip, no_compile_ca
     assert "while" in text  # so the batch runs in chunks
 
 
-# --- the token model's two kernels at the timed sizes (PR 35), in this file
+# --- the token model's kernels at the timed sizes (PR 35, PR 39), in this file
 # because one worker may load the TPU's library and this file's fixture does
 
 
-def test_chunked_kda_scan_and_its_backward_compile_for_a_v5e(one_chip, no_compile_cache):
+@pytest.mark.parametrize("ambient", ["default", "highest"])
+def test_fused_kda_kernels_compile_for_a_v5e(one_chip, no_compile_cache,
+                                             monkeypatch, ambient):
     """One KDA layer of `kimi_linear_48b_a3b_train`: 8,192 tokens, 32
-    heads of 128.  The sub-block sums of ``_decayed_gram`` must stay
-    inside their fusions (2.1 GB each if they were written out)."""
-    from fast_autoaugment_tpu.ops.kda import chunk_kda
+    heads of 128, through the kernels as the chip gets them (the backend
+    here is the CPU, which would interpret them), with bfloat16 operands
+    and under ``highest``.  Forward and backward are one Mosaic kernel
+    each, and beside the inputs' gradients HBM holds the kept states (268
+    MB) and little else."""
+    from fast_autoaugment_tpu.ops import kda
+
+    monkeypatch.setattr(kda, "_on_tpu", lambda: True)
 
     def shape(*dims):
         return jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
@@ -71,12 +78,15 @@ def test_chunked_kda_scan_and_its_backward_compile_for_a_v5e(one_chip, no_compil
     args = (shape(1, 8192, 32, 128),) * 4 + (shape(1, 8192, 32),)
 
     def scalar(*a):
-        out, state = chunk_kda(*a)
+        out, state = kda.chunk_kda(*a)
         return jnp.sum(out) + jnp.sum(state)
 
-    compiled = jax.jit(jax.grad(scalar, argnums=(0, 1, 2, 3, 4))).lower(*args).compile()
-    assert compiled.memory_analysis().temp_size_in_bytes < 6e9
-    assert "while" in compiled.as_text()  # the scan over chunks is a loop
+    with jax.default_matmul_precision(ambient):
+        compiled = jax.jit(jax.grad(scalar, argnums=(0, 1, 2, 3, 4))).lower(*args).compile()
+    text = compiled.as_text()
+    assert "kda_forward" in text and "kda_backward" in text
+    assert text.count("tpu_custom_call") == 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
 
 
 def test_blocked_attention_keeps_one_blocks_scores_live(one_chip, no_compile_cache):

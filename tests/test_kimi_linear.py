@@ -5,14 +5,17 @@ v, g, beta``), the blocked causal softmax against the whole one, the
 expert layer's shares adding up to the uncut layer — and the AdamW the
 model trains with against the plain formula."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
 
+from fast_autoaugment_tpu.core import telemetry
 from fast_autoaugment_tpu.models import get_model, model_conf_of
-from fast_autoaugment_tpu.ops import moe
+from fast_autoaugment_tpu.ops import kda, moe
 from fast_autoaugment_tpu.ops.attention import blocked_causal_attention
 from fast_autoaugment_tpu.ops.kda import chunk_kda, recurrent_kda
 from fast_autoaugment_tpu.ops.optim import (
@@ -39,6 +42,15 @@ def _close(a, b, rel):
 # ------------------------------------------------------------ the KDA scan
 
 
+#: the tests' own small shape (the ``jnp`` form takes it), and the shape
+#: every configuration states, which goes through the fused kernels
+#: (interpreted here: the code the chip runs)
+SMALL = dict(batch=2, heads=3, kdim=16, vdim=8)
+NATIVE = dict(batch=1, heads=2, kdim=128, vdim=128)
+
+_chunked = jax.jit(chunk_kda, static_argnames="chunk")
+
+
 def _kda_inputs(length, decay_spread, seed=0, batch=2, heads=3, kdim=16, vdim=8):
     keys = jax.random.split(jax.random.PRNGKey(seed), 5)
 
@@ -53,42 +65,61 @@ def _kda_inputs(length, decay_spread, seed=0, batch=2, heads=3, kdim=16, vdim=8)
     return q, k, v, g, beta
 
 
-@pytest.mark.parametrize("length, chunk", [(128, 64), (96, 32), (48, 16), (24, 64)])
-def test_chunked_scan_gives_the_recurrences_output_and_state(length, chunk):
-    args = _kda_inputs(length, 1.0)
+def _form_counts():
+    counters = telemetry.registry().counters_snapshot()
+    return {form: counters.get(f'faa_kda_scan_traces_total{{form="{form}"}}', 0.0)
+            for form in ("fused", "chunked_xla")}
+
+
+@pytest.mark.parametrize("length, chunk, shape", [
+    (128, 64, SMALL), (96, 32, SMALL), (48, 16, SMALL), (24, 64, SMALL),
+    (256, 64, NATIVE)], ids=["128-64", "96-32", "48-16", "24-64", "native"])
+def test_chunked_scan_gives_the_recurrences_output_and_state(length, chunk, shape):
+    args = _kda_inputs(length, 1.0, **shape)
     out, state = recurrent_kda(*args)
-    chunked, chunked_state = jax.jit(
-        lambda *a: chunk_kda(*a, chunk=chunk))(*args)
+    chunked, chunked_state = _chunked(*args, chunk=chunk)
     _close(chunked, out, 2e-5)
     _close(chunked_state, state, 2e-5)
 
 
-def test_chunked_scan_takes_an_initial_state():
-    args = _kda_inputs(128, 1.0)
+@pytest.mark.parametrize("shape", [SMALL, NATIVE], ids=["small", "native"])
+def test_chunked_scan_takes_an_initial_state(shape):
+    args = _kda_inputs(128, 1.0, **shape)
     first = tuple(a[:, :64] for a in args)
     second = tuple(a[:, 64:] for a in args)
-    _, carried = chunk_kda(*first)
-    out, state = chunk_kda(*second, initial_state=carried)
+    _, carried = _chunked(*first)
+    out, state = _chunked(*second, initial_state=carried)
     whole, whole_state = recurrent_kda(*args)
     _close(out, whole[:, 64:], 2e-5)
     _close(state, whole_state, 2e-5)
 
 
-def test_a_decay_that_would_overflow_a_factorised_form_does_not():
+@pytest.mark.parametrize("length, shape", [(128, SMALL), (256, NATIVE)],
+                         ids=["small", "native"])
+def test_a_decay_that_would_overflow_a_factorised_form_does_not(length, shape):
     """A channel that decays by e^-1500 a token: exp(-G) inside a chunk
     is far beyond float32, the recurrence itself is tame."""
-    q, k, v, g, beta = _kda_inputs(128, 2.5, seed=3)
+    q, k, v, g, beta = _kda_inputs(length, 2.5, seed=3, **shape)
     assert float(g.min()) < -200
     out, state = recurrent_kda(q, k, v, g, beta)
-    chunked, chunked_state = chunk_kda(q, k, v, g, beta)
+    chunked, chunked_state = _chunked(q, k, v, g, beta)
     assert bool(jnp.isfinite(chunked).all())
     _close(chunked, out, 3e-4)
     _close(chunked_state, state, 3e-4)
 
 
-@pytest.mark.parametrize("argnum, name", list(enumerate(["q", "k", "v", "g", "beta"])))
-def test_chunked_scans_gradient_is_the_recurrences(argnum, name):
-    args = _kda_inputs(128, 1.0, seed=1)
+_SCAN_ARGUMENTS = ["q", "k", "v", "g", "beta", "initial_state"]
+
+
+@functools.lru_cache(maxsize=None)
+def _scan_gradients(native: bool):
+    """``(the recurrence's, the chunked scan's)`` gradients of all six
+    arguments, the cotangent on both the output and the final state."""
+    args = _kda_inputs(256 if native else 128, 1.0, seed=1,
+                       **(NATIVE if native else SMALL))
+    batch, _, heads, kdim = args[0].shape
+    args += (0.1 * jax.random.normal(jax.random.PRNGKey(9),
+                                     (batch, heads, kdim, args[2].shape[-1])),)
 
     def scalar(fn):
         def f(*a):
@@ -96,14 +127,44 @@ def test_chunked_scans_gradient_is_the_recurrences(argnum, name):
             return jnp.sum(out * jnp.cos(out)) + jnp.sum(state ** 2)
         return f
 
-    plain = jax.grad(scalar(recurrent_kda), argnums=argnum)(*args)
-    chunked = jax.jit(jax.grad(scalar(chunk_kda), argnums=argnum))(*args)
-    _close(chunked, plain, 2e-4)
+    every = tuple(range(len(_SCAN_ARGUMENTS)))
+    return (jax.grad(scalar(recurrent_kda), argnums=every)(*args),
+            jax.jit(jax.grad(scalar(chunk_kda), argnums=every))(*args))
+
+
+@pytest.mark.parametrize("native", [False, True], ids=["small", "native"])
+@pytest.mark.parametrize("argnum, name", list(enumerate(_SCAN_ARGUMENTS)))
+def test_chunked_scans_gradient_is_the_recurrences(argnum, name, native):
+    plain, chunked = _scan_gradients(native)
+    _close(chunked[argnum], plain[argnum], 2e-4)
 
 
 def test_a_length_that_is_no_multiple_of_the_chunk_is_refused():
     with pytest.raises(ValueError, match="no multiple"):
         chunk_kda(*_kda_inputs(100, 1.0))
+
+
+@pytest.mark.parametrize("shape, form", [(SMALL, "chunked_xla"), (NATIVE, "fused")],
+                         ids=["small", "native"])
+def test_the_scans_counter_counts_the_form_the_trace_took(shape, form):
+    args = _kda_inputs(64, 1.0, **shape)
+    before = _form_counts()
+    jax.eval_shape(chunk_kda, *args)
+    after = _form_counts()
+    assert {f: after[f] - before[f] for f in after} == {
+        f: float(f == form) for f in after}
+
+
+@pytest.mark.parametrize("ambient, on_tpu, exact", [
+    (None, True, False), ("bfloat16", True, False), ("highest", True, True),
+    ("float32", True, True), (None, False, True)])
+def test_the_kernels_products_take_the_ambient_precision(monkeypatch, ambient,
+                                                         on_tpu, exact):
+    """bfloat16 operands only where an einsum on this backend would take
+    them: on the chip, outside ``default_matmul_precision("highest")``."""
+    monkeypatch.setattr(kda, "_on_tpu", lambda: on_tpu)
+    with jax.default_matmul_precision(ambient or "default"):
+        assert kda._float32_products() is exact
 
 
 # ------------------------------------------------- the blocked softmax
