@@ -37,10 +37,12 @@ __all__ = [
     "KDA",
     "KDA_SCAN",
     "MLA",
+    "MLA_ATTENTION",
     "MOE",
     "MOE_ROUTER",
     "MOE_EXPERTS",
     "LM_HEAD",
+    "MTP",
     "LOSS",
     "OPTIMIZER",
     "EMA",
@@ -84,21 +86,29 @@ SHAKE_SHORTCUT = "faa_shake_shortcut"
 #: ``models/resnet.py``, nested under ``faa_model``: the ImageNet stem
 #: (7x7 stride-2 convolution, BatchNorm, ReLU, 3x3 stride-2 max-pool)
 RESNET_STEM = "faa_resnet_stem"
-#: ``models/kimi_linear.py``, all nested under ``faa_model``: the KDA mixer
+#: the token models (``models/kimi_linear.py``, ``models/glm4_moe_lite.py``,
+#: ``models/token_blocks.py``), all nested under ``faa_model``: the KDA mixer
 #: (projections, short convolutions, gates, output norm and gate) with the
 #: chunked delta-rule recurrence alone inside it (``ops/kda.py``, forward
-#: and backward); the latent-attention mixer; an expert layer (its shared
-#: expert included) with the router (scores, top-k, every expert's load)
-#: and the held experts' part (the assignments sorted by expert, a loop over
-#: the blocks of rows the routing filled: gather, three products, weighted
-#: scatter-add; forward and backward) inside it; the output head's product
+#: and backward); the latent-attention mixer, with its attention core alone
+#: inside it (``ops/attention.py::blocked_causal_attention``: scores,
+#: softmax, weighted sum; forward, backward and what is computed again); an
+#: expert layer (its shared expert included) with the router (scores, top-k,
+#: every expert's load) and the held experts' part (the assignments sorted
+#: by expert, a loop over the blocks of rows the routing filled: gather,
+#: three products, weighted scatter-add; forward and backward) inside it;
+#: the output head's product; a multi-token-prediction module (its two
+#: norms, ``eh_proj`` and its block, whose own ``faa_mla`` and ``faa_moe``
+#: nest inside it; its head and loss stay ``faa_lm_head`` and ``faa_loss``)
 KDA = "faa_kda"
 KDA_SCAN = "faa_kda_scan"
 MLA = "faa_mla"
+MLA_ATTENTION = "faa_mla_attention"
 MOE = "faa_moe"
 MOE_ROUTER = "faa_moe_router"
 MOE_EXPERTS = "faa_moe_experts"
 LM_HEAD = "faa_lm_head"
+MTP = "faa_mtp"
 LOSS = "faa_loss"
 #: ``train/steps.py::step_fn``: update and parameter add; EMA; top-k and sums
 OPTIMIZER = "faa_optimizer"

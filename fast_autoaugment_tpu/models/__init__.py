@@ -8,7 +8,8 @@ are the train step's concern — a module is pure structure.
 Supported types (reference parity): resnet50, resnet200, wresnet40_2,
 wresnet28_10, shakeshake26_2x32d / 2x64d / 2x96d / 2x112d,
 shakeshake26_2x96d_next, pyramid, efficientnet-b0..b7 (+condconv).
-Beyond the reference: kimi_linear, a token model (``models/kimi_linear.py``).
+Beyond the reference, two token models: kimi_linear (``models/kimi_linear.py``)
+and glm4_moe_lite (``models/glm4_moe_lite.py``).
 """
 
 from __future__ import annotations
@@ -29,8 +30,8 @@ def model_conf_of(conf: Any) -> dict:
     """The mapping :func:`get_model` takes, from a whole conf: its
     ``model`` block with the data set, the precision, and — for a model
     that can hold a share of itself — what this chip holds
-    (``models/kimi_linear.py::CUT_KEYS``, top-level conf keys)."""
-    from fast_autoaugment_tpu.models.kimi_linear import CUT_KEYS
+    (``models/token_blocks.py::CUT_KEYS``, top-level conf keys)."""
+    from fast_autoaugment_tpu.models.token_blocks import CUT_KEYS
 
     model_conf = dict(conf["model"], dataset=conf["dataset"])
     model_conf.setdefault("precision", conf.get("precision", "f32"))
@@ -131,6 +132,10 @@ def get_model(conf: Any, num_classes: int) -> nn.Module:
         from fast_autoaugment_tpu.models.kimi_linear import kimi_linear_from_conf
 
         return kimi_linear_from_conf(conf, dtype=dtype)
+    if name == "glm4_moe_lite":
+        from fast_autoaugment_tpu.models.glm4_moe_lite import glm4_moe_lite_from_conf
+
+        return glm4_moe_lite_from_conf(conf, dtype=dtype)
     if name.startswith("efficientnet"):
         from fast_autoaugment_tpu.models.efficientnet import EfficientNet
 

@@ -10,23 +10,13 @@ lists of layers; the FFN is a dense SwiGLU in the leading
 layer beside one shared expert (``ops/moe.py``).
 
 The sizes are the published ``config.json``'s keys, handed over as the
-conf's ``model`` mapping (:func:`kimi_linear_from_conf`).  Three more
-keys say what *this chip* holds of a deployment in which 32 chips share
-each layer and further chips hold further layers — absent, the whole
-model: ``layers_held`` (the first n layers), ``experts_held`` with
-``expert_share`` (experts ``[share * held, (share + 1) * held)`` of every
-expert layer; the router keeps its published width and ``top_k``), and
-``ids_held`` (ids ``[0, n)``: embedding, head, logits and loss are over
-the slice).  No width changes with them.
-
-What a step has to know of the routing an expert layer ``sow``s into
-the :data:`STEP_STATS` collection: the assignments each of *all* its
-experts received.  :meth:`KimiLinear.after_step` turns that into the
-router's next correction bias (``ops/moe.py::balance_bias``: the
-balancing rule between steps, outside the gradient) and into the counts
-the trainer publishes (:meth:`KimiLinear.publish_counts`).  The step body
-and the trainer know of a model only these three names
-(``train/steps.py::make_token_step_body``).
+conf's ``model`` mapping (:func:`kimi_linear_from_conf`), with the three
+keys that say what *this chip* holds of a deployment in which 32 chips
+share each layer and further chips hold further layers
+(``models/token_blocks.py::CUT_KEYS``).  The blocks every token model
+here is made of, and the router's rule between steps behind
+:meth:`KimiLinear.after_step` and :meth:`KimiLinear.publish_counts`, are
+that module's.
 """
 
 from __future__ import annotations
@@ -39,37 +29,26 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from fast_autoaugment_tpu.core import scopes
-from fast_autoaugment_tpu.ops import moe
-from fast_autoaugment_tpu.ops.attention import blocked_causal_attention
+from fast_autoaugment_tpu.models.token_blocks import (
+    CUT_KEYS,
+    INIT,
+    ROUTING,
+    STEP_STATS,
+    ExpertLayer,
+    MLAMixer,
+    RMSNorm,
+    Sizes as _Sizes,
+    SwiGLU,
+    balance_routers,
+    dense as _dense,
+    expert_share_of,
+    publish_router_counts,
+    refuse_unwritten_routing,
+)
 from fast_autoaugment_tpu.ops.kda import chunk_kda
 
 __all__ = ["KimiLinear", "kimi_linear_from_conf", "STEP_STATS", "ROUTING",
-           "CUT_KEYS"]
-
-#: the collection the expert layers ``sow`` a step's loads into
-STEP_STATS = "step_stats"
-#: the collection they ``sow`` every token's chosen experts into
-ROUTING = "routing"
-#: top-level conf keys that say what this chip holds (absent: everything)
-CUT_KEYS = ("layers_held", "experts_held", "ids_held")
-
-_INIT = nn.initializers.normal(0.02)
-
-
-class RMSNorm(nn.Module):
-    eps: float = 1e-5
-
-    @nn.compact
-    def __call__(self, x):
-        weight = self.param("weight", nn.initializers.ones, (x.shape[-1],))
-        x32 = x.astype(jnp.float32)
-        x32 = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + self.eps)
-        return (x32 * weight).astype(x.dtype)
-
-
-def _dense(features: int, name: str, dtype) -> nn.Dense:
-    return nn.Dense(features, use_bias=False, kernel_init=_INIT, name=name,
-                    dtype=dtype)
+           "CUT_KEYS", "ExpertLayer", "SwiGLU"]
 
 
 class ShortConv(nn.Module):
@@ -145,95 +124,6 @@ class KDAMixer(nn.Module):
             out.reshape(batch, length, width))
 
 
-class MLAMixer(nn.Module):
-    """Latent attention with ``q_lora_rank: null`` and no rotary."""
-
-    heads: int
-    nope_dim: int
-    pe_dim: int
-    v_dim: int
-    kv_rank: int
-    eps: float
-    dtype: Any = jnp.float32
-
-    @nn.compact
-    def __call__(self, x):
-        batch, length, hidden = x.shape
-        heads = self.heads
-        q = _dense(heads * (self.nope_dim + self.pe_dim), "q_proj", self.dtype)(
-            x).reshape(batch, length, heads, self.nope_dim + self.pe_dim)
-        latent = _dense(self.kv_rank + self.pe_dim, "kv_a_proj", self.dtype)(x)
-        k_pe = latent[..., self.kv_rank:]
-        kv = _dense(heads * (self.nope_dim + self.v_dim), "kv_b_proj", self.dtype)(
-            RMSNorm(self.eps, name="kv_a_norm")(latent[..., :self.kv_rank])
-        ).reshape(batch, length, heads, self.nope_dim + self.v_dim)
-        out = blocked_causal_attention(
-            q[..., :self.nope_dim], kv[..., :self.nope_dim], kv[..., self.nope_dim:],
-            q_shared=q[..., self.nope_dim:], k_shared=k_pe,
-            scale=(self.nope_dim + self.pe_dim) ** -0.5)
-        return _dense(hidden, "o_proj", self.dtype)(
-            out.reshape(batch, length, heads * self.v_dim))
-
-
-class SwiGLU(nn.Module):
-    width: int
-    dtype: Any = jnp.float32
-
-    @nn.compact
-    def __call__(self, x):
-        hidden = x.shape[-1]
-        gate = _dense(self.width, "gate_proj", self.dtype)(x)
-        up = _dense(self.width, "up_proj", self.dtype)(x)
-        return _dense(hidden, "down_proj", self.dtype)(jax.nn.silu(gate) * up)
-
-
-class ExpertLayer(nn.Module):
-    """The routed experts this chip holds, beside the shared expert."""
-
-    experts: int
-    held: int
-    share: int
-    top_k: int
-    width: int
-    shared: int
-    scale: float
-    renormalize: bool
-    dtype: Any = jnp.float32
-
-    @nn.compact
-    def __call__(self, x):
-        batch, length, hidden = x.shape
-        flat = x.reshape(batch * length, hidden)
-        first = self.share * self.held
-        with jax.named_scope(scopes.MOE_ROUTER):
-            router = self.param("router", _INIT, (hidden, self.experts))
-            # moves the choice, never the weight; no gradient reaches it
-            bias = self.param("e_score_correction_bias", nn.initializers.zeros,
-                              (self.experts,))
-            chosen, weights = moe.route(
-                flat, router.astype(self.dtype), bias, top_k=self.top_k,
-                scale=self.scale, renormalize=self.renormalize)
-            self.sow(STEP_STATS, "load",
-                     moe.assignment_counts(chosen, 0, self.experts))
-            # for a caller that asks (``mutable=[ROUTING]``): which experts
-            # each token chose, to hold a second computation to the same
-            self.sow(ROUTING, "chosen", chosen.reshape(batch, length, self.top_k))
-        shape = (self.held, hidden, self.width)
-        w_gate = self.param("experts_gate", _INIT, shape)
-        w_up = self.param("experts_up", _INIT, shape)
-        w_down = self.param("experts_down", _INIT, (self.held, self.width, hidden))
-        with jax.named_scope(scopes.MOE_EXPERTS):
-            out = moe.held_experts(
-                flat, chosen, weights.astype(self.dtype),
-                w_gate.astype(self.dtype), w_up.astype(self.dtype),
-                w_down.astype(self.dtype), first=first)
-        out = out.reshape(batch, length, hidden)
-        if self.shared:
-            out = out + SwiGLU(self.width * self.shared, self.dtype,
-                               name="shared_experts")(x)
-        return out
-
-
 class Block(nn.Module):
     conf: Any            # the hashable view KimiLinear makes of its sizes
     layer: int           # 1-based, as the published lists count
@@ -264,23 +154,6 @@ class Block(nn.Module):
         return h + ffn
 
 
-class _Sizes:
-    """The sizes a block needs, hashable so that ``nn.remat`` takes them."""
-
-    def __init__(self, **sizes):
-        self.__dict__.update(sizes)
-        self._key = tuple(sorted((k, v) for k, v in sizes.items()))
-
-    def __hash__(self):
-        return hash(self._key)
-
-    def __eq__(self, other):
-        return isinstance(other, _Sizes) and self._key == other._key
-
-    def __repr__(self):
-        return f"_Sizes{self._key}"
-
-
 class KimiLinear(nn.Module):
     sizes: _Sizes
     remat: bool = True
@@ -290,52 +163,12 @@ class KimiLinear(nn.Module):
     step_collection = STEP_STATS
 
     def after_step(self, params, stats):
-        """``(params, counts)`` after an optimizer step whose forward pass
-        sowed `stats`: every expert layer's correction bias moved by the
-        balancing rule, and per layer the assignments the held experts
-        received, in all and the most loaded one's (float32 scalars, for
-        the step's count sums)."""
-        c = self.sizes
-        first = c.expert_share * c.experts_held
-        params, counts = dict(params), {}
-        for layer, entry in sorted(stats.items()):
-            load = sum(entry["moe"]["load"])
-            held = load[first:first + c.experts_held].astype(jnp.float32)
-            counts[f"moe_assigned/{layer}"] = jnp.sum(held)
-            counts[f"moe_largest/{layer}"] = jnp.max(held)
-            if c.bias_update_rate:
-                layer_params = dict(params[layer])
-                layer_params["moe"] = dict(
-                    layer_params["moe"], e_score_correction_bias=moe.balance_bias(
-                        layer_params["moe"]["e_score_correction_bias"], load,
-                        c.bias_update_rate))
-                params[layer] = layer_params
-        return params, counts
+        """``token_blocks.balance_routers`` over this model's sizes."""
+        return balance_routers(self.sizes, params, stats)
 
     def publish_counts(self, rise: dict, registry) -> None:
-        """What :meth:`after_step` counted, where the trainer has synced a
-        stretch of steps' sums anyway (`rise`: the sums over the steps
-        since it last published): the counter
-        ``faa_moe_assignments_total{held,layer}`` and the gauge
-        ``faa_moe_held_load_max_over_mean{layer}``, the most loaded held
-        expert's assignments over the held experts' mean, over those
-        steps."""
-        for key, assigned in rise.items():
-            kind, _, layer = key.partition("/")
-            if kind != "moe_assigned":
-                continue
-            registry.counter(
-                "faa_moe_assignments_total",
-                "token-to-expert assignments that fell to experts this chip "
-                "holds", held="true", layer=layer).inc(assigned)
-            largest = rise.get(f"moe_largest/{layer}")
-            if largest is not None and assigned > 0:
-                registry.gauge(
-                    "faa_moe_held_load_max_over_mean",
-                    "assignments of the most loaded held expert over the held "
-                    "experts' mean, a step at a time, over the steps last "
-                    "published", layer=layer).set(
-                        largest * self.sizes.experts_held / assigned)
+        """``token_blocks.publish_router_counts`` over this model's sizes."""
+        publish_router_counts(self.sizes, rise, registry)
 
     @nn.compact
     def __call__(self, ids, train: bool = False):
@@ -346,7 +179,7 @@ class KimiLinear(nn.Module):
         c = self.sizes
         if ids.dtype not in (jnp.int32, jnp.uint32, jnp.int64):
             ids = ids.astype(jnp.int32)  # an init sample may come as floats
-        table = self.param("embed_tokens", _INIT, (c.ids_held, c.hidden))
+        table = self.param("embed_tokens", INIT, (c.ids_held, c.hidden))
         x = jnp.take(table, ids, axis=0).astype(self.dtype)
         block = nn.remat(Block) if self.remat else Block
         for layer in range(1, c.layers_held + 1):
@@ -365,21 +198,15 @@ def kimi_linear_from_conf(conf: Any, dtype=jnp.float32) -> KimiLinear:
     layers = int(conf["num_hidden_layers"])
     experts = int(conf["num_experts"])
     ids = int(conf["vocab_size"])
-    held = int(conf.get("experts_held") or experts)
-    share = int(conf.get("expert_share") or 0)
-    if experts % held or not 0 <= share < experts // held:
-        raise ValueError(f"experts_held={held}, expert_share={share}: not a "
-                         f"share of {experts} experts")
+    held, share = expert_share_of(conf, experts)
     if conf.get("q_lora_rank") is not None or not conf.get("mla_use_nope", False):
-        raise ValueError("only q_lora_rank: null with mla_use_nope: true "
-                         "(latent attention without rotary) is written down")
-    if int(conf.get("num_expert_group", 1)) != 1 or int(conf.get("topk_group", 1)) != 1:
-        raise ValueError("grouped top-k over more than one group is not "
-                         "written down")
-    if conf.get("moe_router_activation_func", "sigmoid") != "sigmoid":
-        raise ValueError("only the sigmoid router is written down")
-    if int(conf.get("moe_layer_freq", 1)) != 1:
-        raise ValueError("moe_layer_freq other than 1 is not written down")
+        raise ValueError("this family's latent attention has q_lora_rank: null "
+                         "and mla_use_nope: true; a low-rank query and rotary "
+                         "are models/glm4_moe_lite.py's")
+    refuse_unwritten_routing(
+        int(conf.get("num_expert_group", 1)), int(conf.get("topk_group", 1)),
+        conf.get("moe_router_activation_func", "sigmoid"),
+        int(conf.get("moe_layer_freq", 1)))
     sizes = _Sizes(
         hidden=int(conf["hidden_size"]), eps=float(conf["rms_norm_eps"]),
         layers_held=int(conf.get("layers_held") or layers),

@@ -1,0 +1,278 @@
+"""What the token models share: the blocks of a pre-norm decoder whose
+FFN may be a sigmoid-routed expert layer this chip holds a share of
+(``models/kimi_linear.py``, ``models/glm4_moe_lite.py``).
+
+- :class:`RMSNorm`, :class:`SwiGLU`, the bias-free :func:`dense`;
+- :class:`MLAMixer`, latent attention (DeepSeek-V2's MLA): the key-value
+  latent always, the query whole or through a low-rank pair with a norm
+  between (``q_rank``), the shared key part and every head's matching
+  query part rotated by position or left as they are (``rope_theta``);
+- :class:`ExpertLayer`, the routed experts this chip holds beside the
+  shared expert (``ops/moe.py``);
+- :class:`Sizes`, the hashable view of a model's sizes ``nn.remat`` takes;
+- :data:`CUT_KEYS`, the three top-level conf keys that say what *this
+  chip* holds of a deployment — absent, the whole model: ``layers_held``
+  (the first n layers), ``experts_held`` with ``expert_share`` (experts
+  ``[share * held, (share + 1) * held)`` of every expert layer; the
+  router keeps its published width and ``top_k``), and ``ids_held`` (ids
+  ``[0, n)``: embedding, head, logits and loss are over the slice).  No
+  width changes with them;
+- the router's rule between steps.  An expert layer ``sow``s the
+  assignments each of *all* its experts received into the
+  :data:`STEP_STATS` collection; :func:`balance_routers` turns that into
+  every router's next correction bias (``ops/moe.py::balance_bias``,
+  outside the gradient) and into the counts :func:`publish_router_counts`
+  publishes.  A model's ``after_step`` / ``publish_counts`` — the names the
+  step body and the trainer know a model by
+  (``train/steps.py::make_token_step_body``) — call the two.
+"""
+
+from __future__ import annotations
+
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+from flax import linen as nn
+
+from fast_autoaugment_tpu.core import scopes
+from fast_autoaugment_tpu.ops import moe
+from fast_autoaugment_tpu.ops.attention import blocked_causal_attention
+
+__all__ = ["RMSNorm", "SwiGLU", "MLAMixer", "ExpertLayer", "Sizes", "dense",
+           "rotate_by_position", "expert_share_of", "refuse_unwritten_routing",
+           "balance_routers", "publish_router_counts", "INIT", "STEP_STATS",
+           "ROUTING", "CUT_KEYS"]
+
+#: the collection the expert layers ``sow`` a step's loads into
+STEP_STATS = "step_stats"
+#: the collection they ``sow`` every token's chosen experts into
+ROUTING = "routing"
+#: top-level conf keys that say what this chip holds (absent: everything)
+CUT_KEYS = ("layers_held", "experts_held", "ids_held")
+
+INIT = nn.initializers.normal(0.02)
+
+
+class RMSNorm(nn.Module):
+    eps: float = 1e-5
+
+    @nn.compact
+    def __call__(self, x):
+        weight = self.param("weight", nn.initializers.ones, (x.shape[-1],))
+        x32 = x.astype(jnp.float32)
+        x32 = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + self.eps)
+        return (x32 * weight).astype(x.dtype)
+
+
+def dense(features: int, name: str, dtype) -> nn.Dense:
+    return nn.Dense(features, use_bias=False, kernel_init=INIT, name=name,
+                    dtype=dtype)
+
+
+def rotate_by_position(x, theta: float):
+    """Rotary position embedding over the whole last axis of `x` ``[B, T,
+    ..., D]``, token ``t`` at position ``t``: the pair ``(x[2i], x[2i +
+    1])`` turned by ``t * theta ** (-2i / D)`` (DeepSeek-V3's interleaved
+    pairs).  Comes back with the pairs' first members in the first half
+    and the second members in the second, for queries and keys alike, so
+    their products are the interleaved layout's."""
+    length, dim = x.shape[1], x.shape[-1]
+    inverse = theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    angle = jnp.arange(length, dtype=jnp.float32)[:, None] * inverse[None, :]
+    angle = angle.reshape((1, length) + (1,) * (x.ndim - 3) + (dim // 2,))
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    first, second = x[..., 0::2].astype(jnp.float32), x[..., 1::2].astype(jnp.float32)
+    return jnp.concatenate([first * cos - second * sin,
+                            first * sin + second * cos], -1).astype(x.dtype)
+
+
+class MLAMixer(nn.Module):
+    """Latent attention.  `q_rank` None: the query is one product
+    (``q_lora_rank: null``); `rope_theta` None: no rotary."""
+
+    heads: int
+    nope_dim: int
+    pe_dim: int
+    v_dim: int
+    kv_rank: int
+    eps: float
+    dtype: Any = jnp.float32
+    q_rank: int | None = None
+    rope_theta: float | None = None
+
+    @nn.compact
+    def __call__(self, x):
+        batch, length, hidden = x.shape
+        heads = self.heads
+        if self.q_rank is None:
+            q = dense(heads * (self.nope_dim + self.pe_dim), "q_proj", self.dtype)(x)
+        else:
+            q = dense(heads * (self.nope_dim + self.pe_dim), "q_b_proj", self.dtype)(
+                RMSNorm(self.eps, name="q_a_norm")(
+                    dense(self.q_rank, "q_a_proj", self.dtype)(x)))
+        q = q.reshape(batch, length, heads, self.nope_dim + self.pe_dim)
+        latent = dense(self.kv_rank + self.pe_dim, "kv_a_proj", self.dtype)(x)
+        k_pe = latent[..., self.kv_rank:]
+        kv = dense(heads * (self.nope_dim + self.v_dim), "kv_b_proj", self.dtype)(
+            RMSNorm(self.eps, name="kv_a_norm")(latent[..., :self.kv_rank])
+        ).reshape(batch, length, heads, self.nope_dim + self.v_dim)
+        q_nope, k_nope, v = (q[..., :self.nope_dim], kv[..., :self.nope_dim],
+                             kv[..., self.nope_dim:])
+        q_pe = q[..., self.nope_dim:]
+        if self.rope_theta is not None:
+            q_pe = rotate_by_position(q_pe, self.rope_theta)
+            k_pe = rotate_by_position(k_pe, self.rope_theta)
+        with jax.named_scope(scopes.MLA_ATTENTION):
+            out = blocked_causal_attention(
+                q_nope, k_nope, v, q_shared=q_pe, k_shared=k_pe,
+                scale=(self.nope_dim + self.pe_dim) ** -0.5)
+        return dense(hidden, "o_proj", self.dtype)(
+            out.reshape(batch, length, heads * self.v_dim))
+
+
+class SwiGLU(nn.Module):
+    width: int
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        hidden = x.shape[-1]
+        gate = dense(self.width, "gate_proj", self.dtype)(x)
+        up = dense(self.width, "up_proj", self.dtype)(x)
+        return dense(hidden, "down_proj", self.dtype)(jax.nn.silu(gate) * up)
+
+
+class ExpertLayer(nn.Module):
+    """The routed experts this chip holds, beside the shared expert."""
+
+    experts: int
+    held: int
+    share: int
+    top_k: int
+    width: int
+    shared: int
+    scale: float
+    renormalize: bool
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        batch, length, hidden = x.shape
+        flat = x.reshape(batch * length, hidden)
+        first = self.share * self.held
+        with jax.named_scope(scopes.MOE_ROUTER):
+            router = self.param("router", INIT, (hidden, self.experts))
+            # moves the choice, never the weight; no gradient reaches it
+            bias = self.param("e_score_correction_bias", nn.initializers.zeros,
+                              (self.experts,))
+            chosen, weights = moe.route(
+                flat, router.astype(self.dtype), bias, top_k=self.top_k,
+                scale=self.scale, renormalize=self.renormalize)
+            self.sow(STEP_STATS, "load",
+                     moe.assignment_counts(chosen, 0, self.experts))
+            # for a caller that asks (``mutable=[ROUTING]``): which experts
+            # each token chose, to hold a second computation to the same
+            self.sow(ROUTING, "chosen", chosen.reshape(batch, length, self.top_k))
+        shape = (self.held, hidden, self.width)
+        w_gate = self.param("experts_gate", INIT, shape)
+        w_up = self.param("experts_up", INIT, shape)
+        w_down = self.param("experts_down", INIT, (self.held, self.width, hidden))
+        with jax.named_scope(scopes.MOE_EXPERTS):
+            out = moe.held_experts(
+                flat, chosen, weights.astype(self.dtype),
+                w_gate.astype(self.dtype), w_up.astype(self.dtype),
+                w_down.astype(self.dtype), first=first)
+        out = out.reshape(batch, length, hidden)
+        if self.shared:
+            out = out + SwiGLU(self.width * self.shared, self.dtype,
+                               name="shared_experts")(x)
+        return out
+
+
+class Sizes:
+    """The sizes a block needs, hashable so that ``nn.remat`` takes them."""
+
+    def __init__(self, **sizes):
+        self.__dict__.update(sizes)
+        self._key = tuple(sorted((k, v) for k, v in sizes.items()))
+
+    def __hash__(self):
+        return hash(self._key)
+
+    def __eq__(self, other):
+        return isinstance(other, Sizes) and self._key == other._key
+
+    def __repr__(self):
+        return f"Sizes{self._key}"
+
+
+def expert_share_of(conf: Any, experts: int) -> tuple[int, int]:
+    """``(experts_held, expert_share)`` of a conf ``model`` mapping whose
+    expert layers have `experts` experts; refuses what is no share."""
+    held = int(conf.get("experts_held") or experts)
+    share = int(conf.get("expert_share") or 0)
+    if experts % held or not 0 <= share < experts // held:
+        raise ValueError(f"experts_held={held}, expert_share={share}: not a "
+                         f"share of {experts} experts")
+    return held, share
+
+
+def refuse_unwritten_routing(groups: int, chosen_groups: int, activation: str,
+                             layer_freq: int) -> None:
+    """What no token model here has written down of a router."""
+    if groups != 1 or chosen_groups != 1:
+        raise ValueError("grouped top-k over more than one group is not "
+                         "written down")
+    if activation != "sigmoid":
+        raise ValueError("only the sigmoid router is written down")
+    if layer_freq != 1:
+        raise ValueError("moe_layer_freq other than 1 is not written down")
+
+
+def balance_routers(sizes: Sizes, params, stats):
+    """``(params, counts)`` after an optimizer step whose forward pass
+    sowed `stats` (``{layer name: {"moe": {"load": (loads,)}}}``): every
+    expert layer's correction bias moved by the balancing rule, and per
+    layer the assignments the held experts received, in all and the most
+    loaded one's (float32 scalars, for the step's count sums)."""
+    first = sizes.expert_share * sizes.experts_held
+    params, counts = dict(params), {}
+    for layer, entry in sorted(stats.items()):
+        load = sum(entry["moe"]["load"])
+        held = load[first:first + sizes.experts_held].astype(jnp.float32)
+        counts[f"moe_assigned/{layer}"] = jnp.sum(held)
+        counts[f"moe_largest/{layer}"] = jnp.max(held)
+        if sizes.bias_update_rate:
+            layer_params = dict(params[layer])
+            layer_params["moe"] = dict(
+                layer_params["moe"], e_score_correction_bias=moe.balance_bias(
+                    layer_params["moe"]["e_score_correction_bias"], load,
+                    sizes.bias_update_rate))
+            params[layer] = layer_params
+    return params, counts
+
+
+def publish_router_counts(sizes: Sizes, rise: dict, registry) -> None:
+    """What :func:`balance_routers` counted, where the trainer has synced
+    a stretch of steps' sums anyway (`rise`: the sums over the steps since
+    it last published): the counter
+    ``faa_moe_assignments_total{held,layer}`` and the gauge
+    ``faa_moe_held_load_max_over_mean{layer}``, the most loaded held
+    expert's assignments over the held experts' mean, over those steps."""
+    for key, assigned in rise.items():
+        kind, _, layer = key.partition("/")
+        if kind != "moe_assigned":
+            continue
+        registry.counter(
+            "faa_moe_assignments_total",
+            "token-to-expert assignments that fell to experts this chip "
+            "holds", held="true", layer=layer).inc(assigned)
+        largest = rise.get(f"moe_largest/{layer}")
+        if largest is not None and assigned > 0:
+            registry.gauge(
+                "faa_moe_held_load_max_over_mean",
+                "assignments of the most loaded held expert over the held "
+                "experts' mean, a step at a time, over the steps last "
+                "published", layer=layer).set(
+                    largest * sizes.experts_held / assigned)

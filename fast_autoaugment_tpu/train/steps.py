@@ -259,26 +259,51 @@ def make_token_step_body(model, optimizer, *, ema_mu: float = 0.0) -> Callable:
     ``model.after_step(params, sown) -> (params, counts)`` is applied to
     the parameters the optimizer left; `counts` (scalars by name) join the
     step's count sums.  A model without the two names has no such rule.
+
+    A model may have more to say about the loss than one array of logits
+    (a second head behind a second term): it has a method
+    ``loss_terms(inputs, targets) -> (nll [B], top1 [B], further)``, is
+    handed inputs *and* targets and computes the main head's two
+    per-sequence means itself (so it may take its head a block of
+    positions at a time); ``further = {name: (value [B], weight)}`` are
+    terms the loss adds at ``weight * mean(value)`` (weight zero: reported
+    only).  ``loss`` and ``top1`` stay the main head's; each further term
+    is a sum of its own under its name.  A model without the name is
+    applied to the inputs and its logits go into one cross-entropy.
     """
     collection = getattr(model, "step_collection", None)
     mutable = ["batch_stats"] + ([collection] if collection else [])
+    has_terms = hasattr(model, "loss_terms")
 
     def loss_fn(params, batch_stats, ids):
         inputs, targets = ids[:, :-1], ids[:, 1:]
-        with jax.named_scope(scopes.MODEL):
-            logits, mutated = model.apply(
-                {"params": params, "batch_stats": batch_stats}, inputs,
-                train=True, mutable=mutable)
-        with jax.named_scope(scopes.LOSS):
-            nll, correct = _next_token_sums(logits, targets)
-        return nll.mean(), (nll.sum(), correct.sum(),
-                            mutated.get("batch_stats", batch_stats),
-                            mutated.get(collection, {}))
+        variables = {"params": params, "batch_stats": batch_stats}
+        if has_terms:
+            with jax.named_scope(scopes.MODEL):
+                (nll, correct, further), mutated = model.apply(
+                    variables, inputs, targets, mutable=mutable,
+                    method="loss_terms")
+        else:
+            with jax.named_scope(scopes.MODEL):
+                logits, mutated = model.apply(variables, inputs, train=True,
+                                              mutable=mutable)
+            with jax.named_scope(scopes.LOSS):
+                nll, correct = _next_token_sums(logits, targets)
+            further = {}
+        loss = nll.mean()
+        for value, weight in further.values():
+            if weight:
+                loss = loss + weight * value.mean()
+        return loss, (nll.sum(), correct.sum(),
+                      {name: value.sum() for name, (value, _) in further.items()},
+                      mutated.get("batch_stats", batch_stats),
+                      mutated.get(collection, {}))
 
     def step_fn(state: TrainState, ids, labels, policy, key):
         del labels, policy, key
-        (_, (nll, correct, new_batch_stats, stats)), grads = jax.value_and_grad(
-            loss_fn, has_aux=True)(state.params, state.batch_stats, ids)
+        (_, (nll, correct, further, new_batch_stats, stats)), grads = (
+            jax.value_and_grad(loss_fn, has_aux=True)(
+                state.params, state.batch_stats, ids))
         new_state = _advance(state, optimizer, grads, new_batch_stats, ema_mu)
         batch, length = ids.shape[0], ids.shape[1] - 1
         counts = {"tokens": batch * length}
@@ -290,6 +315,7 @@ def make_token_step_body(model, optimizer, *, ema_mu: float = 0.0) -> Callable:
             counts.update(counted)
         with jax.named_scope(scopes.METRICS):
             metrics = {"loss": nll, "top1": correct, "num": jnp.float32(batch),
+                       **further,
                        **{f"{COUNT_PREFIX}{name}": jnp.float32(value)
                           for name, value in counts.items()}}
         return new_state, metrics

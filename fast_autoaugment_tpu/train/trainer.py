@@ -955,12 +955,17 @@ def train_and_eval(
                             batch_stats=jax.tree.map(jnp.copy, state.ema["batch_stats"]),
                         )
                     with telemetry.stage("log"):
-                        for k in ("loss", "top1", "top5"):
-                            if k in train_metrics:  # a token model reports no top-5
+                        # a token model reports no top-5, and may report
+                        # further sums of its own (a second loss term)
+                        further = sorted(set(train_metrics)
+                                         - {"loss", "top1", "top5", "num"})
+                        for k in ("loss", "top1", "top5", *further):
+                            if k in train_metrics:
                                 writers[0].add_scalar(k, train_metrics[k], epoch)
                         logger.info(
-                            "[%s %3d/%3d] loss=%.4f top1=%.4f lr=%.5f",
+                            "[%s %3d/%3d] loss=%.4f top1=%.4f%s lr=%.5f",
                             "train", epoch, epochs, train_metrics["loss"], train_metrics["top1"],
+                            "".join(f" {k}={train_metrics[k]:.4f}" for k in further),
                             float(lr_fn(int(state.step) - 1)),
                         )
 

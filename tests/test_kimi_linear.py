@@ -207,7 +207,14 @@ def test_blocked_attention_is_the_whole_causal_softmax(block, spans, shared):
 # ----------------------------------------------------- the expert layer
 
 
-def _moe_inputs(tokens=64, dim=16, width=8, experts=16, top_k=4, seed=4):
+#: the two token models' expert layers, every width cut: experts, how many a
+#: share holds of the shares that divide them, top-k and the scaling
+SHARES = {"kimi_linear": dict(experts=16, held=4, top_k=4, scale=2.446),
+          "glm4_moe_lite": dict(experts=64, held=8, top_k=4, scale=1.8)}
+
+
+def _moe_inputs(tokens=64, dim=16, width=8, experts=16, top_k=4, seed=4,
+                scale=2.446):
     keys = jax.random.split(jax.random.PRNGKey(seed), 5)
     x = jax.random.normal(keys[0], (tokens, dim))
     router = jax.random.normal(keys[1], (dim, experts))
@@ -215,7 +222,7 @@ def _moe_inputs(tokens=64, dim=16, width=8, experts=16, top_k=4, seed=4):
     w_up = jax.random.normal(keys[3], (experts, dim, width)) * dim ** -0.5
     w_down = jax.random.normal(keys[4], (experts, width, dim)) * width ** -0.5
     chosen, weights = moe.route(x, router, jnp.zeros(experts), top_k=top_k,
-                                scale=2.446)
+                                scale=scale)
     return x, chosen, weights, w_gate, w_up, w_down
 
 
@@ -246,20 +253,25 @@ def test_router_chooses_top_k_of_all_experts_and_renormalises():
                                rtol=1e-5)
 
 
-def test_the_shares_add_up_to_the_uncut_layer():
-    """16 experts over 4 shares of 4: the four routed parts are the whole
-    layer's routed part."""
-    x, chosen, weights, w_gate, w_up, w_down = _moe_inputs()
+@pytest.mark.parametrize("family", sorted(SHARES))
+def test_the_shares_add_up_to_the_uncut_layer(family):
+    """16 experts over 4 shares of 4, or 64 over 8 shares of 8: the routed
+    parts of all the shares are the whole layer's routed part."""
+    sizes = SHARES[family]
+    experts, held, top_k = sizes["experts"], sizes["held"], sizes["top_k"]
+    x, chosen, weights, w_gate, w_up, w_down = _moe_inputs(
+        experts=experts, top_k=top_k, scale=sizes["scale"])
     whole = _every_expert_in_a_loop(x, chosen, weights, w_gate, w_up, w_down)
     shares = [moe.held_experts(
-        x, chosen, weights, w_gate[s:s + 4], w_up[s:s + 4], w_down[s:s + 4],
-        first=s) for s in range(0, 16, 4)]
+        x, chosen, weights, w_gate[s:s + held], w_up[s:s + held],
+        w_down[s:s + held], first=s) for s in range(0, experts, held)]
+    assert len(shares) == experts // held
     _close(sum(shares), whole, 1e-5)
     assert all(float(jnp.abs(share).max()) > 0 for share in shares)
     uncut = moe.held_experts(x, chosen, weights, w_gate, w_up, w_down, first=0)
     _close(uncut, whole, 1e-5)
-    counts = [moe.assignment_counts(chosen, s, 4) for s in range(0, 16, 4)]
-    assert int(sum(c.sum() for c in counts)) == 64 * 4   # no token dropped
+    counts = [moe.assignment_counts(chosen, s, held) for s in range(0, experts, held)]
+    assert int(sum(c.sum() for c in counts)) == 64 * top_k   # no token dropped
 
 
 @pytest.mark.parametrize("rows", [3, 16, 64, 512])
@@ -342,7 +354,21 @@ def test_the_bias_moves_towards_the_mean_load_and_balances_a_skewed_router():
     assert int(after.sum()) == int(before.sum()) == 256 * 4
 
 
-def _tiny_layer_conf(held, share):
+def _tiny_layer_conf(held, share, family="kimi_linear"):
+    if family == "glm4_moe_lite":
+        return {
+            "model": {
+                "type": "glm4_moe_lite", "remat": False, "first_k_dense_replace": 1,
+                "hidden_size": 32, "intermediate_size": 48, "kv_lora_rank": 8,
+                "q_lora_rank": 12, "moe_intermediate_size": 16,
+                "norm_topk_prob": True, "topk_method": "noaux_tc",
+                "num_attention_heads": 2, "n_group": 1, "n_routed_experts": 64,
+                "num_experts_per_tok": 4, "num_hidden_layers": 2,
+                "n_shared_experts": 1, "num_nextn_predict_layers": 1,
+                "qk_nope_head_dim": 8, "qk_rope_head_dim": 4, "rms_norm_eps": 1e-5,
+                "rope_theta": 1e6, "routed_scaling_factor": 1.8, "topk_group": 1,
+                "v_head_dim": 8, "vocab_size": 32, "expert_share": share},
+            "dataset": "synthetic_tokens", "experts_held": held}
     return {
         "model": {
             "type": "kimi_linear", "remat": False, "first_k_dense_replace": 1,
@@ -361,40 +387,50 @@ def _tiny_layer_conf(held, share):
         "dataset": "synthetic_tokens", "experts_held": held}
 
 
-def test_the_models_expert_layer_shares_add_up_with_the_shared_expert_once():
-    """Through the module: layer 2 of a two-layer model holding 4 of 16
-    experts, one share after another with the uncut model's weights; the
-    four routed parts plus the shared expert's, counted once, are the
-    uncut layer's output."""
-    from fast_autoaugment_tpu.models.kimi_linear import ExpertLayer
+@pytest.mark.parametrize("family", sorted(SHARES))
+def test_the_models_expert_layer_shares_add_up_with_the_shared_expert_once(family):
+    """Through the module: layer 2 of a two-layer model, one share after
+    another with the uncut model's weights (4 shares of 4 of 16 experts, or
+    the 8 shares of 8 of 64); the routed parts plus the shared expert's,
+    counted once, are the uncut layer's output — the module's, and the
+    uncut layer of that family's plain reference."""
+    from benchmarks.harness import spec
+    from fast_autoaugment_tpu.models.kimi_linear import ExpertLayer, SwiGLU
 
-    whole = get_model(model_conf_of(_tiny_layer_conf(16, 0)), 32)
+    sizes = SHARES[family]
+    experts, held = sizes["experts"], sizes["held"]
+    whole = get_model(model_conf_of(_tiny_layer_conf(experts, 0, family)), 32)
     ids = jax.random.randint(jax.random.PRNGKey(5), (2, 16), 0, 32)
     params = whole.init({"params": jax.random.PRNGKey(6)}, ids)["params"]
     layer = params["layer2"]["moe"]
     x = jax.random.normal(jax.random.PRNGKey(7), (2, 16, 32))
 
     def apply(held, share, shared, p):
-        module = ExpertLayer(experts=16, held=held, share=share, top_k=4, width=16,
-                             shared=shared, scale=2.446, renormalize=True)
+        module = ExpertLayer(experts=experts, held=held, share=share,
+                             top_k=sizes["top_k"], width=16, shared=shared,
+                             scale=sizes["scale"], renormalize=True)
         return module.apply({"params": p}, x)
 
-    uncut = apply(16, 0, 1, layer)
+    uncut = apply(experts, 0, 1, layer)
     routed = dict(layer)
     shared_expert = routed.pop("shared_experts")
     parts = []
-    for share in range(4):
-        own = dict(routed, **{k: routed[k][4 * share:4 * share + 4]
+    for share in range(experts // held):
+        own = dict(routed, **{k: routed[k][held * share:held * (share + 1)]
                               for k in ("experts_gate", "experts_up", "experts_down")})
-        parts.append(apply(4, share, 0, own))
-    from fast_autoaugment_tpu.models.kimi_linear import SwiGLU
+        parts.append(apply(held, share, 0, own))
     once = SwiGLU(16).apply({"params": shared_expert}, x)
     _close(sum(parts) + once, uncut, 1e-5)
+    reference = spec.load_module("references", family)
+    plain, _ = reference._experts(
+        x.reshape(32, 32), layer, {"top_k": sizes["top_k"], "renormalize": True,
+                                   "routed_scale": sizes["scale"]})
+    _close((sum(parts) + once).reshape(32, 32), plain, 1e-5)
     # and the model is told which experts it holds: a share's parameters
-    held = get_model(model_conf_of(_tiny_layer_conf(4, 2)), 32)
-    shapes = jax.eval_shape(lambda: held.init({"params": jax.random.PRNGKey(6)}, ids))
-    assert shapes["params"]["layer2"]["moe"]["experts_gate"].shape == (4, 32, 16)
-    assert shapes["params"]["layer2"]["moe"]["router"].shape == (32, 16)
+    cut = get_model(model_conf_of(_tiny_layer_conf(held, 2, family)), 32)
+    shapes = jax.eval_shape(lambda: cut.init({"params": jax.random.PRNGKey(6)}, ids))
+    assert shapes["params"]["layer2"]["moe"]["experts_gate"].shape == (held, 32, 16)
+    assert shapes["params"]["layer2"]["moe"]["router"].shape == (32, experts)
 
 
 @pytest.mark.parametrize("bad", [

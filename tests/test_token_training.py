@@ -329,3 +329,167 @@ def test_shipped_conf_is_the_published_model():
     assert shapes["layer2"]["moe"]["experts_gate"].shape == (8, 2304, 1024)
     assert shapes["layer2"]["moe"]["router"].shape == (2304, 256)
     assert shapes["lm_head"]["kernel"].shape == (2304, 20480)
+
+
+# --------------------------------- a model with a second loss term (PR 40)
+
+
+def glm_conf(epochs=3, **top):
+    """A tiny GLM-4.7-Flash (every width cut, the structure kept: a dense
+    layer, four expert layers of which 4 of 8 experts are held, the
+    multi-token-prediction module) on ``synthetic_tokens``."""
+    with open(os.path.join(REPO, "confs", "glm47_flash.yaml")) as fh:
+        conf = yaml.safe_load(fh)
+    conf["model"].update(
+        hidden_size=32, intermediate_size=48, kv_lora_rank=8, q_lora_rank=12,
+        moe_intermediate_size=16, num_attention_heads=2, n_routed_experts=8,
+        num_experts_per_tok=2, qk_nope_head_dim=8, qk_rope_head_dim=4,
+        v_head_dim=8, vocab_size=64)
+    conf.update(layers_held=5, experts_held=4, dataset="synthetic_tokens",
+                batch=BATCH, epoch=epochs, lr=0.02, **top)
+    return Config(conf)
+
+
+@pytest.fixture(scope="module")
+def glm_unbroken(tmp_path_factory):
+    import logging
+
+    path = str(tmp_path_factory.mktemp("glm") / "full.msgpack")
+    lines = []
+
+    class Keep(logging.Handler):
+        def emit(self, record):
+            lines.append(record.getMessage())
+
+    trainer_log = logging.getLogger("faa_tpu.train")
+    handler = Keep()
+    trainer_log.addHandler(handler)
+    registry = telemetry.registry()
+    targets = registry.counter("faa_mtp_targets_total")
+    before = targets.value
+    try:
+        result = _train(path, conf=glm_conf())
+    finally:
+        trainer_log.removeHandler(handler)
+    return path, result, targets.value - before, lines
+
+
+def test_the_shipped_glm_conf_is_the_published_model():
+    with open(os.path.join(REPO, "confs", "glm47_flash.yaml")) as fh:
+        conf = yaml.safe_load(fh)
+    assert not any(key in conf for key in ("layers_held", "experts_held", "ids_held"))
+    shapes = jax.eval_shape(lambda: get_model(model_conf_of(conf), 154880).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))["params"]
+    assert len([k for k in shapes if k.startswith("layer")]) == 47
+    assert shapes["layer47"]["moe"]["experts_gate"].shape == (64, 2048, 1536)
+    assert shapes["layer1"]["mlp"]["up_proj"]["kernel"].shape == (2048, 10240)
+    assert shapes["layer9"]["mla"]["q_b_proj"]["kernel"].shape == (768, 20 * 256)
+    assert shapes["mtp_eh_proj"]["kernel"].shape == (4096, 2048)
+    count = sum(math.prod(a.shape) for a in jax.tree.leaves(shapes))
+    assert 30.5e9 < count < 30.7e9           # 29.94 B and 0.64 B in the module
+
+
+def test_a_second_loss_term_trains_through_train_and_eval(glm_unbroken):
+    _, result, targets, lines = glm_unbroken
+    assert result["steps"] == 3 * STEPS and result["epoch"] == 3
+    # the reported loss stays the main head's; the module's is beside it
+    assert result["loss_train"] < math.log(64) - 0.1
+    assert result["mtp_loss_train"] < math.log(64) and result["mtp_loss_train"] > 0
+    assert 0.0 <= result["mtp_top1_train"] <= 1.0
+    # evaluation computes no module: its sums are the main head's alone
+    assert math.isfinite(result["loss_test"]) and "mtp_loss_test" not in result
+    assert targets == 3 * STEPS * BATCH * (LENGTH - 1)
+    epochs = [line for line in lines if line.startswith("[train")]
+    assert len(epochs) == 3 and all(" mtp_loss=" in line and " mtp_top1=" in line
+                                    for line in epochs)
+    losses = [float(line.split(" loss=")[1].split()[0]) for line in epochs]
+    assert losses[-1] < losses[0]
+    text = telemetry.registry().prometheus_text()
+    assert "faa_mtp_loss " in text
+    assert 'faa_moe_assignments_total{held="true",layer="mtp"}' in text
+    # the trainer's stage tree (PR 38) takes the model as it stands
+    stages = result["stages"]
+    assert stages["train_and_eval.epoch"]["n"] == 3
+    assert stages["train_and_eval.state_init"]["n"] == 1
+    assert any(name.endswith("first_call:train_dispatch") for name in stages)
+
+
+def test_a_preempted_glm_run_resumes_to_the_same_loss(glm_unbroken, tmp_path):
+    full, result, _, _ = glm_unbroken
+    part = str(tmp_path / "part.msgpack")
+    beats = []
+
+    def stop_at_11():
+        beats.append(1)
+        if len(beats) == 11:
+            resilience.request_preemption()
+
+    resilience.clear_preemption()
+    try:
+        with pytest.raises(resilience.PreemptedError):
+            _train(part, conf=glm_conf(), heartbeat=stop_at_11)
+    finally:
+        resilience.clear_preemption()
+    sums = read_metadata(part)["in_epoch"]["sums"]
+    assert {"loss", "mtp_loss", "mtp_top1", f"{COUNT_PREFIX}mtp_targets"} <= set(sums)
+    resumed = _train(part, conf=glm_conf())
+    assert _digest(part) == _digest(full)
+    for key in ("loss_train", "mtp_loss_train", "loss_test"):
+        assert resumed[key] == result[key], key
+
+
+def test_a_model_without_loss_terms_lowers_to_the_step_it_had():
+    """The seam widened for any model: one without ``loss_terms`` (Kimi
+    Linear) is applied to the inputs and its logits go into one
+    cross-entropy, as before — the body's lowered text is the text of the
+    body as PR 35 wrote it, rebuilt here."""
+    from fast_autoaugment_tpu.core import scopes
+    from fast_autoaugment_tpu.train.steps import _advance, _next_token_sums
+
+    conf = _conf()
+    model = get_model(model_conf_of(conf), 64)
+    assert not hasattr(model, "loss_terms")
+    optimizer = build_optimizer(conf["optimizer"], lambda step: 1e-3)
+    collection = model.step_collection
+
+    def body_before(state, ids, labels, policy, key):
+        def loss_fn(params, batch_stats, ids):
+            inputs, targets = ids[:, :-1], ids[:, 1:]
+            with jax.named_scope(scopes.MODEL):
+                logits, mutated = model.apply(
+                    {"params": params, "batch_stats": batch_stats}, inputs,
+                    train=True, mutable=["batch_stats", collection])
+            with jax.named_scope(scopes.LOSS):
+                nll, correct = _next_token_sums(logits, targets)
+            return nll.mean(), (nll.sum(), correct.sum(),
+                                mutated.get("batch_stats", batch_stats),
+                                mutated.get(collection, {}))
+
+        (_, (nll, correct, new_batch_stats, stats)), grads = jax.value_and_grad(
+            loss_fn, has_aux=True)(state.params, state.batch_stats, ids)
+        new_state = _advance(state, optimizer, grads, new_batch_stats, 0.0)
+        counts = {"tokens": ids.shape[0] * (ids.shape[1] - 1)}
+        with jax.named_scope(scopes.OPTIMIZER):
+            params, counted = model.after_step(
+                new_state.params, jax.lax.stop_gradient(stats))
+        new_state = new_state.replace(params=params)
+        counts.update(counted)
+        with jax.named_scope(scopes.METRICS):
+            metrics = {"loss": nll, "top1": correct,
+                       "num": jnp.float32(ids.shape[0]),
+                       **{f"{COUNT_PREFIX}{name}": jnp.float32(value)
+                          for name, value in counts.items()}}
+        return new_state, metrics
+
+    ids = jnp.asarray(load_dataset("synthetic_tokens", "")[0].images[:BATCH])
+    state = jax.eval_shape(lambda: create_train_state(
+        model, optimizer, jax.random.PRNGKey(0), ids[:, :-1], use_ema=False))
+    arguments = (state, ids, jnp.zeros(BATCH, jnp.int32), None, None)
+
+    def lowered(body):
+        text = jax.jit(body).lower(*arguments).as_text()
+        return text.replace("jit_body_before", "jit_step_fn")
+
+    now = lowered(make_token_step_body(model, optimizer))
+    assert now == lowered(body_before)
+    assert len(now) > 100_000
