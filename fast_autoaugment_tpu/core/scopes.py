@@ -91,8 +91,12 @@ RESNET_STEM = "faa_resnet_stem"
 #: (projections, short convolutions, gates, output norm and gate) with the
 #: chunked delta-rule recurrence alone inside it (``ops/kda.py``, forward
 #: and backward); the latent-attention mixer, with its attention core alone
-#: inside it (``ops/attention.py::blocked_causal_attention``: scores,
-#: softmax, weighted sum; forward, backward and what is computed again); an
+#: inside it (``ops/attention.py::blocked_causal_attention``: at the
+#: configurations' shapes the fused kernels ``mla_attention_forward`` and,
+#: under ``transpose(jvp(...))``, ``mla_attention_backward`` with the XLA
+#: operations that lay their operands out, else scores, softmax and
+#: weighted sum a block of queries at a time; forward, backward and what
+#: ``nn.remat`` computes again); an
 #: expert layer (its shared expert included) with the router (scores, top-k,
 #: every expert's load) and the held experts' part (the assignments sorted
 #: by expert, a loop over the blocks of rows the routing filled: gather,

@@ -1,38 +1,347 @@
-"""Causal softmax attention computed a block of queries at a time.
+"""Causal softmax attention whose scores never reach HBM.
 
-Whole, the scores of 32 heads over 8,192 tokens are 8.6 GB in float32.
-:func:`blocked_causal_attention` walks the queries in blocks, one whole
-softmax a block, inside a ``lax.scan`` — a loop, so that one block's
-scores are live at a time in the forward pass and in the backward pass
-alike (as independent blocks the compiler scheduled all of them at once:
-9.3 GB of temporaries in the step of Kimi Linear's cut, and an
-``optimization_barrier`` chain ordered the forward pass alone;
-compile-time analysis for the v5e, PR 35).  A block's body is wrapped in
-``jax.checkpoint``: the backward pass computes its scores again from
-``q``, ``k`` and ``v`` rather than keeping every block's probabilities.
+``softmax(causal(q k^T + q_shared k_shared^T) * scale) v``: the score of
+latent attention (DeepSeek-V2's MLA; Kimi Linear runs it without rotary,
+GLM-4.7-Flash with) has two parts, a head's own ``q_nope . k_nope`` and
+``q_pe . k_pe`` against a key part that all heads share.  Whole, the
+scores of 32 heads over 8,192 tokens are 8.6 GB in float32.
 
-A loop has one shape for all its steps, so a block cannot meet just the
-keys before it.  The sequence is therefore cut into `spans` static
-spans, each with a loop of its own over keys that end where the span
-ends: with four spans 62.5% of the full square is computed (the causal
-half is 50%, whole blocks of 512 would need 53%).
+**Two forms, chosen by shape** (:func:`_fused_tile`).  Where a head's
+values are whole lanes (a multiple of 128), its own key at least 128
+wide, the sequence at least two tiles long and one head's whole sequence
+inside the kernels' VMEM budget (~12.8 k tokens at 256 + 256) — both
+configurations' shapes: 128 + 64 / 128 and 192 + 64 / 256 at 8,192
+tokens —
+:func:`blocked_causal_attention` runs a pair of fused TPU kernels under
+a ``jax.custom_vjp`` (:func:`_fused_attention`; interpreted where the
+backend is no TPU, so a CPU test runs the code the chip runs).  The tile
+follows from the sequence length (512, else 256 or 128: the largest that
+divides it at least twice).  The shared key part is copied to every head
+and the key padded with zeros to whole lanes in HBM (256 a head in both
+configurations: 42 MB a layer in bfloat16) so that one product a tile
+serves both parts of the score; differentiating that copy sums the
+part's gradient over the heads.  The kernels read ``[B, T, H, D]`` as it
+lies, a head's tile a strided block of ``[B, T, H * D]``: nothing is
+transposed.
 
-The score of latent attention (DeepSeek-V2's MLA, as Kimi Linear runs it
-without rotary) has two parts: a head's own ``q_nope . k_nope`` and
-``q_pe . k_pe`` against a key part that all heads share, so `k_shared`
-is taken apart and never copied to every head.
+*Forward* (:func:`_forward_kernel`): a grid step is one tile of one
+head's queries.  That head's keys and values, the whole sequence, sit in
+VMEM (fetched once a head: 8 MB in bfloat16), and the step walks the key
+tiles up to the diagonal — a loop whose length is the step's own, so the
+tiles above the diagonal are never met and only the one on it is masked
+— with a running maximum, a running sum and the output rescaled as the
+maximum grows (the online softmax).  A tile's scores ``[512, 512]`` live
+in VMEM from their product to their weighted sum.  Out come the
+attention's output and every row's log-sum-exp ``[B, H, T]``.
+
+*Backward* (:func:`_backward_kernel`), written by hand: a grid step is
+one tile of one head's keys against that head's query tiles from the
+diagonal on, with ``q``, the output's cotangent and ``dq`` whole in VMEM
+(``dq`` gathers every key tile's share there and is written once a
+head).  A tile's probabilities are computed again from ``q``, ``k`` and
+the kept log-sum-exp, and the five products of a tile (scores, the
+weights' cotangent, ``dv``, ``dk``, ``dq``) follow.  The tile is held
+keys-by-queries: what belongs to a query (its log-sum-exp, ``delta =
+sum(o * do)``) is then a row, and no sum runs along the lanes.  The
+residuals are the operands as the products take them, the output and the
+log-sum-exp: no probability is kept, and nothing is under
+``jax.checkpoint``.
+
+Any other shape (the tests' heads of 8 / 5 / 4, a sequence under two
+tiles or one no tile divides) takes :func:`_blocked_xla`: queries in
+blocks, one whole softmax a block, inside a ``lax.scan`` — a loop, so
+that one block's scores are live at a time in the forward pass and in
+the backward pass alike.  A block's body is wrapped in
+``jax.checkpoint``.  A loop has one shape for all its steps, so the
+sequence is cut into `spans` static spans, each with a loop of its own
+over keys that end where the span ends: with four spans 62.5% of the full
+square is computed.  `k_shared` is taken apart there and never copied.
+
+The products take the ambient matmul precision, as an ``einsum`` does
+(``ops/kda.py``'s rule): on the chip by default the kernels round their
+operands to bfloat16 (in HBM, once: the residuals are the rounded
+copies) and sum in float32, the softmax is float32; under
+``jax.default_matmul_precision("highest")`` every product is float32.
+
+``faa_mla_attention_traces_total{form}`` counts, at trace time, which
+form a program got: ``fused`` or ``blocked_xla``.
 """
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from fast_autoaugment_tpu.core import telemetry
+from fast_autoaugment_tpu.ops import kda
 
 __all__ = ["blocked_causal_attention", "DEFAULT_QUERY_BLOCK", "DEFAULT_SPANS"]
 
 DEFAULT_QUERY_BLOCK = 512
 DEFAULT_SPANS = 4
+LANES = kda.LANES
+#: the tiles of the fused kernels, largest first: a sequence takes the
+#: first that divides it at least twice
+TILES = (512, 256, 128)
+#: what the kernels may keep in VMEM (the v5e has 128 MiB)
+VMEM_LIMIT_BYTES = 100 * 1024 * 1024
 
+
+def blocked_causal_attention(q, k, v, *, scale: float, q_shared=None,
+                             k_shared=None, block: int = DEFAULT_QUERY_BLOCK,
+                             spans: int = DEFAULT_SPANS):
+    """``softmax(causal(q k^T + q_shared k_shared^T) * scale) v``.
+
+    `q`, `k`: ``[B, T, H, D]``; `v`: ``[B, T, H, Dv]``; `q_shared`
+    ``[B, T, H, Ds]`` with `k_shared` ``[B, T, Ds]`` (one key part for all
+    heads), or neither.  Returns ``[B, T, H, Dv]``.  `block` and `spans`
+    shape the XLA form alone; the kernels' tile follows from the shapes."""
+    tile = _fused_tile(q, v, q_shared)
+    # trace time: which form each program that holds an attention core got
+    telemetry.registry().counter(
+        "faa_mla_attention_traces_total", "latent-attention cores traced into a "
+        "program, by the form that computes them",
+        form="blocked_xla" if tile is None else "fused").inc()
+    if tile is None:
+        return _blocked_xla(q, k, v, q_shared, k_shared, scale, block, spans)
+    batch, length, heads, _ = q.shape
+    if q_shared is not None:
+        # the shared key part, copied to every head: one product a tile
+        # then serves both parts of the score, and differentiating this
+        # line sums the part's gradient over the heads
+        q = jnp.concatenate([q, q_shared], -1)
+        k = jnp.concatenate([k, jnp.broadcast_to(
+            k_shared[:, :, None], (batch, length, heads, k_shared.shape[-1]))], -1)
+    short = -q.shape[-1] % LANES      # zeros: they add nothing to a score
+    q, k = (jnp.pad(a, ((0, 0),) * 3 + ((0, short),)) for a in (q, k))
+    return _fused_attention(q, k, v, float(scale), tile, kda._float32_products(),
+                            not kda._on_tpu())
+
+
+def _fused_tile(q, v, q_shared) -> int | None:
+    """The tile of the fused kernels for these shapes, or None where they
+    take the XLA form: a head's values are whole lanes and its own key at
+    least one row of them, the sequence is at least two tiles, and what
+    the kernels keep of one head's whole sequence fits VMEM."""
+    length = q.shape[1]
+    if v.shape[-1] % LANES or q.shape[-1] < LANES:
+        return None
+    width = q.shape[-1] + (0 if q_shared is None else q_shared.shape[-1])
+    width += -width % LANES
+    # the backward kernel's: q and the cotangent, float32 at the most, and
+    # dq, each twice (the pipeline's two buffers)
+    if 2 * 4 * length * (2 * width + v.shape[-1]) > VMEM_LIMIT_BYTES * 3 // 4:
+        return None
+    return next((t for t in TILES if length % t == 0 and length >= 2 * t), None)
+
+
+# ------------------------------------------------------- the fused kernels
+#
+# Both kernels see ``[B, T, H, D]`` as ``[B, T, H * D]``, the same bytes: a
+# head's ``[tile, D]`` is then a block whose last dimension is whole lanes,
+# fetched by a strided DMA, and nothing is transposed in HBM.
+
+def _causal(tile: int, *, keys_first: bool):
+    """``[tile, tile]``: whether the query sees the key, on the diagonal."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 1)
+    return col >= row if keys_first else row >= col
+
+
+def _as_row(column):
+    """``[n, 1]`` -> ``[1, n]`` with nothing but masks and sums over
+    sublanes: 128 rows at a time against the identity."""
+    size = column.shape[0]
+    row = jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 1)
+    return jnp.concatenate([
+        jnp.sum(jnp.where(row == col, column[first:first + LANES], 0.0), 0,
+                keepdims=True)
+        for first in range(0, size, LANES)], 1)
+
+
+def _forward_kernel(q_ref, k_ref, v_ref, o_ref, *lse_ref, scale: float, exact: bool):
+    """One tile of one head's queries against that head's keys up to the
+    diagonal: `k_ref`, `v_ref` hold the head's whole sequence (fetched once
+    a head), a tile of scores lives from its product to its weighted sum,
+    and the softmax is the running one (maximum, sum, rescaled output).
+    `lse_ref` ``[1, 1, N, tile]``, where a backward pass follows: every
+    row's log-sum-exp, a tile a row."""
+    i = pl.program_id(2)
+    tile = q_ref.shape[1]
+    q = q_ref[0]
+
+    def against(j, carry, seen=None):
+        top, total, out = carry
+        keys = pl.ds(pl.multiple_of(j * tile, tile), tile)
+        scores = kda._dot(q, k_ref[0, keys, :], kda._NT, exact) * scale
+        if seen is not None:
+            scores = jnp.where(seen, scores, -jnp.inf)
+        new_top = jnp.maximum(top, jnp.max(scores, -1, keepdims=True))
+        shrink = jnp.exp(top - new_top)
+        weights = jnp.exp(scores - new_top)
+        total = shrink * total + jnp.sum(weights, -1, keepdims=True)
+        out = shrink * out + kda._dot(weights, v_ref[0, keys, :], kda._NN, exact)
+        return new_top, total, out
+
+    carry = (jnp.full((tile, 1), -jnp.inf, jnp.float32),
+             jnp.zeros((tile, 1), jnp.float32),
+             jnp.zeros((tile, v_ref.shape[-1]), jnp.float32))
+    # the key tiles wholly below the diagonal, then the one on it
+    carry = jax.lax.fori_loop(0, i, against, carry)
+    top, total, out = against(i, carry, _causal(tile, keys_first=False))
+    o_ref[0] = out / total
+    for ref in lse_ref:
+        ref[0, 0, pl.ds(i, 1), :] = _as_row(top + jnp.log(total))
+
+
+def _backward_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                     dq_ref, dk_ref, dv_ref, *, scale: float, exact: bool):
+    """One tile of one head's keys against that head's queries from the
+    diagonal on: `q_ref`, `do_ref` and `dq_ref` hold the head's whole
+    sequence (`dq_ref` stays in VMEM while the key tiles go by and gathers
+    every tile's share).  A tile's probabilities are computed again from
+    `q`, `k` and the log-sum-exp, keys along the rows: what belongs to a
+    query (`lse`, `delta = sum(o * do)`) is then a row vector, and no sum
+    runs along the lanes."""
+    j = pl.program_id(2)
+    tile = k_ref.shape[1]
+    count = q_ref.shape[1] // tile
+    k, v = k_ref[0], v_ref[0]
+
+    @pl.when(j == 0)
+    def _():
+        dq_ref[...] = jnp.zeros_like(dq_ref)
+
+    def against(i, carry, seen=None):
+        dk, dv = carry
+        queries = pl.ds(pl.multiple_of(i * tile, tile), tile)
+        q, do = q_ref[0, queries, :], do_ref[0, queries, :]
+        scores = kda._dot(k, q, kda._NT, exact) * scale             # [keys, queries]
+        weights = jnp.exp(scores - lse_ref[0, 0, pl.ds(i, 1), :])
+        if seen is not None:
+            weights = jnp.where(seen, weights, 0.0)
+        d_weights = kda._dot(v, do, kda._NT, exact)
+        d_scores = weights * (d_weights - delta_ref[0, 0, pl.ds(i, 1), :]) * scale
+        weights, d_scores = kda._operand(weights, exact), kda._operand(d_scores, exact)
+        dq_ref[0, queries, :] += kda._dot(d_scores, k, kda._TN, exact)
+        return (dk + kda._dot(d_scores, q, kda._NN, exact),
+                dv + kda._dot(weights, do, kda._NN, exact))
+
+    carry = against(j, (jnp.zeros(k.shape, jnp.float32), jnp.zeros(v.shape, jnp.float32)),
+                    _causal(tile, keys_first=True))
+    dk_ref[0], dv_ref[0] = jax.lax.fori_loop(j + 1, count, against, carry)
+
+
+class _Blocks:
+    """What both kernels' ``pallas_call``s share: the grid (batch, head,
+    tile of the sequence) and the blocks of ``[B, T, H * D]`` and of the
+    rows' ``[B, H, N, tile]``."""
+
+    def __init__(self, q, tile: int, interpret: bool):
+        self.batch, self.length, self.heads, _ = q.shape
+        self.tile = tile
+        self.count = self.length // tile
+        self.options = dict(
+            grid=(self.batch, self.heads, self.count), interpret=interpret,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary"),
+                vmem_limit_bytes=VMEM_LIMIT_BYTES))
+
+    def one(self, width):       # a tile of one head
+        return pl.BlockSpec((1, self.tile, width), lambda b, h, n: (b, n, h))
+
+    def whole(self, width):     # one head's whole sequence
+        return pl.BlockSpec((1, self.length, width), lambda b, h, n: (b, 0, h))
+
+    @property
+    def rows(self):             # of [B, H, N, tile]
+        return pl.BlockSpec((1, 1, self.count, self.tile), lambda b, h, n: (b, h, 0, 0))
+
+    @property
+    def rows_shape(self):
+        return jax.ShapeDtypeStruct((self.batch, self.heads, self.count, self.tile),
+                                    jnp.float32)
+
+
+def _flat(a):
+    """``[B, T, H, D]`` -> ``[B, T, H * D]``: the same bytes."""
+    return a.reshape(a.shape[0], a.shape[1], -1)
+
+
+# Jitted by themselves, as ``ops/kda.py``'s are: a model's blocks call them
+# at the same shapes, and a ``jit`` inside a trace is traced and lowered to
+# Mosaic once a program.
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "tile", "exact", "interpret", "keep"))
+def _forward(q, k, v, scale: float, tile: int, exact: bool, interpret: bool, keep: bool):
+    """``out [B, T, H, Dv]`` and, with `keep`, the rows' log-sum-exp ``[B,
+    H, N, tile]``; `q`, `k`, `v` as the products take them."""
+    blocks = _Blocks(q, tile, interpret)
+    width, vdim = q.shape[-1], v.shape[-1]
+    out_shape = [jax.ShapeDtypeStruct(_flat(v).shape, jnp.float32)]
+    out_specs = [blocks.one(vdim)]
+    if keep:
+        out_shape.append(blocks.rows_shape)
+        out_specs.append(blocks.rows)
+    out, *lse = pl.pallas_call(
+        functools.partial(_forward_kernel, scale=scale, exact=exact),
+        out_shape=out_shape,
+        in_specs=[blocks.one(width), blocks.whole(width), blocks.whole(vdim)],
+        out_specs=out_specs, name="mla_attention_forward", **blocks.options,
+    )(_flat(q), _flat(k), _flat(v))
+    return (out.reshape(v.shape), *lse)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "tile", "exact", "interpret"))
+def _backward(q, k, v, out, lse, d_out, scale: float, tile: int, exact: bool,
+              interpret: bool):
+    blocks = _Blocks(q, tile, interpret)
+    width, vdim = q.shape[-1], v.shape[-1]
+    # what every score of a row owes through the row's sum
+    delta = jnp.sum(out * d_out, -1).transpose(0, 2, 1).reshape(lse.shape)
+    like = lambda a: jax.ShapeDtypeStruct(_flat(a).shape, jnp.float32)
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_backward_kernel, scale=scale, exact=exact),
+        out_shape=[like(q), like(k), like(v)],
+        in_specs=[blocks.whole(width), blocks.one(width), blocks.one(vdim),
+                  blocks.whole(vdim), blocks.rows, blocks.rows],
+        out_specs=[blocks.whole(width), blocks.one(width), blocks.one(vdim)],
+        name="mla_attention_backward", **blocks.options,
+    )(_flat(q), _flat(k), _flat(v), _flat(kda._operand(d_out, exact)), lse, delta)
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _fused_attention(q, k, v, scale: float, tile: int, exact: bool, interpret: bool):
+    """The causal softmax through the kernels; `q`, `k` ``[B, T, H, D]``
+    with D whole lanes (every part of the score in it), float32.  `exact`:
+    float32 products; `interpret`: no TPU to compile them for."""
+    q, k, v = (kda._operand(a, exact) for a in (q, k, v))
+    return _forward(q, k, v, scale, tile, exact, interpret, keep=False)[0]
+
+
+def _fused_attention_fwd(q, k, v, scale: float, tile: int, exact: bool, interpret: bool):
+    # kept as the products take them: bfloat16 unless `exact`
+    q, k, v = (kda._operand(a, exact) for a in (q, k, v))
+    out, lse = _forward(q, k, v, scale, tile, exact, interpret, keep=True)
+    return out, (q, k, v, out, lse)
+
+
+def _fused_attention_bwd(scale: float, tile: int, exact: bool, interpret: bool,
+                         residuals, d_out):
+    return _backward(*residuals, d_out, scale, tile, exact, interpret)
+
+
+_fused_attention.defvjp(_fused_attention_fwd, _fused_attention_bwd)
+
+
+# ------------------------------------------------- the form in jnp and XLA
 
 def _attend(q, q_shared, k, k_shared, v, first, scale: float):
     """One block of queries, whose first token is token `first`, against
@@ -47,14 +356,9 @@ def _attend(q, q_shared, k, k_shared, v, first, scale: float):
     return jnp.einsum("bhqk,bkhd->bqhd", weights, v)
 
 
-def blocked_causal_attention(q, k, v, *, scale: float, q_shared=None,
-                             k_shared=None, block: int = DEFAULT_QUERY_BLOCK,
-                             spans: int = DEFAULT_SPANS):
-    """``softmax(causal(q k^T + q_shared k_shared^T) * scale) v``.
-
-    `q`, `k`: ``[B, T, H, D]``; `v`: ``[B, T, H, Dv]``; `q_shared`
-    ``[B, T, H, Ds]`` with `k_shared` ``[B, T, Ds]`` (one key part for all
-    heads), or neither.  Returns ``[B, T, H, Dv]``."""
+def _blocked_xla(q, k, v, q_shared, k_shared, scale: float, block: int, spans: int):
+    """:func:`blocked_causal_attention` for the shapes the kernels do not
+    take: queries in blocks of `block`, inside `spans` ``lax.scan``s."""
     batch, length = q.shape[:2]
     block = min(block, length)
     if length % block:

@@ -458,8 +458,10 @@ def train_and_eval(
             ema_mu = float(optimizer_conf.get("ema", 0.0) or 0.0)
 
             if tokens:
-                # parameter shapes do not depend on the length: a short sample
-                sample = jnp.zeros((1, min(total_train.images.shape[1] - 1, 64)), jnp.int32)
+                # parameter shapes do not depend on the length: a short sample,
+                # of the shortest length at which every operation takes the form
+                # a step's length takes (ops/attention.py: two tiles of 128)
+                sample = jnp.zeros((1, min(total_train.images.shape[1] - 1, 256)), jnp.int32)
             else:
                 sample = jnp.zeros((2, image, image, 3), jnp.float32)
             rng = jax.random.PRNGKey(seed)

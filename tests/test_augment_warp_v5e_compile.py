@@ -89,25 +89,44 @@ def test_fused_kda_kernels_compile_for_a_v5e(one_chip, no_compile_cache,
     assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
 
 
-def test_blocked_attention_keeps_one_blocks_scores_live(one_chip, no_compile_cache):
-    """The MLA layer's softmax at 8,192 tokens and 32 heads: whole, the
-    scores are 8.6 GB; blocked in loops, forward and backward stay under
-    a quarter of that."""
+@pytest.mark.parametrize("ambient", ["default", "highest"])
+@pytest.mark.parametrize("heads, dim, shared, vdim", [(32, 128, 64, 128), (20, 192, 64, 256)],
+                         ids=["kimi_linear_48b_a3b_train", "glm47_flash_train"])
+def test_fused_attention_kernels_compile_for_a_v5e(one_chip, no_compile_cache, monkeypatch,
+                                                   heads, dim, shared, vdim, ambient):
+    """One latent-attention core of each token cell at 8,192 tokens,
+    through the kernels as the chip gets them, with bfloat16 operands and
+    under ``highest``.  Forward and backward are one Mosaic kernel each.
+    Whole, the scores of 32 heads are 8.6 GB in float32 and the XLA form
+    kept up to a quarter of that; beside the kernels HBM holds the
+    operands as the products take them (the shared key part copied to
+    every head, padded to whole lanes: 256 a head in both cells), their
+    gradients and the rows' sums, and nothing the size of a tile's scores
+    times the sequence."""
+    from fast_autoaugment_tpu.ops import kda
     from fast_autoaugment_tpu.ops.attention import blocked_causal_attention
+
+    monkeypatch.setattr(kda, "_on_tpu", lambda: True)
 
     def shape(*dims):
         return jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
 
-    q, k, v = shape(1, 8192, 32, 128), shape(1, 8192, 32, 128), shape(1, 8192, 32, 128)
-    q_pe, k_pe = shape(1, 8192, 32, 64), shape(1, 8192, 64)
+    args = (shape(1, 8192, heads, dim), shape(1, 8192, heads, dim),
+            shape(1, 8192, heads, vdim), shape(1, 8192, heads, shared),
+            shape(1, 8192, shared))
 
     def scalar(q, k, v, q_pe, k_pe):
         return jnp.sum(blocked_causal_attention(
-            q, k, v, q_shared=q_pe, k_shared=k_pe, scale=192 ** -0.5))
+            q, k, v, q_shared=q_pe, k_shared=k_pe, scale=(dim + shared) ** -0.5))
 
-    compiled = jax.jit(jax.grad(scalar, argnums=(0, 1, 2, 3, 4))).lower(
-        q, k, v, q_pe, k_pe).compile()
-    assert compiled.memory_analysis().temp_size_in_bytes < 0.25 * 8.6e9
+    with jax.default_matmul_precision(ambient):
+        compiled = jax.jit(jax.grad(scalar, argnums=(0, 1, 2, 3, 4))).lower(*args).compile()
+    text = compiled.as_text()
+    assert "mla_attention_forward" in text and "mla_attention_backward" in text
+    assert text.count("tpu_custom_call") == 2
+    # q and k at 256 a head and v, as operands (float32 under `highest`)
+    # and as gradients: 1.2 GB at the most, 32 heads under `highest`
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.4e9
 
 
 def test_grouped_experts_and_their_backward_compile_for_a_v5e(one_chip, no_compile_cache):

@@ -167,7 +167,7 @@ def test_the_kernels_products_take_the_ambient_precision(monkeypatch, ambient,
         assert kda._float32_products() is exact
 
 
-# ------------------------------------------------- the blocked softmax
+# ------------------------------------------------- the causal softmax
 
 
 def _whole_softmax(q, k, v, q_shared, k_shared, scale):
@@ -180,28 +180,156 @@ def _whole_softmax(q, k, v, q_shared, k_shared, scale):
     return jnp.einsum("bhqk,bkhd->bqhd", weights, v)
 
 
+def _attention_inputs(shared, batch=2, length=64, heads=3, dim=8, shared_dim=5, vdim=4):
+    keys = jax.random.split(jax.random.PRNGKey(2), 5)
+    return (jax.random.normal(keys[0], (batch, length, heads, dim)),
+            jax.random.normal(keys[1], (batch, length, heads, dim)),
+            jax.random.normal(keys[2], (batch, length, heads, vdim)),
+            jax.random.normal(keys[3], (batch, length, heads, shared_dim)) if shared else None,
+            jax.random.normal(keys[4], (batch, length, shared_dim)) if shared else None)
+
+
+def _attention_form_counts():
+    counters = telemetry.registry().counters_snapshot()
+    return {form: counters.get(f'faa_mla_attention_traces_total{{form="{form}"}}', 0.0)
+            for form in ("fused", "blocked_xla")}
+
+
 @pytest.mark.parametrize("block, spans, shared", [
     (8, 4, True), (8, 2, False), (16, 4, True), (64, 4, True), (32, 1, False)])
 def test_blocked_attention_is_the_whole_causal_softmax(block, spans, shared):
-    keys = jax.random.split(jax.random.PRNGKey(2), 5)
-    q = jax.random.normal(keys[0], (2, 64, 3, 8))
-    k = jax.random.normal(keys[1], (2, 64, 3, 8))
-    v = jax.random.normal(keys[2], (2, 64, 3, 4))
-    q_shared = jax.random.normal(keys[3], (2, 64, 3, 5)) if shared else None
-    k_shared = jax.random.normal(keys[4], (2, 64, 5)) if shared else None
+    q, k, v, q_shared, k_shared = _attention_inputs(shared)
 
     def blocked(q, k, v):
         return blocked_causal_attention(q, k, v, scale=0.3, q_shared=q_shared,
                                         k_shared=k_shared, block=block, spans=spans)
 
+    before = _attention_form_counts()
     whole = _whole_softmax(q, k, v, q_shared, k_shared, 0.3)
     _close(jax.jit(blocked)(q, k, v), whole, 1e-5)
+    after = _attention_form_counts()
+    assert after["fused"] == before["fused"]  # a head of 8: the XLA body's
+    assert after["blocked_xla"] > before["blocked_xla"]
     plain = jax.grad(lambda *a: jnp.sum(jnp.sin(_whole_softmax(
         *a, q_shared, k_shared, 0.3))), argnums=(0, 1, 2))(q, k, v)
     ours = jax.grad(lambda *a: jnp.sum(jnp.sin(blocked(*a))),
                     argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(ours, plain):
         _close(a, b, 1e-4)
+
+
+#: both configurations' head widths (own key, shared key, value), which go
+#: through the fused kernels (interpreted here: the code the chip runs).
+#: 384 tokens are three tiles of 128: a key tile is the one on the
+#: diagonal, one wholly below it, or never met
+WIDTHS = {"kimi_linear": dict(dim=128, shared_dim=64, vdim=128),
+          "glm4_moe_lite": dict(dim=192, shared_dim=64, vdim=256)}
+NATIVE_LENGTH = 384
+_ATTENTION_ARGUMENTS = ["q", "k", "v", "q_shared", "k_shared"]
+#: how far from the whole float32 softmax: float32 products (what
+#: ``highest`` asks) as the small shapes above, bfloat16 operands (the
+#: chip's default) inside the tighter of the deployed limits
+_ATTENTION_TOLERANCES = {"highest": (1e-5, 1e-4), "default": (0.02, 0.02)}
+
+
+@functools.lru_cache(maxsize=None)
+def _native_attention(family: str, shared: bool, ambient: str):
+    """``(ours, the whole softmax's)``, each the output and the gradients
+    of every argument there is."""
+    widths = WIDTHS[family]
+    args = tuple(a for a in _attention_inputs(
+        shared, batch=1, length=NATIVE_LENGTH, heads=2, **widths) if a is not None)
+    scale = (widths["dim"] + widths["shared_dim"] * shared) ** -0.5
+
+    def ours(q, k, v, q_shared=None, k_shared=None):
+        return blocked_causal_attention(q, k, v, scale=scale, q_shared=q_shared,
+                                        k_shared=k_shared)
+
+    def whole(q, k, v, q_shared=None, k_shared=None):
+        return _whole_softmax(q, k, v, q_shared, k_shared, scale)
+
+    def both(fn):
+        every = tuple(range(len(args)))
+        return (fn(*args),) + jax.grad(
+            lambda *a: jnp.sum(jnp.sin(fn(*a))), argnums=every)(*args)
+
+    # off the chip the ambient precision is float32 whatever it says: the
+    # operands are rounded as the chip's default would round them
+    exact = {"highest": kda._float32_products, "default": lambda: False}[ambient]
+    with pytest.MonkeyPatch.context() as patch, jax.default_matmul_precision("highest"):
+        patch.setattr(kda, "_float32_products", exact)
+        return both(jax.jit(ours)), both(whole)
+
+
+@pytest.mark.parametrize("ambient", ["highest", "default"])
+@pytest.mark.parametrize("shared", [True, False], ids=["shared", "own"])
+@pytest.mark.parametrize("family", sorted(WIDTHS))
+def test_fused_attention_is_the_whole_causal_softmax(family, shared, ambient):
+    ours, whole = _native_attention(family, shared, ambient)
+    _close(ours[0], whole[0], _ATTENTION_TOLERANCES[ambient][0])
+
+
+@pytest.mark.parametrize("ambient", ["highest", "default"])
+@pytest.mark.parametrize("argnum, shared", [
+    pytest.param(n, shared, id=f"{name}-{'shared' if shared else 'own'}")
+    for shared in (True, False)
+    for n, name in enumerate(_ATTENTION_ARGUMENTS[:5 if shared else 3])])
+@pytest.mark.parametrize("family", sorted(WIDTHS))
+def test_fused_attentions_gradient_is_the_whole_softmaxs(family, argnum, shared, ambient):
+    ours, whole = _native_attention(family, shared, ambient)
+    _close(ours[1 + argnum], whole[1 + argnum], _ATTENTION_TOLERANCES[ambient][1])
+
+
+@pytest.mark.parametrize("widths, length, form", [
+    (dict(), 64, "blocked_xla"), (WIDTHS["kimi_linear"], 256, "fused"),
+    (WIDTHS["glm4_moe_lite"], 1024, "fused"),
+    # a sequence under two tiles, and one no tile divides
+    (WIDTHS["glm4_moe_lite"], 128, "blocked_xla"),
+    (WIDTHS["kimi_linear"], 320, "blocked_xla")],
+    ids=["small", "kimi_linear", "glm4_moe_lite", "one-tile", "no-tile"])
+def test_the_attentions_counter_counts_the_form_the_trace_took(widths, length, form):
+    q, k, v, q_shared, k_shared = _attention_inputs(
+        True, batch=1, length=length, heads=2, **widths)
+    before = _attention_form_counts()
+    jax.eval_shape(functools.partial(blocked_causal_attention, scale=0.1, block=64),
+                   q, k, v, q_shared=q_shared, k_shared=k_shared)
+    after = _attention_form_counts()
+    assert {f: after[f] - before[f] for f in after} == {
+        f: float(f == form) for f in after}
+
+
+def _sizes(jaxpr):
+    """The size of every array a jaxpr computes, the programs it calls
+    included but for a kernel's body (that is VMEM's)."""
+    for eqn in jaxpr.eqns:
+        yield from (var.aval.size for var in eqn.outvars if hasattr(var.aval, "size"))
+        if eqn.primitive.name != "pallas_call":
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from _sizes(sub)
+
+
+@pytest.mark.parametrize("family", sorted(WIDTHS))
+def test_the_fused_forms_probabilities_never_leave_the_kernels(family):
+    """Neither a residual of the ``vjp`` nor any array of the gradient's
+    program is as large as one head's scores; the XLA form's are."""
+    length, heads = 1024, 2
+    args = _attention_inputs(True, batch=1, length=length, heads=heads, **WIDTHS[family])
+    scale = 0.1
+
+    def attention(*a):
+        return blocked_causal_attention(*a[:3], scale=scale, q_shared=a[3], k_shared=a[4])
+
+    residuals = jax.eval_shape(lambda *a: jax.vjp(attention, *a)[1], *args)
+    kept = [leaf.shape for leaf in jax.tree_util.tree_leaves(residuals)]
+    assert (1, heads, 2, 512) in kept  # the rows' log-sum-exp, a tile a row
+    assert max(np.prod(shape) for shape in kept) == length * heads * 256
+    gradient = jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(attention(*a)),
+                                       argnums=(0, 1, 2, 3, 4)))(*args)
+    assert max(_sizes(gradient.jaxpr)) < length * length
+    small = _attention_inputs(True, batch=1, length=length, heads=heads)
+    blocked = jax.make_jaxpr(jax.grad(lambda *a: jnp.sum(attention(*a)),
+                                      argnums=(0, 1, 2, 3, 4)))(*small)
+    assert max(_sizes(blocked.jaxpr)) >= heads * 512 * length
 
 
 # ----------------------------------------------------- the expert layer
