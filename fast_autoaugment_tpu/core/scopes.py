@@ -43,6 +43,9 @@ __all__ = [
     "MOE_EXPERTS",
     "LM_HEAD",
     "MTP",
+    "MAMBA2",
+    "SSD_SCAN",
+    "GQA",
     "LOSS",
     "OPTIMIZER",
     "EMA",
@@ -87,7 +90,8 @@ SHAKE_SHORTCUT = "faa_shake_shortcut"
 #: (7x7 stride-2 convolution, BatchNorm, ReLU, 3x3 stride-2 max-pool)
 RESNET_STEM = "faa_resnet_stem"
 #: the token models (``models/kimi_linear.py``, ``models/glm4_moe_lite.py``,
-#: ``models/token_blocks.py``), all nested under ``faa_model``: the KDA mixer
+#: ``models/nemotron_h.py``, ``models/token_blocks.py``), all nested under
+#: ``faa_model``: the KDA mixer
 #: (projections, short convolutions, gates, output norm and gate) with the
 #: chunked delta-rule recurrence alone inside it (``ops/kda.py``, forward
 #: and backward); the latent-attention mixer, with its attention core alone
@@ -113,6 +117,17 @@ MOE_ROUTER = "faa_moe_router"
 MOE_EXPERTS = "faa_moe_experts"
 LM_HEAD = "faa_lm_head"
 MTP = "faa_mtp"
+#: ``models/nemotron_h.py``, nested under ``faa_model``: a Mamba-2 mixer
+#: (``in_proj``, the causal convolution, the step's softplus, the gated
+#: grouped norm, ``out_proj``) with the chunked state-space scan alone
+#: inside it (``ops/ssd.py::chunk_ssd``, forward and backward); and a
+#: grouped-query attention mixer whole (its four projections and the causal
+#: softmax: the fused kernels of ``ops/attention.py`` with the key-value
+#: heads repeated in front of them; the core has no scope of its own there,
+#: ``faa_mla_attention`` stays the latent-attention cores')
+MAMBA2 = "faa_mamba2"
+SSD_SCAN = "faa_ssd_scan"
+GQA = "faa_gqa"
 LOSS = "faa_loss"
 #: ``train/steps.py::step_fn``: update and parameter add; EMA; top-k and sums
 OPTIMIZER = "faa_optimizer"

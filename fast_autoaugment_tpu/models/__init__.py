@@ -8,8 +8,9 @@ are the train step's concern — a module is pure structure.
 Supported types (reference parity): resnet50, resnet200, wresnet40_2,
 wresnet28_10, shakeshake26_2x32d / 2x64d / 2x96d / 2x112d,
 shakeshake26_2x96d_next, pyramid, efficientnet-b0..b7 (+condconv).
-Beyond the reference, two token models: kimi_linear (``models/kimi_linear.py``)
-and glm4_moe_lite (``models/glm4_moe_lite.py``).
+Beyond the reference, three token models: kimi_linear
+(``models/kimi_linear.py``), glm4_moe_lite (``models/glm4_moe_lite.py``) and
+nemotron_h (``models/nemotron_h.py``).
 """
 
 from __future__ import annotations
@@ -136,6 +137,10 @@ def get_model(conf: Any, num_classes: int) -> nn.Module:
         from fast_autoaugment_tpu.models.glm4_moe_lite import glm4_moe_lite_from_conf
 
         return glm4_moe_lite_from_conf(conf, dtype=dtype)
+    if name == "nemotron_h":
+        from fast_autoaugment_tpu.models.nemotron_h import nemotron_h_from_conf
+
+        return nemotron_h_from_conf(conf, dtype=dtype)
     if name.startswith("efficientnet"):
         from fast_autoaugment_tpu.models.efficientnet import EfficientNet
 

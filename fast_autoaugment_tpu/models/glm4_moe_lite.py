@@ -63,6 +63,7 @@ from fast_autoaugment_tpu.models.token_blocks import (
     ROUTING,
     STEP_STATS,
     ExpertLayer,
+    Kernel,
     MLAMixer,
     RMSNorm,
     Sizes,
@@ -106,16 +107,6 @@ class Block(nn.Module):
                                   c.routed_scale, c.renormalize, self.dtype,
                                   name="moe")(normed)
         return h + ffn
-
-
-class _Kernel(nn.Module):
-    """A matrix under ``nn.Dense``'s name for it, handed out whole."""
-
-    shape: tuple[int, int]
-
-    @nn.compact
-    def __call__(self):
-        return self.param("kernel", INIT, self.shape)
 
 
 class Glm4MoeLite(nn.Module):
@@ -168,7 +159,7 @@ class Glm4MoeLite(nn.Module):
         for layer in range(1, c.layers_held + 1):
             x = block(c, layer <= c.dense_layers, self.dtype,
                       name=f"layer{layer}")(x)
-        head = _Kernel((c.hidden, c.ids_held), name="lm_head")()
+        head = Kernel((c.hidden, c.ids_held), name="lm_head")()
         normed = RMSNorm(c.eps, name="norm")(x)
         mtp = None
         if c.mtp_modules and (want != "logits" or self.is_initializing()):

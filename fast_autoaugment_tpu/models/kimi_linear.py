@@ -44,6 +44,7 @@ from fast_autoaugment_tpu.models.token_blocks import (
     expert_share_of,
     publish_router_counts,
     refuse_unwritten_routing,
+    step_bias_init,
 )
 from fast_autoaugment_tpu.ops.kda import chunk_kda
 
@@ -71,13 +72,8 @@ def _a_log_init(key, shape, dtype=jnp.float32):
     return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
 
 
-def _dt_bias_init(key, shape, dtype=jnp.float32):
-    """The inverse softplus of a step drawn log-uniformly in
-    [0.001, 0.1] (fla's initialisation)."""
-    dt = jnp.exp(jax.random.uniform(key, shape, dtype) *
-                 (math.log(0.1) - math.log(0.001)) + math.log(0.001))
-    dt = jnp.maximum(dt, 1e-4)
-    return dt + jnp.log(-jnp.expm1(-dt))
+#: fla's initialisation: a step drawn log-uniformly in [0.001, 0.1]
+_dt_bias_init = step_bias_init(0.001, 0.1, 1e-4)
 
 
 class KDAMixer(nn.Module):

@@ -1,14 +1,17 @@
 """What the token models share: the blocks of a pre-norm decoder whose
 FFN may be a sigmoid-routed expert layer this chip holds a share of
-(``models/kimi_linear.py``, ``models/glm4_moe_lite.py``).
+(``models/kimi_linear.py``, ``models/glm4_moe_lite.py``,
+``models/nemotron_h.py``).
 
-- :class:`RMSNorm`, :class:`SwiGLU`, the bias-free :func:`dense`;
+- :class:`RMSNorm`, the bias-free :func:`dense`, a bare :class:`Kernel`,
+  and two feed-forward forms: :class:`SwiGLU` and :class:`SquaredReLU`;
 - :class:`MLAMixer`, latent attention (DeepSeek-V2's MLA): the key-value
   latent always, the query whole or through a low-rank pair with a norm
   between (``q_rank``), the shared key part and every head's matching
   query part rotated by position or left as they are (``rope_theta``);
 - :class:`ExpertLayer`, the routed experts this chip holds beside the
-  shared expert (``ops/moe.py``);
+  shared expert (``ops/moe.py``), both of one form (``ops/moe.py::FORMS``),
+  the shared one at ``width * shared`` or at a width of its own;
 - :class:`Sizes`, the hashable view of a model's sizes ``nn.remat`` takes;
 - :data:`CUT_KEYS`, the three top-level conf keys that say what *this
   chip* holds of a deployment — absent, the whole model: ``layers_held``
@@ -29,6 +32,7 @@ FFN may be a sigmoid-routed expert layer this chip holds a share of
 
 from __future__ import annotations
 
+import math
 from typing import Any
 
 import jax
@@ -39,7 +43,8 @@ from fast_autoaugment_tpu.core import scopes
 from fast_autoaugment_tpu.ops import moe
 from fast_autoaugment_tpu.ops.attention import blocked_causal_attention
 
-__all__ = ["RMSNorm", "SwiGLU", "MLAMixer", "ExpertLayer", "Sizes", "dense",
+__all__ = ["RMSNorm", "SwiGLU", "SquaredReLU", "Kernel", "MLAMixer", "ExpertLayer",
+           "FEED_FORWARDS", "Sizes", "dense", "step_bias_init",
            "rotate_by_position", "expert_share_of", "refuse_unwritten_routing",
            "balance_routers", "publish_router_counts", "INIT", "STEP_STATS",
            "ROUTING", "CUT_KEYS"]
@@ -63,6 +68,21 @@ class RMSNorm(nn.Module):
         x32 = x.astype(jnp.float32)
         x32 = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + self.eps)
         return (x32 * weight).astype(x.dtype)
+
+
+def step_bias_init(low: float, high: float, floor: float):
+    """An initializer for the bias under a softplus that makes a step (a
+    recurrent mixer's ``dt_bias``): the inverse softplus of a step drawn
+    log-uniformly in ``[low, high]`` and floored (fla's and Mamba's
+    initialisation)."""
+
+    def init(key, shape, dtype=jnp.float32):
+        dt = jnp.exp(jax.random.uniform(key, shape, dtype)
+                     * (math.log(high) - math.log(low)) + math.log(low))
+        dt = jnp.maximum(dt, floor)
+        return dt + jnp.log(-jnp.expm1(-dt))
+
+    return init
 
 
 def dense(features: int, name: str, dtype) -> nn.Dense:
@@ -131,6 +151,17 @@ class MLAMixer(nn.Module):
             out.reshape(batch, length, heads * self.v_dim))
 
 
+class Kernel(nn.Module):
+    """A matrix under ``nn.Dense``'s name for it, handed out whole (an
+    output head whose product a blocked loss takes a block at a time)."""
+
+    shape: tuple[int, int]
+
+    @nn.compact
+    def __call__(self):
+        return self.param("kernel", INIT, self.shape)
+
+
 class SwiGLU(nn.Module):
     width: int
     dtype: Any = jnp.float32
@@ -143,8 +174,31 @@ class SwiGLU(nn.Module):
         return dense(hidden, "down_proj", self.dtype)(jax.nn.silu(gate) * up)
 
 
+class SquaredReLU(nn.Module):
+    """``W_down relu(W_up x)^2``: two matrices, no gate (``mlp_hidden_act:
+    relu2``)."""
+
+    width: int
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        up = dense(self.width, "up_proj", self.dtype)(x)
+        return dense(x.shape[-1], "down_proj", self.dtype)(
+            jnp.square(jax.nn.relu(up)))
+
+
+#: the shared expert: the feed-forward module of each of the experts' forms
+#: (``ops/moe.py::FORMS``, which names a routed expert's matrices)
+FEED_FORWARDS = {"swiglu": SwiGLU, "relu2": SquaredReLU}
+assert set(FEED_FORWARDS) == set(moe.FORMS)
+
+
 class ExpertLayer(nn.Module):
-    """The routed experts this chip holds, beside the shared expert."""
+    """The routed experts this chip holds, beside the shared expert.
+    `form`: what an expert computes, routed and shared alike
+    (``ops/moe.py::FORMS``); `shared_width`: the shared expert's width
+    where it has one of its own (absent: ``width * shared``)."""
 
     experts: int
     held: int
@@ -155,6 +209,8 @@ class ExpertLayer(nn.Module):
     scale: float
     renormalize: bool
     dtype: Any = jnp.float32
+    form: str = "swiglu"
+    shared_width: int | None = None
 
     @nn.compact
     def __call__(self, x):
@@ -174,19 +230,21 @@ class ExpertLayer(nn.Module):
             # for a caller that asks (``mutable=[ROUTING]``): which experts
             # each token chose, to hold a second computation to the same
             self.sow(ROUTING, "chosen", chosen.reshape(batch, length, self.top_k))
-        shape = (self.held, hidden, self.width)
-        w_gate = self.param("experts_gate", INIT, shape)
-        w_up = self.param("experts_up", INIT, shape)
-        w_down = self.param("experts_down", INIT, (self.held, self.width, hidden))
+        names, _ = moe.FORMS[self.form]
+        matrices = [self.param(
+            f"experts_{name}", INIT,
+            (self.held, self.width, hidden) if name == "down"
+            else (self.held, hidden, self.width)) for name in names]
         with jax.named_scope(scopes.MOE_EXPERTS):
             out = moe.held_experts(
                 flat, chosen, weights.astype(self.dtype),
-                w_gate.astype(self.dtype), w_up.astype(self.dtype),
-                w_down.astype(self.dtype), first=first)
+                *(w.astype(self.dtype) for w in matrices), first=first,
+                form=self.form)
         out = out.reshape(batch, length, hidden)
         if self.shared:
-            out = out + SwiGLU(self.width * self.shared, self.dtype,
-                               name="shared_experts")(x)
+            out = out + FEED_FORWARDS[self.form](
+                self.shared_width or self.width * self.shared, self.dtype,
+                name="shared_experts")(x)
         return out
 
 
@@ -219,8 +277,13 @@ def expert_share_of(conf: Any, experts: int) -> tuple[int, int]:
 
 
 def refuse_unwritten_routing(groups: int, chosen_groups: int, activation: str,
-                             layer_freq: int) -> None:
-    """What no token model here has written down of a router."""
+                             layer_freq: int = 1) -> None:
+    """What no token model here has written down of a router: a top-k
+    taken group by group, scores other than a sigmoid's (a softmax over
+    the experts), and expert layers at a fixed stride among dense ones.
+    (Which layers hold experts by a pattern string, experts of two
+    matrices and a shared expert of a width of its own are written:
+    ``models/nemotron_h.py``.)"""
     if groups != 1 or chosen_groups != 1:
         raise ValueError("grouped top-k over more than one group is not "
                          "written down")

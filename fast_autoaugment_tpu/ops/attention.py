@@ -118,6 +118,10 @@ def blocked_causal_attention(q, k, v, *, scale: float, q_shared=None,
             k_shared[:, :, None], (batch, length, heads, k_shared.shape[-1]))], -1)
     short = -q.shape[-1] % LANES      # zeros: they add nothing to a score
     q, k = (jnp.pad(a, ((0, 0),) * 3 + ((0, short),)) for a in (q, k))
+    # float32 in, whatever the model's activations are: under `highest` the
+    # kernels' products are float32 ones, which Mosaic refuses bfloat16
+    # operands for (a model in ``precision: bf16`` under a float32 comparison)
+    q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
     return _fused_attention(q, k, v, float(scale), tile, kda._float32_products(),
                             not kda._on_tpu())
 

@@ -9,7 +9,8 @@ held)`` — in a deployment the others live on other chips — and computes
 what *its* experts add for the tokens routed to them,
 
     y = sum over e chosen and held of  w_e * E_e(x),
-    E(x) = W_down (silu(W_gate x) * W_up x).
+    E(x) = W_down (silu(W_gate x) * W_up x)        (``form="swiglu"``)
+    E(x) = W_down relu(W_up x)^2                   (``form="relu2"``).
 
 What the absent experts would add is left out here, as it is in the
 benchmark's reference; nothing stands in for the other chips or for the
@@ -25,7 +26,7 @@ its initial weights sends nearly every token of a step to the same
 ``top_k`` experts (PERF.md section 6, PR 35).
 
 **The held experts' part is grouped**: the assignments that fell to held
-experts are sorted by expert, and an expert's tokens go through its three
+experts are sorted by expert, and an expert's tokens go through its
 products a block of rows at a time (:func:`held_experts`), in a loop
 whose trip count is the blocks the routing filled.  So the products done
 follow the routing — a balanced router's ``tokens * top_k / experts``
@@ -44,7 +45,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-__all__ = ["route", "held_experts", "assignment_counts", "balance_bias"]
+__all__ = ["route", "held_experts", "assignment_counts", "balance_bias", "FORMS"]
 
 #: rows of one block of an expert's tokens (:func:`held_experts`)
 BLOCK_ROWS = 512
@@ -97,6 +98,18 @@ def _products(taken, weight, gate, up, down):
     return out * weight[:, None].astype(out.dtype)
 
 
+def _relu2_products(taken, weight, up, down):
+    out = jnp.dot(jnp.square(jax.nn.relu(jnp.dot(taken, up))), down)
+    return out * weight[:, None].astype(out.dtype)
+
+
+#: an expert's form by name: the names of its matrices, in the order
+#: :func:`held_experts` takes them, and ``(rows, weight, *the expert's
+#: matrices) -> weighted rows``
+FORMS = {"swiglu": (("gate", "up", "down"), _products),
+         "relu2": (("up", "down"), _relu2_products)}
+
+
 def _block_inputs(index, x, weight_of, token_of, counts, rows: int):
     """``(expert, start, token [rows], taken [rows, D], weight [rows])``
     of a block; rows past the expert's last assignment get weight zero
@@ -110,14 +123,16 @@ def _block_inputs(index, x, weight_of, token_of, counts, rows: int):
     return expert, start, mine, token, taken, weight
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7,))
-def _grouped(x, weight_of, w_gate, w_up, w_down, token_of, counts, rows: int):
-    """The sum over the sorted assignments, a block of `rows` at a time."""
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _grouped(x, weight_of, matrices, token_of, counts, rows: int, form: str):
+    """The sum over the sorted assignments, a block of `rows` at a time;
+    `matrices`: the held experts' weights, as ``FORMS[form]`` names them."""
+    _, products = FORMS[form]
 
     def block(index, total):
         expert, _, _, token, taken, weight = _block_inputs(
             index, x, weight_of, token_of, counts, rows)
-        out = _products(taken, weight, w_gate[expert], w_up[expert], w_down[expert])
+        out = products(taken, weight, *(w[expert] for w in matrices))
         return total.at[token].add(out, mode="drop", indices_are_sorted=True,
                                    unique_indices=True)
 
@@ -125,21 +140,22 @@ def _grouped(x, weight_of, w_gate, w_up, w_down, token_of, counts, rows: int):
     return jax.lax.fori_loop(0, blocks, block, jnp.zeros_like(x))
 
 
-def _grouped_fwd(x, weight_of, w_gate, w_up, w_down, token_of, counts, rows):
-    return (_grouped(x, weight_of, w_gate, w_up, w_down, token_of, counts, rows),
-            (x, weight_of, w_gate, w_up, w_down, token_of, counts))
+def _grouped_fwd(x, weight_of, matrices, token_of, counts, rows, form):
+    return (_grouped(x, weight_of, matrices, token_of, counts, rows, form),
+            (x, weight_of, matrices, token_of, counts))
 
 
-def _grouped_bwd(rows, kept, d_total):
-    x, weight_of, w_gate, w_up, w_down, token_of, counts = kept
+def _grouped_bwd(rows, form, kept, d_total):
+    x, weight_of, matrices, token_of, counts = kept
+    _, products = FORMS[form]
 
     def block(index, grads):
-        d_x, d_weight_of, d_gate, d_up, d_down = grads
+        d_x, d_weight_of, d_matrices = grads
         expert, start, mine, token, taken, weight = _block_inputs(
             index, x, weight_of, token_of, counts, rows)
-        _, vjp = jax.vjp(_products, taken, weight, w_gate[expert], w_up[expert],
-                         w_down[expert])
-        d_taken, d_weight, d_g, d_u, d_d = vjp(jnp.take(
+        _, vjp = jax.vjp(products, taken, weight,
+                         *(w[expert] for w in matrices))
+        d_taken, d_weight, *d_expert = vjp(jnp.take(
             d_total, token, axis=0, mode="fill", fill_value=0,
             indices_are_sorted=True, unique_indices=True))
         d_x = d_x.at[token].add(d_taken, mode="drop", indices_are_sorted=True,
@@ -148,24 +164,26 @@ def _grouped_bwd(rows, kept, d_total):
         d_weight = jnp.where(mine, d_weight, 0) + jax.lax.dynamic_slice(
             d_weight_of, (start,), (rows,))
         d_weight_of = jax.lax.dynamic_update_slice(d_weight_of, d_weight, (start,))
-        return (d_x, d_weight_of, d_gate.at[expert].add(d_g),
-                d_up.at[expert].add(d_u), d_down.at[expert].add(d_d))
+        return (d_x, d_weight_of, tuple(
+            d_w.at[expert].add(d_e) for d_w, d_e in zip(d_matrices, d_expert)))
 
     blocks = jnp.sum((counts + rows - 1) // rows)
-    grads = jax.lax.fori_loop(0, blocks, block, tuple(
-        jnp.zeros_like(a) for a in (x, weight_of, w_gate, w_up, w_down)))
+    grads = jax.lax.fori_loop(0, blocks, block, (
+        jnp.zeros_like(x), jnp.zeros_like(weight_of),
+        tuple(jnp.zeros_like(w) for w in matrices)))
     return (*grads, None, None)
 
 
 _grouped.defvjp(_grouped_fwd, _grouped_bwd)
 
 
-def held_experts(x, chosen, weights, w_gate, w_up, w_down, *, first: int,
-                 block_rows: int = BLOCK_ROWS):
+def held_experts(x, chosen, weights, *matrices, first: int,
+                 block_rows: int = BLOCK_ROWS, form: str = "swiglu"):
     """What the held experts add: `x` ``[N, D]``, `chosen` and `weights`
-    from :func:`route`, the held experts' weights ``[held, D, F]`` (gate,
-    up) and ``[held, F, D]`` (down), `first` the index of the first held
-    expert among all.  Returns ``[N, D]``.
+    from :func:`route`, `matrices` the held experts' weights as their
+    `form` takes them (:data:`FORMS` — ``swiglu``: gate and up ``[held, D,
+    F]``, down ``[held, F, D]``; ``relu2``: up and down), `first` the
+    index of the first held expert among all.  Returns ``[N, D]``.
 
     The assignments are sorted by expert (those to experts not held
     last; within an expert by token).  An expert's assignments fill
@@ -173,8 +191,10 @@ def held_experts(x, chosen, weights, w_gate, w_up, w_down, *, first: int,
     takes them through the expert's products and adds the weighted result
     to its tokens.  A block's ``[rows, F]`` activations are computed
     again in the backward pass and kept for none."""
+    if form not in FORMS:
+        raise ValueError(f"unknown expert form {form!r} (have {sorted(FORMS)})")
     tokens, top_k = chosen.shape
-    held = w_gate.shape[0]
+    held = matrices[0].shape[0]
     rows = min(block_rows, tokens)
     local = (chosen - first).reshape(-1)
     local = jnp.where((local >= 0) & (local < held), local, held)
@@ -182,5 +202,5 @@ def held_experts(x, chosen, weights, w_gate, w_up, w_down, *, first: int,
     # a block that starts inside the assignments may read past their end
     token_of = jnp.pad((order // top_k).astype(jnp.int32), (0, rows))
     weight_of = jnp.pad(weights.reshape(-1)[order], (0, rows))
-    return _grouped(x, weight_of, w_gate, w_up, w_down, token_of,
-                    assignment_counts(chosen, first, held), rows)
+    return _grouped(x, weight_of, matrices, token_of,
+                    assignment_counts(chosen, first, held), rows, form)

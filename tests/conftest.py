@@ -35,6 +35,31 @@ if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
 import pytest  # noqa: E402
 
 
+@pytest.fixture(scope="module", autouse=True)
+def expert_layer_metrics_end_with_their_module():
+    """A token program publishes a counter and a gauge a layer
+    (``faa_moe_*{layer=...}``), and the benchmark's token programs hand the
+    readers every such child the process's registry holds.  What a test
+    module's runs registered is taken out again when the module ends, so
+    that another token file's tests in the same xdist worker read their
+    own model's layers alone (PR 41 saw ``layer="mtp"`` survive into the
+    Kimi file's rehearsal).  Counters that were there keep counting."""
+    from fast_autoaugment_tpu.core import telemetry
+
+    registry = telemetry.registry()
+
+    def expert_layer_children():
+        with registry._lock:
+            return {key for key in registry._metrics if key[0].startswith("faa_moe_")}
+
+    held = expert_layer_children()
+    yield
+    new = expert_layer_children() - held
+    with registry._lock:
+        for key in new:
+            registry._metrics.pop(key, None)
+
+
 @pytest.fixture(scope="session")
 def devices8():
     import jax

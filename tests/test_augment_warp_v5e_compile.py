@@ -155,6 +155,89 @@ def test_grouped_experts_and_their_backward_compile_for_a_v5e(one_chip, no_compi
     assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
 
 
+# --- the third token cell's shapes (PR 42): the attention kernels on repeated
+# key-value heads, the scan, and the grouped loop under the two-matrix form
+
+
+@pytest.mark.parametrize("ambient", ["default", "highest"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_fused_attention_kernels_take_repeated_key_value_heads_and_either_dtype(
+        one_chip, no_compile_cache, monkeypatch, dtype, ambient):
+    """`nemotron3_nano_30b_a3b_train`'s one attention layer: 32 query
+    heads of 128 at 8,192 tokens on 2 key-value heads repeated in front of
+    the kernels, no shared key part.  A model in ``precision: bf16`` hands
+    the core bfloat16 activations; under ``highest`` (the float32
+    comparison's control) the kernels' products are float32 ones, which
+    Mosaic refuses bfloat16 operands for — the chip refused it on PR 42's
+    first controls call — so the core takes float32 in whatever comes."""
+    from fast_autoaugment_tpu.ops import kda
+    from fast_autoaugment_tpu.ops.attention import blocked_causal_attention
+
+    monkeypatch.setattr(kda, "_on_tpu", lambda: True)
+
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def scalar(q, k, v):
+        k, v = (jnp.repeat(a, 16, axis=2) for a in (k, v))
+        return jnp.sum(blocked_causal_attention(q, k, v, scale=128 ** -0.5))
+
+    with jax.default_matmul_precision(ambient):
+        compiled = jax.jit(jax.grad(scalar, argnums=(0, 1, 2))).lower(
+            shape(1, 8192, 32, 128), shape(1, 8192, 2, 128),
+            shape(1, 8192, 2, 128)).compile()
+    text = compiled.as_text()
+    assert "mla_attention_forward" in text and "mla_attention_backward" in text
+    assert text.count("tpu_custom_call") == 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.4e9
+
+
+def test_chunked_state_space_scan_compiles_for_a_v5e_inside_its_budget(
+        one_chip, no_compile_cache):
+    """One Mamba-2 layer's scan of `nemotron3_nano_30b_a3b_train`: 8,192
+    tokens in 64 chunks of 128, 64 heads of 64 over a state of 128, 8
+    groups; forward and JAX's own backward.  The chunk-local tensors are
+    ``[64 chunks, 64 heads, 128, 128]`` (268 MB in float32): a handful of
+    them live at once, nothing the size of the whole sequence squared."""
+    from fast_autoaugment_tpu.ops.ssd import chunk_ssd
+
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
+
+    args = (shape(1, 8192, 64, 64), shape(1, 8192, 64), shape(64),
+            shape(1, 8192, 8, 128), shape(1, 8192, 8, 128), shape(64))
+
+    def scalar(*a):
+        return jnp.sum(chunk_ssd(*a, chunk=128))
+
+    compiled = jax.jit(jax.grad(scalar, argnums=tuple(range(6)))).lower(*args).compile()
+    assert " while(" in compiled.as_text()               # the chunk states' pass
+    assert compiled.memory_analysis().temp_size_in_bytes < 3.0e9
+
+
+def test_grouped_two_matrix_experts_and_their_backward_compile_for_a_v5e(
+        one_chip, no_compile_cache):
+    """One expert layer's held share of `nemotron3_nano_30b_a3b_train`:
+    8,192 tokens, top-6 of 128, 8 relu2 experts of 2,688 x 1,856 held:
+    the same two loops under the second form (``ops/moe.py::FORMS``)."""
+    from fast_autoaugment_tpu.ops import moe
+
+    def shape(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    x, chosen, weights = shape((8192, 2688)), shape((8192, 6), jnp.int32), shape((8192, 6))
+    up, down = shape((8, 2688, 1856)), shape((8, 1856, 2688))
+
+    def scalar(x, weights, up, down, chosen):
+        return jnp.sum(moe.held_experts(x, chosen, weights, up, down, first=0,
+                                        form="relu2"))
+
+    compiled = jax.jit(jax.value_and_grad(scalar, argnums=(0, 1, 2, 3))).lower(
+        x, weights, up, down, chosen).compile()
+    assert compiled.as_text().count(" while(") >= 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.7e9
+
+
 # --- the device cache's batch gather at CIFAR's size (PR 37), in this file
 # for the same reason
 

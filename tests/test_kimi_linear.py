@@ -335,10 +335,12 @@ def test_the_fused_forms_probabilities_never_leave_the_kernels(family):
 # ----------------------------------------------------- the expert layer
 
 
-#: the two token models' expert layers, every width cut: experts, how many a
-#: share holds of the shares that divide them, top-k and the scaling
+#: the three token models' expert layers, every width cut: experts, how many
+#: a share holds of the shares that divide them, top-k, the scaling and, where
+#: an expert is no SwiGLU, its form (``ops/moe.py::FORMS``)
 SHARES = {"kimi_linear": dict(experts=16, held=4, top_k=4, scale=2.446),
-          "glm4_moe_lite": dict(experts=64, held=8, top_k=4, scale=1.8)}
+          "glm4_moe_lite": dict(experts=64, held=8, top_k=4, scale=1.8),
+          "nemotron_h": dict(experts=128, held=8, top_k=6, scale=2.5, form="relu2")}
 
 
 def _moe_inputs(tokens=64, dim=16, width=8, experts=16, top_k=4, seed=4,
@@ -354,12 +356,14 @@ def _moe_inputs(tokens=64, dim=16, width=8, experts=16, top_k=4, seed=4,
     return x, chosen, weights, w_gate, w_up, w_down
 
 
-def _every_expert_in_a_loop(x, chosen, weights, w_gate, w_up, w_down):
+def _every_expert_in_a_loop(x, chosen, weights, w_gate, w_up, w_down,
+                            form="swiglu"):
     out = jnp.zeros_like(x)
-    for e in range(w_gate.shape[0]):
+    for e in range(w_up.shape[0]):
         weight = jnp.sum(jnp.where(chosen == e, weights, 0.0), -1)
-        out = out + weight[:, None] * (
-            (jax.nn.silu(x @ w_gate[e]) * (x @ w_up[e])) @ w_down[e])
+        hidden = (jax.nn.silu(x @ w_gate[e]) * (x @ w_up[e]) if form == "swiglu"
+                  else jnp.square(jax.nn.relu(x @ w_up[e])))
+        out = out + weight[:, None] * (hidden @ w_down[e])
     return out
 
 
@@ -383,20 +387,23 @@ def test_router_chooses_top_k_of_all_experts_and_renormalises():
 
 @pytest.mark.parametrize("family", sorted(SHARES))
 def test_the_shares_add_up_to_the_uncut_layer(family):
-    """16 experts over 4 shares of 4, or 64 over 8 shares of 8: the routed
-    parts of all the shares are the whole layer's routed part."""
+    """16 experts over 4 shares of 4, 64 over 8 shares of 8, or 128
+    two-matrix relu2 experts over 16 shares of 8: the routed parts of all
+    the shares are the whole layer's routed part."""
     sizes = SHARES[family]
     experts, held, top_k = sizes["experts"], sizes["held"], sizes["top_k"]
+    form = sizes.get("form", "swiglu")
     x, chosen, weights, w_gate, w_up, w_down = _moe_inputs(
         experts=experts, top_k=top_k, scale=sizes["scale"])
-    whole = _every_expert_in_a_loop(x, chosen, weights, w_gate, w_up, w_down)
+    whole = _every_expert_in_a_loop(x, chosen, weights, w_gate, w_up, w_down, form)
+    matrices = (w_gate, w_up, w_down) if form == "swiglu" else (w_up, w_down)
     shares = [moe.held_experts(
-        x, chosen, weights, w_gate[s:s + held], w_up[s:s + held],
-        w_down[s:s + held], first=s) for s in range(0, experts, held)]
+        x, chosen, weights, *(w[s:s + held] for w in matrices), first=s, form=form)
+        for s in range(0, experts, held)]
     assert len(shares) == experts // held
     _close(sum(shares), whole, 1e-5)
     assert all(float(jnp.abs(share).max()) > 0 for share in shares)
-    uncut = moe.held_experts(x, chosen, weights, w_gate, w_up, w_down, first=0)
+    uncut = moe.held_experts(x, chosen, weights, *matrices, first=0, form=form)
     _close(uncut, whole, 1e-5)
     counts = [moe.assignment_counts(chosen, s, held) for s in range(0, experts, held)]
     assert int(sum(c.sum() for c in counts)) == 64 * top_k   # no token dropped
@@ -483,6 +490,21 @@ def test_the_bias_moves_towards_the_mean_load_and_balances_a_skewed_router():
 
 
 def _tiny_layer_conf(held, share, family="kimi_linear"):
+    if family == "nemotron_h":
+        return {
+            "model": {
+                "type": "nemotron_h", "remat": False, "hidden_size": 32,
+                "hybrid_override_pattern": "ME", "num_hidden_layers": 2,
+                "layer_norm_epsilon": 1e-5, "mamba_num_heads": 2,
+                "mamba_head_dim": 8, "n_groups": 1, "ssm_state_size": 8,
+                "conv_kernel": 4, "chunk_size": 8, "num_attention_heads": 2,
+                "num_key_value_heads": 1, "head_dim": 8, "n_routed_experts": 128,
+                "num_experts_per_tok": 6, "moe_intermediate_size": 16,
+                "moe_shared_expert_intermediate_size": 24, "n_shared_experts": 1,
+                "mlp_hidden_act": "relu2", "n_group": 1, "topk_group": 1,
+                "norm_topk_prob": True, "routed_scaling_factor": 2.5,
+                "vocab_size": 32, "expert_share": share},
+            "dataset": "synthetic_tokens", "experts_held": held}
     if family == "glm4_moe_lite":
         return {
             "model": {
@@ -518,15 +540,20 @@ def _tiny_layer_conf(held, share, family="kimi_linear"):
 @pytest.mark.parametrize("family", sorted(SHARES))
 def test_the_models_expert_layer_shares_add_up_with_the_shared_expert_once(family):
     """Through the module: layer 2 of a two-layer model, one share after
-    another with the uncut model's weights (4 shares of 4 of 16 experts, or
-    the 8 shares of 8 of 64); the routed parts plus the shared expert's,
-    counted once, are the uncut layer's output — the module's, and the
-    uncut layer of that family's plain reference."""
+    another with the uncut model's weights (4 shares of 4 of 16 experts,
+    the 8 shares of 8 of 64, or the 16 shares of 8 of 128 relu2 experts
+    beside a shared expert of a width of its own); the routed parts plus
+    the shared expert's, counted once, are the uncut layer's output — the
+    module's, and the uncut layer of that family's plain reference."""
     from benchmarks.harness import spec
-    from fast_autoaugment_tpu.models.kimi_linear import ExpertLayer, SwiGLU
+    from fast_autoaugment_tpu.models.token_blocks import FEED_FORWARDS, ExpertLayer
 
     sizes = SHARES[family]
     experts, held = sizes["experts"], sizes["held"]
+    form = sizes.get("form", "swiglu")
+    feed_forward, (names, _) = FEED_FORWARDS[form], moe.FORMS[form]
+    # the third family's shared expert is wider than its routed ones
+    shared_width = 24 if family == "nemotron_h" else None
     whole = get_model(model_conf_of(_tiny_layer_conf(experts, 0, family)), 32)
     ids = jax.random.randint(jax.random.PRNGKey(5), (2, 16), 0, 32)
     params = whole.init({"params": jax.random.PRNGKey(6)}, ids)["params"]
@@ -536,7 +563,8 @@ def test_the_models_expert_layer_shares_add_up_with_the_shared_expert_once(famil
     def apply(held, share, shared, p):
         module = ExpertLayer(experts=experts, held=held, share=share,
                              top_k=sizes["top_k"], width=16, shared=shared,
-                             scale=sizes["scale"], renormalize=True)
+                             scale=sizes["scale"], renormalize=True, form=form,
+                             shared_width=shared_width)
         return module.apply({"params": p}, x)
 
     uncut = apply(experts, 0, 1, layer)
@@ -545,9 +573,10 @@ def test_the_models_expert_layer_shares_add_up_with_the_shared_expert_once(famil
     parts = []
     for share in range(experts // held):
         own = dict(routed, **{k: routed[k][held * share:held * (share + 1)]
-                              for k in ("experts_gate", "experts_up", "experts_down")})
+                              for k in (f"experts_{name}" for name in names)})
         parts.append(apply(held, share, 0, own))
-    once = SwiGLU(16).apply({"params": shared_expert}, x)
+    once = feed_forward(shared_width or 16).apply({"params": shared_expert}, x)
+    assert shared_expert["up_proj"]["kernel"].shape == (32, shared_width or 16)
     _close(sum(parts) + once, uncut, 1e-5)
     reference = spec.load_module("references", family)
     plain, _ = reference._experts(
@@ -557,7 +586,8 @@ def test_the_models_expert_layer_shares_add_up_with_the_shared_expert_once(famil
     # and the model is told which experts it holds: a share's parameters
     cut = get_model(model_conf_of(_tiny_layer_conf(held, 2, family)), 32)
     shapes = jax.eval_shape(lambda: cut.init({"params": jax.random.PRNGKey(6)}, ids))
-    assert shapes["params"]["layer2"]["moe"]["experts_gate"].shape == (held, 32, 16)
+    assert shapes["params"]["layer2"]["moe"]["experts_up"].shape == (held, 32, 16)
+    assert ("experts_gate" in shapes["params"]["layer2"]["moe"]) == (form == "swiglu")
     assert shapes["params"]["layer2"]["moe"]["router"].shape == (32, experts)
 
 

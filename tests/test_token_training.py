@@ -493,3 +493,85 @@ def test_a_model_without_loss_terms_lowers_to_the_step_it_had():
     now = lowered(make_token_step_body(model, optimizer))
     assert now == lowered(body_before)
     assert len(now) > 100_000
+
+
+# ------------------------------------------ a third token model: nemotron_h
+
+
+def nemotron_conf(epochs=2, **top):
+    """A tiny Nemotron-H (every width cut, the structure kept: the cut's
+    nine layers by the pattern — four Mamba-2, four expert layers of which
+    4 of 16 relu2 experts are held, one attention layer on 2 key-value
+    heads) on ``synthetic_tokens``."""
+    with open(os.path.join(REPO, "confs", "nemotron3_nano_30b_a3b.yaml")) as fh:
+        conf = yaml.safe_load(fh)
+    conf["model"].update(
+        hidden_size=32, mamba_num_heads=4, mamba_head_dim=8, n_groups=2,
+        ssm_state_size=16, chunk_size=8, num_attention_heads=4,
+        num_key_value_heads=2, head_dim=8, n_routed_experts=16,
+        num_experts_per_tok=2, moe_intermediate_size=16,
+        moe_shared_expert_intermediate_size=24, vocab_size=64,
+        hybrid_override_pattern="MEMEM*EMEM", num_hidden_layers=10)
+    conf.update(layers_held=9, experts_held=4, dataset="synthetic_tokens",
+                batch=BATCH, epoch=epochs, lr=0.02, **top)
+    return Config(conf)
+
+
+@pytest.fixture(scope="module")
+def nemotron_unbroken(tmp_path_factory):
+    path = str(tmp_path_factory.mktemp("nemotron") / "full.msgpack")
+    before = telemetry.registry().counters_snapshot()
+    result = _train(path, conf=nemotron_conf())
+    after = telemetry.registry().counters_snapshot()
+    return path, result, {k: v - before.get(k, 0.0) for k, v in after.items()}
+
+
+def test_one_mixer_a_layer_trains_through_train_and_eval(nemotron_unbroken):
+    _, result, rise = nemotron_unbroken
+    assert result["steps"] == 2 * STEPS and result["epoch"] == 2
+    assert math.isfinite(result["loss_train"]) and math.isfinite(result["loss_test"])
+    assert result["loss_train"] < math.log(64) - 0.1
+    assert rise["faa_tokens_total"] == 2 * STEPS * BATCH * LENGTH
+    # the expert layers are the pattern's, not "every layer after the first"
+    layers = sorted(key.split('layer="')[1].split('"')[0] for key in rise
+                    if key.startswith("faa_moe_assignments_total") and rise[key] > 0)
+    assert layers == ["layer2", "layer4", "layer7", "layer9"]
+    # trace time: every program's scans took the chunked form
+    assert rise['faa_ssd_scan_traces_total{form="chunked_xla"}'] >= 4
+    assert rise.get('faa_ssd_scan_traces_total{form="recurrent"}', 0.0) == 0
+    assert "train_dispatch" in result["compile_cache"]["labels"]
+    assert result["stages"]["train_and_eval.epoch"]["n"] == 2
+
+
+def test_a_preempted_nemotron_run_resumes_to_the_same_digest_and_losses(
+        nemotron_unbroken, tmp_path):
+    full, result, _ = nemotron_unbroken
+    part = str(tmp_path / "part.msgpack")
+    beats = []
+
+    def stop_at_11():
+        beats.append(1)
+        if len(beats) == 11:
+            resilience.request_preemption()
+
+    resilience.clear_preemption()
+    try:
+        with pytest.raises(resilience.PreemptedError):
+            _train(part, conf=nemotron_conf(), heartbeat=stop_at_11)
+    finally:
+        resilience.clear_preemption()
+    meta = read_metadata(part)
+    assert meta["preempted"] is True and meta["step"] == 10
+    assert f"{COUNT_PREFIX}moe_assigned/layer7" in meta["in_epoch"]["sums"]
+    resumed = _train(part, conf=nemotron_conf())
+    assert resumed["steps"] == 2 * STEPS
+    assert _digest(part) == _digest(full)
+    for key in ("loss_train", "top1_train", "loss_test"):
+        assert resumed[key] == result[key], key
+
+
+def test_an_only_eval_restore_takes_the_nemotron_checkpoint(nemotron_unbroken):
+    full, result, _ = nemotron_unbroken
+    evaluated = _train(full, conf=nemotron_conf(), only_eval=True)
+    assert evaluated["steps"] == 2 * STEPS
+    assert evaluated["loss_test"] == result["loss_test"]
