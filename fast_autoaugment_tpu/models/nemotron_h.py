@@ -14,10 +14,12 @@ says, layer by layer:
        ``C`` (a group's ``ssm_state_size``; head ``h`` reads group ``h //
        (heads / n_groups)``); ``Δ = softplus(dt + dt_bias)`` and ``A =
        -exp(A_log)`` a head; the state-space scan with its ``D`` skip
-       (``ops/ssd.py``, chunks of ``chunk_size``); the gated, grouped norm
-       ``w * g(y * silu(z))``, ``g`` an RMS normalisation over each of the
-       ``n_groups`` groups of channels on their own (gate first, then
-       norm); ``out_proj``.
+       (``ops/ssd.py``, chunks of ``chunk_size``: a pair of fused kernels
+       at the published shapes, which read ``x``, ``B`` and ``C`` as the
+       rows sliced here, the ``jnp`` form at any other); the gated,
+       grouped norm ``w * g(y * silu(z))``, ``g`` an RMS normalisation
+       over each of the ``n_groups`` groups of channels on their own (gate
+       first, then norm); ``out_proj``.
 ``*``  grouped-query attention: ``q_proj`` to ``num_attention_heads`` heads
        of ``head_dim``, ``k_proj`` and ``v_proj`` to ``num_key_value_heads``,
        key-value head ``g`` serving the query heads ``[g * n, (g + 1) * n)``,
@@ -98,12 +100,16 @@ def causal_conv(x, kernel, bias):
 def gated_group_norm(y, z, weight, groups: int, eps: float):
     """``weight * g(y * silu(z))``: the gate first, then an RMS
     normalisation over each of `groups` groups of the last axis' channels
-    on their own."""
+    on their own.  Group by group on slices of the rows as they lie (a
+    group's channels are adjacent lanes): the factor's way back over its
+    group is then a broadcast along the lanes inside the fusion, where a
+    ``[..., groups, channels]`` view of rows is another tiling and XLA
+    wrote the broadcast factor and the view back out whole (PERF.md
+    section 6, PR 43)."""
     gated = (y * jax.nn.silu(z)).astype(jnp.float32)
-    grouped = gated.reshape(gated.shape[:-1] + (groups, -1))
-    grouped = grouped * jax.lax.rsqrt(
-        jnp.mean(grouped * grouped, -1, keepdims=True) + eps)
-    return (grouped.reshape(gated.shape) * weight).astype(y.dtype)
+    normed = [part * jax.lax.rsqrt(jnp.mean(part * part, -1, keepdims=True) + eps)
+              for part in jnp.split(gated, groups, axis=-1)]
+    return (jnp.concatenate(normed, -1) * weight).astype(y.dtype)
 
 
 def _a_log_init(key, shape, dtype=jnp.float32):

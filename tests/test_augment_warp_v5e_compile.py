@@ -192,27 +192,57 @@ def test_fused_attention_kernels_take_repeated_key_value_heads_and_either_dtype(
     assert compiled.memory_analysis().temp_size_in_bytes < 1.4e9
 
 
-def test_chunked_state_space_scan_compiles_for_a_v5e_inside_its_budget(
-        one_chip, no_compile_cache):
-    """One Mamba-2 layer's scan of `nemotron3_nano_30b_a3b_train`: 8,192
-    tokens in 64 chunks of 128, 64 heads of 64 over a state of 128, 8
-    groups; forward and JAX's own backward.  The chunk-local tensors are
-    ``[64 chunks, 64 heads, 128, 128]`` (268 MB in float32): a handful of
-    them live at once, nothing the size of the whole sequence squared."""
+def _scan_gradient_compiled(one_chip, length, chunk):
+    """Every argument's gradient of one Mamba-2 layer's scan at
+    `nemotron3_nano_30b_a3b_train`'s widths (64 heads of 64 over a state
+    of 128, 8 groups), compiled for the described chip."""
     from fast_autoaugment_tpu.ops.ssd import chunk_ssd
 
     def shape(*dims):
         return jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
 
-    args = (shape(1, 8192, 64, 64), shape(1, 8192, 64), shape(64),
-            shape(1, 8192, 8, 128), shape(1, 8192, 8, 128), shape(64))
+    args = (shape(1, length, 64, 64), shape(1, length, 64), shape(64),
+            shape(1, length, 8, 128), shape(1, length, 8, 128), shape(64))
 
     def scalar(*a):
-        return jnp.sum(chunk_ssd(*a, chunk=128))
+        return jnp.sum(chunk_ssd(*a, chunk=chunk))
 
-    compiled = jax.jit(jax.grad(scalar, argnums=tuple(range(6)))).lower(*args).compile()
-    assert " while(" in compiled.as_text()               # the chunk states' pass
-    assert compiled.memory_analysis().temp_size_in_bytes < 3.0e9
+    return jax.jit(jax.grad(scalar, argnums=tuple(range(6)))).lower(*args).compile()
+
+
+@pytest.mark.parametrize("ambient", ["default", "highest"])
+def test_fused_ssd_kernels_compile_for_a_v5e(one_chip, no_compile_cache, monkeypatch,
+                                             ambient):
+    """One Mamba-2 layer's scan of `nemotron3_nano_30b_a3b_train`: 8,192
+    tokens in 64 chunks of 128, through the kernels as the chip gets them
+    (the backend here is the CPU, which would interpret them), with
+    bfloat16 operands and under ``highest``.  Forward and backward are one
+    Mosaic kernel each; beside the arguments' gradients HBM holds the kept
+    start states (134 MB) and this test's own turn of ``x`` and ``y`` to
+    rows and back (the mixer hands rows), and nothing the size of a
+    chunk's ``[64 heads, 128, 128]`` times the chunks (268 MB each in the
+    ``jnp`` form, a handful alive at once: 3 GB was its budget here)."""
+    from fast_autoaugment_tpu.ops import kda
+
+    monkeypatch.setattr(kda, "_on_tpu", lambda: True)
+    with jax.default_matmul_precision(ambient):
+        compiled = _scan_gradient_compiled(one_chip, 8192, 128)
+    text = compiled.as_text()
+    assert "ssd_forward" in text and "ssd_backward" in text
+    assert text.count("tpu_custom_call") == 2
+    assert " while(" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
+
+
+def test_chunked_state_space_scan_compiles_for_a_v5e_where_the_kernels_do_not_serve(
+        one_chip, no_compile_cache):
+    """The same widths in chunks of 64, which the kernels are not written
+    for: the ``jnp`` form, forward and JAX's own backward, its chunk
+    states' pass a loop and no kernel in the program."""
+    compiled = _scan_gradient_compiled(one_chip, 2048, 64)
+    text = compiled.as_text()
+    assert " while(" in text and "tpu_custom_call" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
 
 
 def test_grouped_two_matrix_experts_and_their_backward_compile_for_a_v5e(

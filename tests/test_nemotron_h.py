@@ -11,6 +11,7 @@ file has not written down, refused.  Through ``train_and_eval``:
 ``tests/test_token_training.py``; the configuration's files:
 ``tests/benchmarks/test_bench_nemotron_h.py``."""
 
+import functools
 import json
 import math
 import os
@@ -26,6 +27,7 @@ from fast_autoaugment_tpu.core import telemetry
 from fast_autoaugment_tpu.models import get_model, model_conf_of
 from fast_autoaugment_tpu.models import nemotron_h as family
 from fast_autoaugment_tpu.models.token_blocks import ROUTING, STEP_STATS
+from fast_autoaugment_tpu.ops import kda, ssd
 from fast_autoaugment_tpu.ops.ssd import chunk_ssd, recurrent_ssd
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -51,6 +53,14 @@ def _close(a, b, rel):
 
 _SCAN_ARGUMENTS = ["x", "dt", "a", "b", "c", "d"]
 
+#: the tests' own small shape (the ``jnp`` form takes it), and the shape
+#: every published layer of the family states: chunks of 128, a state of
+#: 128, heads of 64 that fill whole tiles of 128 lanes a group (two tiles
+#: a group here, two groups), which goes through the fused kernels
+#: (interpreted here: the code the chip runs)
+SMALL = dict(length=32)
+NATIVE = dict(length=256, batch=1, heads=8, width=64, groups=2, size=128)
+
 
 def _scan_inputs(length=32, *, dt_low=1e-3, dt_high=0.1, batch=2, heads=6,
                  width=8, groups=2, size=16, seed=0):
@@ -67,45 +77,72 @@ def _scan_inputs(length=32, *, dt_low=1e-3, dt_high=0.1, batch=2, heads=6,
     return x, dt, a, b, c, d
 
 
+def _form_counts():
+    snapshot = telemetry.registry().counters_snapshot()
+    return {form: snapshot.get(f'faa_ssd_scan_traces_total{{form="{form}"}}', 0.0)
+            for form in ("fused", "chunked_xla", "recurrent")}
+
+
 @pytest.mark.parametrize("dt_high", [0.1, 4.0], ids=["published_steps", "large_steps"])
-@pytest.mark.parametrize("chunk", [32, 16, 4], ids=["one_chunk", "two", "eight"])
-def test_chunked_scan_gives_the_recurrences_output(chunk, dt_high):
-    """Heads-a-group 3, a chunk that divides the sequence 1, 2 and 8 times,
-    the step at both ends of its range: softplus's small end (1e-3: a head
+@pytest.mark.parametrize("shape, chunk", [(SMALL, 32), (SMALL, 16), (SMALL, 4), (NATIVE, 128)],
+                         ids=["one_chunk", "two", "eight", "native"])
+def test_chunked_scan_gives_the_recurrences_output(shape, chunk, dt_high):
+    """Heads-a-group 3, a chunk that divides the sequence 1, 2 and 8 times
+    (and the fused kernels' two chunks of 128, four heads a group), the
+    step at both ends of its range: softplus's small end (1e-3: a head
     that forgets nothing in a chunk) and steps of up to 4 against ``A`` of
     up to -64 (a head whose state is gone within a token)."""
-    x, dt, a, b, c, d = arguments = _scan_inputs(dt_high=dt_high)
+    x, dt, a, b, c, d = arguments = _scan_inputs(dt_high=dt_high, **shape)
+    half = x.shape[1] // 2
     y = recurrent_ssd(*arguments)
     ours = chunk_ssd(*arguments, chunk=chunk)
-    assert ours.shape == y.shape == (2, 32, 6, 8)
-    _close(ours, y, 2e-5)
+    assert ours.shape == y.shape == x.shape
+    # 128 large steps a chunk: both forms read the same 4.6e-5 there, a
+    # running sum of -3,000 less another
+    rel = 1e-4 if chunk == 128 and dt_high > 1 else 2e-5
+    _close(ours, y, rel)
     # and the state that chunks hand on is read: the later half of the
     # sequence alone, from S = 0, is another result
-    alone = chunk_ssd(x[:, 16:], dt[:, 16:], a, b[:, 16:], c[:, 16:], d,
-                      chunk=min(chunk, 16))
-    assert float(jnp.abs(alone - y[:, 16:]).max()) > 1e-3
+    alone = chunk_ssd(x[:, half:], dt[:, half:], a, b[:, half:], c[:, half:], d,
+                      chunk=min(chunk, half))
+    assert float(jnp.abs(alone - y[:, half:]).max()) > 1e-3
+    _close(alone, recurrent_ssd(x[:, half:], dt[:, half:], a, b[:, half:],
+                                c[:, half:], d), rel)
 
 
-def test_a_decay_that_would_overflow_a_factorised_form_does_not():
+@pytest.mark.parametrize("shape, form", [(SMALL, "chunked_xla"), (NATIVE, "fused")],
+                         ids=["small", "native"])
+def test_a_decay_that_would_overflow_a_factorised_form_does_not(shape, form):
     """Steps of 8 against ``A = -64``: ``exp(-sum)`` of a chunk's running
     sum is ``exp(16,384)``; every exponent the chunked form takes is a sum
     over a span and non-positive, so nothing is inf or nan and the result
-    is the recurrence's."""
-    x, dt, a, b, c, d = _scan_inputs()
+    is the recurrence's.  One head forgets at ``Δ A = -50`` a token beside
+    heads that forget nothing."""
+    x, dt, a, b, c, d = _scan_inputs(**shape)
+    length = x.shape[1]
+    whole = 128 if form == "fused" else length
     dt, a = jnp.full_like(dt, 8.0), jnp.full_like(a, -64.0)
     with np.errstate(over="ignore"):
         assert not np.isfinite(np.exp(np.float32(8.0 * 64.0 * 32)))
-    for chunk in (32, 8):
+    for chunk in (whole, whole // 4):
         ours = chunk_ssd(x, dt, a, b, c, d, chunk=chunk)
         assert bool(jnp.isfinite(ours).all())
         _close(ours, recurrent_ssd(x, dt, a, b, c, d), 1e-5)
-    grads = jax.grad(lambda *args: jnp.sum(chunk_ssd(*args, chunk=32) ** 2),
-                     argnums=(0, 1, 2))(x, dt, a, b, c, d)
-    assert all(bool(jnp.isfinite(g).all()) for g in grads)
+    mixed = a.at[1].set(-50.0 / 8.0).at[2:].set(-1e-4)
+    every = tuple(range(6))
+    grads = jax.grad(lambda *args: jnp.sum(chunk_ssd(*args, chunk=whole) ** 2),
+                     argnums=every)(x, dt, mixed, b, c, d)
+    plain = jax.grad(lambda *args: jnp.sum(recurrent_ssd(*args) ** 2),
+                     argnums=every)(x, dt, mixed, b, c, d)
+    for ours, theirs in zip(grads, plain):
+        assert bool(jnp.isfinite(ours).all())
+        _close(ours, theirs, 2e-4)
 
 
-def _scan_gradients(chunk):
-    arguments = _scan_inputs(dt_high=1.0)
+def _scan_gradients(shape, chunk):
+    # steps of up to 1 in chunks of up to 32; the published range in chunks
+    # of 128, where a running sum is as long as at the published sizes
+    arguments = _scan_inputs(dt_high=1.0 if chunk < 128 else 0.1, **shape)
 
     def objective(fn):
         def loss(*args):
@@ -116,10 +153,11 @@ def _scan_gradients(chunk):
             objective(lambda *args: chunk_ssd(*args, chunk=chunk)))
 
 
-@pytest.fixture(scope="module", params=[8, 32], ids=["four_chunks", "one_chunk"])
+@pytest.fixture(scope="module", params=[(SMALL, 8), (SMALL, 32), (NATIVE, 128)],
+                ids=["four_chunks", "one_chunk", "native"])
 def scan_gradients(request):
     with jax.default_matmul_precision("highest"):
-        return _scan_gradients(request.param)
+        return _scan_gradients(*request.param)
 
 
 @pytest.mark.parametrize("argnum, name", list(enumerate(_SCAN_ARGUMENTS)))
@@ -128,6 +166,47 @@ def test_chunked_scans_gradient_is_the_recurrences(scan_gradients, argnum, name)
     assert ours[argnum].shape == plain[argnum].shape
     _close(ours[argnum], plain[argnum], 1e-4)
     assert float(jnp.abs(plain[argnum]).max()) > 0
+
+
+#: how far the fused form may lie from the ``jnp`` form in float32:
+#: float32 products (what ``highest`` asks) as close as two orders of
+#: summation, bfloat16 operands (the chip's default) inside the deployed
+#: limit of the configuration's comparison
+_FUSED_TOLERANCES = {"highest": (1e-5, 2e-5), "default": (0.02, 0.05)}
+
+
+@pytest.fixture(scope="module", params=sorted(_FUSED_TOLERANCES))
+def fused_and_jnp(request):
+    """``(ambient, the fused form's, the jnp form's)``: each the output
+    and every argument's gradient, two sequences of three chunks."""
+    arguments = _scan_inputs(**dict(NATIVE, length=384, batch=2, heads=4))
+
+    def both(fn):
+        return (fn(*arguments),) + jax.grad(
+            lambda *args: jnp.sum(jnp.sin(fn(*args))), argnums=tuple(range(6)))(*arguments)
+
+    # off the chip the ambient precision is float32 whatever it says: the
+    # operands are rounded as the chip's default would round them
+    exact = {"highest": kda._float32_products, "default": lambda: False}[request.param]
+    with pytest.MonkeyPatch.context() as patch, jax.default_matmul_precision("highest"):
+        patch.setattr(kda, "_float32_products", exact)
+        return (request.param, both(jax.jit(lambda *args: chunk_ssd(*args))),
+                both(lambda *args: ssd._chunk_ssd_xla(*args, 128)))
+
+
+@pytest.mark.parametrize("which, name", list(enumerate(["y"] + _SCAN_ARGUMENTS)))
+def test_fused_scan_is_the_jnp_form_under_either_precision(fused_and_jnp, which, name):
+    ambient, fused, plain = fused_and_jnp
+    assert fused[which].shape == plain[which].shape
+    _close(fused[which], plain[which], _FUSED_TOLERANCES[ambient][which > 0])
+
+
+def test_two_sequences_in_a_batch_are_each_their_own():
+    arguments = _scan_inputs(**dict(NATIVE, batch=2, heads=4))
+    both = chunk_ssd(*arguments)
+    for i in range(2):
+        alone = chunk_ssd(*(v[i:i + 1] if v.ndim > 1 else v for v in arguments))
+        np.testing.assert_array_equal(np.asarray(alone[0]), np.asarray(both[i]))
 
 
 def test_a_length_that_is_no_multiple_of_the_chunk_is_refused():
@@ -141,22 +220,25 @@ def test_a_length_that_is_no_multiple_of_the_chunk_is_refused():
         chunk_ssd(x[:, :, :5], dt[:, :, :5], a[:5], b, c, d[:5], chunk=16)
 
 
-def test_the_scans_counter_counts_the_form_the_trace_took():
-    def counts():
-        snapshot = telemetry.registry().counters_snapshot()
-        return {form: snapshot.get(f'faa_ssd_scan_traces_total{{form="{form}"}}', 0.0)
-                for form in ("chunked_xla", "recurrent")}
-
-    arguments = _scan_inputs()
-    before = counts()
-    jax.jit(lambda *args: chunk_ssd(*args, chunk=16)).lower(*arguments)
-    middle = counts()
-    jax.jit(recurrent_ssd).lower(*arguments)
-    after = counts()
-    assert middle == {"chunked_xla": before["chunked_xla"] + 1,
-                      "recurrent": before["recurrent"]}
-    assert after == {"chunked_xla": middle["chunked_xla"],
-                     "recurrent": middle["recurrent"] + 1}
+@pytest.mark.parametrize("shape, chunk, form", [
+    (SMALL, 16, "chunked_xla"), (NATIVE, 128, "fused"),
+    # the kernels' widths in a sequence under a chunk, in chunks of 64, with
+    # a state of 64, and with three heads of 64 a group (a tile and a half)
+    (dict(NATIVE, length=64), 128, "chunked_xla"), (NATIVE, 64, "chunked_xla"),
+    (dict(NATIVE, size=64), 128, "chunked_xla"),
+    (dict(NATIVE, heads=6), 128, "chunked_xla")],
+    ids=["small", "native", "short", "chunk-64", "state-64", "half-tile"])
+def test_the_scans_counter_counts_the_form_the_trace_took(shape, chunk, form):
+    arguments = _scan_inputs(**shape)
+    before = _form_counts()
+    jax.eval_shape(functools.partial(chunk_ssd, chunk=chunk), *arguments)
+    middle = _form_counts()
+    jax.eval_shape(lambda *args: recurrent_ssd(*args), *arguments)   # a trace of its own
+    after = _form_counts()
+    assert {f: middle[f] - before[f] for f in middle} == {
+        f: float(f == form) for f in middle}
+    assert {f: after[f] - middle[f] for f in after} == {
+        f: float(f == "recurrent") for f in after}
 
 
 # ---------------------------------------- convolution, norm, the three mixers
