@@ -41,6 +41,7 @@ __all__ = [
     "MOE",
     "MOE_ROUTER",
     "MOE_EXPERTS",
+    "MOE_COMBINE",
     "LM_HEAD",
     "MTP",
     "MAMBA2",
@@ -104,7 +105,7 @@ RESNET_STEM = "faa_resnet_stem"
 #: expert layer (its shared expert included) with the router (scores, top-k,
 #: every expert's load) and the held experts' part (the assignments sorted
 #: by expert, a loop over the blocks of rows the routing filled: gather,
-#: three products, weighted scatter-add; forward and backward) inside it;
+#: its products, the weighted rows into the sum; forward and backward) inside it;
 #: the output head's product; a multi-token-prediction module (its two
 #: norms, ``eh_proj`` and its block, whose own ``faa_mla`` and ``faa_moe``
 #: nest inside it; its head and loss stay ``faa_lm_head`` and ``faa_loss``)
@@ -115,6 +116,10 @@ MLA_ATTENTION = "faa_mla_attention"
 MOE = "faa_moe"
 MOE_ROUTER = "faa_moe_router"
 MOE_EXPERTS = "faa_moe_experts"
+#: ``ops/moe.py::_combine``, nested under ``faa_moe_experts``: the kernel
+#: ``moe_combine`` alone, a block's rows added into the ``[tokens, 1,
+#: hidden]`` sum (forward, what ``nn.remat`` computes again, and ``d_x``)
+MOE_COMBINE = "faa_moe_combine"
 LM_HEAD = "faa_lm_head"
 MTP = "faa_mtp"
 #: ``models/nemotron_h.py``, nested under ``faa_model``: a Mamba-2 mixer

@@ -36,6 +36,16 @@ static and **no token is dropped** whatever the router does.  A loop of
 a length only the device knows cannot be differentiated by JAX, so the
 backward pass is written here too (``jax.custom_vjp``): the same loop,
 each block's products differentiated by ``jax.vjp``.
+
+**A block's rows reach the sum through a kernel** (:func:`_combine`, the
+Mosaic kernel ``moe_combine``; interpreted where the backend is no TPU):
+both loops carry their ``[tokens, hidden]`` sum (the result; ``d_x``) as
+``[tokens, 1, hidden]``, in place, and a block's rows of it are copied
+into VMEM all at once, added to and copied back — the float32 adds
+``sum.at[token].add(rows, mode="drop")`` does, in its order, so the same
+bits.  Rows of whole 128-lane tiles of 32-bit words go that way, which
+every configuration's are; any other width or type keeps XLA's scatter
+(:func:`_sum_of_rows`: the choice is ``x.shape`` and ``x.dtype`` alone).
 """
 
 from __future__ import annotations
@@ -44,6 +54,12 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from fast_autoaugment_tpu.core import scopes
+from fast_autoaugment_tpu.ops import kda
+from fast_autoaugment_tpu.ops.kda import LANES
 
 __all__ = ["route", "held_experts", "assignment_counts", "balance_bias", "FORMS"]
 
@@ -123,21 +139,105 @@ def _block_inputs(index, x, weight_of, token_of, counts, rows: int):
     return expert, start, mine, token, taken, weight
 
 
+# ------------------------------------------------- a block's rows into the sum
+#
+# XLA's scatter updates an ``(8, 128)``-tiled ``[tokens, hidden]`` sum a row
+# after another, a row being one sublane of ``hidden / 128`` tiles: 0.43 to
+# 0.93 ms a block of 512 rows whose bytes owe 0.02 (PERF.md section 6,
+# PR 44).  The loops carry the sum as ``[tokens, 1, hidden]`` instead, where
+# a row is whole tiles and one stretch of HBM, and the kernel moves rows
+# with the chip's copy engine, all of a block's in flight at once.
+
+
+def _combine_kernel(token_ref, _, rows_ref, total_ref, taken, mine, landed, brought):
+    """``total[token[r]] += rows[r]`` for the block's rows whose token
+    lies inside `total_ref` (the second operand's own buffer, in HBM):
+    their rows of the sum copied into `taken`, one add, and copied back.
+    `rows_ref` ``[R, D]`` is in HBM too, tiled ``(8, 128)`` as the
+    products leave it; the copy into `mine` lays it out as `taken` is."""
+    tokens = total_ref.shape[0]
+    bring = pltpu.make_async_copy(rows_ref, mine.at[:, 0], brought)
+    bring.start()
+
+    def each_row(act):
+        def row(r, carry):
+            t = token_ref[r]
+
+            @pl.when(t < tokens)
+            def _():
+                act(total_ref.at[t], taken.at[r])
+            return carry
+
+        jax.lax.fori_loop(0, taken.shape[0], row, 0)
+
+    # one semaphore counts every row's copy: they are of one size, so as
+    # many waits as starts have seen them all land
+    each_row(lambda kept, held: pltpu.make_async_copy(kept, held, landed).start())
+    each_row(lambda kept, held: pltpu.make_async_copy(kept, held, landed).wait())
+    bring.wait()
+    taken[...] += mine[...]
+    each_row(lambda kept, held: pltpu.make_async_copy(held, kept, landed).start())
+    each_row(lambda kept, held: pltpu.make_async_copy(held, kept, landed).wait())
+
+
+def _combine(total, token, rows):
+    """`rows` ``[R, D]`` added into `total` ``[N, 1, D]`` at the rows
+    `token` ``[R]`` names, in place.  A token past the last row adds
+    nothing, and no two of a call's tokens inside `total` may be the same:
+    the float32 adds of ``total.at[token, 0].add(rows, mode="drop")``, as
+    one Mosaic kernel (interpreted where the backend is no TPU)."""
+    block = pltpu.VMEM((rows.shape[0], 1, rows.shape[1]), rows.dtype)
+    with jax.named_scope(scopes.MOE_COMBINE):
+        return pl.pallas_call(
+            _combine_kernel,
+            out_shape=jax.ShapeDtypeStruct(total.shape, total.dtype),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=(1,),
+                in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 2,
+                out_specs=pl.BlockSpec(memory_space=pl.ANY),
+                scratch_shapes=[block, block, pltpu.SemaphoreType.DMA(()),
+                                pltpu.SemaphoreType.DMA(())]),
+            input_output_aliases={1: 0}, name="moe_combine",
+            interpret=not kda._on_tpu(),
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=64 * 1024 * 1024),
+        )(token, total, rows)
+
+
+def _scatter(total, token, rows):
+    """The same into `total` ``[N, D]``, by XLA."""
+    return total.at[token].add(rows, mode="drop", indices_are_sorted=True,
+                               unique_indices=True)
+
+
+def _sum_of_rows(x):
+    """``(zero, add, whole)`` for a sum of rows like `x`'s ``[N, D]``: the
+    sum as the loops carry it, ``add(total, token, rows [R, D])`` for a
+    block, and ``whole(total)`` as ``[N, D]``.  Mosaic copies a row of
+    32-bit words in whole tiles of 128 lanes; an `x` of any other width or
+    type keeps XLA's scatter."""
+    tokens, hidden = x.shape
+    if x.dtype.itemsize == 4 and hidden % LANES == 0:
+        return (jnp.zeros((tokens, 1, hidden), x.dtype), _combine,
+                lambda total: total[:, 0])
+    return jnp.zeros_like(x), _scatter, lambda total: total
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
 def _grouped(x, weight_of, matrices, token_of, counts, rows: int, form: str):
     """The sum over the sorted assignments, a block of `rows` at a time;
     `matrices`: the held experts' weights, as ``FORMS[form]`` names them."""
     _, products = FORMS[form]
+    zero, add, whole = _sum_of_rows(x)
 
     def block(index, total):
         expert, _, _, token, taken, weight = _block_inputs(
             index, x, weight_of, token_of, counts, rows)
-        out = products(taken, weight, *(w[expert] for w in matrices))
-        return total.at[token].add(out, mode="drop", indices_are_sorted=True,
-                                   unique_indices=True)
+        return add(total, token,
+                   products(taken, weight, *(w[expert] for w in matrices)))
 
     blocks = jnp.sum((counts + rows - 1) // rows)
-    return jax.lax.fori_loop(0, blocks, block, jnp.zeros_like(x))
+    return whole(jax.lax.fori_loop(0, blocks, block, zero))
 
 
 def _grouped_fwd(x, weight_of, matrices, token_of, counts, rows, form):
@@ -148,6 +248,7 @@ def _grouped_fwd(x, weight_of, matrices, token_of, counts, rows, form):
 def _grouped_bwd(rows, form, kept, d_total):
     x, weight_of, matrices, token_of, counts = kept
     _, products = FORMS[form]
+    zero, add, whole = _sum_of_rows(x)
 
     def block(index, grads):
         d_x, d_weight_of, d_matrices = grads
@@ -158,8 +259,7 @@ def _grouped_bwd(rows, form, kept, d_total):
         d_taken, d_weight, *d_expert = vjp(jnp.take(
             d_total, token, axis=0, mode="fill", fill_value=0,
             indices_are_sorted=True, unique_indices=True))
-        d_x = d_x.at[token].add(d_taken, mode="drop", indices_are_sorted=True,
-                                unique_indices=True)
+        d_x = add(d_x, token, d_taken)
         # the block's window may reach into the next expert's assignments
         d_weight = jnp.where(mine, d_weight, 0) + jax.lax.dynamic_slice(
             d_weight_of, (start,), (rows,))
@@ -168,10 +268,10 @@ def _grouped_bwd(rows, form, kept, d_total):
             d_w.at[expert].add(d_e) for d_w, d_e in zip(d_matrices, d_expert)))
 
     blocks = jnp.sum((counts + rows - 1) // rows)
-    grads = jax.lax.fori_loop(0, blocks, block, (
-        jnp.zeros_like(x), jnp.zeros_like(weight_of),
+    d_x, *grads = jax.lax.fori_loop(0, blocks, block, (
+        zero, jnp.zeros_like(weight_of),
         tuple(jnp.zeros_like(w) for w in matrices)))
-    return (*grads, None, None)
+    return (whole(d_x), *grads, None, None)
 
 
 _grouped.defvjp(_grouped_fwd, _grouped_bwd)
@@ -189,8 +289,11 @@ def held_experts(x, chosen, weights, *matrices, first: int,
     last; within an expert by token).  An expert's assignments fill
     ``ceil(count / block_rows)`` blocks; a block gathers its tokens' rows,
     takes them through the expert's products and adds the weighted result
-    to its tokens.  A block's ``[rows, F]`` activations are computed
-    again in the backward pass and kept for none."""
+    to its tokens' rows of the sum, in place: by the kernel ``moe_combine``
+    where `x` is whole tiles of 32-bit words (interpreted where the
+    backend is no TPU), else by XLA's scatter, the same adds either way.
+    A block's ``[rows, F]`` activations are computed again in the backward
+    pass and kept for none."""
     if form not in FORMS:
         raise ValueError(f"unknown expert form {form!r} (have {sorted(FORMS)})")
     tokens, top_k = chosen.shape

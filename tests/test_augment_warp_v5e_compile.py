@@ -129,20 +129,56 @@ def test_fused_attention_kernels_compile_for_a_v5e(one_chip, no_compile_cache, m
     assert compiled.memory_analysis().temp_size_in_bytes < 1.4e9
 
 
-def test_grouped_experts_and_their_backward_compile_for_a_v5e(one_chip, no_compile_cache):
+def _assert_the_sum_is_combined_in_place(compiled, width):
+    """Of a compiled ``held_experts`` and its backward pass over 8,192
+    tokens of `width`: both loops add their blocks through the kernel, no
+    scatter over the whole sum is left, and inside a loop's body nothing
+    but the kernel (and the body's own argument) makes an array the size
+    of the sum — no copy in front of the aliased operand."""
+    import re
+
+    text = compiled.as_text()
+    assert text.count("moe_combine") >= 2 and text.count("tpu_custom_call") == 2
+    whole = rf"f32\[8192,(?:1,)?{width}\]"
+    assert not re.search(rf"= {whole}\S* scatter\(", text)
+    computations, lines = {}, None
+    for line in text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            lines = computations.setdefault(
+                re.match(r"(?:ENTRY )?%?([\w.\-]+)", line).group(1), [])
+        elif lines is not None:
+            lines.append(line)
+    bodies = set(re.findall(r"body=%?([\w.\-]+)", text))
+    assert len(bodies) >= 2
+    makers = {m.group(1) for body in bodies for line in computations[body]
+              if (m := re.search(rf"= {whole}\S* ([\w-]+)\(", line))}
+    # ``broadcast``: this test's cotangent, the gradient of a plain sum
+    assert "custom-call" in makers
+    assert makers <= {"get-tuple-element", "custom-call", "broadcast"}, makers
+
+
+@pytest.mark.parametrize("width, top_k, expert_width", [(2304, 8, 1024), (2048, 4, 1536)],
+                         ids=["kimi_linear_48b_a3b_train", "glm47_flash_train"])
+def test_grouped_experts_and_their_backward_compile_for_a_v5e(
+        one_chip, no_compile_cache, monkeypatch, width, top_k, expert_width):
     """One expert layer's held share of `kimi_linear_48b_a3b_train`: 8,192
-    tokens, top-8 of 256, 8 experts of 2,304 x 1,024 held.  Both loops
-    over the blocks the routing filled (forward, and the backward pass
-    ``ops/moe.py`` writes itself) stay loops, and nothing the size of the
-    worst case (every token through every held expert: 65,536 rows) is
+    tokens, top-8 of 256, 8 experts of 2,304 x 1,024 held; and of
+    `glm47_flash_train`: top-4 of 64, 8 experts of 2,048 x 1,536.  Both
+    loops over the blocks the routing filled (forward, and the backward
+    pass ``ops/moe.py`` writes itself) stay loops, and nothing the size of
+    the worst case (every token through every held expert: 65,536 rows) is
     written out."""
-    from fast_autoaugment_tpu.ops import moe
+    from fast_autoaugment_tpu.ops import kda, moe
+
+    monkeypatch.setattr(kda, "_on_tpu", lambda: True)
 
     def shape(dims, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
-    x, chosen, weights = shape((8192, 2304)), shape((8192, 8), jnp.int32), shape((8192, 8))
-    gate, up, down = shape((8, 2304, 1024)), shape((8, 2304, 1024)), shape((8, 1024, 2304))
+    x, chosen, weights = (shape((8192, width)), shape((8192, top_k), jnp.int32),
+                          shape((8192, top_k)))
+    gate, up, down = (shape((8, width, expert_width)), shape((8, width, expert_width)),
+                      shape((8, expert_width, width)))
 
     def scalar(x, weights, gate, up, down, chosen):
         return jnp.sum(moe.held_experts(x, chosen, weights, gate, up, down, first=0))
@@ -153,6 +189,7 @@ def test_grouped_experts_and_their_backward_compile_for_a_v5e(one_chip, no_compi
     # inputs, their gradients and one block's rows: far under the 604 MB
     # that 65,536 gathered rows alone would take
     assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
+    _assert_the_sum_is_combined_in_place(compiled, width)
 
 
 # --- the third token cell's shapes (PR 42): the attention kernels on repeated
@@ -246,11 +283,13 @@ def test_chunked_state_space_scan_compiles_for_a_v5e_where_the_kernels_do_not_se
 
 
 def test_grouped_two_matrix_experts_and_their_backward_compile_for_a_v5e(
-        one_chip, no_compile_cache):
+        one_chip, no_compile_cache, monkeypatch):
     """One expert layer's held share of `nemotron3_nano_30b_a3b_train`:
     8,192 tokens, top-6 of 128, 8 relu2 experts of 2,688 x 1,856 held:
     the same two loops under the second form (``ops/moe.py::FORMS``)."""
-    from fast_autoaugment_tpu.ops import moe
+    from fast_autoaugment_tpu.ops import kda, moe
+
+    monkeypatch.setattr(kda, "_on_tpu", lambda: True)
 
     def shape(dims, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
@@ -266,6 +305,7 @@ def test_grouped_two_matrix_experts_and_their_backward_compile_for_a_v5e(
         x, weights, up, down, chosen).compile()
     assert compiled.as_text().count(" while(") >= 2
     assert compiled.memory_analysis().temp_size_in_bytes < 0.7e9
+    _assert_the_sum_is_combined_in_place(compiled, 2688)
 
 
 # --- the device cache's batch gather at CIFAR's size (PR 37), in this file

@@ -459,6 +459,83 @@ def test_a_held_shares_gradients_are_the_loops(rows):
         _close(a, b, 1e-4)
 
 
+@pytest.mark.parametrize("tail", ["no_row_dropped", "part_filled", "every_row_dropped"])
+@pytest.mark.parametrize("hidden", [128, 2688, 200])
+@pytest.mark.parametrize("rows", [8, 512])
+def test_the_combine_kernel_adds_what_the_scatter_adds(rows, hidden, tail):
+    """``ops/moe.py::_combine`` (interpreted here) against XLA's
+    scatter-add on the same operands, bit for bit: a sum that is not zero
+    going in, a block's tokens sorted and distinct, the rows past an
+    expert's last assignment named past the last token."""
+    tokens = 640
+    live = {"no_row_dropped": rows, "part_filled": rows * 5 // 8,
+            "every_row_dropped": 0}[tail]
+    rng = np.random.default_rng(rows + hidden + live)
+    token = tokens + np.arange(rows)
+    token[:live] = np.sort(rng.choice(tokens, live, replace=False))
+    token = jnp.asarray(token, jnp.int32)
+    total = jnp.asarray(rng.standard_normal((tokens, hidden)), jnp.float32)
+    block = jnp.asarray(rng.standard_normal((rows, hidden)), jnp.float32)
+    scattered = total.at[token].add(block, mode="drop", indices_are_sorted=True,
+                                    unique_indices=True)
+    combined = jax.jit(moe._combine)(total[:, None], token, block)[:, 0]
+    assert np.array_equal(np.asarray(combined), np.asarray(scattered))
+    assert np.array_equal(np.asarray(combined[token[:live]]),
+                          np.asarray(total[token[:live]] + block[:live]))
+    assert (live == 0) == np.array_equal(np.asarray(combined), np.asarray(total))
+
+
+def _adds_of(x):
+    """How a share's loops add a block's rows into a sum of rows like `x`'s."""
+    return moe._sum_of_rows(x)[1]
+
+
+def test_a_sum_of_whole_float32_tiles_goes_through_the_kernel():
+    """The choice is the activations' width and type alone: rows of whole
+    128-lane tiles of 32-bit words take the kernel (every token cell's:
+    2,304, 2,048 and 2,688 wide, float32), anything else XLA's scatter."""
+    for width in (128, 2048, 2304, 2688):
+        assert _adds_of(jnp.zeros((8, width))) is moe._combine
+    assert _adds_of(jnp.zeros((8, 16))) is moe._scatter
+    assert _adds_of(jnp.zeros((8, 200))) is moe._scatter
+    assert _adds_of(jnp.zeros((8, 128), jnp.bfloat16)) is moe._scatter
+    x, chosen, weights, *matrices = _moe_inputs(dim=128)
+    traced = str(jax.make_jaxpr(
+        lambda x: jax.grad(lambda x: jnp.sum(moe.held_experts(
+            x, chosen, weights, *matrices, first=0)))(x))(x))
+    # the scatter-adds left are the experts' own gradients, ``d_w.at[expert]``
+    assert traced.count("moe_combine") >= 2
+    assert "scatter-add" in traced and "f32[64,128] = scatter-add" not in traced
+
+
+@pytest.mark.parametrize("routing", ["routed", "collapsed"])
+@pytest.mark.parametrize("rows", [5, 64, 512])
+def test_the_kernels_loops_give_the_plain_loops_sum_and_gradients(rows, routing):
+    """The share at a width the kernel takes (128): its sum and every
+    gradient against the plain loop over experts, blocks part-filled
+    (5 rows), whole, and larger than the tokens; and under the routing of
+    a collapsed router, every token to the same held experts."""
+    x, chosen, weights, w_gate, w_up, w_down = _moe_inputs(dim=128)
+    if routing == "collapsed":
+        chosen = jnp.tile(jnp.asarray([[5, 1, 6, 14]], jnp.int32), (64, 1))
+
+    def ours(x, weights, w_gate, w_up, w_down):
+        return jnp.sin(moe.held_experts(
+            x, chosen, weights, w_gate, w_up, w_down, first=4, block_rows=rows))
+
+    def plain(x, weights, w_gate, w_up, w_down):
+        held = jnp.where((chosen >= 4) & (chosen < 8), weights, 0.0)
+        return jnp.sin(_every_expert_in_a_loop(
+            x, chosen - 4, held, w_gate, w_up, w_down))
+
+    args = (x, weights, w_gate[4:8], w_up[4:8], w_down[4:8])
+    _close(jax.jit(ours)(*args), plain(*args), 1e-5)
+    for a, b in zip(
+            jax.jit(jax.grad(lambda *a: jnp.sum(ours(*a)), argnums=range(5)))(*args),
+            jax.grad(lambda *a: jnp.sum(plain(*a)), argnums=range(5))(*args)):
+        _close(a, b, 1e-4)
+
+
 def test_the_bias_moves_towards_the_mean_load_and_balances_a_skewed_router():
     """``b_e += rate * sign(mean load - load_e)``: one step by hand, and
     a router whose scores favour four experts for every token spreads
