@@ -66,6 +66,7 @@ __all__ = [
     "DEFAULT_CACHE_DIR",
     "configure_compile_cache",
     "seam_jit",
+    "roomy",
     "instrument_jitted",
     "aot_compile",
     "compile_cache_stats",
@@ -202,6 +203,36 @@ def _record(label: str, sec: float, verdict: str) -> None:
                     label, sec, verdict)
 
 
+def roomy(fn: Callable) -> Callable:
+    """`fn` itself, its frame given a stretch of the interpreter's frame
+    stack for all that it calls (a decorator; no frame is added).
+
+    CPython (3.11 on) keeps a thread's frames in chunks of 16 KiB and
+    unmaps a chunk the moment its first frame returns, so a frame that
+    happens to END a chunk maps and unmaps one for every call it makes.
+    Where JAX's trace and lowering recurse 50-300 frames deep and loop
+    there over thousands of equations that bites, and where a chunk ends
+    is decided by the sizes of all the frames under the jit call — the
+    trainer's, its caller's, the script's.  One frame more under
+    ``train_and_eval`` put Mosaic's loop over a kernel's equations on
+    such an end: the Kimi cell's ``jaxpr to MLIR`` read 32 s against 3 s,
+    every call a ``munmap`` in a process full of the TPU runtime's
+    threads (my chip runs, PR 45: PERF.md §6).  `fn`'s code is made to
+    declare a stack of 2**15 slots it never uses, so the interpreter
+    gives its frame a chunk of its own (512 KiB, the upper half free)
+    and what it calls runs inside that: no end of a chunk to sit on,
+    whatever is under it."""
+    fn.__code__ = fn.__code__.replace(co_stacksize=1 << 15)
+    return fn
+
+
+@roomy
+def _with_room(fn: Callable, *args: Any, **kwargs: Any):
+    """``fn(*args, **kwargs)`` in a :func:`roomy` frame: every seam's
+    first call, whoever makes it."""
+    return fn(*args, **kwargs)
+
+
 class _SeamWrapped:
     """A jitted callable instrumented at its first invocation.
 
@@ -232,7 +263,7 @@ class _SeamWrapped:
         # parent's self-time is then its own work, not this compile or load
         with telemetry.stage(f"first_call:{self._seam_label}"):
             t0 = time.perf_counter()
-            out = self._jitted(*args, **kwargs)
+            out = _with_room(self._jitted, *args, **kwargs)
             sec = time.perf_counter() - t0
         self._first_done = True
         _record(self._seam_label, sec, _classify(h0, m0))

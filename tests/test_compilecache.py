@@ -384,3 +384,54 @@ def test_exit77_resume_reports_cache_hit(tmp_path):
                   r2.stderr)
     assert m, r2.stderr[-2000:]
     assert int(m.group(1)) > 0, "resumed process reported no cache hits"
+
+
+# ---------------------------------------------------------------------------
+# the first call's own stretch of the interpreter's frame stack (_with_room)
+# ---------------------------------------------------------------------------
+
+
+def _faults_by_depth(call, depths, calls=1000):
+    """Minor page faults of a loop of `calls` small calls made `depth`
+    plain frames down, for each depth, entered through `call(fn, depth)`."""
+    import resource
+
+    def faults():
+        return resource.getrusage(resource.RUSAGE_SELF).ru_minflt
+
+    # a callee whose frame is larger than `down`'s: the scan over depths
+    # moves the loop's frame along a chunk in steps of `down`'s frame, and
+    # the loop thrashes where less than the callee's frame is left
+    leaf = eval("lambda " + ", ".join(f"a{i}=0" for i in range(40)) + ": a0")
+
+    def hot():
+        before = faults()
+        for _ in range(calls):
+            leaf()
+        return faults() - before
+
+    def down(depth):
+        return hot() if depth == 0 else down(depth - 1)
+
+    return {depth: call(down, depth) for depth in depths}
+
+
+def test_with_room_passes_the_call_through():
+    assert cc._with_room(lambda a, b=0: (a, b), 1, b=2) == (1, 2)
+    with pytest.raises(KeyError, match="gone"):
+        cc._with_room({}.__getitem__, "gone")
+
+
+def test_with_room_keeps_a_loop_off_the_end_of_a_stack_chunk():
+    """CPython keeps frames in 16 KiB chunks and unmaps a chunk when its
+    first frame returns: at some depth a loop of calls maps and unmaps
+    one per call (a page fault each, at least).  Under `_with_room` no
+    depth does."""
+    depths, calls = range(0, 320), 1000
+    plain = _faults_by_depth(lambda fn, depth: fn(depth), depths, calls)
+    if max(plain.values()) < calls:
+        pytest.skip("this interpreter maps no stack chunk per call at any "
+                    f"depth to {depths[-1]}: nothing for _with_room to keep off")
+    roomy = _faults_by_depth(cc._with_room, depths, calls)
+    assert max(roomy.values()) < calls // 4, (
+        max(plain.values()), {d: f for d, f in roomy.items() if f >= calls // 4})
