@@ -47,6 +47,8 @@ __all__ = [
     "MAMBA2",
     "SSD_SCAN",
     "GQA",
+    "SWA",
+    "GQA_ATTENTION",
     "LOSS",
     "OPTIMIZER",
     "EMA",
@@ -91,7 +93,7 @@ SHAKE_SHORTCUT = "faa_shake_shortcut"
 #: (7x7 stride-2 convolution, BatchNorm, ReLU, 3x3 stride-2 max-pool)
 RESNET_STEM = "faa_resnet_stem"
 #: the token models (``models/kimi_linear.py``, ``models/glm4_moe_lite.py``,
-#: ``models/nemotron_h.py``, ``models/token_blocks.py``), all nested under
+#: ``models/nemotron_h.py``, ``models/afmoe.py``, ``models/token_blocks.py``), all nested under
 #: ``faa_model``: the KDA mixer
 #: (projections, short convolutions, gates, output norm and gate) with the
 #: chunked delta-rule recurrence alone inside it (``ops/kda.py``, forward
@@ -126,13 +128,25 @@ MTP = "faa_mtp"
 #: (``in_proj``, the causal convolution, the step's softplus, the gated
 #: grouped norm, ``out_proj``) with the chunked state-space scan alone
 #: inside it (``ops/ssd.py::chunk_ssd``, forward and backward); and a
-#: grouped-query attention mixer whole (its four projections and the causal
+#: grouped-query attention mixer whole (its projections and the causal
 #: softmax: the fused kernels of ``ops/attention.py`` with the key-value
-#: heads repeated in front of them; the core has no scope of its own there,
-#: ``faa_mla_attention`` stays the latent-attention cores')
+#: heads repeated in front of them; ``models/afmoe.py``'s mixers too, with
+#: their norms a head, rotary and gate; ``faa_mla_attention`` stays the
+#: latent-attention cores', a grouped-query core's scope is
+#: ``faa_gqa_attention`` below)
 MAMBA2 = "faa_mamba2"
 SSD_SCAN = "faa_ssd_scan"
 GQA = "faa_gqa"
+#: ``models/token_blocks.py::GQAMixer`` (``models/afmoe.py``'s mixers and
+#: ``models/nemotron_h.py``'s), nested under ``faa_gqa``: a mixer whose key
+#: span is shorter than the whole past (a window layer), whole; and, in
+#: every grouped-query mixer, the attention core alone
+#: (``ops/attention.py::blocked_causal_attention``'s call: the fused kernels
+#: with the XLA operations that repeat the key-value heads and round the
+#: operands in front of them; forward, backward and what ``nn.remat``
+#: computes again)
+SWA = "faa_swa"
+GQA_ATTENTION = "faa_gqa_attention"
 LOSS = "faa_loss"
 #: ``train/steps.py::step_fn``: update and parameter add; EMA; top-k and sums
 OPTIMIZER = "faa_optimizer"

@@ -8,9 +8,9 @@ are the train step's concern — a module is pure structure.
 Supported types (reference parity): resnet50, resnet200, wresnet40_2,
 wresnet28_10, shakeshake26_2x32d / 2x64d / 2x96d / 2x112d,
 shakeshake26_2x96d_next, pyramid, efficientnet-b0..b7 (+condconv).
-Beyond the reference, three token models: kimi_linear
-(``models/kimi_linear.py``), glm4_moe_lite (``models/glm4_moe_lite.py``) and
-nemotron_h (``models/nemotron_h.py``).
+Beyond the reference, four token models: kimi_linear
+(``models/kimi_linear.py``), glm4_moe_lite (``models/glm4_moe_lite.py``),
+nemotron_h (``models/nemotron_h.py``) and afmoe (``models/afmoe.py``).
 """
 
 from __future__ import annotations
@@ -141,6 +141,10 @@ def get_model(conf: Any, num_classes: int) -> nn.Module:
         from fast_autoaugment_tpu.models.nemotron_h import nemotron_h_from_conf
 
         return nemotron_h_from_conf(conf, dtype=dtype)
+    if name == "afmoe":
+        from fast_autoaugment_tpu.models.afmoe import afmoe_from_conf
+
+        return afmoe_from_conf(conf, dtype=dtype)
     if name.startswith("efficientnet"):
         from fast_autoaugment_tpu.models.efficientnet import EfficientNet
 

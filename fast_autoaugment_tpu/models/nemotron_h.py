@@ -25,10 +25,11 @@ says, layer by layer:
        key-value head ``g`` serving the query heads ``[g * n, (g + 1) * n)``,
        one causal softmax at ``head_dim ** -0.5``, ``o_proj``.  **No
        position encoding**: the family's layers take their order from the
-       Mamba-2 layers around them.  The core is ``ops/attention.py``'s (the
-       fused kernels at the configuration's shapes), given the key-value
-       heads repeated to every query head: the repeat's transpose sums a
-       group's gradient.
+       Mamba-2 layers around them.  ``models/token_blocks.py::GQAMixer``
+       with none of its optional parts; the core is ``ops/attention.py``'s
+       (the fused kernels at the configuration's shapes), given the
+       key-value heads repeated to every query head: the repeat's transpose
+       sums a group's gradient.
 ``E``  a sigmoid-routed expert layer beside one shared expert
        (``models/token_blocks.py::ExpertLayer``, ``ops/moe.py``) whose
        experts are two matrices and a squared ReLU (``mlp_hidden_act:
@@ -66,6 +67,7 @@ from fast_autoaugment_tpu.models.token_blocks import (
     ROUTING,
     STEP_STATS,
     ExpertLayer,
+    GQAMixer,
     Kernel,
     RMSNorm,
     Sizes,
@@ -76,7 +78,6 @@ from fast_autoaugment_tpu.models.token_blocks import (
     refuse_unwritten_routing,
     step_bias_init,
 )
-from fast_autoaugment_tpu.ops.attention import blocked_causal_attention
 from fast_autoaugment_tpu.ops.lm_head import blocked_next_token_sums
 from fast_autoaugment_tpu.ops.ssd import chunk_ssd
 
@@ -156,25 +157,6 @@ class Mamba2Mixer(nn.Module):
                         name="out_proj", dtype=self.dtype)(y)
 
 
-class GQAMixer(nn.Module):
-    conf: Any
-    dtype: Any = jnp.float32
-
-    @nn.compact
-    def __call__(self, x):
-        c = self.conf
-        batch, length, hidden = x.shape
-        q = dense(c.heads * c.head_dim, "q_proj", self.dtype)(x).reshape(
-            batch, length, c.heads, c.head_dim)
-        k, v = (dense(c.kv_heads * c.head_dim, f"{name}_proj", self.dtype)(x)
-                .reshape(batch, length, c.kv_heads, c.head_dim) for name in "kv")
-        # key-value head g serves the query heads [g * n, (g + 1) * n)
-        k, v = (jnp.repeat(a, c.heads // c.kv_heads, axis=2) for a in (k, v))
-        out = blocked_causal_attention(q, k, v, scale=c.head_dim ** -0.5)
-        return dense(hidden, "o_proj", self.dtype)(
-            out.astype(self.dtype).reshape(batch, length, c.heads * c.head_dim))
-
-
 class Layer(nn.Module):
     conf: Any
     kind: str            # a character of the pattern
@@ -189,7 +171,8 @@ class Layer(nn.Module):
                 return x + Mamba2Mixer(c, self.dtype, name="mamba")(normed)
         if self.kind == ATTENTION:
             with jax.named_scope(scopes.GQA):
-                return x + GQAMixer(c, self.dtype, name="attn")(normed)
+                return x + GQAMixer(c.heads, c.kv_heads, c.head_dim, self.dtype,
+                                    name="attn")(normed)
         with jax.named_scope(scopes.MOE):
             return x + ExpertLayer(
                 c.experts, c.experts_held, c.expert_share, c.top_k, c.expert_width,

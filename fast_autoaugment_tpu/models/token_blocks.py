@@ -1,7 +1,7 @@
 """What the token models share: the blocks of a pre-norm decoder whose
 FFN may be a sigmoid-routed expert layer this chip holds a share of
 (``models/kimi_linear.py``, ``models/glm4_moe_lite.py``,
-``models/nemotron_h.py``).
+``models/nemotron_h.py``, ``models/afmoe.py``).
 
 - :class:`RMSNorm`, the bias-free :func:`dense`, a bare :class:`Kernel`,
   and two feed-forward forms: :class:`SwiGLU` and :class:`SquaredReLU`;
@@ -9,6 +9,9 @@ FFN may be a sigmoid-routed expert layer this chip holds a share of
   latent always, the query whole or through a low-rank pair with a norm
   between (``q_rank``), the shared key part and every head's matching
   query part rotated by position or left as they are (``rope_theta``);
+- :class:`GQAMixer`, grouped-query attention, bare (Nemotron-H's) or with
+  a norm a head on queries and keys, rotary, a key span and a gate on the
+  output (afmoe's), each by an argument;
 - :class:`ExpertLayer`, the routed experts this chip holds beside the
   shared expert (``ops/moe.py``), both of one form (``ops/moe.py::FORMS``),
   the shared one at ``width * shared`` or at a width of its own;
@@ -43,7 +46,8 @@ from fast_autoaugment_tpu.core import scopes
 from fast_autoaugment_tpu.ops import moe
 from fast_autoaugment_tpu.ops.attention import blocked_causal_attention
 
-__all__ = ["RMSNorm", "SwiGLU", "SquaredReLU", "Kernel", "MLAMixer", "ExpertLayer",
+__all__ = ["RMSNorm", "SwiGLU", "SquaredReLU", "Kernel", "MLAMixer", "GQAMixer",
+           "ExpertLayer",
            "FEED_FORWARDS", "Sizes", "dense", "step_bias_init",
            "rotate_by_position", "expert_share_of", "refuse_unwritten_routing",
            "balance_routers", "publish_router_counts", "INIT", "STEP_STATS",
@@ -90,19 +94,27 @@ def dense(features: int, name: str, dtype) -> nn.Dense:
                     dtype=dtype)
 
 
-def rotate_by_position(x, theta: float):
+def rotate_by_position(x, theta: float, pairs: str = "interleaved"):
     """Rotary position embedding over the whole last axis of `x` ``[B, T,
-    ..., D]``, token ``t`` at position ``t``: the pair ``(x[2i], x[2i +
-    1])`` turned by ``t * theta ** (-2i / D)`` (DeepSeek-V3's interleaved
-    pairs).  Comes back with the pairs' first members in the first half
-    and the second members in the second, for queries and keys alike, so
-    their products are the interleaved layout's."""
+    ..., D]``, token ``t`` at position ``t``: pair ``i`` turned by ``t *
+    theta ** (-2i / D)``.  `pairs` says which two channels pair ``i`` is:
+    ``interleaved``, ``(x[2i], x[2i + 1])`` (DeepSeek-V3's layout) — comes
+    back with the pairs' first members in the first half and the second
+    members in the second, for queries and keys alike, so their products
+    are the interleaved layout's; ``halves``, ``(x[i], x[i + D/2])`` (the
+    ``rotate_half`` layout), every channel back in its own place."""
     length, dim = x.shape[1], x.shape[-1]
     inverse = theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
     angle = jnp.arange(length, dtype=jnp.float32)[:, None] * inverse[None, :]
     angle = angle.reshape((1, length) + (1,) * (x.ndim - 3) + (dim // 2,))
     cos, sin = jnp.cos(angle), jnp.sin(angle)
-    first, second = x[..., 0::2].astype(jnp.float32), x[..., 1::2].astype(jnp.float32)
+    if pairs == "interleaved":
+        first, second = x[..., 0::2], x[..., 1::2]
+    elif pairs == "halves":
+        first, second = x[..., :dim // 2], x[..., dim // 2:]
+    else:
+        raise ValueError(f"pairs={pairs!r}: 'interleaved' or 'halves'")
+    first, second = first.astype(jnp.float32), second.astype(jnp.float32)
     return jnp.concatenate([first * cos - second * sin,
                             first * sin + second * cos], -1).astype(x.dtype)
 
@@ -149,6 +161,54 @@ class MLAMixer(nn.Module):
                 scale=(self.nope_dim + self.pe_dim) ** -0.5)
         return dense(hidden, "o_proj", self.dtype)(
             out.reshape(batch, length, heads * self.v_dim))
+
+
+class GQAMixer(nn.Module):
+    """Grouped-query attention: `heads` query heads of `head_dim` on
+    `kv_heads` key-value heads, key-value head ``g`` serving the query
+    heads ``[g * n, (g + 1) * n)``, one causal softmax at ``head_dim **
+    -0.5``, no bias.  Each of the rest is left out where it is not given:
+    `qk_norm_eps`, an RMS norm over every head's ``head_dim`` channels of
+    the queries and of the keys (one weight each, ``q_norm`` and
+    ``k_norm``); `rope_theta`, queries and keys rotated by position over
+    all of ``head_dim``, pairs ``(i, i + head_dim / 2)``; `window`, the key
+    span (``ops/attention.py``); `gated`, the output times ``sigmoid(gate_proj
+    x)`` in front of ``o_proj``.  The core is ``ops/attention.py``'s (the
+    fused kernels at the configurations' shapes), given the key-value heads
+    repeated to every query head: the repeat's transpose sums a group's
+    gradient."""
+
+    heads: int
+    kv_heads: int
+    head_dim: int
+    dtype: Any = jnp.float32
+    qk_norm_eps: float | None = None
+    rope_theta: float | None = None
+    window: int | None = None
+    gated: bool = False
+
+    @nn.compact
+    def __call__(self, x):
+        batch, length, hidden = x.shape
+        q = dense(self.heads * self.head_dim, "q_proj", self.dtype)(x).reshape(
+            batch, length, self.heads, self.head_dim)
+        k, v = (dense(self.kv_heads * self.head_dim, f"{name}_proj", self.dtype)(x)
+                .reshape(batch, length, self.kv_heads, self.head_dim) for name in "kv")
+        if self.qk_norm_eps is not None:
+            q = RMSNorm(self.qk_norm_eps, name="q_norm")(q)
+            k = RMSNorm(self.qk_norm_eps, name="k_norm")(k)
+        if self.rope_theta is not None:
+            q, k = (rotate_by_position(a, self.rope_theta, "halves") for a in (q, k))
+        with jax.named_scope(scopes.GQA_ATTENTION):
+            # key-value head g serves the query heads [g * n, (g + 1) * n)
+            k, v = (jnp.repeat(a, self.heads // self.kv_heads, axis=2) for a in (k, v))
+            out = blocked_causal_attention(q, k, v, scale=self.head_dim ** -0.5,
+                                           window=self.window)
+        out = out.astype(self.dtype).reshape(batch, length, self.heads * self.head_dim)
+        if self.gated:
+            out = out * jax.nn.sigmoid(
+                dense(self.heads * self.head_dim, "gate_proj", self.dtype)(x))
+        return dense(hidden, "o_proj", self.dtype)(out)
 
 
 class Kernel(nn.Module):

@@ -6,12 +6,19 @@ GLM-4.7-Flash with) has two parts, a head's own ``q_nope . k_nope`` and
 ``q_pe . k_pe`` against a key part that all heads share.  Whole, the
 scores of 32 heads over 8,192 tokens are 8.6 GB in float32.
 
+**A key span** (`window`): query ``i`` sees key ``j`` iff ``0 <= i - j <
+window``, the query's own key among them; without one, the whole causal
+past.  Both forms take it, and grouped-query attention comes through the
+same function with no shared key part (``models/token_blocks.py::
+GQAMixer``: afmoe's window and full layers, Nemotron-H's full ones).
+
 **Two forms, chosen by shape** (:func:`_fused_tile`).  Where a head's
 values are whole lanes (a multiple of 128), its own key at least 128
 wide, the sequence at least two tiles long and one head's whole sequence
-inside the kernels' VMEM budget (~12.8 k tokens at 256 + 256) — both
-configurations' shapes: 128 + 64 / 128 and 192 + 64 / 256 at 8,192
-tokens —
+inside the kernels' VMEM budget (2 x 4 x T x (2 x key width + value
+width) bytes within 78.6 MB: 12,800 tokens at a key and values of 256 +
+256, 25,600 at 128 + 128) — the configurations' shapes: 128 + 64 / 128
+and 192 + 64 / 256 at 8,192 tokens, 128 / 128 at 8,192 and at 16,384 —
 :func:`blocked_causal_attention` runs a pair of fused TPU kernels under
 a ``jax.custom_vjp`` (:func:`_fused_attention`; interpreted where the
 backend is no TPU, so a CPU test runs the code the chip runs).  The tile
@@ -32,13 +39,22 @@ tiles above the diagonal are never met and only the one on it is masked
 — with a running maximum, a running sum and the output rescaled as the
 maximum grows (the online softmax).  A tile's scores ``[512, 512]`` live
 in VMEM from their product to their weighted sum.  Out come the
-attention's output and every row's log-sum-exp ``[B, H, T]``.
+attention's output and every row's log-sum-exp ``[B, H, T]``.  Under a
+key span the walk starts ``ceil((window - 1) / tile)`` tiles under the
+diagonal and not at 0, so the tiles behind the band are never met
+either: the diagonal tile first (every row has a key in it, so the
+running maximum is finite from then on), the tiles wholly inside the
+band unmasked, the one at the band's trailing edge (two where the span
+is no whole number of tiles) masked by the span — ``key > query``,
+strictly, where the span is whole tiles.
 
 *Backward* (:func:`_backward_kernel`), written by hand: a grid step is
 one tile of one head's keys against that head's query tiles from the
-diagonal on, with ``q``, the output's cotangent and ``dq`` whole in VMEM
-(``dq`` gathers every key tile's share there and is written once a
-head).  A tile's probabilities are computed again from ``q``, ``k`` and
+diagonal on — under a key span, up to the last query tile whose band
+reaches it, ``ceil((window - 1) / tile)`` past its own — with ``q``, the
+output's cotangent and ``dq`` whole in VMEM (``dq`` gathers every key
+tile's share there and is written once a head).  A tile's probabilities
+are computed again from ``q``, ``k`` and
 the kept log-sum-exp, and the five products of a tile (scores, the
 weights' cotangent, ``dv``, ``dk``, ``dq``) follow.  The tile is held
 keys-by-queries: what belongs to a query (its log-sum-exp, ``delta =
@@ -56,6 +72,7 @@ the backward pass alike.  A block's body is wrapped in
 sequence is cut into `spans` static spans, each with a loop of its own
 over keys that end where the span ends: with four spans 62.5% of the full
 square is computed.  `k_shared` is taken apart there and never copied.
+A key span is a mask there and skips nothing.
 
 The products take the ambient matmul precision, as an ``einsum`` does
 (``ops/kda.py``'s rule): on the chip by default the kernels round their
@@ -64,7 +81,12 @@ copies) and sum in float32, the softmax is float32; under
 ``jax.default_matmul_precision("highest")`` every product is float32.
 
 ``faa_mla_attention_traces_total{form}`` counts, at trace time, which
-form a program got: ``fused`` or ``blocked_xla``.
+form a program got: ``fused`` or ``blocked_xla``;
+``faa_attention_cores_traced_total{form, span}`` the same by key span
+(``none`` without one), and ``faa_attention_key_tiles_total{span, kind}``
+the key tiles a core's loops meet over one head's sequence (``visited``)
+beside the causal half's (``causal``): 150 of 528 at 16,384 tokens, a
+tile of 512 and a span of 2,048.
 """
 
 from __future__ import annotations
@@ -93,21 +115,35 @@ VMEM_LIMIT_BYTES = 100 * 1024 * 1024
 
 def blocked_causal_attention(q, k, v, *, scale: float, q_shared=None,
                              k_shared=None, block: int = DEFAULT_QUERY_BLOCK,
-                             spans: int = DEFAULT_SPANS):
+                             spans: int = DEFAULT_SPANS, window: int | None = None):
     """``softmax(causal(q k^T + q_shared k_shared^T) * scale) v``.
 
     `q`, `k`: ``[B, T, H, D]``; `v`: ``[B, T, H, Dv]``; `q_shared`
     ``[B, T, H, Ds]`` with `k_shared` ``[B, T, Ds]`` (one key part for all
     heads), or neither.  Returns ``[B, T, H, Dv]``.  `block` and `spans`
-    shape the XLA form alone; the kernels' tile follows from the shapes."""
+    shape the XLA form alone; the kernels' tile follows from the shapes.
+    `window`: the key span, query ``i`` sees key ``j`` iff ``0 <= i - j <
+    window`` (its own among them); None, or a span the sequence does not
+    outgrow: the whole causal past."""
+    length = q.shape[1]
+    if window is not None:
+        if window < 1:
+            raise ValueError(f"window={window}: a query sees its own key at least")
+        if window >= length:
+            window = None
     tile = _fused_tile(q, v, q_shared)
     # trace time: which form each program that holds an attention core got
+    form = "blocked_xla" if tile is None else "fused"
     telemetry.registry().counter(
         "faa_mla_attention_traces_total", "latent-attention cores traced into a "
-        "program, by the form that computes them",
-        form="blocked_xla" if tile is None else "fused").inc()
+        "program, by the form that computes them", form=form).inc()
+    telemetry.registry().counter(
+        "faa_attention_cores_traced_total", "attention cores traced into a "
+        "program, by the form that computes them and their key span",
+        form=form, span=_span_label(window)).inc()
     if tile is None:
-        return _blocked_xla(q, k, v, q_shared, k_shared, scale, block, spans)
+        return _blocked_xla(q, k, v, q_shared, k_shared, scale, block, spans, window)
+    _count_key_tiles(window, *key_tiles(length, tile, window))
     batch, length, heads, _ = q.shape
     if q_shared is not None:
         # the shared key part, copied to every head: one product a tile
@@ -123,7 +159,39 @@ def blocked_causal_attention(q, k, v, *, scale: float, q_shared=None,
     # operands for (a model in ``precision: bf16`` under a float32 comparison)
     q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
     return _fused_attention(q, k, v, float(scale), tile, kda._float32_products(),
-                            not kda._on_tpu())
+                            not kda._on_tpu(), window)
+
+
+def _reach(window: int, tile: int) -> int:
+    """How many key tiles below the diagonal one a query tile's band
+    reaches: ``ceil((window - 1) / tile)``."""
+    return -(-(window - 1) // tile)
+
+
+def key_tiles(length: int, tile: int, window: int | None) -> tuple[int, int]:
+    """``(visited, causal)``: the key tiles the fused kernels' loops meet
+    over one head's sequence, and the causal half's (150 of 528 at 16,384
+    tokens, a tile of 512 and a span of 2,048)."""
+    count = length // tile
+    causal = count * (count + 1) // 2
+    if window is None:
+        return causal, causal
+    reach = _reach(window, tile)
+    return sum(min(i, reach) + 1 for i in range(count)), causal
+
+
+def _span_label(window: int | None) -> str:
+    return "none" if window is None else str(window)
+
+
+def _count_key_tiles(window: int | None, visited: int, causal: int) -> None:
+    """Trace time: the key tiles a core's loops meet over one head's
+    sequence, beside the causal half's."""
+    for kind, tiles in (("visited", visited), ("causal", causal)):
+        telemetry.registry().counter(
+            "faa_attention_key_tiles_total", "key tiles the traced attention "
+            "cores' loops meet over one head's sequence (visited), beside the "
+            "causal half's", span=_span_label(window), kind=kind).inc(tiles)
 
 
 def _fused_tile(q, v, q_shared) -> int | None:
@@ -149,11 +217,27 @@ def _fused_tile(q, v, q_shared) -> int | None:
 # head's ``[tile, D]`` is then a block whose last dimension is whole lanes,
 # fetched by a strided DMA, and nothing is transposed in HBM.
 
-def _causal(tile: int, *, keys_first: bool):
-    """``[tile, tile]``: whether the query sees the key, on the diagonal."""
+def _causal(tile: int, *, keys_first: bool, window: int | None = None):
+    """``[tile, tile]``: whether the query sees the key, on the diagonal;
+    under a `window` shorter than a tile the band cuts that tile too."""
     row = jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 1)
-    return col >= row if keys_first else row >= col
+    seen = col >= row if keys_first else row >= col
+    if window is not None and window < tile:
+        seen &= _in_band(tile, 0, window, keys_first=keys_first)
+    return seen
+
+
+def _in_band(tile: int, apart, window: int, *, keys_first: bool):
+    """``[tile, tile]``: whether the key is still inside the query's span
+    of `window`, the query's tile `apart` tiles past the key's: ``apart *
+    tile + query - key < window``.  At the band's trailing edge of a span
+    that is whole tiles (``apart * tile == window``) that is ``key >
+    query``, strictly: the causal mask's complement."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 1)
+    ahead = col - row if keys_first else row - col        # query - key
+    return ahead < window - apart * tile
 
 
 def _as_row(column):
@@ -168,13 +252,18 @@ def _as_row(column):
         for first in range(0, size, LANES)], 1)
 
 
-def _forward_kernel(q_ref, k_ref, v_ref, o_ref, *lse_ref, scale: float, exact: bool):
+def _forward_kernel(q_ref, k_ref, v_ref, o_ref, *lse_ref, scale: float, exact: bool,
+                    window: int | None):
     """One tile of one head's queries against that head's keys up to the
     diagonal: `k_ref`, `v_ref` hold the head's whole sequence (fetched once
     a head), a tile of scores lives from its product to its weighted sum,
     and the softmax is the running one (maximum, sum, rescaled output).
     `lse_ref` ``[1, 1, N, tile]``, where a backward pass follows: every
-    row's log-sum-exp, a tile a row."""
+    row's log-sum-exp, a tile a row.  With a `window` the walk starts
+    ``ceil((window - 1) / tile)`` tiles under the diagonal and not at 0:
+    the diagonal tile first (every row has a key in it, so the running
+    maximum is finite from then on), the tiles wholly inside the band
+    unmasked, the one or two at its trailing edge masked by the span."""
     i = pl.program_id(2)
     tile = q_ref.shape[1]
     q = q_ref[0]
@@ -195,16 +284,33 @@ def _forward_kernel(q_ref, k_ref, v_ref, o_ref, *lse_ref, scale: float, exact: b
     carry = (jnp.full((tile, 1), -jnp.inf, jnp.float32),
              jnp.zeros((tile, 1), jnp.float32),
              jnp.zeros((tile, v_ref.shape[-1]), jnp.float32))
-    # the key tiles wholly below the diagonal, then the one on it
-    carry = jax.lax.fori_loop(0, i, against, carry)
-    top, total, out = against(i, carry, _causal(tile, keys_first=False))
+    if window is None:
+        # the key tiles wholly below the diagonal, then the one on it
+        carry = jax.lax.fori_loop(0, i, against, carry)
+        top, total, out = against(i, carry, _causal(tile, keys_first=False))
+    else:
+        carry = against(i, carry, _causal(tile, keys_first=False, window=window))
+        first, whole = _band(i, window, tile)
+        carry = jax.lax.fori_loop(whole, i, against, carry)
+        top, total, out = jax.lax.fori_loop(
+            first, whole, lambda j, carry: against(j, carry, _in_band(
+                tile, i - j, window, keys_first=False)), carry)
     o_ref[0] = out / total
     for ref in lse_ref:
         ref[0, 0, pl.ds(i, 1), :] = _as_row(top + jnp.log(total))
 
 
+def _band(i, window: int, tile: int):
+    """``(first, whole)`` for the query tile `i` under a span of `window`:
+    the first key tile its band reaches, and the first that lies wholly
+    inside the band (the diagonal tile `i` where none below it does)."""
+    first = jnp.maximum(i - _reach(window, tile), 0)
+    return first, jnp.maximum(i - max(window // tile - 1, 0), first)
+
+
 def _backward_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                     dq_ref, dk_ref, dv_ref, *, scale: float, exact: bool):
+                     dq_ref, dk_ref, dv_ref, *, scale: float, exact: bool,
+                     window: int | None):
     """One tile of one head's keys against that head's queries from the
     diagonal on: `q_ref`, `do_ref` and `dq_ref` hold the head's whole
     sequence (`dq_ref` stays in VMEM while the key tiles go by and gathers
@@ -236,9 +342,21 @@ def _backward_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         return (dk + kda._dot(d_scores, q, kda._NN, exact),
                 dv + kda._dot(weights, do, kda._NN, exact))
 
-    carry = against(j, (jnp.zeros(k.shape, jnp.float32), jnp.zeros(v.shape, jnp.float32)),
-                    _causal(tile, keys_first=True))
-    dk_ref[0], dv_ref[0] = jax.lax.fori_loop(j + 1, count, against, carry)
+    carry = (jnp.zeros(k.shape, jnp.float32), jnp.zeros(v.shape, jnp.float32))
+    if window is None:
+        carry = against(j, carry, _causal(tile, keys_first=True))
+        dk_ref[0], dv_ref[0] = jax.lax.fori_loop(j + 1, count, against, carry)
+    else:
+        # the query tiles whose band reaches this key tile: its own, those
+        # it lies wholly inside the band of, the one or two it is the
+        # trailing edge of
+        carry = against(j, carry, _causal(tile, keys_first=True, window=window))
+        whole = jnp.minimum(j + max(window // tile, 1), count)
+        carry = jax.lax.fori_loop(j + 1, whole, against, carry)
+        dk_ref[0], dv_ref[0] = jax.lax.fori_loop(
+            whole, jnp.minimum(j + _reach(window, tile) + 1, count),
+            lambda i, carry: against(i, carry, _in_band(
+                tile, i - j, window, keys_first=True)), carry)
 
 
 class _Blocks:
@@ -282,8 +400,10 @@ def _flat(a):
 # Mosaic once a program.
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "tile", "exact", "interpret", "keep"))
-def _forward(q, k, v, scale: float, tile: int, exact: bool, interpret: bool, keep: bool):
+@functools.partial(jax.jit, static_argnames=("scale", "tile", "exact", "interpret", "keep",
+                                             "window"))
+def _forward(q, k, v, scale: float, tile: int, exact: bool, interpret: bool, keep: bool,
+             window: int | None):
     """``out [B, T, H, Dv]`` and, with `keep`, the rows' log-sum-exp ``[B,
     H, N, tile]``; `q`, `k`, `v` as the products take them."""
     blocks = _Blocks(q, tile, interpret)
@@ -294,7 +414,7 @@ def _forward(q, k, v, scale: float, tile: int, exact: bool, interpret: bool, kee
         out_shape.append(blocks.rows_shape)
         out_specs.append(blocks.rows)
     out, *lse = pl.pallas_call(
-        functools.partial(_forward_kernel, scale=scale, exact=exact),
+        functools.partial(_forward_kernel, scale=scale, exact=exact, window=window),
         out_shape=out_shape,
         in_specs=[blocks.one(width), blocks.whole(width), blocks.whole(vdim)],
         out_specs=out_specs, name="mla_attention_forward", **blocks.options,
@@ -302,16 +422,17 @@ def _forward(q, k, v, scale: float, tile: int, exact: bool, interpret: bool, kee
     return (out.reshape(v.shape), *lse)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "tile", "exact", "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "tile", "exact", "interpret",
+                                             "window"))
 def _backward(q, k, v, out, lse, d_out, scale: float, tile: int, exact: bool,
-              interpret: bool):
+              interpret: bool, window: int | None):
     blocks = _Blocks(q, tile, interpret)
     width, vdim = q.shape[-1], v.shape[-1]
     # what every score of a row owes through the row's sum
     delta = jnp.sum(out * d_out, -1).transpose(0, 2, 1).reshape(lse.shape)
     like = lambda a: jax.ShapeDtypeStruct(_flat(a).shape, jnp.float32)
     dq, dk, dv = pl.pallas_call(
-        functools.partial(_backward_kernel, scale=scale, exact=exact),
+        functools.partial(_backward_kernel, scale=scale, exact=exact, window=window),
         out_shape=[like(q), like(k), like(v)],
         in_specs=[blocks.whole(width), blocks.one(width), blocks.one(vdim),
                   blocks.whole(vdim), blocks.rows, blocks.rows],
@@ -321,25 +442,28 @@ def _backward(q, k, v, out, lse, d_out, scale: float, tile: int, exact: bool,
     return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _fused_attention(q, k, v, scale: float, tile: int, exact: bool, interpret: bool):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _fused_attention(q, k, v, scale: float, tile: int, exact: bool, interpret: bool,
+                     window: int | None):
     """The causal softmax through the kernels; `q`, `k` ``[B, T, H, D]``
     with D whole lanes (every part of the score in it), float32.  `exact`:
-    float32 products; `interpret`: no TPU to compile them for."""
+    float32 products; `interpret`: no TPU to compile them for; `window`:
+    the key span, shorter than the sequence, or None."""
     q, k, v = (kda._operand(a, exact) for a in (q, k, v))
-    return _forward(q, k, v, scale, tile, exact, interpret, keep=False)[0]
+    return _forward(q, k, v, scale, tile, exact, interpret, keep=False, window=window)[0]
 
 
-def _fused_attention_fwd(q, k, v, scale: float, tile: int, exact: bool, interpret: bool):
+def _fused_attention_fwd(q, k, v, scale: float, tile: int, exact: bool, interpret: bool,
+                         window: int | None):
     # kept as the products take them: bfloat16 unless `exact`
     q, k, v = (kda._operand(a, exact) for a in (q, k, v))
-    out, lse = _forward(q, k, v, scale, tile, exact, interpret, keep=True)
+    out, lse = _forward(q, k, v, scale, tile, exact, interpret, keep=True, window=window)
     return out, (q, k, v, out, lse)
 
 
 def _fused_attention_bwd(scale: float, tile: int, exact: bool, interpret: bool,
-                         residuals, d_out):
-    return _backward(*residuals, d_out, scale, tile, exact, interpret)
+                         window: int | None, residuals, d_out):
+    return _backward(*residuals, d_out, scale, tile, exact, interpret, window=window)
 
 
 _fused_attention.defvjp(_fused_attention_fwd, _fused_attention_bwd)
@@ -347,22 +471,29 @@ _fused_attention.defvjp(_fused_attention_fwd, _fused_attention_bwd)
 
 # ------------------------------------------------- the form in jnp and XLA
 
-def _attend(q, q_shared, k, k_shared, v, first, scale: float):
+def _attend(q, q_shared, k, k_shared, v, first, scale: float, window: int | None):
     """One block of queries, whose first token is token `first`, against
-    the keys ``[0, k.shape[1])``."""
+    the keys ``[0, k.shape[1])``; under a `window` the keys further back
+    than the span are masked like those ahead."""
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k)
     if q_shared is not None:
         scores = scores + jnp.einsum("bqhd,bkd->bhqk", q_shared, k_shared)
     rows = first + jnp.arange(q.shape[1])[:, None]
     seen = rows >= jnp.arange(k.shape[1])[None, :]
+    if window is not None:
+        seen &= rows - jnp.arange(k.shape[1])[None, :] < window
     scores = jnp.where(seen, scores.astype(jnp.float32) * scale, -jnp.inf)
     weights = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", weights, v)
 
 
-def _blocked_xla(q, k, v, q_shared, k_shared, scale: float, block: int, spans: int):
+def _blocked_xla(q, k, v, q_shared, k_shared, scale: float, block: int, spans: int,
+                 window: int | None):
     """:func:`blocked_causal_attention` for the shapes the kernels do not
-    take: queries in blocks of `block`, inside `spans` ``lax.scan``s."""
+    take: queries in blocks of `block`, inside `spans` ``lax.scan``s.  A
+    `window` is a mask here and skips nothing: a block of queries meets
+    every key up to the end of its scan's stretch, and the key-tile counter
+    says so."""
     batch, length = q.shape[:2]
     block = min(block, length)
     if length % block:
@@ -372,7 +503,9 @@ def _blocked_xla(q, k, v, q_shared, k_shared, scale: float, block: int, spans: i
     while blocks % spans:  # a short sequence has fewer blocks than spans
         spans -= 1
     per_span = blocks // spans
-    attend = jax.checkpoint(_attend, static_argnums=(6,))
+    _count_key_tiles(window, per_span * per_span * spans * (spans + 1) // 2,
+                     blocks * (blocks + 1) // 2)
+    attend = jax.checkpoint(_attend, static_argnums=(6, 7))
 
     def in_blocks(a, start, stop):  # [B, T, ...] -> [n, B, block, ...]
         a = a[:, start:stop]
@@ -387,7 +520,7 @@ def _blocked_xla(q, k, v, q_shared, k_shared, scale: float, block: int, spans: i
         def step(first, xs, keys=keys):
             q_block, q_shared_block = xs
             return first + block, attend(q_block, q_shared_block, keys[0],
-                                         keys[1], keys[2], first, scale)
+                                         keys[1], keys[2], first, scale, window)
 
         xs = (in_blocks(q, start, stop),
               None if q_shared is None else in_blocks(q_shared, start, stop))

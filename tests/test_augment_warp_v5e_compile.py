@@ -341,3 +341,45 @@ def test_batch_taken_from_the_stored_form_copies_no_whole_cache(
     makers = {m.group(1) for line in compiled.as_text().splitlines()
               if (m := whole.search(line))}
     assert makers <= {"parameter"}, makers  # nothing makes an array that size
+
+
+# --- the fourth token cell's shapes (PR 46): the attention kernels under a
+# key span, and without one, at 16,384 tokens
+
+
+@pytest.mark.parametrize("ambient", ["default", "highest"])
+@pytest.mark.parametrize("window", [2048, None, 1000], ids=["window", "full", "no_whole_tiles"])
+def test_fused_attention_kernels_take_a_key_span_at_16384_tokens(
+        one_chip, no_compile_cache, monkeypatch, window, ambient):
+    """`trinity_mini_train`'s mixers: 32 query heads of 128 at 16,384
+    tokens on 4 key-value heads repeated in front of the kernels, a window
+    layer's span of 2,048 keys (four tiles of 512), a full layer's whole
+    past, and a span that is no whole number of tiles (two tiles at the
+    band's trailing edge take the mask).  One head's whole sequence fits
+    the kernels' VMEM at 128 + 128 (2 x 4 x 16,384 x 384 = 50.3 MB of the
+    78.6 the reckoning allows), so all take the fused form, under
+    ``highest`` too; the loops' bounds are scalars the kernels compute from
+    the grid step, which Mosaic has to take."""
+    from fast_autoaugment_tpu.ops import kda
+    from fast_autoaugment_tpu.ops.attention import blocked_causal_attention
+
+    monkeypatch.setattr(kda, "_on_tpu", lambda: True)
+
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
+
+    def scalar(q, k, v):
+        k, v = (jnp.repeat(a, 8, axis=2) for a in (k, v))
+        return jnp.sum(blocked_causal_attention(q, k, v, scale=128 ** -0.5,
+                                                window=window))
+
+    with jax.default_matmul_precision(ambient):
+        compiled = jax.jit(jax.grad(scalar, argnums=(0, 1, 2))).lower(
+            shape(1, 16384, 32, 128), shape(1, 16384, 4, 128),
+            shape(1, 16384, 4, 128)).compile()
+    text = compiled.as_text()
+    assert "mla_attention_forward" in text and "mla_attention_backward" in text
+    assert text.count("tpu_custom_call") == 2
+    # q, k, v at 32 heads of 128 as operands and as gradients, the output
+    # and its cotangent: 268 MB each in float32
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.8e9

@@ -366,7 +366,10 @@ def test_two_key_value_heads_are_the_attention_with_the_heads_written_out(tiny):
     conf, model, params, _, _ = tiny
     x = jax.random.normal(jax.random.PRNGKey(10), (2, 32, 32))
     attn = params["layer6"]["attn"]
-    ours = family.GQAMixer(model.sizes).apply({"params": attn}, x)
+    def mixer(sizes):   # ``token_blocks.GQAMixer``, bare, at the model's sizes
+        return family.GQAMixer(sizes.heads, sizes.kv_heads, sizes.head_dim)
+
+    ours = mixer(model.sizes).apply({"params": attn}, x)
 
     def written_out(order):
         full = get_model(model_conf_of(tiny_conf(num_key_value_heads=4)), 48)
@@ -374,7 +377,7 @@ def test_two_key_value_heads_are_the_attention_with_the_heads_written_out(tiny):
         for name in ("k_proj", "v_proj"):
             heads = np.asarray(attn[name]["kernel"]).reshape(32, 2, 8)
             out[name] = {"kernel": jnp.asarray(heads[:, order].reshape(32, 32))}
-        return family.GQAMixer(full.sizes).apply({"params": out}, x)
+        return mixer(full.sizes).apply({"params": out}, x)
 
     _close(written_out([0, 0, 1, 1]), ours, 1e-5)
     assert float(jnp.abs(written_out([0, 0, 0, 0]) - ours).max()) > 1e-3

@@ -19,7 +19,7 @@ import numpy as np
 import pytest
 import yaml
 
-from fast_autoaugment_tpu.core import resilience, telemetry
+from fast_autoaugment_tpu.core import compilecache, resilience, telemetry
 from fast_autoaugment_tpu.core.checkpoint import read_metadata
 from fast_autoaugment_tpu.core.config import Config
 from fast_autoaugment_tpu.data.datasets import (
@@ -78,6 +78,9 @@ def unbroken(tmp_path_factory):
     path = str(tmp_path_factory.mktemp("unbroken") / "full.msgpack")
     tokens = telemetry.registry().counter("faa_tokens_total")
     before = tokens.value
+    # the seam's labels are the process's: under ``--dist loadfile`` a
+    # host-fed run of another file may have left its ``train_step`` here
+    compilecache._reset_stats_for_tests()
     result = _train(path)
     return path, result, tokens.value - before
 
@@ -575,3 +578,4 @@ def test_an_only_eval_restore_takes_the_nemotron_checkpoint(nemotron_unbroken):
     evaluated = _train(full, conf=nemotron_conf(), only_eval=True)
     assert evaluated["steps"] == 2 * STEPS
     assert evaluated["loss_test"] == result["loss_test"]
+
