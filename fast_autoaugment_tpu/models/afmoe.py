@@ -70,6 +70,7 @@ from fast_autoaugment_tpu.models.token_blocks import (
     expert_share_of,
     publish_router_counts,
     refuse_unwritten_routing,
+    remat_block,
 )
 from fast_autoaugment_tpu.ops.lm_head import blocked_next_token_sums
 
@@ -136,7 +137,7 @@ class Afmoe(nn.Module):
         x = jnp.take(table, ids, axis=0).astype(self.dtype)
         if c.embed_scale != 1.0:
             x = x * jnp.asarray(c.embed_scale, self.dtype)
-        block = nn.remat(Block) if self.remat else Block
+        block = remat_block(Block) if self.remat else Block
         for index, kind in enumerate(c.layer_types[:c.layers_held], start=1):
             x = block(c, kind == WINDOW, index <= c.dense_layers, self.dtype,
                       name=f"layer{index}")(x)

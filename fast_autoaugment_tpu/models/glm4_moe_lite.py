@@ -73,6 +73,7 @@ from fast_autoaugment_tpu.models.token_blocks import (
     expert_share_of,
     publish_router_counts,
     refuse_unwritten_routing,
+    remat_block,
 )
 from fast_autoaugment_tpu.ops.lm_head import blocked_next_token_sums
 
@@ -155,7 +156,7 @@ class Glm4MoeLite(nn.Module):
             ids = ids.astype(jnp.int32)  # an init sample may come as floats
         table = self.param("embed_tokens", INIT, (c.ids_held, c.hidden))
         x = jnp.take(table, ids, axis=0).astype(self.dtype)
-        block = nn.remat(Block) if self.remat else Block
+        block = remat_block(Block) if self.remat else Block
         for layer in range(1, c.layers_held + 1):
             x = block(c, layer <= c.dense_layers, self.dtype,
                       name=f"layer{layer}")(x)

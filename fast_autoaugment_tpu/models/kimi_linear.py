@@ -44,6 +44,7 @@ from fast_autoaugment_tpu.models.token_blocks import (
     expert_share_of,
     publish_router_counts,
     refuse_unwritten_routing,
+    remat_block,
     step_bias_init,
 )
 from fast_autoaugment_tpu.ops.kda import chunk_kda
@@ -177,7 +178,7 @@ class KimiLinear(nn.Module):
             ids = ids.astype(jnp.int32)  # an init sample may come as floats
         table = self.param("embed_tokens", INIT, (c.ids_held, c.hidden))
         x = jnp.take(table, ids, axis=0).astype(self.dtype)
-        block = nn.remat(Block) if self.remat else Block
+        block = remat_block(Block) if self.remat else Block
         for layer in range(1, c.layers_held + 1):
             x = block(c, layer, self.dtype, name=f"layer{layer}")(x)
         x = RMSNorm(c.eps, name="norm")(x)

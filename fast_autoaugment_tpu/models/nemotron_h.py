@@ -76,6 +76,7 @@ from fast_autoaugment_tpu.models.token_blocks import (
     expert_share_of,
     publish_router_counts,
     refuse_unwritten_routing,
+    remat_block,
     step_bias_init,
 )
 from fast_autoaugment_tpu.ops.lm_head import blocked_next_token_sums
@@ -204,7 +205,7 @@ class NemotronH(nn.Module):
             ids = ids.astype(jnp.int32)  # an init sample may come as floats
         table = self.param("embed_tokens", INIT, (c.ids_held, c.hidden))
         x = jnp.take(table, ids, axis=0).astype(self.dtype)
-        layer = nn.remat(Layer) if self.remat else Layer
+        layer = remat_block(Layer) if self.remat else Layer
         for index, kind in enumerate(c.pattern[:c.layers_held], start=1):
             x = layer(c, kind, self.dtype, name=f"layer{index}")(x)
         head = Kernel((c.hidden, c.ids_held), name="lm_head")()

@@ -16,6 +16,12 @@ FFN may be a sigmoid-routed expert layer this chip holds a share of
   shared expert (``ops/moe.py``), both of one form (``ops/moe.py::FORMS``),
   the shared one at ``width * shared`` or at a width of its own;
 - :class:`Sizes`, the hashable view of a model's sizes ``nn.remat`` takes;
+- :func:`remat_block`, the ``nn.remat`` every family wraps its block in
+  (``model.remat``; false: no ``nn.remat`` at all): a block's input is kept
+  and the block computed again in the backward pass, but for the fused
+  attention cores' output and log-sum-exp, which are kept too
+  (``ops/attention.py`` names them), so that the forward kernel runs once
+  a step;
 - :data:`CUT_KEYS`, the three top-level conf keys that say what *this
   chip* holds of a deployment — absent, the whole model: ``layers_held``
   (the first n layers), ``experts_held`` with ``expert_share`` (experts
@@ -44,11 +50,15 @@ from flax import linen as nn
 
 from fast_autoaugment_tpu.core import scopes
 from fast_autoaugment_tpu.ops import moe
-from fast_autoaugment_tpu.ops.attention import blocked_causal_attention
+from fast_autoaugment_tpu.ops.attention import (
+    LSE_NAME,
+    OUT_NAME,
+    blocked_causal_attention,
+)
 
 __all__ = ["RMSNorm", "SwiGLU", "SquaredReLU", "Kernel", "MLAMixer", "GQAMixer",
            "ExpertLayer",
-           "FEED_FORWARDS", "Sizes", "dense", "step_bias_init",
+           "FEED_FORWARDS", "Sizes", "remat_block", "dense", "step_bias_init",
            "rotate_by_position", "expert_share_of", "refuse_unwritten_routing",
            "balance_routers", "publish_router_counts", "INIT", "STEP_STATS",
            "ROUTING", "CUT_KEYS"]
@@ -323,6 +333,16 @@ class Sizes:
 
     def __repr__(self):
         return f"Sizes{self._key}"
+
+
+def remat_block(block):
+    """`block`, a module class, under ``nn.remat``: its input is kept and
+    the block computed again in the backward pass — but for the fused
+    attention cores' output and log-sum-exp (``ops/attention.py`` names
+    them), which are kept beside it, so that the forward kernel runs once
+    a step: the backward kernel needs nothing else of it."""
+    return nn.remat(block, policy=jax.checkpoint_policies.save_only_these_names(
+        OUT_NAME, LSE_NAME))
 
 
 def expert_share_of(conf: Any, experts: int) -> tuple[int, int]:

@@ -60,8 +60,26 @@ weights' cotangent, ``dv``, ``dk``, ``dq``) follow.  The tile is held
 keys-by-queries: what belongs to a query (its log-sum-exp, ``delta =
 sum(o * do)``) is then a row, and no sum runs along the lanes.  The
 residuals are the operands as the products take them, the output and the
-log-sum-exp: no probability is kept, and nothing is under
-``jax.checkpoint``.
+log-sum-exp: no probability is kept.
+
+*What a checkpoint keeps.*  The kernels are not under a ``jax.checkpoint``
+of their own, but a model's block is (``nn.remat``), and a block computed
+again runs the forward rule again to rebuild these residuals.  So the rule
+names its two products (``checkpoint_name``: :data:`OUT_NAME`, the float32
+output as the kernel wrote it — ``delta`` is taken from it, a rounded copy
+would be another gradient — and :data:`LSE_NAME`, the rows' log-sum-exp),
+and returns the named output as the primal one too, so that what reads it
+(a gate, ``o_proj``) reads the kept array.
+``models/token_blocks.py::remat_block`` wraps a block with the policy that
+keeps the two (``4 * T * H * Dv + 4 * H * T`` bytes a core: 268 MB at
+16,384 tokens and 32 heads of 128): the backward kernel then takes them
+from the primal pass, the kernel in the block computed again has no
+consumer left and is removed as dead code — the forward kernel runs once a
+step.  The operands are still computed again, as the rest of the block is
+(projections, norms, rotary, the key-value repeat, the rounding to
+bfloat16): the backward kernel takes ``q``, ``k``, ``v`` from there.  A
+name is the identity in the lowered program: with no such policy round the
+call (``remat: false``, a forward-only program) nothing changes.
 
 Any other shape (the tests' heads of 8 / 5 / 4, a sequence under two
 tiles or one no tile divides) takes :func:`_blocked_xla`: queries in
@@ -87,6 +105,11 @@ form a program got: ``fused`` or ``blocked_xla``;
 the key tiles a core's loops meet over one head's sequence (``visited``)
 beside the causal half's (``causal``): 150 of 528 at 16,384 tokens, a
 tile of 512 and a span of 2,048.
+``faa_attention_outputs_named_total{span}`` counts the cores whose forward
+rule named its products — the cores offered to a policy — and
+``faa_attention_kept_bytes_total{span}`` the bytes of the two arrays, what
+keeping them costs; that a policy took them shows in the program (the
+forward kernel once a core).
 """
 
 from __future__ import annotations
@@ -95,16 +118,22 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from fast_autoaugment_tpu.core import telemetry
 from fast_autoaugment_tpu.ops import kda
 
-__all__ = ["blocked_causal_attention", "DEFAULT_QUERY_BLOCK", "DEFAULT_SPANS"]
+__all__ = ["blocked_causal_attention", "DEFAULT_QUERY_BLOCK", "DEFAULT_SPANS",
+           "OUT_NAME", "LSE_NAME"]
 
 DEFAULT_QUERY_BLOCK = 512
 DEFAULT_SPANS = 4
+#: what the fused forward rule names (``checkpoint_name``) for a checkpoint
+#: policy to keep: the kernel's output and the rows' log-sum-exp
+OUT_NAME = "faa_attention_out"
+LSE_NAME = "faa_attention_lse"
 LANES = kda.LANES
 #: the tiles of the fused kernels, largest first: a sequence takes the
 #: first that divides it at least twice
@@ -192,6 +221,20 @@ def _count_key_tiles(window: int | None, visited: int, causal: int) -> None:
             "faa_attention_key_tiles_total", "key tiles the traced attention "
             "cores' loops meet over one head's sequence (visited), beside the "
             "causal half's", span=_span_label(window), kind=kind).inc(tiles)
+
+
+def _count_named(window: int | None, kept_bytes: int) -> None:
+    """Trace time: a core whose forward rule named its output and
+    log-sum-exp, and the bytes of the two — what a policy that keeps them
+    keeps."""
+    telemetry.registry().counter(
+        "faa_attention_outputs_named_total", "fused attention cores whose forward "
+        "rule named the output and the log-sum-exp for a checkpoint policy to keep",
+        span=_span_label(window)).inc()
+    telemetry.registry().counter(
+        "faa_attention_kept_bytes_total", "bytes of the output and the log-sum-exp "
+        "those cores offer a checkpoint policy",
+        span=_span_label(window)).inc(kept_bytes)
 
 
 def _fused_tile(q, v, q_shared) -> int | None:
@@ -458,6 +501,12 @@ def _fused_attention_fwd(q, k, v, scale: float, tile: int, exact: bool, interpre
     # kept as the products take them: bfloat16 unless `exact`
     q, k, v = (kda._operand(a, exact) for a in (q, k, v))
     out, lse = _forward(q, k, v, scale, tile, exact, interpret, keep=True, window=window)
+    # named for a checkpoint policy to keep (``token_blocks.remat_block``):
+    # the backward rule then takes these two from the primal pass, and the
+    # kernel has no consumer left in what the policy computes again.  The
+    # primal output is the named array too: what reads it reads the kept one
+    out, lse = checkpoint_name(out, OUT_NAME), checkpoint_name(lse, LSE_NAME)
+    _count_named(window, out.nbytes + lse.nbytes)
     return out, (q, k, v, out, lse)
 
 
