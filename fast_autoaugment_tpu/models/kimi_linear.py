@@ -77,7 +77,29 @@ def _a_log_init(key, shape, dtype=jnp.float32):
 _dt_bias_init = step_bias_init(0.001, 0.1, 1e-4)
 
 
+#: tokens in a tile of a float32 ``[T, H * K]`` array on the chip
+SUBLANES = 8
+
+
+def _by_tile(x, heads: int):
+    """``[B, T, H * K]`` cut as the chip tiles it, ``[B, T/8, 8, H, K]``: a
+    tile is eight tokens of one head's lanes.  Cut so, XLA reads and
+    writes a head's lanes where the array lies; cut to ``[B, T, H, K]`` it
+    first moves the whole array to tiles of ``(H, K)``, and back after."""
+    batch, length, width = x.shape
+    rows = math.gcd(length, SUBLANES)
+    return x.reshape(batch, length // rows, rows, heads, width // heads)
+
+
 class KDAMixer(nn.Module):
+    """Every array between a projection and ``o_proj`` stays ``[B, T, H *
+    K]``, the heads side by side as the projection writes them and as
+    ``ops/kda.py``'s kernels read them.  What is per head: the unit
+    lengths of ``q`` and ``k`` are the kernels' (``unit_scale``), ``A_log``
+    is a vector ``[H * K]``, and the output norm runs over the last axis
+    of the array cut as it is tiled (:func:`_by_tile`); no array is cut
+    into ``(H, K)`` tiles on the way."""
+
     heads: int
     head_dim: int
     taps: int
@@ -86,39 +108,31 @@ class KDAMixer(nn.Module):
 
     @nn.compact
     def __call__(self, x):                                   # [B, T, D]
-        batch, length, hidden = x.shape
         heads, dim = self.heads, self.head_dim
         width = heads * dim
 
         def branch(name):
             y = _dense(width, f"{name}_proj", self.dtype)(x)
-            return ShortConv(self.taps, name=f"{name}_conv")(y).reshape(
-                batch, length, heads, dim)
+            return ShortConv(self.taps, name=f"{name}_conv")(y)
 
         q, k, v = branch("q"), branch("k"), branch("v")
-
-        def unit(a):
-            a32 = a.astype(jnp.float32)
-            return a32 * jax.lax.rsqrt(jnp.sum(a32 * a32, -1, keepdims=True) + 1e-6)
-
-        q, k = unit(q) * dim ** -0.5, unit(k)
         a_log = self.param("A_log", _a_log_init, (heads,))
         dt_bias = self.param("dt_bias", _dt_bias_init, (width,))
         gate = _dense(width, "f_b_proj", self.dtype)(
             _dense(dim, "f_a_proj", self.dtype)(x))
-        gate = jax.nn.softplus(gate.astype(jnp.float32) + dt_bias).reshape(
-            batch, length, heads, dim)
-        g = -jnp.exp(a_log)[:, None] * gate
+        gate = jax.nn.softplus(gate.astype(jnp.float32) + dt_bias)
+        g = jnp.repeat(-jnp.exp(a_log), dim) * gate
         beta = jax.nn.sigmoid(
             _dense(heads, "b_proj", self.dtype)(x).astype(jnp.float32))
         with jax.named_scope(scopes.KDA_SCAN):
-            out, _ = chunk_kda(q, k, v, g, beta)
-        out = RMSNorm(self.eps, name="o_norm")(out.astype(self.dtype))
+            # q and k to unit length a head, q by dim ** -0.5: in the kernels
+            out, _ = chunk_kda(q, k, v, g, beta, unit_scale=dim ** -0.5)
+        out = RMSNorm(self.eps, name="o_norm")(
+            _by_tile(out.astype(self.dtype), heads)).reshape(out.shape)
         out_gate = _dense(width, "g_b_proj", self.dtype)(
             _dense(dim, "g_a_proj", self.dtype)(x))
-        out = out * jax.nn.sigmoid(out_gate).reshape(batch, length, heads, dim)
-        return _dense(hidden, "o_proj", self.dtype)(
-            out.reshape(batch, length, width))
+        return _dense(x.shape[-1], "o_proj", self.dtype)(
+            out * jax.nn.sigmoid(out_gate))
 
 
 class Block(nn.Module):

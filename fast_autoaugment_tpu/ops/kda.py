@@ -42,11 +42,25 @@ A grid step takes one chunk of every head; what is local to a chunk lives
 in VMEM from the operation that makes it to the ones that use it, and the
 state is carried in VMEM along the sequential chunk axis.  HBM sees ``q,
 k, v, g, beta`` in, ``o`` out and one state a chunk kept for the backward
-pass.  There the reference rows are those of a binary tree over the
+pass — ``q, k, v, g, o`` and their gradients as ``[B, T, H * K]``, the
+heads side by side as a projection of width ``H * K`` writes them, so
+that nothing is moved between the projections' tiling and the kernels'
+(:func:`chunk_kda` takes and gives that shape, or ``[B, T, H, K]``; with
+`unit_scale` the kernels also bring a head's ``q`` and ``k`` rows to unit
+length once loaded, a lane reduction of the window, and the backward
+kernel hands back the gradients of the rows as they came).  In
+a grid step's block ``[C, H * K]`` a token is a sublane and a head 128
+lanes: a head's chunk is the dense window ``[:, h * 128:(h + 1) * 128]``.
+There the reference rows are those of a binary tree over the
 chunk: at level ``b`` (32, 16, ... 1) every token of an odd block of
 ``b`` tokens meets every token of the even block before it through the
 row between the two blocks, one product on the MXU a level, and the
-levels' masks partition the lower triangle.  The system's inverse
+levels' masks partition the lower triangle.  The levels' sums of ``g``
+run along the sublanes, a segmented scan by log steps: every token holds
+the sum of its block through itself and the sum after itself, and a
+level up takes the sibling block's whole sum from the token ``b``
+sublanes away — sums of ``g`` itself at every level
+(:func:`_decay_factors`).  The system's inverse
 follows the same tree (the inverse of a block of ``2b`` from those of its
 two halves: block forward substitution, two products a level), so
 nothing is solved row by row.  Two heads' chunks are stacked for these
@@ -59,7 +73,7 @@ every product's cotangent is a product of the same shapes.  The decay's
 gradient needs no pass of its own: a Gram's entry depends on ``G_t -
 G_s`` alone, so ``dG_t = x_t * dx_t - k_t * dk_t`` row by row from the
 Gram's own input gradients, and ``dg`` is the reverse running sum of
-``dG`` over the chunk.
+``dG`` over the chunk, again by log steps along the sublanes.
 
 Any other shape (the tests' ``K`` of 16, chunks of 16 or 32, a sequence
 shorter than a chunk) takes :func:`_chunk_kda_xla`: the same chunked
@@ -88,7 +102,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from fast_autoaugment_tpu.core import telemetry
 
-__all__ = ["chunk_kda", "recurrent_kda", "DEFAULT_CHUNK", "SUB_BLOCK"]
+__all__ = ["chunk_kda", "recurrent_kda", "unit_factor", "DEFAULT_CHUNK", "SUB_BLOCK"]
 
 DEFAULT_CHUNK = 64
 SUB_BLOCK = 16
@@ -121,11 +135,22 @@ def recurrent_kda(q, k, v, g, beta, initial_state=None):
     return jnp.moveaxis(out, 0, 1), state
 
 
-def chunk_kda(q, k, v, g, beta, initial_state=None, *, chunk: int = DEFAULT_CHUNK):
+def chunk_kda(q, k, v, g, beta, initial_state=None, *, chunk: int = DEFAULT_CHUNK,
+              unit_scale: float | None = None):
     """:func:`recurrent_kda` in chunks of `chunk` tokens (T a multiple of
-    it, or shorter than it): same arguments, same results."""
-    batch, length, heads, kdim = q.shape
-    vdim = v.shape[-1]
+    it, or shorter than it): same arguments, same results.  `q, k, v, g`
+    may also come as a projection leaves them, ``[B, T, H * K]`` with the
+    heads side by side (H is `beta`'s), and `o` then comes back so: the
+    layout the kernels read, which a model keeps from end to end.  With
+    `unit_scale`, `q` and `k` are first brought to unit length over a
+    head's K channels (:func:`unit_factor`) and `q` times `unit_scale`:
+    the kernels do it to the tile they have loaded, so the normalised
+    arrays never exist in HBM."""
+    batch, length, heads = beta.shape
+    by_head = lambda a: a.reshape(batch, length, heads, -1)
+    side_by_side = lambda a: a.reshape(batch, length, -1)
+    given = side_by_side if q.ndim == 3 else by_head
+    kdim, vdim = (a.size // beta.size for a in (q, v))
     chunk = min(chunk, length)
     if length % chunk:
         raise ValueError(f"sequence length {length} is no multiple of the "
@@ -139,18 +164,26 @@ def chunk_kda(q, k, v, g, beta, initial_state=None, *, chunk: int = DEFAULT_CHUN
         "faa_kda_scan_traces_total", "KDA recurrences traced into a program, "
         "by the form that computes them",
         form="fused" if fused else "chunked_xla").inc()
-    if not fused:
-        return _chunk_kda_xla(q, k, v, g, beta, state, chunk)
-    return _fused_kda(q, k, v, g, beta, state, _float32_products(), not _on_tpu())
+    if fused:
+        out, state = _fused_kda(*map(side_by_side, (q, k, v, g)), beta, state,
+                                _float32_products(), not _on_tpu(), unit_scale)
+    else:
+        q, k, v, g = map(by_head, (q, k, v, g))
+        if unit_scale is not None:
+            q, k = q * unit_factor(q, unit_scale), k * unit_factor(k)
+        out, state = _chunk_kda_xla(q, k, v, g, beta, state, chunk)
+    return given(out), state
 
 
 # ------------------------------------------------------- the fused kernels
 #
-# Both kernels take ``q, k, v, g`` as ``[B, T * H, K]``: the layout
-# ``[B, T, H, K]`` has on the chip, seen as rows.  A grid step's block is
-# one chunk of every head, ``[C * H, K]``; head ``h`` of it is every H-th
-# row from ``h`` (a load with a sublane stride), token ``t`` of it the H
-# rows from ``t * H``.
+# Both kernels take ``q, k, v, g`` and give ``o`` and the gradients as ``[B,
+# T, H * K]``: what a projection of width ``H * K`` writes, a tile of it
+# eight tokens of one head's 128 lanes.  A grid step's block is one chunk
+# of every head, ``[C, H * K]``; token ``t`` of it is sublane ``t``, head
+# ``h`` the lane-aligned window ``[:, h * 128:(h + 1) * 128]`` (a dense
+# ``[64, 128]`` load at a lane offset the loop over groups computes), and a
+# sum over tokens runs along the sublanes (:func:`_decay_factors`).
 
 _NN = (((1,), (0,)), ((), ()))
 _NT = (((1,), (1,)), ((), ()))
@@ -183,41 +216,62 @@ def _dot(a, b, dims, exact: bool):
         preferred_element_type=jnp.float32)
 
 
-def _decay_factors(g_ref, f_ref, sums_ref, heads: int):
-    """Every decay a chunk's products need, for all heads at once.
+def unit_factor(x, scale: float = 1.0):
+    """``scale / sqrt(sum x^2 + 1e-6)`` over the last axis, kept: `x`
+    times it has rows of length `scale`."""
+    return jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6) * scale
 
-    `g_ref`: a ``[1, C * H, K]`` block, so a token is H whole rows and a
-    running sum over tokens is a sum of such row groups.  Level ``l``
-    (blocks of ``b = 2^l`` tokens) gets ``f_ref[l]``: for a token of an
-    odd block ``exp`` of the sum of ``g`` from its block's start through
-    itself, for one of an even block ``exp`` of the sum after it to its
-    block's end — what takes either to the row between the two blocks.
-    The two sums of the whole chunk are ``f_ref[L] = exp G`` and
-    ``f_ref[L + 1] = exp(total - G)``.  Each is summed from `g` itself,
-    never as a difference of long sums: `sums_ref` ``[2, C * H, K]``
-    holds both sums of the level, and going up a level an odd block's
-    tokens take the whole of the even block in front, the even block's
-    the whole of the odd block behind.  The loops over tokens are
-    unrolled (a token's rows are then a static slice) and traced once."""
-    chunk = g_ref.shape[1] // heads
+
+def _unit_cotangent(y, factor, scale: float, d_y):
+    """The cotangent of `x` where ``y = x * factor`` and ``factor =
+    unit_factor(x, scale)``."""
+    unit = y * (1.0 / scale)
+    return factor * (d_y - unit * jnp.sum(unit * d_y, -1, keepdims=True))
+
+
+def _decay_factors(g, f_ref, chunk: int):
+    """Every decay a group's products need.
+
+    `g`: the group's chunks stacked, ``[R, K]``, so a token is one
+    sublane and a running sum over tokens runs along the sublanes: a
+    segmented scan by log steps, a level a step.  Level ``l`` (blocks of
+    ``b = 2^l`` tokens) gets ``f_ref[l]``: for a token of an odd block
+    ``exp`` of the sum of ``g`` from its block's start through itself,
+    for one of an even block ``exp`` of the sum after it to its block's
+    end — what takes either to the row between the two blocks.  The two
+    sums of the whole chunk are ``f_ref[L] = exp G`` and ``f_ref[L + 1] =
+    exp(total - G)``.  Each is summed from `g` itself, never as a
+    difference of long sums: every token holds both sums of its block
+    (`through` itself and `after` itself: together the whole block's),
+    and going up a level an odd block's tokens take the whole of the even
+    block in front from the token ``b`` sublanes before them, the even
+    block's the whole of the odd block behind from the one ``b`` after
+    (``pltpu.roll``; a stacked head's rows are never reached: the masks
+    are the token's bits)."""
+    rows = g.shape[0]
     levels = chunk.bit_length() - 1
-    rows = lambda t: pl.ds(t * heads, heads)
-    sums_ref[0] = g_ref[0]
-    sums_ref[1] = jnp.zeros_like(g_ref[0])
+    token = jax.lax.broadcasted_iota(jnp.int32, g.shape, 0)
+    through, after = g, jnp.zeros_like(g)
     for level in range(levels):
-        def token(t, carry, level=level):
-            odd = (t >> level) & 1
-            mine = sums_ref[1 - odd, rows(t), :]
-            f_ref[level, rows(t), :] = jnp.exp(mine)
-            # tokens come in order: the odd block's last still holds the
-            # odd block's own sum when the even block's tokens read it
-            last = (((t >> level) | 1) - odd << level) + (1 << level) - 1
-            sums_ref[1 - odd, rows(t), :] = mine + sums_ref[0, rows(last), :]
-            return carry
+        size = 1 << level
+        odd = (token & size) != 0
+        f_ref[level] = jnp.exp(jnp.where(odd, through, after))
+        block = through + after
+        through = through + jnp.where(odd, pltpu.roll(block, size, 0), 0.0)
+        after = after + jnp.where(odd, 0.0, pltpu.roll(block, rows - size, 0))
+    f_ref[levels] = jnp.exp(through)
+    f_ref[levels + 1] = jnp.exp(after)
 
-        jax.lax.fori_loop(0, chunk, token, 0, unroll=True)
-    f_ref[levels] = jnp.exp(sums_ref[0])
-    f_ref[levels + 1] = jnp.exp(sums_ref[1])
+
+def _sum_from_each_token(x, chunk: int):
+    """``[R, K]`` -> the sum over a chunk's tokens from each token
+    onwards, along the sublanes by log steps."""
+    rows = x.shape[0]
+    token = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0) & (chunk - 1)
+    for level in range(chunk.bit_length() - 1):
+        size = 1 << level
+        x = x + jnp.where(token + size < chunk, pltpu.roll(x, rows - size, 0), 0.0)
+    return x
 
 
 def _column(rows, index):
@@ -234,14 +288,20 @@ class _Group:
     diagonal.  Holds the group's inputs and what is local to its chunks:
     both Gram matrices and the system's inverse, block-diagonal by head."""
 
-    def __init__(self, index, heads: int, chunk: int, q_ref, k_ref, beta_ref, f_ref,
-                 exact: bool):
+    def __init__(self, index, heads: int, chunk: int, q_ref, k_ref, g_ref, beta_ref,
+                 f_ref, exact: bool, unit_scale: float | None):
         count = _group_size(heads)
-        self.chunk, self.exact = chunk, exact
+        self.chunk, self.exact, self.unit_scale = chunk, exact, unit_scale
         self.heads = [index * count + j for j in range(count)]
-        self._rows = [pl.ds(h, chunk, stride=heads) for h in self.heads]
+        self._lanes = [pl.ds(pl.multiple_of(h * LANES, LANES), LANES)
+                       for h in self.heads]
         self._f_ref = f_ref
         self.q, self.k = self.load(q_ref), self.load(k_ref)
+        if unit_scale is not None:
+            self._q_factor = unit_factor(self.q, unit_scale)
+            self._k_factor = unit_factor(self.k)
+            self.q, self.k = self.q * self._q_factor, self.k * self._k_factor
+        _decay_factors(self.load(g_ref), f_ref, chunk)
         self.beta = jnp.concatenate([_column(beta_ref[0], h) for h in self.heads], 0)
         self.levels = chunk.bit_length() - 1
         self.size = size = count * chunk
@@ -275,12 +335,21 @@ class _Group:
         self.inverse = inverse
 
     def load(self, ref):
-        """The group's rows of a ``[1, C * H, D]`` block, ``[R, D]``."""
-        return jnp.concatenate([ref[0, rows, :] for rows in self._rows], 0)
+        """The group's heads of a ``[1, C, H * D]`` block, stacked:
+        ``[R, D]``."""
+        return jnp.concatenate([ref[0, :, lanes] for lanes in self._lanes], 0)
 
     def store(self, ref, value):
-        for rows, part in zip(self._rows, self.by_head(value)):
-            ref[0, rows, :] = part
+        for lanes, part in zip(self._lanes, self.by_head(value)):
+            ref[0, :, lanes] = part
+
+    def store_gradients(self, dq_ref, dk_ref, d_q, d_k):
+        """`d_q`, `d_k`: of `self.q`, `self.k`; stored: of what was loaded."""
+        if self.unit_scale is not None:
+            d_q = _unit_cotangent(self.q, self._q_factor, self.unit_scale, d_q)
+            d_k = _unit_cotangent(self.k, self._k_factor, 1.0, d_k)
+        self.store(dq_ref, d_q)
+        self.store(dk_ref, d_k)
 
     def by_head(self, stacked):
         """``[R, D]`` -> a ``[C, D]`` a head."""
@@ -288,7 +357,7 @@ class _Group:
                 for j in range(len(self.heads))]
 
     def factor(self, level):
-        return jnp.concatenate([self._f_ref[level, rows, :] for rows in self._rows], 0)
+        return self._f_ref[level]
 
     def pairs(self, level):
         """``[R, R]``: the token pairs that meet at `level`."""
@@ -330,15 +399,14 @@ def _for_each_group(heads: int, group):
 
 
 def _forward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, s0_ref,
-                    o_ref, s_ref, *rest, exact: bool):
+                    o_ref, s_ref, *rest, exact: bool, unit_scale: float | None):
     """One chunk of every head.  `s_ref` ``[1, H, V, K]`` is the state,
     transposed so that a channel's decay scales a column; it stays in
     VMEM along the chunk axis.  `rest`: the kept start states' block
-    (where a backward pass will want them), then the factors' scratch
-    and the scratch of their sums."""
-    *kept_ref, f_ref, sums_ref = rest
-    heads = s_ref.shape[1]
-    chunk = q_ref.shape[1] // heads
+    (where a backward pass will want them), then the scratch of a
+    group's factors."""
+    *kept_ref, f_ref = rest
+    heads, chunk = s_ref.shape[1], q_ref.shape[1]
 
     @pl.when(pl.program_id(1) == 0)
     def _():
@@ -346,10 +414,10 @@ def _forward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, s0_ref,
 
     for ref in kept_ref:
         ref[0, :, 0] = s_ref[0]
-    _decay_factors(g_ref, f_ref, sums_ref, heads)
 
     def group(i):
-        part = _Group(i, heads, chunk, q_ref, k_ref, beta_ref, f_ref, exact)
+        part = _Group(i, heads, chunk, q_ref, k_ref, g_ref, beta_ref, f_ref, exact,
+                      unit_scale)
         states = [s_ref[0, h] for h in part.heads]           # [V, K]
         k_s, q_s = part.through_state(states, part.k * part.eg, part.q * part.eg)
         u = _dot(part.inverse, part.beta * (part.load(v_ref) - k_s), _NN, exact)
@@ -363,20 +431,18 @@ def _forward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, s0_ref,
 
 def _backward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, kept_ref, do_ref, ds1_ref,
                      dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, ds_ref,
-                     f_ref, sums_ref, dsum_ref, dtotal_ref, *, exact: bool):
+                     f_ref, *, exact: bool, unit_scale: float | None):
     """The chunk the forward kernel's grid step took, in reverse order;
     `ds_ref` ``[1, H, V, K]`` carries the state's cotangent."""
-    heads = ds_ref.shape[1]
-    chunk = q_ref.shape[1] // heads
+    heads, chunk = ds_ref.shape[1], q_ref.shape[1]
 
     @pl.when(pl.program_id(1) == 0)
     def _():
         ds_ref[...] = ds1_ref[...]
 
-    _decay_factors(g_ref, f_ref, sums_ref, heads)
-
     def group(i):
-        part = _Group(i, heads, chunk, q_ref, k_ref, beta_ref, f_ref, exact)
+        part = _Group(i, heads, chunk, q_ref, k_ref, g_ref, beta_ref, f_ref, exact,
+                      unit_scale)
         q, k, beta, size = part.q, part.k, part.beta, part.size
         d_o = part.load(do_ref)
         states = [kept_ref[0, h, 0] for h in part.heads]     # [V, K]
@@ -397,7 +463,7 @@ def _backward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, kept_ref, do_ref, ds1
         d_v = beta * d_r
 
         # what touches a head's own state, head by head
-        d_qg, d_kg, d_kd = [], [], []
+        d_qg, d_kg, d_kd, d_total = [], [], [], []
         lane = jax.lax.broadcasted_iota(jnp.int32, dbeta_ref.shape[1:], 1)
         for j, (h, state, d_state) in enumerate(zip(part.heads, states, d_states)):
             cut = lambda a: part.by_head(a)[j]
@@ -409,11 +475,12 @@ def _backward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, kept_ref, do_ref, ds1
             ds_ref[0, h] = part.total_decay(j) * d_state + _dot(
                 both, jnp.concatenate([cut(q_g), cut(k_g)], 0), _TN, exact)
             # what every token of the chunk owes through the total
-            dtotal_ref[pl.ds(h, 1), :] = (
+            d_total.append(jnp.broadcast_to(
                 part.total_decay(j) * jnp.sum(state * d_state, 0, keepdims=True)
-                + jnp.sum(cut(k_d) * d_kd[-1], 0, keepdims=True))
+                + jnp.sum(cut(k_d) * d_kd[-1], 0, keepdims=True), cut(k_d).shape))
             dbeta_ref[0] = jnp.where(lane == h, cut(d_beta), dbeta_ref[0])
-        d_qg, d_kg, d_kd = (jnp.concatenate(a, 0) for a in (d_qg, d_kg, d_kd))
+        d_qg, d_kg, d_kd, d_total = (jnp.concatenate(a, 0)
+                                     for a in (d_qg, d_kg, d_kd, d_total))
 
         # the two Grams: as the left operand (q or k of the later token)
         # and as the right one (k of the earlier token)
@@ -431,19 +498,15 @@ def _backward_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, kept_ref, do_ref, ds1
             dq_left += f * left[:size]
             dk_left += f * left[size:]
             dk_right += f * _dot(met, both_f, _TN, exact)
-        part.store(dq_ref, d_qg * part.eg + dq_left)
-        part.store(dk_ref, d_kg * part.eg + d_kd * part.ed + dk_left + dk_right)
+        part.store_gradients(dq_ref, dk_ref, d_qg * part.eg + dq_left,
+                             d_kg * part.eg + d_kd * part.ed + dk_left + dk_right)
         part.store(dv_ref, d_v)
-        # dG_t
-        part.store(dsum_ref, q_g * d_qg + k_g * d_kg - k_d * d_kd
-                   + q * dq_left + k * (dk_left - dk_right))
+        # G_t sums g over the tokens up to t: dg is dG's sum from t onwards
+        d_sum = (q_g * d_qg + k_g * d_kg - k_d * d_kd
+                 + q * dq_left + k * (dk_left - dk_right))
+        part.store(dg_ref, d_total + _sum_from_each_token(d_sum, chunk))
 
     _for_each_group(heads, group)
-    # G_t sums g over the tokens up to t: dg is dG's sum from t onwards
-    running = dtotal_ref[...]
-    for t in reversed(range(chunk)):
-        running = running + dsum_ref[0, pl.ds(t * heads, heads), :]
-        dg_ref[0, pl.ds(t * heads, heads), :] = running
 
 
 class _Blocks:
@@ -451,24 +514,24 @@ class _Blocks:
     the chunks in order), and a grid step's blocks; `at` maps the step
     along the chunk axis to the chunk it takes."""
 
-    def __init__(self, q, v, at, interpret: bool):
-        self.batch, length, self.heads, self.kdim = q.shape
-        self.vdim = v.shape[-1]
+    def __init__(self, beta, at, interpret: bool):
+        self.batch, length, self.heads = beta.shape
         self.count = length // DEFAULT_CHUNK
         self.at = at
         levels = DEFAULT_CHUNK.bit_length() - 1
-        self.rows = DEFAULT_CHUNK * self.heads
-        #: the decays' factors of every level, and their two sums
-        self.factors = [pltpu.VMEM((levels + 2, self.rows, self.kdim), jnp.float32),
-                        pltpu.VMEM((2, self.rows, self.kdim), jnp.float32)]
+        #: a group's decay factors of every level and of the whole chunk
+        self.factors = pltpu.VMEM(
+            (levels + 2, _group_size(self.heads) * DEFAULT_CHUNK, LANES), jnp.float32)
         self.options = dict(
             grid=(self.batch, self.count), interpret=interpret,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary"),
                 vmem_limit_bytes=100 * 1024 * 1024))
 
-    def tokens(self, width):    # of [B, T * H, width]
-        return pl.BlockSpec((1, self.rows, width), lambda b, n: (b, self.at(n), 0))
+    @property
+    def tokens(self):           # of [B, T, H * 128]
+        return pl.BlockSpec((1, DEFAULT_CHUNK, self.heads * LANES),
+                            lambda b, n: (b, self.at(n), 0))
 
     @property
     def gates(self):            # of beta [B, T, H]
@@ -477,22 +540,16 @@ class _Blocks:
 
     @property
     def kept(self):             # of [B, H, N, V, K]
-        return pl.BlockSpec((1, self.heads, 1, self.vdim, self.kdim),
+        return pl.BlockSpec((1, self.heads, 1, LANES, LANES),
                             lambda b, n: (b, 0, self.at(n), 0, 0))
 
     @property
     def state(self):            # of [B, H, V, K]
-        return pl.BlockSpec((1, self.heads, self.vdim, self.kdim),
-                            lambda b, n: (b, 0, 0, 0))
+        return pl.BlockSpec((1, self.heads, LANES, LANES), lambda b, n: (b, 0, 0, 0))
 
     def state_shape(self, *chunks):
         return jax.ShapeDtypeStruct(
-            (self.batch, self.heads, *chunks, self.vdim, self.kdim), jnp.float32)
-
-
-def _as_rows(a):
-    """``[B, T, H, D]`` -> ``[B, T * H, D]``: the same bytes."""
-    return a.reshape(a.shape[0], -1, a.shape[-1])
+            (self.batch, self.heads, *chunks, LANES, LANES), jnp.float32)
 
 
 # Both are jitted by themselves: a model's layers call them at the same
@@ -502,70 +559,65 @@ def _as_rows(a):
 # cell, warm: twelve calls a step).
 
 
-@functools.partial(jax.jit, static_argnames=("exact", "interpret", "keep"))
-def _forward(q, k, v, g, beta, state, exact: bool, interpret: bool, keep: bool):
+@functools.partial(jax.jit, static_argnames=("exact", "interpret", "unit_scale", "keep"))
+def _forward(q, k, v, g, beta, state, exact: bool, interpret: bool,
+             unit_scale: float | None, keep: bool):
     """``(o, final state [B, H, V, K])`` and, with `keep`, every chunk's
     start state ``[B, H, N, V, K]``."""
-    blocks = _Blocks(q, v, lambda n: n, interpret)
-    kdim, vdim = blocks.kdim, blocks.vdim
-    out_shape = [jax.ShapeDtypeStruct(_as_rows(v).shape, jnp.float32),
-                 blocks.state_shape()]
-    out_specs = [blocks.tokens(vdim), blocks.state]
+    blocks = _Blocks(beta, lambda n: n, interpret)
+    out_shape = [jax.ShapeDtypeStruct(v.shape, jnp.float32), blocks.state_shape()]
+    out_specs = [blocks.tokens, blocks.state]
     if keep:
         out_shape.append(blocks.state_shape(blocks.count))
         out_specs.append(blocks.kept)
-    out, *states = pl.pallas_call(
-        functools.partial(_forward_kernel, exact=exact),
+    return pl.pallas_call(
+        functools.partial(_forward_kernel, exact=exact, unit_scale=unit_scale),
         out_shape=out_shape,
-        in_specs=[blocks.tokens(kdim), blocks.tokens(kdim), blocks.tokens(vdim),
-                  blocks.tokens(kdim), blocks.gates, blocks.state],
-        out_specs=out_specs, scratch_shapes=blocks.factors,
+        in_specs=[blocks.tokens] * 4 + [blocks.gates, blocks.state],
+        out_specs=out_specs, scratch_shapes=[blocks.factors],
         name="kda_forward", **blocks.options,
-    )(_as_rows(q), _as_rows(k), _as_rows(v), _as_rows(g), beta,
-      jnp.swapaxes(state, -1, -2))
-    return (out.reshape(v.shape), *states)
+    )(q, k, v, g, beta, jnp.swapaxes(state, -1, -2))
 
 
-@functools.partial(jax.jit, static_argnames=("exact", "interpret"))
+@functools.partial(jax.jit, static_argnames=("exact", "interpret", "unit_scale"))
 def _backward(q, k, v, g, beta, kept_states, d_out, d_state, exact: bool,
-              interpret: bool):
+              interpret: bool, unit_scale: float | None):
     count = q.shape[1] // DEFAULT_CHUNK
-    blocks = _Blocks(q, v, lambda n: count - 1 - n, interpret)
-    kdim, vdim = blocks.kdim, blocks.vdim
+    blocks = _Blocks(beta, lambda n: count - 1 - n, interpret)
     like = lambda a: jax.ShapeDtypeStruct(a.shape, jnp.float32)
-    rows = [_as_rows(a) for a in (q, k, v, g)]
-    dq, dk, dv, dg, dbeta, d_state = pl.pallas_call(
-        functools.partial(_backward_kernel, exact=exact),
-        out_shape=[*map(like, rows), like(beta), blocks.state_shape()],
-        in_specs=[blocks.tokens(kdim), blocks.tokens(kdim), blocks.tokens(vdim),
-                  blocks.tokens(kdim), blocks.gates, blocks.kept,
-                  blocks.tokens(vdim), blocks.state],
-        out_specs=[blocks.tokens(kdim), blocks.tokens(kdim), blocks.tokens(vdim),
-                   blocks.tokens(kdim), blocks.gates, blocks.state],
-        scratch_shapes=[*blocks.factors,
-                        pltpu.VMEM((1, blocks.rows, kdim), jnp.float32),
-                        pltpu.VMEM((blocks.heads, kdim), jnp.float32)],
+    *gradients, d_state = pl.pallas_call(
+        functools.partial(_backward_kernel, exact=exact, unit_scale=unit_scale),
+        out_shape=[*map(like, (q, k, v, g, beta)), blocks.state_shape()],
+        in_specs=[blocks.tokens] * 4 + [blocks.gates, blocks.kept, blocks.tokens,
+                                        blocks.state],
+        out_specs=[blocks.tokens] * 4 + [blocks.gates, blocks.state],
+        scratch_shapes=[blocks.factors],
         name="kda_backward", **blocks.options,
-    )(*rows, beta, kept_states, _as_rows(d_out), jnp.swapaxes(d_state, -1, -2))
-    return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape),
-            dg.reshape(g.shape), dbeta, jnp.swapaxes(d_state, -1, -2))
+    )(q, k, v, g, beta, kept_states, d_out, jnp.swapaxes(d_state, -1, -2))
+    return (*gradients, jnp.swapaxes(d_state, -1, -2))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
-def _fused_kda(q, k, v, g, beta, state, exact: bool, interpret: bool):
-    """The recurrence through the kernels; all float32, `state` given.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def _fused_kda(q, k, v, g, beta, state, exact: bool, interpret: bool,
+               unit_scale: float | None):
+    """The recurrence through the kernels; all float32, `state` given,
+    `q, k, v, g` and the output ``[B, T, H * 128]``.
     `exact`: float32 products; `interpret`: no TPU to compile them for."""
-    out, final = _forward(q, k, v, g, beta, state, exact, interpret, keep=False)
+    out, final = _forward(q, k, v, g, beta, state, exact, interpret, unit_scale,
+                          keep=False)
     return out, jnp.swapaxes(final, -1, -2)
 
 
-def _fused_kda_fwd(q, k, v, g, beta, state, exact: bool, interpret: bool):
-    out, final, kept = _forward(q, k, v, g, beta, state, exact, interpret, keep=True)
+def _fused_kda_fwd(q, k, v, g, beta, state, exact: bool, interpret: bool,
+                   unit_scale: float | None):
+    out, final, kept = _forward(q, k, v, g, beta, state, exact, interpret, unit_scale,
+                                keep=True)
     return (out, jnp.swapaxes(final, -1, -2)), (q, k, v, g, beta, kept)
 
 
-def _fused_kda_bwd(exact: bool, interpret: bool, residuals, cotangents):
-    return _backward(*residuals, *cotangents, exact, interpret)
+def _fused_kda_bwd(exact: bool, interpret: bool, unit_scale: float | None, residuals,
+                   cotangents):
+    return _backward(*residuals, *cotangents, exact, interpret, unit_scale)
 
 
 _fused_kda.defvjp(_fused_kda_fwd, _fused_kda_bwd)

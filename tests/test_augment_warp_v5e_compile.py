@@ -10,6 +10,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -63,9 +64,9 @@ def test_tiled_warp_compiles_for_a_v5e_inside_its_budget(one_chip, no_compile_ca
 def test_fused_kda_kernels_compile_for_a_v5e(one_chip, no_compile_cache,
                                              monkeypatch, ambient):
     """One KDA layer of `kimi_linear_48b_a3b_train`: 8,192 tokens, 32
-    heads of 128, through the kernels as the chip gets them (the backend
-    here is the CPU, which would interpret them), with bfloat16 operands
-    and under ``highest``.  Forward and backward are one Mosaic kernel
+    heads of 128 side by side as the projections leave them, through the
+    kernels as the chip gets them (the backend here is the CPU, which
+    would interpret them), with bfloat16 operands and under ``highest``.  Forward and backward are one Mosaic kernel
     each, and beside the inputs' gradients HBM holds the kept states (268
     MB) and little else."""
     from fast_autoaugment_tpu.ops import kda
@@ -75,7 +76,7 @@ def test_fused_kda_kernels_compile_for_a_v5e(one_chip, no_compile_cache,
     def shape(*dims):
         return jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
 
-    args = (shape(1, 8192, 32, 128),) * 4 + (shape(1, 8192, 32),)
+    args = (shape(1, 8192, 32 * 128),) * 4 + (shape(1, 8192, 32),)
 
     def scalar(*a):
         out, state = kda.chunk_kda(*a)
@@ -87,6 +88,45 @@ def test_fused_kda_kernels_compile_for_a_v5e(one_chip, no_compile_cache,
     assert "kda_forward" in text and "kda_backward" in text
     assert text.count("tpu_custom_call") == 2
     assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
+
+
+def test_a_kda_mixer_moves_no_projection_between_tilings_on_a_v5e(
+        one_chip, no_compile_cache, monkeypatch):
+    """One KDA mixer of `kimi_linear_48b_a3b_train` under ``nn.remat``,
+    forward and backward at 8,192 tokens: the program XLA makes of it
+    holds no ``copy``, ``reshape`` or ``transpose`` of an array as large
+    as a projection's output (8,192 x 4,096 float32: 134 MB) — what stood
+    some 25 times a layer round kernels that read ``[B, T * H, K]`` rows
+    (PR 39 to PR 47).  Only the block's own input (8,192 x 2,304) is
+    still copied."""
+    import re
+
+    from flax import linen as nn
+
+    from fast_autoaugment_tpu.models.kimi_linear import KDAMixer
+    from fast_autoaugment_tpu.models.token_blocks import remat_block
+    from fast_autoaugment_tpu.ops import kda
+
+    monkeypatch.setattr(kda, "_on_tpu", lambda: True)
+
+    class Layer(nn.Module):
+        @nn.compact
+        def __call__(self, x):
+            return x + KDAMixer(32, 128, 4, 1e-5, name="kda")(x)
+
+    layer = remat_block(Layer)()
+    x = jax.ShapeDtypeStruct((1, 8192, 2304), jnp.float32, sharding=one_chip)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(layer.init, jax.random.PRNGKey(0), x))
+    compiled = jax.jit(jax.grad(
+        lambda p, x: jnp.sum(layer.apply(p, x) ** 2), argnums=(0, 1))).lower(
+            params, x).compile()
+    text = compiled.as_text()
+    assert "kda_forward" in text and "kda_backward" in text
+    moved = re.findall(r"= f32\[([\d,]+)\][^ ]* (copy|reshape|transpose)\(", text)
+    sizes = [np.prod([int(d) for d in dims.split(",")]) for dims, _ in moved]
+    assert moved and max(sizes) < 8192 * 4096, [m for m in moved if "4096" in m[0]]
 
 
 @pytest.mark.parametrize("ambient", ["default", "highest"])

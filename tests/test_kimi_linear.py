@@ -15,6 +15,7 @@ import pytest
 
 from fast_autoaugment_tpu.core import telemetry
 from fast_autoaugment_tpu.models import get_model, model_conf_of
+from fast_autoaugment_tpu.models.kimi_linear import KDAMixer
 from fast_autoaugment_tpu.ops import kda, moe
 from fast_autoaugment_tpu.ops.attention import blocked_causal_attention
 from fast_autoaugment_tpu.ops.kda import chunk_kda, recurrent_kda
@@ -47,6 +48,10 @@ def _close(a, b, rel):
 #: (interpreted here: the code the chip runs)
 SMALL = dict(batch=2, heads=3, kdim=16, vdim=8)
 NATIVE = dict(batch=1, heads=2, kdim=128, vdim=128)
+#: three heads: the kernels take them one at a time; four: two groups of
+#: two, so a group whose lanes are not the block's first
+NATIVE_3 = dict(NATIVE, heads=3)
+NATIVE_4 = dict(NATIVE, heads=4)
 
 _chunked = jax.jit(chunk_kda, static_argnames="chunk")
 
@@ -73,7 +78,9 @@ def _form_counts():
 
 @pytest.mark.parametrize("length, chunk, shape", [
     (128, 64, SMALL), (96, 32, SMALL), (48, 16, SMALL), (24, 64, SMALL),
-    (256, 64, NATIVE)], ids=["128-64", "96-32", "48-16", "24-64", "native"])
+    (256, 64, NATIVE), (128, 64, NATIVE_3), (128, 64, NATIVE_4)],
+    ids=["128-64", "96-32", "48-16", "24-64", "native", "native-3-heads",
+         "native-4-heads"])
 def test_chunked_scan_gives_the_recurrences_output_and_state(length, chunk, shape):
     args = _kda_inputs(length, 1.0, **shape)
     out, state = recurrent_kda(*args)
@@ -94,8 +101,8 @@ def test_chunked_scan_takes_an_initial_state(shape):
     _close(state, whole_state, 2e-5)
 
 
-@pytest.mark.parametrize("length, shape", [(128, SMALL), (256, NATIVE)],
-                         ids=["small", "native"])
+@pytest.mark.parametrize("length, shape", [(128, SMALL), (256, NATIVE), (128, NATIVE_4)],
+                         ids=["small", "native", "native-4-heads"])
 def test_a_decay_that_would_overflow_a_factorised_form_does_not(length, shape):
     """A channel that decays by e^-1500 a token: exp(-G) inside a chunk
     is far beyond float32, the recurrence itself is tame."""
@@ -111,12 +118,18 @@ def test_a_decay_that_would_overflow_a_factorised_form_does_not(length, shape):
 _SCAN_ARGUMENTS = ["q", "k", "v", "g", "beta", "initial_state"]
 
 
+#: (length, shape) of the gradients' inputs
+_GRADIENT_SHAPES = {"small": (128, SMALL), "native": (256, NATIVE),
+                    "native-3-heads": (128, NATIVE_3),
+                    "native-4-heads": (128, NATIVE_4)}
+
+
 @functools.lru_cache(maxsize=None)
-def _scan_gradients(native: bool):
+def _scan_gradients(native):
     """``(the recurrence's, the chunked scan's)`` gradients of all six
     arguments, the cotangent on both the output and the final state."""
-    args = _kda_inputs(256 if native else 128, 1.0, seed=1,
-                       **(NATIVE if native else SMALL))
+    length, shape = _GRADIENT_SHAPES[native]
+    args = _kda_inputs(length, 1.0, seed=1, **shape)
     batch, _, heads, kdim = args[0].shape
     args += (0.1 * jax.random.normal(jax.random.PRNGKey(9),
                                      (batch, heads, kdim, args[2].shape[-1])),)
@@ -132,11 +145,96 @@ def _scan_gradients(native: bool):
             jax.jit(jax.grad(scalar(chunk_kda), argnums=every))(*args))
 
 
-@pytest.mark.parametrize("native", [False, True], ids=["small", "native"])
+@pytest.mark.parametrize("native", list(_GRADIENT_SHAPES))
 @pytest.mark.parametrize("argnum, name", list(enumerate(_SCAN_ARGUMENTS)))
 def test_chunked_scans_gradient_is_the_recurrences(argnum, name, native):
     plain, chunked = _scan_gradients(native)
     _close(chunked[argnum], plain[argnum], 2e-4)
+
+
+@pytest.mark.parametrize("shape", [SMALL, NATIVE_4], ids=["small", "native-4-heads"])
+def test_heads_side_by_side_are_the_same_scan(shape):
+    """``[B, T, H * K]`` as a projection leaves it, in and out: the scan
+    of the same numbers cut ``[B, T, H, K]``."""
+    q, k, v, g, beta = _kda_inputs(128, 1.0, **shape)
+    flat = lambda a: a.reshape(*a.shape[:2], -1)
+    out, state = _chunked(q, k, v, g, beta)
+    side_by_side, same_state = _chunked(flat(q), flat(k), flat(v), flat(g), beta)
+    assert side_by_side.shape == flat(v).shape
+    np.testing.assert_array_equal(side_by_side, flat(out))
+    np.testing.assert_array_equal(same_state, state)
+
+
+@pytest.mark.parametrize("shape", [SMALL, NATIVE_4], ids=["small", "native-4-heads"])
+def test_unit_length_taken_by_the_scan_is_unit_length_in_front_of_it(shape):
+    """``unit_scale``: `q` and `k` of any length go in, and the output,
+    the state and the gradients of both are those of the scan given the
+    rows brought to unit length (and `q` scaled) beforehand."""
+    q, k, v, g, beta = _kda_inputs(128, 1.0, seed=5, **shape)
+    q, k = 3.0 * q * (1.0 + beta[..., None]), 0.5 * k * (2.0 - beta[..., None])
+    scale = q.shape[-1] ** -0.5
+
+    def scalar(scan):
+        def f(q, k):
+            out, state = scan(q, k)
+            return jnp.sum(out * jnp.cos(out)) + jnp.sum(state ** 2), (out, state)
+        return jax.jit(jax.value_and_grad(f, argnums=(0, 1), has_aux=True))
+
+    def in_front(q, k):
+        return chunk_kda(q * kda.unit_factor(q, scale), k * kda.unit_factor(k),
+                         v, g, beta)
+
+    (_, plain), plain_grads = scalar(in_front)(q, k)
+    (_, taken), taken_grads = scalar(
+        lambda q, k: chunk_kda(q, k, v, g, beta, unit_scale=scale))(q, k)
+    for a, b in zip(taken + taken_grads, plain + plain_grads):
+        _close(a, b, 2e-5)
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr, the programs it calls included but for
+    a kernel's body."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name != "pallas_call":
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from _equations(sub)
+
+
+def test_the_mixer_hands_the_kernels_the_projections_own_layout():
+    """The gradient's program of a KDA mixer at the native head shape:
+    every array the two kernels read or write along the tokens is ``[B,
+    T, H * K]`` as a projection writes it (``beta`` and its gradient
+    ``[B, T, H]``), no array of the program is cut to ``[B, T, H, K]``
+    and none the size of a projection's output is transposed, on the way
+    from a projection to a call or from one back: XLA is asked for no
+    tiling that it would have to move such an array to."""
+    batch, length, heads, dim, hidden = 1, 192, 4, 128, 32
+    mixer = KDAMixer(heads, dim, 4, 1e-5)
+    x = jax.random.normal(jax.random.PRNGKey(0), (batch, length, hidden))
+    params = mixer.init(jax.random.PRNGKey(1), x)
+    program = jax.make_jaxpr(jax.grad(
+        lambda p, x: jnp.sum(mixer.apply(p, x) ** 2), argnums=(0, 1)))(params, x)
+    equations = list(_equations(program.jaxpr))
+    calls = {eqn.params["name"]: eqn for eqn in equations
+             if eqn.primitive.name == "pallas_call"}
+    assert sorted(calls) == ["kda_backward", "kda_forward"]
+    tokens, gates = (batch, length, heads * dim), (batch, length, heads)
+
+    def along_tokens(variables):
+        return sorted(v.aval.shape for v in variables if v.aval.shape[1] == length)
+
+    forward, backward = calls["kda_forward"], calls["kda_backward"]
+    assert along_tokens(forward.invars) == [gates] + [tokens] * 4    # q k v g
+    assert along_tokens(forward.outvars) == [tokens]
+    assert along_tokens(backward.invars) == [gates] + [tokens] * 5   # and do
+    assert along_tokens(backward.outvars) == [gates] + [tokens] * 4
+    shapes = [v.aval.shape for eqn in equations for v in eqn.outvars
+              if hasattr(v.aval, "shape")]
+    assert tokens in shapes and (batch, length, heads, dim) not in shapes
+    transposed = [eqn.invars[0].aval for eqn in equations
+                  if eqn.primitive.name == "transpose"]
+    assert transposed and max(a.size for a in transposed) < np.prod(tokens)
 
 
 def test_a_length_that_is_no_multiple_of_the_chunk_is_refused():
