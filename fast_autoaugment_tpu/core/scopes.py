@@ -49,6 +49,8 @@ __all__ = [
     "GQA",
     "SWA",
     "GQA_ATTENTION",
+    "SHORT_CONV",
+    "SHORT_CONV_GATE",
     "LOSS",
     "OPTIMIZER",
     "EMA",
@@ -93,7 +95,8 @@ SHAKE_SHORTCUT = "faa_shake_shortcut"
 #: (7x7 stride-2 convolution, BatchNorm, ReLU, 3x3 stride-2 max-pool)
 RESNET_STEM = "faa_resnet_stem"
 #: the token models (``models/kimi_linear.py``, ``models/glm4_moe_lite.py``,
-#: ``models/nemotron_h.py``, ``models/afmoe.py``, ``models/token_blocks.py``), all nested under
+#: ``models/nemotron_h.py``, ``models/afmoe.py``, ``models/lfm2_moe.py``,
+#: ``models/token_blocks.py``), all nested under
 #: ``faa_model``: the KDA mixer
 #: (projections, short convolutions, gates, output norm and gate) with the
 #: chunked delta-rule recurrence alone inside it (``ops/kda.py``, forward
@@ -147,6 +150,15 @@ GQA = "faa_gqa"
 #: computes again)
 SWA = "faa_swa"
 GQA_ATTENTION = "faa_gqa_attention"
+#: ``models/lfm2_moe.py`` round ``models/token_blocks.py::ShortConvMixer``,
+#: nested under ``faa_model``: a short-convolution mixer whole (``in_proj``,
+#: the two gates, the taps, ``out_proj``; forward, backward and what
+#: ``nn.remat`` computes again) and, inside it, what lies between its two
+#: projections alone (the split, ``B * z``, the depthwise causal taps, ``C *
+#: c`` and their cotangents: memory-bound elementwise work over ``[T, 3
+#: hidden]``)
+SHORT_CONV = "faa_short_conv"
+SHORT_CONV_GATE = "faa_short_conv_gate"
 LOSS = "faa_loss"
 #: ``train/steps.py::step_fn``: update and parameter add; EMA; top-k and sums
 OPTIMIZER = "faa_optimizer"

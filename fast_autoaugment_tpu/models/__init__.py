@@ -8,9 +8,10 @@ are the train step's concern — a module is pure structure.
 Supported types (reference parity): resnet50, resnet200, wresnet40_2,
 wresnet28_10, shakeshake26_2x32d / 2x64d / 2x96d / 2x112d,
 shakeshake26_2x96d_next, pyramid, efficientnet-b0..b7 (+condconv).
-Beyond the reference, four token models: kimi_linear
+Beyond the reference, five token models: kimi_linear
 (``models/kimi_linear.py``), glm4_moe_lite (``models/glm4_moe_lite.py``),
-nemotron_h (``models/nemotron_h.py``) and afmoe (``models/afmoe.py``).
+nemotron_h (``models/nemotron_h.py``), afmoe (``models/afmoe.py``) and
+lfm2_moe (``models/lfm2_moe.py``).
 """
 
 from __future__ import annotations
@@ -145,6 +146,10 @@ def get_model(conf: Any, num_classes: int) -> nn.Module:
         from fast_autoaugment_tpu.models.afmoe import afmoe_from_conf
 
         return afmoe_from_conf(conf, dtype=dtype)
+    if name == "lfm2_moe":
+        from fast_autoaugment_tpu.models.lfm2_moe import lfm2_moe_from_conf
+
+        return lfm2_moe_from_conf(conf, dtype=dtype)
     if name.startswith("efficientnet"):
         from fast_autoaugment_tpu.models.efficientnet import EfficientNet
 

@@ -40,6 +40,7 @@ from fast_autoaugment_tpu.models.token_blocks import (
     Sizes as _Sizes,
     SwiGLU,
     balance_routers,
+    causal_conv,
     dense as _dense,
     expert_share_of,
     publish_router_counts,
@@ -62,11 +63,7 @@ class ShortConv(nn.Module):
     def __call__(self, x):                                   # [B, T, C]
         kernel = self.param("kernel", nn.initializers.normal(
             1.0 / math.sqrt(self.taps)), (self.taps, x.shape[-1]))
-        padded = jnp.pad(x, ((0, 0), (self.taps - 1, 0), (0, 0)))
-        length = x.shape[1]
-        out = sum(padded[:, i:i + length] * kernel[i].astype(x.dtype)
-                  for i in range(self.taps))
-        return jax.nn.silu(out)
+        return jax.nn.silu(causal_conv(x, kernel))
 
 
 def _a_log_init(key, shape, dtype=jnp.float32):

@@ -72,6 +72,7 @@ from fast_autoaugment_tpu.models.token_blocks import (
     RMSNorm,
     Sizes,
     balance_routers,
+    causal_conv,
     dense,
     expert_share_of,
     publish_router_counts,
@@ -87,16 +88,6 @@ __all__ = ["NemotronH", "nemotron_h_from_conf", "causal_conv", "gated_group_norm
 
 #: the pattern's characters
 MAMBA, ATTENTION, EXPERTS, DENSE = "M", "*", "E", "-"
-
-
-def causal_conv(x, kernel, bias):
-    """Depthwise causal convolution over time: `x` ``[B, T, C]``, `kernel`
-    ``[taps, C]`` (its last tap meets the token itself), `bias` ``[C]``;
-    zeros before the sequence."""
-    taps, length = kernel.shape[0], x.shape[1]
-    padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
-    return sum(padded[:, i:i + length] * kernel[i].astype(x.dtype)
-               for i in range(taps)) + bias.astype(x.dtype)
 
 
 def gated_group_norm(y, z, weight, groups: int, eps: float):

@@ -1,7 +1,7 @@
 """What the token models share: the blocks of a pre-norm decoder whose
 FFN may be a sigmoid-routed expert layer this chip holds a share of
 (``models/kimi_linear.py``, ``models/glm4_moe_lite.py``,
-``models/nemotron_h.py``, ``models/afmoe.py``).
+``models/nemotron_h.py``, ``models/afmoe.py``, ``models/lfm2_moe.py``).
 
 - :class:`RMSNorm`, the bias-free :func:`dense`, a bare :class:`Kernel`,
   and two feed-forward forms: :class:`SwiGLU` and :class:`SquaredReLU`;
@@ -12,9 +12,13 @@ FFN may be a sigmoid-routed expert layer this chip holds a share of
 - :class:`GQAMixer`, grouped-query attention, bare (Nemotron-H's) or with
   a norm a head on queries and keys, rotary, a key span and a gate on the
   output (afmoe's), each by an argument;
+- :func:`causal_conv`, a depthwise causal convolution over a few taps, and
+  :class:`ShortConvMixer`, lfm2_moe's mixer: such a convolution gated on
+  both sides between two projections, no activation;
 - :class:`ExpertLayer`, the routed experts this chip holds beside the
-  shared expert (``ops/moe.py``), both of one form (``ops/moe.py::FORMS``),
-  the shared one at ``width * shared`` or at a width of its own;
+  shared expert where the family has one (``ops/moe.py``), both of one
+  form (``ops/moe.py::FORMS``), the shared one at ``width * shared`` or at
+  a width of its own;
 - :class:`Sizes`, the hashable view of a model's sizes ``nn.remat`` takes;
 - :func:`remat_block`, the ``nn.remat`` every family wraps its block in
   (``model.remat``; false: no ``nn.remat`` at all): a block's input is kept
@@ -48,7 +52,7 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-from fast_autoaugment_tpu.core import scopes
+from fast_autoaugment_tpu.core import scopes, telemetry
 from fast_autoaugment_tpu.ops import moe
 from fast_autoaugment_tpu.ops.attention import (
     LSE_NAME,
@@ -57,7 +61,7 @@ from fast_autoaugment_tpu.ops.attention import (
 )
 
 __all__ = ["RMSNorm", "SwiGLU", "SquaredReLU", "Kernel", "MLAMixer", "GQAMixer",
-           "ExpertLayer",
+           "ShortConvMixer", "ExpertLayer", "causal_conv",
            "FEED_FORWARDS", "Sizes", "remat_block", "dense", "step_bias_init",
            "rotate_by_position", "expert_share_of", "refuse_unwritten_routing",
            "balance_routers", "publish_router_counts", "INIT", "STEP_STATS",
@@ -221,6 +225,43 @@ class GQAMixer(nn.Module):
         return dense(hidden, "o_proj", self.dtype)(out)
 
 
+def causal_conv(x, kernel, bias=None):
+    """Depthwise causal convolution over time: `x` ``[B, T, C]``, `kernel`
+    ``[taps, C]`` (its last tap meets the token itself), `bias` ``[C]`` or
+    none; zeros before the sequence."""
+    taps, length = kernel.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
+    out = sum(padded[:, i:i + length] * kernel[i].astype(x.dtype)
+              for i in range(taps))
+    return out if bias is None else out + bias.astype(x.dtype)
+
+
+class ShortConvMixer(nn.Module):
+    """lfm2_moe's mixer, a doubly gated short convolution: ``(B, C, z) =
+    split3(in_proj u)`` (hidden -> 3 hidden, in that order), ``s = B * z``,
+    ``c = causal_conv(s)`` over `taps` taps (depthwise, no bias, no
+    activation), ``out_proj(C * c)``.  What lies between the two
+    projections — both gates and the taps — is under ``faa_short_conv_gate``;
+    the family puts the mixer whole under ``faa_short_conv``."""
+
+    taps: int
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        hidden = x.shape[-1]
+        telemetry.registry().counter(
+            "faa_short_conv_traces_total", "short-convolution mixers traced into a "
+            "program, by their taps", taps=str(self.taps)).inc()
+        projected = dense(3 * hidden, "in_proj", self.dtype)(x)
+        kernel = self.param("conv_kernel", nn.initializers.normal(
+            1.0 / math.sqrt(self.taps)), (self.taps, hidden))
+        with jax.named_scope(scopes.SHORT_CONV_GATE):
+            before, after, z = jnp.split(projected, 3, axis=-1)
+            gated = after * causal_conv(before * z, kernel)
+        return dense(hidden, "out_proj", self.dtype)(gated)
+
+
 class Kernel(nn.Module):
     """A matrix under ``nn.Dense``'s name for it, handed out whole (an
     output head whose product a blocked loss takes a block at a time)."""
@@ -265,10 +306,12 @@ assert set(FEED_FORWARDS) == set(moe.FORMS)
 
 
 class ExpertLayer(nn.Module):
-    """The routed experts this chip holds, beside the shared expert.
-    `form`: what an expert computes, routed and shared alike
-    (``ops/moe.py::FORMS``); `shared_width`: the shared expert's width
-    where it has one of its own (absent: ``width * shared``)."""
+    """The routed experts this chip holds, beside the shared expert
+    (`shared` 0: the family has none).  `form`: what an expert computes,
+    routed and shared alike (``ops/moe.py::FORMS``); `shared_width`: the
+    shared expert's width where it has one of its own (absent: ``width *
+    shared``); `renorm_eps`: what the renormalisation adds to the chosen
+    scores' sum (``ops/moe.py::route``)."""
 
     experts: int
     held: int
@@ -281,6 +324,7 @@ class ExpertLayer(nn.Module):
     dtype: Any = jnp.float32
     form: str = "swiglu"
     shared_width: int | None = None
+    renorm_eps: float = 1e-20
 
     @nn.compact
     def __call__(self, x):
@@ -294,7 +338,7 @@ class ExpertLayer(nn.Module):
                               (self.experts,))
             chosen, weights = moe.route(
                 flat, router.astype(self.dtype), bias, top_k=self.top_k,
-                scale=self.scale, renormalize=self.renormalize)
+                scale=self.scale, renormalize=self.renormalize, eps=self.renorm_eps)
             self.sow(STEP_STATS, "load",
                      moe.assignment_counts(chosen, 0, self.experts))
             # for a caller that asks (``mutable=[ROUTING]``): which experts

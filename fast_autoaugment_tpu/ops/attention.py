@@ -13,15 +13,18 @@ same function with no shared key part (``models/token_blocks.py::
 GQAMixer``: afmoe's window and full layers, Nemotron-H's full ones).
 
 **Two forms, chosen by shape** (:func:`_fused_tile`).  Where a head's
-values are whole lanes (a multiple of 128), its own key at least 128
-wide, the sequence at least two tiles long and one head's whole sequence
-inside the kernels' VMEM budget (2 x 4 x T x (2 x key width + value
-width) bytes within 78.6 MB: 12,800 tokens at a key and values of 256 +
-256, 25,600 at 128 + 128) — the configurations' shapes: 128 + 64 / 128
-and 192 + 64 / 256 at 8,192 tokens, 128 / 128 at 8,192 and at 16,384 —
-:func:`blocked_causal_attention` runs a pair of fused TPU kernels under
-a ``jax.custom_vjp`` (:func:`_fused_attention`; interpreted where the
-backend is no TPU, so a CPU test runs the code the chip runs).  The tile
+values are whole lanes (a multiple of 128) and its own key at least 128
+wide — or its key and values are exactly 64 wide each, with no shared key
+part and an even head count (*paired heads*, below) — the sequence at
+least two tiles long and one head's (one pair's) whole sequence inside
+the kernels' VMEM budget (2 x 4 x T x (2 x key width + value width) bytes
+within 78.6 MB: 12,800 tokens at a key and values of 256 + 256, 25,600 at
+128 + 128 and at a pair of 64 + 64) — the configurations' shapes: 128 + 64
+/ 128 and 192 + 64 / 256 at 8,192 tokens, 128 / 128 at 8,192 and at
+16,384, 64 / 64 at 16,384 — :func:`blocked_causal_attention` runs a pair
+of fused TPU kernels under a ``jax.custom_vjp`` (:func:`_fused_attention`;
+interpreted where the backend is no TPU, so a CPU test runs the code the
+chip runs).  The tile
 follows from the sequence length (512, else 256 or 128: the largest that
 divides it at least twice).  The shared key part is copied to every head
 and the key padded with zeros to whole lanes in HBM (256 a head in both
@@ -30,6 +33,27 @@ serves both parts of the score; differentiating that copy sums the
 part's gradient over the heads.  The kernels read ``[B, T, H, D]`` as it
 lies, a head's tile a strided block of ``[B, T, H * D]``: nothing is
 transposed.
+
+*Paired heads.*  A head of 64 is half a row of lanes, and a block's last
+dimension has to be whole rows: the kernels then take **two adjacent
+heads to a 128-lane block** of ``[B, T, H * 64]`` as it lies — heads ``2p``
+and ``2p + 1``, a grid step a pair — and nothing is padded or copied in
+HBM.  Inside a step the pair's queries (forward; keys and values
+backward) are stacked (:func:`_stack_pair`): the first head's rows with
+the second head's lanes at zero over the second head's rows with the
+first's at zero, so that one 128-wide product against the block as it
+lies gives each head's scores from its own half of the lanes, each head
+has its own running maximum, sum and log-sum-exp (rows ``[B, H, N,
+tile]`` as for any head), and a product over the stacked rows adds each
+head's share of ``dq`` into its own lanes; the outputs, ``dk`` and ``dv``
+are folded back to the block (:func:`_fold_pair`).  Half of every
+128-wide product is zeros: the MXU does a head of 128's work for a head
+of 64's mathematics, which the cores' share of their roofline shows.
+Each head has keys and values of its own in its half (grouped-query
+attention hands the kernels the key-value heads repeated to every query
+head).  An odd head count, a width of 64 beside a shared key part or
+beside values of another width, and any other width under 128 fall back
+to the XLA form.
 
 *Forward* (:func:`_forward_kernel`): a grid step is one tile of one
 head's queries.  That head's keys and values, the whole sequence, sit in
@@ -105,6 +129,9 @@ form a program got: ``fused`` or ``blocked_xla``;
 the key tiles a core's loops meet over one head's sequence (``visited``)
 beside the causal half's (``causal``): 150 of 528 at 16,384 tokens, a
 tile of 512 and a span of 2,048.
+``faa_attention_head_blocks_traced_total{heads_a_block}`` counts the fused
+cores by the heads a block of their kernels holds (``1``, or ``2`` for
+paired heads of 64).
 ``faa_attention_outputs_named_total{span}`` counts the cores whose forward
 rule named its products — the cores offered to a policy — and
 ``faa_attention_kept_bytes_total{span}`` the bytes of the two arrays, what
@@ -135,6 +162,8 @@ DEFAULT_SPANS = 4
 OUT_NAME = "faa_attention_out"
 LSE_NAME = "faa_attention_lse"
 LANES = kda.LANES
+#: a head width the kernels take two to a block of :data:`LANES`
+HALF = LANES // 2
 #: the tiles of the fused kernels, largest first: a sequence takes the
 #: first that divides it at least twice
 TILES = (512, 256, 128)
@@ -161,6 +190,7 @@ def blocked_causal_attention(q, k, v, *, scale: float, q_shared=None,
         if window >= length:
             window = None
     tile = _fused_tile(q, v, q_shared)
+    paired = tile is not None and v.shape[-1] == HALF
     # trace time: which form each program that holds an attention core got
     form = "blocked_xla" if tile is None else "fused"
     telemetry.registry().counter(
@@ -173,6 +203,10 @@ def blocked_causal_attention(q, k, v, *, scale: float, q_shared=None,
     if tile is None:
         return _blocked_xla(q, k, v, q_shared, k_shared, scale, block, spans, window)
     _count_key_tiles(window, *key_tiles(length, tile, window))
+    telemetry.registry().counter(
+        "faa_attention_head_blocks_traced_total", "fused attention cores traced "
+        "into a program, by the heads a 128-lane block of their kernels holds",
+        heads_a_block="2" if paired else "1").inc()
     batch, length, heads, _ = q.shape
     if q_shared is not None:
         # the shared key part, copied to every head: one product a tile
@@ -181,8 +215,9 @@ def blocked_causal_attention(q, k, v, *, scale: float, q_shared=None,
         q = jnp.concatenate([q, q_shared], -1)
         k = jnp.concatenate([k, jnp.broadcast_to(
             k_shared[:, :, None], (batch, length, heads, k_shared.shape[-1]))], -1)
-    short = -q.shape[-1] % LANES      # zeros: they add nothing to a score
-    q, k = (jnp.pad(a, ((0, 0),) * 3 + ((0, short),)) for a in (q, k))
+    if not paired:                    # a pair of heads of 64 is whole lanes as it lies
+        short = -q.shape[-1] % LANES  # zeros: they add nothing to a score
+        q, k = (jnp.pad(a, ((0, 0),) * 3 + ((0, short),)) for a in (q, k))
     # float32 in, whatever the model's activations are: under `highest` the
     # kernels' products are float32 ones, which Mosaic refuses bfloat16
     # operands for (a model in ``precision: bf16`` under a float32 comparison)
@@ -239,17 +274,29 @@ def _count_named(window: int | None, kept_bytes: int) -> None:
 
 def _fused_tile(q, v, q_shared) -> int | None:
     """The tile of the fused kernels for these shapes, or None where they
-    take the XLA form: a head's values are whole lanes and its own key at
-    least one row of them, the sequence is at least two tiles, and what
-    the kernels keep of one head's whole sequence fits VMEM."""
+    take the XLA form.  Admitted: a head's values whole lanes (a multiple
+    of 128) and its own key at least one row of them; or a key and values
+    of exactly half a row (64) each, no shared key part and an even head
+    count, which the kernels take two adjacent heads to a 128-lane block
+    (:data:`HALF`).  Either way the sequence is at least two tiles, and
+    what the kernels keep of one head's (one pair's) whole sequence fits
+    VMEM.  Still falling back: any other width under 128, a width of 64
+    beside a shared key part, beside values of another width or on an odd
+    head count."""
     length = q.shape[1]
-    if v.shape[-1] % LANES or q.shape[-1] < LANES:
+    if v.shape[-1] == HALF:
+        if q.shape[-1] != HALF or q_shared is not None or q.shape[2] % 2:
+            return None
+        width = values = LANES        # a block is two heads side by side
+    elif v.shape[-1] % LANES or q.shape[-1] < LANES:
         return None
-    width = q.shape[-1] + (0 if q_shared is None else q_shared.shape[-1])
-    width += -width % LANES
+    else:
+        width = q.shape[-1] + (0 if q_shared is None else q_shared.shape[-1])
+        width += -width % LANES
+        values = v.shape[-1]
     # the backward kernel's: q and the cotangent, float32 at the most, and
     # dq, each twice (the pipeline's two buffers)
-    if 2 * 4 * length * (2 * width + v.shape[-1]) > VMEM_LIMIT_BYTES * 3 // 4:
+    if 2 * 4 * length * (2 * width + values) > VMEM_LIMIT_BYTES * 3 // 4:
         return None
     return next((t for t in TILES if length % t == 0 and length >= 2 * t), None)
 
@@ -295,8 +342,36 @@ def _as_row(column):
         for first in range(0, size, LANES)], 1)
 
 
+def _low_half(shape):
+    """Whether a lane belongs to the first head of a pair's block."""
+    return jax.lax.broadcasted_iota(jnp.int32, shape, 1) < HALF
+
+
+def _stack_pair(block):
+    """A pair's ``[n, 128]`` block as ``[2n, 128]``: the first head's rows
+    with the second head's lanes at zero, then the second head's with the
+    first's at zero.  A product over the lanes against the pair's block
+    as it lies is then each head's own, and a product over these rows adds
+    each head's part into its own lanes."""
+    low, zero = _low_half(block.shape), jnp.zeros_like(block)
+    return jnp.concatenate([jnp.where(low, block, zero),
+                            jnp.where(low, zero, block)], 0)
+
+
+def _fold_pair(stacked):
+    """``[2n, 128]``, a pair's heads one under the other, each with its
+    result over all the lanes -> ``[n, 128]``: each head's own lanes."""
+    half = stacked.shape[0] // 2
+    return jnp.where(_low_half((half, LANES)), stacked[:half], stacked[half:])
+
+
+def _twice(seen):
+    """A tile's mask for a pair's two heads, one under the other."""
+    return jnp.concatenate([seen, seen], 0)
+
+
 def _forward_kernel(q_ref, k_ref, v_ref, o_ref, *lse_ref, scale: float, exact: bool,
-                    window: int | None):
+                    window: int | None, pair: bool):
     """One tile of one head's queries against that head's keys up to the
     diagonal: `k_ref`, `v_ref` hold the head's whole sequence (fetched once
     a head), a tile of scores lives from its product to its weighted sum,
@@ -306,17 +381,23 @@ def _forward_kernel(q_ref, k_ref, v_ref, o_ref, *lse_ref, scale: float, exact: b
     ``ceil((window - 1) / tile)`` tiles under the diagonal and not at 0:
     the diagonal tile first (every row has a key in it, so the running
     maximum is finite from then on), the tiles wholly inside the band
-    unmasked, the one or two at its trailing edge masked by the span."""
+    unmasked, the one or two at its trailing edge masked by the span.
+    With `pair` the blocks hold two heads of 64 side by side: the queries
+    are stacked (:func:`_stack_pair`), so a tile's scores, maxima, sums and
+    outputs are ``[2 * tile, ...]``, the first head's rows over the
+    second's, each against its own half of the keys' and values' lanes."""
     i = pl.program_id(2)
     tile = q_ref.shape[1]
-    q = q_ref[0]
+    q = _stack_pair(q_ref[0]) if pair else q_ref[0]
+    rows = q.shape[0]
+    masked = _twice if pair else (lambda seen: seen)
 
     def against(j, carry, seen=None):
         top, total, out = carry
         keys = pl.ds(pl.multiple_of(j * tile, tile), tile)
         scores = kda._dot(q, k_ref[0, keys, :], kda._NT, exact) * scale
         if seen is not None:
-            scores = jnp.where(seen, scores, -jnp.inf)
+            scores = jnp.where(masked(seen), scores, -jnp.inf)
         new_top = jnp.maximum(top, jnp.max(scores, -1, keepdims=True))
         shrink = jnp.exp(top - new_top)
         weights = jnp.exp(scores - new_top)
@@ -324,9 +405,9 @@ def _forward_kernel(q_ref, k_ref, v_ref, o_ref, *lse_ref, scale: float, exact: b
         out = shrink * out + kda._dot(weights, v_ref[0, keys, :], kda._NN, exact)
         return new_top, total, out
 
-    carry = (jnp.full((tile, 1), -jnp.inf, jnp.float32),
-             jnp.zeros((tile, 1), jnp.float32),
-             jnp.zeros((tile, v_ref.shape[-1]), jnp.float32))
+    carry = (jnp.full((rows, 1), -jnp.inf, jnp.float32),
+             jnp.zeros((rows, 1), jnp.float32),
+             jnp.zeros((rows, v_ref.shape[-1]), jnp.float32))
     if window is None:
         # the key tiles wholly below the diagonal, then the one on it
         carry = jax.lax.fori_loop(0, i, against, carry)
@@ -338,9 +419,16 @@ def _forward_kernel(q_ref, k_ref, v_ref, o_ref, *lse_ref, scale: float, exact: b
         top, total, out = jax.lax.fori_loop(
             first, whole, lambda j, carry: against(j, carry, _in_band(
                 tile, i - j, window, keys_first=False)), carry)
-    o_ref[0] = out / total
+    if not pair:
+        o_ref[0] = out / total
+        for ref in lse_ref:
+            ref[0, 0, pl.ds(i, 1), :] = _as_row(top + jnp.log(total))
+        return
+    o_ref[0] = _fold_pair(out / total)
     for ref in lse_ref:
-        ref[0, 0, pl.ds(i, 1), :] = _as_row(top + jnp.log(total))
+        both = _as_row(top + jnp.log(total))                 # [1, 2 * tile]
+        ref[0, 0, pl.ds(i, 1), :] = both[:, :tile]
+        ref[0, 1, pl.ds(i, 1), :] = both[:, tile:]
 
 
 def _band(i, window: int, tile: int):
@@ -353,18 +441,33 @@ def _band(i, window: int, tile: int):
 
 def _backward_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                      dq_ref, dk_ref, dv_ref, *, scale: float, exact: bool,
-                     window: int | None):
+                     window: int | None, pair: bool):
     """One tile of one head's keys against that head's queries from the
     diagonal on: `q_ref`, `do_ref` and `dq_ref` hold the head's whole
     sequence (`dq_ref` stays in VMEM while the key tiles go by and gathers
     every tile's share).  A tile's probabilities are computed again from
     `q`, `k` and the log-sum-exp, keys along the rows: what belongs to a
     query (`lse`, `delta = sum(o * do)`) is then a row vector, and no sum
-    runs along the lanes."""
+    runs along the lanes.  With `pair` the keys and values of the block's
+    two heads of 64 are stacked (:func:`_stack_pair`): a tile is ``[2 *
+    tile keys, tile queries]``, each head's keys against the queries' and
+    the cotangent's whole block, its own rows of `lse` and `delta`; `dq`
+    gathers both heads' shares, each into its own lanes, and `dk`, `dv`
+    are folded back to the block once the query tiles have gone by."""
     j = pl.program_id(2)
     tile = k_ref.shape[1]
     count = q_ref.shape[1] // tile
     k, v = k_ref[0], v_ref[0]
+    if pair:
+        k, v = _stack_pair(k), _stack_pair(v)
+        first_head = jax.lax.broadcasted_iota(jnp.int32, (2 * tile, tile), 0) < tile
+    masked = _twice if pair else (lambda seen: seen)
+
+    def of_queries(ref, i):
+        """What belongs to the query tile `i`, a row a head."""
+        if not pair:
+            return ref[0, 0, pl.ds(i, 1), :]
+        return jnp.where(first_head, ref[0, 0, pl.ds(i, 1), :], ref[0, 1, pl.ds(i, 1), :])
 
     @pl.when(j == 0)
     def _():
@@ -375,20 +478,22 @@ def _backward_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         queries = pl.ds(pl.multiple_of(i * tile, tile), tile)
         q, do = q_ref[0, queries, :], do_ref[0, queries, :]
         scores = kda._dot(k, q, kda._NT, exact) * scale             # [keys, queries]
-        weights = jnp.exp(scores - lse_ref[0, 0, pl.ds(i, 1), :])
+        weights = jnp.exp(scores - of_queries(lse_ref, i))
         if seen is not None:
-            weights = jnp.where(seen, weights, 0.0)
+            weights = jnp.where(masked(seen), weights, 0.0)
         d_weights = kda._dot(v, do, kda._NT, exact)
-        d_scores = weights * (d_weights - delta_ref[0, 0, pl.ds(i, 1), :]) * scale
+        d_scores = weights * (d_weights - of_queries(delta_ref, i)) * scale
         weights, d_scores = kda._operand(weights, exact), kda._operand(d_scores, exact)
         dq_ref[0, queries, :] += kda._dot(d_scores, k, kda._TN, exact)
         return (dk + kda._dot(d_scores, q, kda._NN, exact),
                 dv + kda._dot(weights, do, kda._NN, exact))
 
     carry = (jnp.zeros(k.shape, jnp.float32), jnp.zeros(v.shape, jnp.float32))
+    fold = _fold_pair if pair else (lambda gradient: gradient)
     if window is None:
         carry = against(j, carry, _causal(tile, keys_first=True))
-        dk_ref[0], dv_ref[0] = jax.lax.fori_loop(j + 1, count, against, carry)
+        dk, dv = jax.lax.fori_loop(j + 1, count, against, carry)
+        dk_ref[0], dv_ref[0] = fold(dk), fold(dv)
     else:
         # the query tiles whose band reaches this key tile: its own, those
         # it lies wholly inside the band of, the one or two it is the
@@ -396,23 +501,30 @@ def _backward_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         carry = against(j, carry, _causal(tile, keys_first=True, window=window))
         whole = jnp.minimum(j + max(window // tile, 1), count)
         carry = jax.lax.fori_loop(j + 1, whole, against, carry)
-        dk_ref[0], dv_ref[0] = jax.lax.fori_loop(
+        dk, dv = jax.lax.fori_loop(
             whole, jnp.minimum(j + _reach(window, tile) + 1, count),
             lambda i, carry: against(i, carry, _in_band(
                 tile, i - j, window, keys_first=True)), carry)
+        dk_ref[0], dv_ref[0] = fold(dk), fold(dv)
 
 
 class _Blocks:
     """What both kernels' ``pallas_call``s share: the grid (batch, head,
     tile of the sequence) and the blocks of ``[B, T, H * D]`` and of the
-    rows' ``[B, H, N, tile]``."""
+    rows' ``[B, H, N, tile]``.  `pair`: heads of 64, two to a block and to
+    a grid step; `width` and `vdim`, a block's lanes of keys and of values,
+    are then a pair's."""
 
-    def __init__(self, q, tile: int, interpret: bool):
+    def __init__(self, q, v, tile: int, interpret: bool):
         self.batch, self.length, self.heads, _ = q.shape
         self.tile = tile
         self.count = self.length // tile
+        self.pair = v.shape[-1] == HALF
+        self.heads_a_block = 2 if self.pair else 1
+        self.width, self.vdim = (LANES, LANES) if self.pair else (q.shape[-1], v.shape[-1])
         self.options = dict(
-            grid=(self.batch, self.heads, self.count), interpret=interpret,
+            grid=(self.batch, self.heads // self.heads_a_block, self.count),
+            interpret=interpret,
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary"),
                 vmem_limit_bytes=VMEM_LIMIT_BYTES))
@@ -425,7 +537,8 @@ class _Blocks:
 
     @property
     def rows(self):             # of [B, H, N, tile]
-        return pl.BlockSpec((1, 1, self.count, self.tile), lambda b, h, n: (b, h, 0, 0))
+        return pl.BlockSpec((1, self.heads_a_block, self.count, self.tile),
+                            lambda b, h, n: (b, h, 0, 0))
 
     @property
     def rows_shape(self):
@@ -449,15 +562,16 @@ def _forward(q, k, v, scale: float, tile: int, exact: bool, interpret: bool, kee
              window: int | None):
     """``out [B, T, H, Dv]`` and, with `keep`, the rows' log-sum-exp ``[B,
     H, N, tile]``; `q`, `k`, `v` as the products take them."""
-    blocks = _Blocks(q, tile, interpret)
-    width, vdim = q.shape[-1], v.shape[-1]
+    blocks = _Blocks(q, v, tile, interpret)
+    width, vdim = blocks.width, blocks.vdim
     out_shape = [jax.ShapeDtypeStruct(_flat(v).shape, jnp.float32)]
     out_specs = [blocks.one(vdim)]
     if keep:
         out_shape.append(blocks.rows_shape)
         out_specs.append(blocks.rows)
     out, *lse = pl.pallas_call(
-        functools.partial(_forward_kernel, scale=scale, exact=exact, window=window),
+        functools.partial(_forward_kernel, scale=scale, exact=exact, window=window,
+                          pair=blocks.pair),
         out_shape=out_shape,
         in_specs=[blocks.one(width), blocks.whole(width), blocks.whole(vdim)],
         out_specs=out_specs, name="mla_attention_forward", **blocks.options,
@@ -469,13 +583,14 @@ def _forward(q, k, v, scale: float, tile: int, exact: bool, interpret: bool, kee
                                              "window"))
 def _backward(q, k, v, out, lse, d_out, scale: float, tile: int, exact: bool,
               interpret: bool, window: int | None):
-    blocks = _Blocks(q, tile, interpret)
-    width, vdim = q.shape[-1], v.shape[-1]
+    blocks = _Blocks(q, v, tile, interpret)
+    width, vdim = blocks.width, blocks.vdim
     # what every score of a row owes through the row's sum
     delta = jnp.sum(out * d_out, -1).transpose(0, 2, 1).reshape(lse.shape)
     like = lambda a: jax.ShapeDtypeStruct(_flat(a).shape, jnp.float32)
     dq, dk, dv = pl.pallas_call(
-        functools.partial(_backward_kernel, scale=scale, exact=exact, window=window),
+        functools.partial(_backward_kernel, scale=scale, exact=exact, window=window,
+                          pair=blocks.pair),
         out_shape=[like(q), like(k), like(v)],
         in_specs=[blocks.whole(width), blocks.one(width), blocks.one(vdim),
                   blocks.whole(vdim), blocks.rows, blocks.rows],

@@ -67,16 +67,19 @@ __all__ = ["route", "held_experts", "assignment_counts", "balance_bias", "FORMS"
 BLOCK_ROWS = 512
 
 
-def route(x, router, bias, *, top_k: int, scale: float, renormalize: bool = True):
+def route(x, router, bias, *, top_k: int, scale: float, renormalize: bool = True,
+          eps: float = 1e-20):
     """``(chosen [N, top_k] int32, weights [N, top_k] float32)`` for the
     tokens `x` ``[N, D]``: sigmoid scores over all ``router.shape[1]``
     experts, the `top_k` largest of ``score + bias`` (`bias` moves the
-    choice and never the weight), weights renormalised and scaled."""
+    choice and never the weight), weights renormalised — the chosen scores
+    over their sum plus `eps`, a family's own (lfm2_moe's 1e-6) — and
+    scaled."""
     scores = jax.nn.sigmoid(jnp.dot(x, router).astype(jnp.float32))
     _, chosen = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), top_k)
     weights = jnp.take_along_axis(scores, chosen, axis=-1)
     if renormalize:
-        weights = weights / (jnp.sum(weights, -1, keepdims=True) + 1e-20)
+        weights = weights / (jnp.sum(weights, -1, keepdims=True) + eps)
     return chosen.astype(jnp.int32), weights * scale
 
 

@@ -423,3 +423,42 @@ def test_fused_attention_kernels_take_a_key_span_at_16384_tokens(
     # q, k, v at 32 heads of 128 as operands and as gradients, the output
     # and its cotangent: 268 MB each in float32
     assert compiled.memory_analysis().temp_size_in_bytes < 2.8e9
+
+
+# --- the fifth token cell's shapes (PR 49): heads of 64, two to a 128-lane
+# block of the attention kernels, at 16,384 tokens
+
+
+@pytest.mark.parametrize("ambient", ["default", "highest"])
+def test_fused_attention_kernels_take_paired_heads_of_64_at_16384_tokens(
+        one_chip, no_compile_cache, monkeypatch, ambient):
+    """`lfm2_8b_a1b_train`'s attention mixers: 32 query heads of 64 at
+    16,384 tokens on 8 key-value heads repeated in front of the kernels.  A
+    pair of heads is one 128-lane block of ``[1, 16384, 2048]`` as it lies
+    (2 x 4 x 16,384 x 384 = 50.3 MB of the 78.6 the reckoning allows a
+    pair), so the kernels take the fused form, with bfloat16 operands and
+    under ``highest``: the stacked operands' masks and concatenations, the
+    rows' halves and the folds are what Mosaic has to take."""
+    from fast_autoaugment_tpu.ops import kda
+    from fast_autoaugment_tpu.ops.attention import blocked_causal_attention
+
+    monkeypatch.setattr(kda, "_on_tpu", lambda: True)
+
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
+
+    def scalar(q, k, v):
+        k, v = (jnp.repeat(a, 4, axis=2) for a in (k, v))
+        return jnp.sum(blocked_causal_attention(q, k, v, scale=64 ** -0.5))
+
+    with jax.default_matmul_precision(ambient):
+        compiled = jax.jit(jax.grad(scalar, argnums=(0, 1, 2))).lower(
+            shape(1, 16384, 32, 64), shape(1, 16384, 8, 64),
+            shape(1, 16384, 8, 64)).compile()
+    text = compiled.as_text()
+    assert "mla_attention_forward" in text and "mla_attention_backward" in text
+    assert text.count("tpu_custom_call") == 2
+    # q, k, v at 32 heads of 64 as operands and as gradients, the output and
+    # its cotangent: 134 MB each in float32; no score matrix (a head's alone
+    # is 1.07 GB)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.4e9
