@@ -48,7 +48,7 @@ from fast_autoaugment_tpu.models.token_blocks import (
     remat_block,
     step_bias_init,
 )
-from fast_autoaugment_tpu.ops.kda import chunk_kda
+from fast_autoaugment_tpu.ops.kda import by_tile as _by_tile, chunk_kda
 
 __all__ = ["KimiLinear", "kimi_linear_from_conf", "STEP_STATS", "ROUTING",
            "CUT_KEYS", "ExpertLayer", "SwiGLU"]
@@ -74,27 +74,13 @@ def _a_log_init(key, shape, dtype=jnp.float32):
 _dt_bias_init = step_bias_init(0.001, 0.1, 1e-4)
 
 
-#: tokens in a tile of a float32 ``[T, H * K]`` array on the chip
-SUBLANES = 8
-
-
-def _by_tile(x, heads: int):
-    """``[B, T, H * K]`` cut as the chip tiles it, ``[B, T/8, 8, H, K]``: a
-    tile is eight tokens of one head's lanes.  Cut so, XLA reads and
-    writes a head's lanes where the array lies; cut to ``[B, T, H, K]`` it
-    first moves the whole array to tiles of ``(H, K)``, and back after."""
-    batch, length, width = x.shape
-    rows = math.gcd(length, SUBLANES)
-    return x.reshape(batch, length // rows, rows, heads, width // heads)
-
-
 class KDAMixer(nn.Module):
     """Every array between a projection and ``o_proj`` stays ``[B, T, H *
     K]``, the heads side by side as the projection writes them and as
     ``ops/kda.py``'s kernels read them.  What is per head: the unit
     lengths of ``q`` and ``k`` are the kernels' (``unit_scale``), ``A_log``
     is a vector ``[H * K]``, and the output norm runs over the last axis
-    of the array cut as it is tiled (:func:`_by_tile`); no array is cut
+    of the array cut as it is tiled (``ops/kda.py::by_tile``); no array is cut
     into ``(H, K)`` tiles on the way."""
 
     heads: int

@@ -11,7 +11,9 @@ FFN may be a sigmoid-routed expert layer this chip holds a share of
   query part rotated by position or left as they are (``rope_theta``);
 - :class:`GQAMixer`, grouped-query attention, bare (Nemotron-H's) or with
   a norm a head on queries and keys, rotary, a key span and a gate on the
-  output (afmoe's), each by an argument;
+  output (afmoe's), each by an argument; at a head of 128 on the
+  projections' own rows from ``q_proj`` to ``o_proj``, the norm and the
+  rotation one pass over them (:class:`HeadNormRotate`, ``ops/headnorm.py``);
 - :func:`causal_conv`, a depthwise causal convolution over a few taps, and
   :class:`ShortConvMixer`, lfm2_moe's mixer: such a convolution gated on
   both sides between two projections, no activation;
@@ -59,8 +61,10 @@ from fast_autoaugment_tpu.ops.attention import (
     OUT_NAME,
     blocked_causal_attention,
 )
+from fast_autoaugment_tpu.ops.headnorm import head_norm_rotate
+from fast_autoaugment_tpu.ops.kda import LANES
 
-__all__ = ["RMSNorm", "SwiGLU", "SquaredReLU", "Kernel", "MLAMixer", "GQAMixer",
+__all__ = ["RMSNorm", "SwiGLU", "SquaredReLU", "Kernel", "MLAMixer", "GQAMixer", "HeadNormRotate",
            "ShortConvMixer", "ExpertLayer", "causal_conv",
            "FEED_FORWARDS", "Sizes", "remat_block", "dense", "step_bias_init",
            "rotate_by_position", "expert_share_of", "refuse_unwritten_routing",
@@ -177,6 +181,31 @@ class MLAMixer(nn.Module):
             out.reshape(batch, length, heads * self.v_dim))
 
 
+class HeadNormRotate(nn.Module):
+    """A norm a head and a rotation by position on ``[B, T, heads * 128]``
+    rows, one pass of ``ops/headnorm.py``'s kernels, under ``RMSNorm``'s
+    parameter (``weight``, one for all heads); `eps` None: no norm, `theta`
+    None: no rotation, neither: the rows as they came."""
+
+    heads: int
+    eps: float | None
+    theta: float | None
+
+    @nn.compact
+    def __call__(self, x):
+        if self.eps is None and self.theta is None:
+            return x
+        dim = x.shape[-1] // self.heads
+        weight = None if self.eps is None else self.param(
+            "weight", nn.initializers.ones, (dim,))
+        angle = None
+        if self.theta is not None:
+            inverse = self.theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+            angle = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * inverse
+        return head_norm_rotate(x, self.heads, weight=weight, eps=self.eps or 0.0,
+                                angle=angle).astype(x.dtype)
+
+
 class GQAMixer(nn.Module):
     """Grouped-query attention: `heads` query heads of `head_dim` on
     `kv_heads` key-value heads, key-value head ``g`` serving the query
@@ -187,10 +216,24 @@ class GQAMixer(nn.Module):
     ``k_norm``); `rope_theta`, queries and keys rotated by position over
     all of ``head_dim``, pairs ``(i, i + head_dim / 2)``; `window`, the key
     span (``ops/attention.py``); `gated`, the output times ``sigmoid(gate_proj
-    x)`` in front of ``o_proj``.  The core is ``ops/attention.py``'s (the
-    fused kernels at the configurations' shapes), given the key-value heads
-    repeated to every query head: the repeat's transpose sums a group's
-    gradient."""
+    x)`` in front of ``o_proj``.
+
+    Every array between a projection and ``o_proj`` stays ``[B, T, heads *
+    head_dim]``, the heads side by side as the projection writes them and
+    as ``ops/attention.py``'s kernels read and write them (``heads=``): the
+    norm a head and the rotation are one pass over those rows
+    (:class:`HeadNormRotate`: a head's sum along its 128 lanes, the
+    rotation's partner a lane roll), and the key-value heads are
+    handed over as they are, `kv_heads` of them — the kernels' index maps
+    give a group its head, and their backward pass sums a group's
+    gradient.  No array is cut into ``(heads, head_dim)`` tiles on the way
+    and none is repeated in HBM.  That is for heads of one row of lanes
+    (128).  A narrower head (lfm2_moe's 64, the tests' 8) is no whole
+    tile's lanes, and cut as the chip tiles it XLA moves every array for
+    real: those (and a wider one) keep ``[B, T, heads, head_dim]`` from the projections to
+    the core, which repeats a group's key-value heads itself where its
+    kernels take two heads of 64 to a block (``ops/attention.py``) and in
+    front of its XLA form."""
 
     heads: int
     kv_heads: int
@@ -204,24 +247,36 @@ class GQAMixer(nn.Module):
     @nn.compact
     def __call__(self, x):
         batch, length, hidden = x.shape
-        q = dense(self.heads * self.head_dim, "q_proj", self.dtype)(x).reshape(
-            batch, length, self.heads, self.head_dim)
+        width = self.heads * self.head_dim
+        # a head of one row of lanes: rows as the projections write them, all the way
+        rows = self.head_dim == LANES
+        q = dense(width, "q_proj", self.dtype)(x)
         k, v = (dense(self.kv_heads * self.head_dim, f"{name}_proj", self.dtype)(x)
-                .reshape(batch, length, self.kv_heads, self.head_dim) for name in "kv")
-        if self.qk_norm_eps is not None:
-            q = RMSNorm(self.qk_norm_eps, name="q_norm")(q)
-            k = RMSNorm(self.qk_norm_eps, name="k_norm")(k)
-        if self.rope_theta is not None:
-            q, k = (rotate_by_position(a, self.rope_theta, "halves") for a in (q, k))
+                for name in "kv")
+
+        def by_head(a, heads):
+            return a.reshape(batch, length, heads, self.head_dim)
+
+        def a_head(a, heads, name):
+            """The norm and the rotation, a head at a time where it lies."""
+            if rows:
+                return HeadNormRotate(heads, self.qk_norm_eps, self.rope_theta, name=name)(a)
+            cut = by_head(a, heads)
+            if self.qk_norm_eps is not None:
+                cut = RMSNorm(self.qk_norm_eps, name=name)(cut)
+            if self.rope_theta is not None:
+                cut = rotate_by_position(cut, self.rope_theta, "halves")
+            return cut
+
+        q, k = a_head(q, self.heads, "q_norm"), a_head(k, self.kv_heads, "k_norm")
+        v = v if rows else by_head(v, self.kv_heads)
         with jax.named_scope(scopes.GQA_ATTENTION):
-            # key-value head g serves the query heads [g * n, (g + 1) * n)
-            k, v = (jnp.repeat(a, self.heads // self.kv_heads, axis=2) for a in (k, v))
             out = blocked_causal_attention(q, k, v, scale=self.head_dim ** -0.5,
-                                           window=self.window)
-        out = out.astype(self.dtype).reshape(batch, length, self.heads * self.head_dim)
+                                           window=self.window,
+                                           heads=self.heads if rows else None)
+        out = out.astype(self.dtype).reshape(batch, length, width)
         if self.gated:
-            out = out * jax.nn.sigmoid(
-                dense(self.heads * self.head_dim, "gate_proj", self.dtype)(x))
+            out = out * jax.nn.sigmoid(dense(width, "gate_proj", self.dtype)(x))
         return dense(hidden, "o_proj", self.dtype)(out)
 
 

@@ -10,16 +10,21 @@ scores of 32 heads over 8,192 tokens are 8.6 GB in float32.
 window``, the query's own key among them; without one, the whole causal
 past.  Both forms take it, and grouped-query attention comes through the
 same function with no shared key part (``models/token_blocks.py::
-GQAMixer``: afmoe's window and full layers, Nemotron-H's full ones).
+GQAMixer``: afmoe's window and full layers, Nemotron-H's full ones,
+lfm2_moe's): `k` and `v` come with the key-value heads they have, fewer
+than `q`'s, key-value head ``g`` serving the query heads ``[g * n, (g + 1)
+* n)``.
 
 **Two forms, chosen by shape** (:func:`_fused_tile`).  Where a head's
 values are whole lanes (a multiple of 128) and its own key at least 128
 wide — or its key and values are exactly 64 wide each, with no shared key
 part and an even head count (*paired heads*, below) — the sequence at
 least two tiles long and one head's (one pair's) whole sequence inside
-the kernels' VMEM budget (2 x 4 x T x (2 x key width + value width) bytes
-within 78.6 MB: 12,800 tokens at a key and values of 256 + 256, 25,600 at
-128 + 128 and at a pair of 64 + 64) — the configurations' shapes: 128 + 64
+the kernels' VMEM budget (2 x 4 x T x (2 x key width + value width) bytes,
+and 4 x T x (key width + value width) more where a key-value head serves
+a group, within 78.6 MB: 12,800 tokens at a key and values of 256 + 256,
+25,600 at 128 + 128 and at a pair of 64 + 64, 19,200 at 128 + 128 under a
+group) — the configurations' shapes: 128 + 64
 / 128 and 192 + 64 / 256 at 8,192 tokens, 128 / 128 at 8,192 and at
 16,384, 64 / 64 at 16,384 — :func:`blocked_causal_attention` runs a pair
 of fused TPU kernels under a ``jax.custom_vjp`` (:func:`_fused_attention`;
@@ -30,9 +35,37 @@ divides it at least twice).  The shared key part is copied to every head
 and the key padded with zeros to whole lanes in HBM (256 a head in both
 configurations: 42 MB a layer in bfloat16) so that one product a tile
 serves both parts of the score; differentiating that copy sums the
-part's gradient over the heads.  The kernels read ``[B, T, H, D]`` as it
-lies, a head's tile a strided block of ``[B, T, H * D]``: nothing is
-transposed.
+part's gradient over the heads.  The kernels read and write ``[B, T, H *
+D]``, the heads side by side as a projection of that width writes them: a
+head's tile is a strided block of 128-lane rows, and nothing is
+transposed.  ``[B, T, H, D]`` in is the same bytes (and so is the result
+cut into heads again), but what XLA does with an array of that shape
+round the kernels is its own affair — it tiles ``(H, D)``, or puts the
+tokens along the lanes for a norm a head, and moves every operand and
+every result between that and the kernels' rows (57.8 ms of a 752 ms step
+in `trinity_mini_train`, PERF.md section 6, PR 50).  A caller that has the
+rows hands them over as they are (``heads=``) and gets rows back
+(``models/token_blocks.py::GQAMixer``, whose norm a head and rotation are
+``ops/headnorm.py``'s pass over the same rows).
+
+*A group's key-value head by index map.*  Where `k` and `v` have fewer
+heads than `q`, the kernels' grid runs over the query heads and a block
+of `k`, `v` is grid head ``h``'s ``h // n`` of ``[B, T, G * D]``: the ``n``
+grid steps of a group that follow one another name the same block, so the
+pipeline fetches it once, and no repeat is written to HBM (537 MB a layer
+in float32 at 16,384 tokens and 32 heads of 128 on 4).  No repeat is
+differentiated either, so the backward kernel owns the group's sum: its
+``dk``, ``dv`` blocks are the key-value head's whole sequence ``[T, D]`` in
+float32, indexed by the group — they stay in VMEM while the group's query
+heads go by (the first head's share of a key tile is written, the others'
+added), as ``dq`` stays while a head's key tiles go by, and leave for HBM
+once a group, in one buffer each (there is nothing to fetch and one write
+a group to wait for: 16.8 MB at 16,384 tokens beside the 50.3 of ``q``,
+the cotangent and ``dq``).  The grid's head axis is then sequential.
+``heads == kv_heads`` is the identity map and every block what it was: one
+path, by the shapes.  Only the order of the group's sum in ``dk``, ``dv``
+differs from what differentiating a repeat gave; the forward output is the
+repeat's bit for bit.
 
 *Paired heads.*  A head of 64 is half a row of lanes, and a block's last
 dimension has to be whole rows: the kernels then take **two adjacent
@@ -49,9 +82,12 @@ head's share of ``dq`` into its own lanes; the outputs, ``dk`` and ``dv``
 are folded back to the block (:func:`_fold_pair`).  Half of every
 128-wide product is zeros: the MXU does a head of 128's work for a head
 of 64's mathematics, which the cores' share of their roofline shows.
-Each head has keys and values of its own in its half (grouped-query
-attention hands the kernels the key-value heads repeated to every query
-head).  An odd head count, a width of 64 beside a shared key part or
+Each head has keys and values of its own in its half: a key-value head of
+64 is half a block, so for pairs alone a group's key-value heads are still
+repeated to every query head in HBM, in front of the kernels (laying one
+half over both in VMEM would be a lane shuffle of every key tile in every
+step; no cell's gain rests on it).  An odd head count, a width of 64
+beside a shared key part or
 beside values of another width, and any other width under 128 fall back
 to the XLA form.
 
@@ -100,8 +136,8 @@ keeps the two (``4 * T * H * Dv + 4 * H * T`` bytes a core: 268 MB at
 from the primal pass, the kernel in the block computed again has no
 consumer left and is removed as dead code — the forward kernel runs once a
 step.  The operands are still computed again, as the rest of the block is
-(projections, norms, rotary, the key-value repeat, the rounding to
-bfloat16): the backward kernel takes ``q``, ``k``, ``v`` from there.  A
+(projections, norms, rotary, the rounding to bfloat16): the backward
+kernel takes ``q``, ``k``, ``v`` from there.  A
 name is the identity in the lowered program: with no such policy round the
 call (``remat: false``, a forward-only program) nothing changes.
 
@@ -131,7 +167,11 @@ beside the causal half's (``causal``): 150 of 528 at 16,384 tokens, a
 tile of 512 and a span of 2,048.
 ``faa_attention_head_blocks_traced_total{heads_a_block}`` counts the fused
 cores by the heads a block of their kernels holds (``1``, or ``2`` for
-paired heads of 64).
+paired heads of 64), ``faa_attention_kv_heads_mapped_total{group}`` by the
+query heads their index maps give a key-value head (``1`` where every head
+has its own, and for pairs), and
+``faa_attention_kv_repeat_bytes_saved_total`` adds up the bytes of `k` and
+`v` a repeat would have written for them.
 ``faa_attention_outputs_named_total{span}`` counts the cores whose forward
 rule named its products — the cores offered to a policy — and
 ``faa_attention_kept_bytes_total{span}`` the bytes of the two arrays, what
@@ -142,6 +182,7 @@ forward kernel once a core).
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -173,24 +214,53 @@ VMEM_LIMIT_BYTES = 100 * 1024 * 1024
 
 def blocked_causal_attention(q, k, v, *, scale: float, q_shared=None,
                              k_shared=None, block: int = DEFAULT_QUERY_BLOCK,
-                             spans: int = DEFAULT_SPANS, window: int | None = None):
+                             spans: int = DEFAULT_SPANS, window: int | None = None,
+                             heads: int | None = None):
     """``softmax(causal(q k^T + q_shared k_shared^T) * scale) v``.
 
-    `q`, `k`: ``[B, T, H, D]``; `v`: ``[B, T, H, Dv]``; `q_shared`
-    ``[B, T, H, Ds]`` with `k_shared` ``[B, T, Ds]`` (one key part for all
-    heads), or neither.  Returns ``[B, T, H, Dv]``.  `block` and `spans`
-    shape the XLA form alone; the kernels' tile follows from the shapes.
-    `window`: the key span, query ``i`` sees key ``j`` iff ``0 <= i - j <
-    window`` (its own among them); None, or a span the sequence does not
-    outgrow: the whole causal past."""
-    length = q.shape[1]
+    `q` ``[B, T, H, D]``, `k` ``[B, T, G, D]``, `v` ``[B, T, G, Dv]`` with H
+    a multiple of G (key-value head ``g`` serves the query heads ``[g * n,
+    (g + 1) * n)``); `q_shared` ``[B, T, H, Ds]`` with `k_shared` ``[B, T,
+    Ds]`` (one key part for all heads, ``G == H``), or neither.  Returns
+    ``[B, T, H, Dv]``.  With `heads` = H the three are the heads side by
+    side as a projection writes them, ``[B, T, H * D]``, ``[B, T, G * D]``
+    and ``[B, T, G * Dv]``, and so is the result, ``[B, T, H * Dv]``: the
+    fused kernels' own rows, never cut into heads on the way.  `block` and
+    `spans` shape the XLA form alone; the kernels' tile follows from the
+    shapes.  `window`: the key span, query ``i`` sees key ``j`` iff ``0 <=
+    i - j < window`` (its own among them); None, or a span the sequence
+    does not outgrow: the whole causal past."""
+    batch, length = q.shape[:2]
+    rows = heads is not None
+    if rows:
+        if q_shared is not None:
+            raise ValueError("heads side by side carry no shared key part")
+        dim = q.shape[-1] // heads
+        kv_heads = k.shape[-1] // dim
+
+        def cut(a, n):               # off the kernels' path: XLA moves the array
+            return a.reshape(batch, length, n, a.shape[-1] // n)
+    else:
+        heads, kv_heads, dim = q.shape[2], k.shape[2], q.shape[3]
+        cut = lambda a, n: a
+    group = heads // kv_heads
+    vdim = math.prod(v.shape[2:]) // kv_heads
+    if group * kv_heads != heads or math.prod(k.shape[2:]) != kv_heads * dim:
+        raise ValueError(f"queries {q.shape} on keys {k.shape}, values {v.shape}: "
+                         "no whole number of query heads a key-value head")
+
+    def every_head(a):               # key-value head g for the query heads [g * n, (g + 1) * n)
+        a = cut(a, kv_heads)
+        return a if group == 1 else jnp.repeat(a, group, axis=2)
+
     if window is not None:
         if window < 1:
             raise ValueError(f"window={window}: a query sees its own key at least")
         if window >= length:
             window = None
-    tile = _fused_tile(q, v, q_shared)
-    paired = tile is not None and v.shape[-1] == HALF
+    shared = 0 if q_shared is None else q_shared.shape[-1]
+    tile = _fused_tile(length, heads, group, dim, vdim, shared)
+    paired = tile is not None and vdim == HALF
     # trace time: which form each program that holds an attention core got
     form = "blocked_xla" if tile is None else "fused"
     telemetry.registry().counter(
@@ -201,29 +271,37 @@ def blocked_causal_attention(q, k, v, *, scale: float, q_shared=None,
         "program, by the form that computes them and their key span",
         form=form, span=_span_label(window)).inc()
     if tile is None:
-        return _blocked_xla(q, k, v, q_shared, k_shared, scale, block, spans, window)
+        out = _blocked_xla(cut(q, heads), every_head(k), every_head(v), q_shared, k_shared,
+                           scale, block, spans, window)
+        return _flat(out) if rows else out
     _count_key_tiles(window, *key_tiles(length, tile, window))
     telemetry.registry().counter(
         "faa_attention_head_blocks_traced_total", "fused attention cores traced "
         "into a program, by the heads a 128-lane block of their kernels holds",
         heads_a_block="2" if paired else "1").inc()
-    batch, length, heads, _ = q.shape
-    if q_shared is not None:
+    if paired and group > 1:
+        # a key-value head of 64 is half a block of lanes: repeated in HBM, the
+        # pair path as it was before the kernels mapped a group to its head
+        k, v, group, kv_heads = every_head(k), every_head(v), 1, heads
+    _count_mapped(group, k, v)
+    if shared:
         # the shared key part, copied to every head: one product a tile
         # then serves both parts of the score, and differentiating this
         # line sums the part's gradient over the heads
         q = jnp.concatenate([q, q_shared], -1)
         k = jnp.concatenate([k, jnp.broadcast_to(
-            k_shared[:, :, None], (batch, length, heads, k_shared.shape[-1]))], -1)
-    if not paired:                    # a pair of heads of 64 is whole lanes as it lies
-        short = -q.shape[-1] % LANES  # zeros: they add nothing to a score
-        q, k = (jnp.pad(a, ((0, 0),) * 3 + ((0, short),)) for a in (q, k))
+            k_shared[:, :, None], (batch, length, heads, shared))], -1)
+    short = -(dim + shared) % LANES   # zeros: they add nothing to a score
+    if short and not paired:          # a pair of heads of 64 is whole lanes as it lies
+        q, k = (jnp.pad(cut(a, n), ((0, 0),) * 3 + ((0, short),))
+                for a, n in ((q, heads), (k, kv_heads)))
     # float32 in, whatever the model's activations are: under `highest` the
     # kernels' products are float32 ones, which Mosaic refuses bfloat16
     # operands for (a model in ``precision: bf16`` under a float32 comparison)
-    q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
-    return _fused_attention(q, k, v, float(scale), tile, kda._float32_products(),
-                            not kda._on_tpu(), window)
+    q, k, v = (_flat(a).astype(jnp.float32) for a in (q, k, v))
+    out = _fused_attention(q, k, v, float(scale), tile, heads, kda._float32_products(),
+                           not kda._on_tpu(), window)
+    return out if rows else out.reshape(batch, length, heads, vdim)
 
 
 def _reach(window: int, tile: int) -> int:
@@ -272,40 +350,61 @@ def _count_named(window: int | None, kept_bytes: int) -> None:
         span=_span_label(window)).inc(kept_bytes)
 
 
-def _fused_tile(q, v, q_shared) -> int | None:
-    """The tile of the fused kernels for these shapes, or None where they
-    take the XLA form.  Admitted: a head's values whole lanes (a multiple
-    of 128) and its own key at least one row of them; or a key and values
-    of exactly half a row (64) each, no shared key part and an even head
+def _count_mapped(group: int, k, v) -> None:
+    """Trace time: a fused core by the query heads its kernels' index maps
+    give a key-value head, and the bytes of `k` and `v` as handed that a
+    repeat to every query head would have written."""
+    telemetry.registry().counter(
+        "faa_attention_kv_heads_mapped_total", "fused attention cores traced into a "
+        "program, by the query heads their kernels' index maps give a key-value head",
+        group=str(group)).inc()
+    telemetry.registry().counter(
+        "faa_attention_kv_repeat_bytes_saved_total", "bytes of keys and values a "
+        "repeat to every query head would have written for those cores").inc(
+            group * (k.nbytes + v.nbytes) if group > 1 else 0)
+
+
+def _fused_tile(length: int, heads: int, group: int, dim: int, vdim: int,
+                shared: int = 0) -> int | None:
+    """The tile of the fused kernels for `heads` query heads of `dim` (and
+    a shared key part of `shared`) on values of `vdim`, `group` query heads
+    a key-value head, over `length` tokens — or None where they take the
+    XLA form.  Admitted: a head's values whole lanes (a multiple of 128)
+    and its own key at least one row of them; or a key and values of
+    exactly half a row (64) each, no shared key part and an even head
     count, which the kernels take two adjacent heads to a 128-lane block
     (:data:`HALF`).  Either way the sequence is at least two tiles, and
     what the kernels keep of one head's (one pair's) whole sequence fits
     VMEM.  Still falling back: any other width under 128, a width of 64
     beside a shared key part, beside values of another width or on an odd
-    head count."""
-    length = q.shape[1]
-    if v.shape[-1] == HALF:
-        if q.shape[-1] != HALF or q_shared is not None or q.shape[2] % 2:
+    head count, and a shared key part beside grouped heads."""
+    if vdim == HALF:
+        if dim != HALF or shared or heads % 2:
             return None
         width = values = LANES        # a block is two heads side by side
-    elif v.shape[-1] % LANES or q.shape[-1] < LANES:
+        group = 1                     # whose key-value heads come repeated
+    elif vdim % LANES or dim < LANES or (shared and group > 1):
         return None
     else:
-        width = q.shape[-1] + (0 if q_shared is None else q_shared.shape[-1])
+        width = dim + shared
         width += -width % LANES
-        values = v.shape[-1]
+        values = vdim
     # the backward kernel's: q and the cotangent, float32 at the most, and
-    # dq, each twice (the pipeline's two buffers)
-    if 2 * 4 * length * (2 * width + values) > VMEM_LIMIT_BYTES * 3 // 4:
+    # dq, each twice (the pipeline's two buffers); of a group the sums dk and
+    # dv besides, once (:func:`_Blocks.gathered`)
+    kept = 2 * 4 * length * (2 * width + values)
+    if group > 1:
+        kept += 4 * length * (width + values)
+    if kept > VMEM_LIMIT_BYTES * 3 // 4:
         return None
     return next((t for t in TILES if length % t == 0 and length >= 2 * t), None)
 
 
 # ------------------------------------------------------- the fused kernels
 #
-# Both kernels see ``[B, T, H, D]`` as ``[B, T, H * D]``, the same bytes: a
-# head's ``[tile, D]`` is then a block whose last dimension is whole lanes,
-# fetched by a strided DMA, and nothing is transposed in HBM.
+# Both kernels see the heads side by side, ``[B, T, H * D]``: a head's
+# ``[tile, D]`` is then a block whose last dimension is whole lanes, fetched
+# by a strided DMA, and nothing is transposed in HBM.
 
 def _causal(tile: int, *, keys_first: bool, window: int | None = None):
     """``[tile, tile]``: whether the query sees the key, on the diagonal;
@@ -441,7 +540,7 @@ def _band(i, window: int, tile: int):
 
 def _backward_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                      dq_ref, dk_ref, dv_ref, *, scale: float, exact: bool,
-                     window: int | None, pair: bool):
+                     window: int | None, pair: bool, group: int):
     """One tile of one head's keys against that head's queries from the
     diagonal on: `q_ref`, `do_ref` and `dq_ref` hold the head's whole
     sequence (`dq_ref` stays in VMEM while the key tiles go by and gathers
@@ -453,7 +552,12 @@ def _backward_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     tile keys, tile queries]``, each head's keys against the queries' and
     the cotangent's whole block, its own rows of `lse` and `delta`; `dq`
     gathers both heads' shares, each into its own lanes, and `dk`, `dv`
-    are folded back to the block once the query tiles have gone by."""
+    are folded back to the block once the query tiles have gone by.  With
+    a `group` of query heads on the key-value head `k_ref`, `v_ref` show,
+    `dk_ref`, `dv_ref` hold that head's whole sequence and stay in VMEM
+    while the group's query heads go by, as `dq_ref` does while a head's
+    key tiles do: the first head's share of a tile is written, the others'
+    added, and the group's sum leaves for HBM once."""
     j = pl.program_id(2)
     tile = k_ref.shape[1]
     count = q_ref.shape[1] // tile
@@ -489,11 +593,9 @@ def _backward_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dv + kda._dot(weights, do, kda._NN, exact))
 
     carry = (jnp.zeros(k.shape, jnp.float32), jnp.zeros(v.shape, jnp.float32))
-    fold = _fold_pair if pair else (lambda gradient: gradient)
     if window is None:
         carry = against(j, carry, _causal(tile, keys_first=True))
         dk, dv = jax.lax.fori_loop(j + 1, count, against, carry)
-        dk_ref[0], dv_ref[0] = fold(dk), fold(dv)
     else:
         # the query tiles whose band reaches this key tile: its own, those
         # it lies wholly inside the band of, the one or two it is the
@@ -505,7 +607,22 @@ def _backward_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             whole, jnp.minimum(j + _reach(window, tile) + 1, count),
             lambda i, carry: against(i, carry, _in_band(
                 tile, i - j, window, keys_first=True)), carry)
-        dk_ref[0], dv_ref[0] = fold(dk), fold(dv)
+    if pair:
+        dk, dv = _fold_pair(dk), _fold_pair(dv)
+    if group == 1:
+        dk_ref[0], dv_ref[0] = dk, dv
+        return
+    keys = pl.ds(pl.multiple_of(j * tile, tile), tile)
+    first = pl.program_id(1) % group == 0
+
+    @pl.when(first)
+    def _():
+        dk_ref[0, keys, :], dv_ref[0, keys, :] = dk, dv
+
+    @pl.when(jnp.logical_not(first))
+    def _():
+        dk_ref[0, keys, :] += dk
+        dv_ref[0, keys, :] += dv
 
 
 class _Blocks:
@@ -513,27 +630,51 @@ class _Blocks:
     tile of the sequence) and the blocks of ``[B, T, H * D]`` and of the
     rows' ``[B, H, N, tile]``.  `pair`: heads of 64, two to a block and to
     a grid step; `width` and `vdim`, a block's lanes of keys and of values,
-    are then a pair's."""
+    are then a pair's.  `group`: the query heads a key-value head, from the
+    widths of `q` and `k`: a block of `k`, `v` (`of_group`) is the grid's
+    head over `group`, so the `group` steps that follow one another name one
+    block and it is fetched once; the identity where every head has its
+    own."""
 
-    def __init__(self, q, v, tile: int, interpret: bool):
-        self.batch, self.length, self.heads, _ = q.shape
-        self.tile = tile
+    def __init__(self, q, k, v, heads: int, tile: int, interpret: bool):
+        self.batch, self.length, _ = q.shape
+        self.heads, self.tile = heads, tile
         self.count = self.length // tile
-        self.pair = v.shape[-1] == HALF
+        self.group = q.shape[-1] // k.shape[-1]
+        self.pair = q.shape[-1] // heads == HALF
         self.heads_a_block = 2 if self.pair else 1
-        self.width, self.vdim = (LANES, LANES) if self.pair else (q.shape[-1], v.shape[-1])
+        self.width, self.vdim = (LANES, LANES) if self.pair else (
+            q.shape[-1] // heads, v.shape[-1] * self.group // heads)
         self.options = dict(
-            grid=(self.batch, self.heads // self.heads_a_block, self.count),
+            grid=(self.batch, heads // self.heads_a_block, self.count),
             interpret=interpret,
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary"),
+                # a group's sums of dk, dv pass from head to head in VMEM
+                dimension_semantics=("parallel", "parallel" if self.group == 1 else
+                                     "arbitrary", "arbitrary"),
                 vmem_limit_bytes=VMEM_LIMIT_BYTES))
 
-    def one(self, width):       # a tile of one head
-        return pl.BlockSpec((1, self.tile, width), lambda b, h, n: (b, n, h))
+    def _head(self, of_group: bool):
+        if of_group and self.group > 1:
+            return lambda h: h // self.group
+        return lambda h: h
 
-    def whole(self, width):     # one head's whole sequence
-        return pl.BlockSpec((1, self.length, width), lambda b, h, n: (b, 0, h))
+    def one(self, width, of_group=False):       # a tile of one head
+        head = self._head(of_group)
+        return pl.BlockSpec((1, self.tile, width), lambda b, h, n: (b, n, head(h)))
+
+    def whole(self, width, of_group=False, **how):     # one head's whole sequence
+        head = self._head(of_group)
+        return pl.BlockSpec((1, self.length, width), lambda b, h, n: (b, 0, head(h)), **how)
+
+    def gathered(self, width):
+        """Where the backward kernel puts `dk`, `dv`: a head's tile where
+        every head has its own, else the key-value head's whole sequence,
+        which stays while its group goes by — in one buffer, there being
+        nothing to fetch and one write a group to wait for."""
+        if self.group == 1:
+            return self.one(width)
+        return self.whole(width, of_group=True, pipeline_mode=pl.Buffered(1))
 
     @property
     def rows(self):             # of [B, H, N, tile]
@@ -556,66 +697,76 @@ def _flat(a):
 # Mosaic once a program.
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "tile", "exact", "interpret", "keep",
-                                             "window"))
-def _forward(q, k, v, scale: float, tile: int, exact: bool, interpret: bool, keep: bool,
-             window: int | None):
-    """``out [B, T, H, Dv]`` and, with `keep`, the rows' log-sum-exp ``[B,
+@functools.partial(jax.jit, static_argnames=("scale", "tile", "heads", "exact", "interpret",
+                                             "keep", "window"))
+def _forward(q, k, v, scale: float, tile: int, heads: int, exact: bool, interpret: bool,
+             keep: bool, window: int | None):
+    """``out [B, T, H * Dv]`` and, with `keep`, the rows' log-sum-exp ``[B,
     H, N, tile]``; `q`, `k`, `v` as the products take them."""
-    blocks = _Blocks(q, v, tile, interpret)
+    blocks = _Blocks(q, k, v, heads, tile, interpret)
     width, vdim = blocks.width, blocks.vdim
-    out_shape = [jax.ShapeDtypeStruct(_flat(v).shape, jnp.float32)]
+    out_shape = [jax.ShapeDtypeStruct(
+        (blocks.batch, blocks.length, v.shape[-1] * blocks.group), jnp.float32)]
     out_specs = [blocks.one(vdim)]
     if keep:
         out_shape.append(blocks.rows_shape)
         out_specs.append(blocks.rows)
-    out, *lse = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_forward_kernel, scale=scale, exact=exact, window=window,
                           pair=blocks.pair),
         out_shape=out_shape,
-        in_specs=[blocks.one(width), blocks.whole(width), blocks.whole(vdim)],
+        in_specs=[blocks.one(width), blocks.whole(width, of_group=True),
+                  blocks.whole(vdim, of_group=True)],
         out_specs=out_specs, name="mla_attention_forward", **blocks.options,
-    )(_flat(q), _flat(k), _flat(v))
-    return (out.reshape(v.shape), *lse)
+    )(q, k, v)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "tile", "exact", "interpret",
+@functools.partial(jax.jit, static_argnames=("scale", "tile", "heads", "exact", "interpret",
                                              "window"))
-def _backward(q, k, v, out, lse, d_out, scale: float, tile: int, exact: bool,
+def _backward(q, k, v, out, lse, d_out, scale: float, tile: int, heads: int, exact: bool,
               interpret: bool, window: int | None):
-    blocks = _Blocks(q, v, tile, interpret)
+    blocks = _Blocks(q, k, v, heads, tile, interpret)
     width, vdim = blocks.width, blocks.vdim
-    # what every score of a row owes through the row's sum
-    delta = jnp.sum(out * d_out, -1).transpose(0, 2, 1).reshape(lse.shape)
-    like = lambda a: jax.ShapeDtypeStruct(_flat(a).shape, jnp.float32)
-    dq, dk, dv = pl.pallas_call(
+    # what every score of a row owes through the row's sum: over a head's
+    # lanes, read where the rows lie (a head of 64 is no whole tile's lanes)
+    owed = out * d_out
+    owed = owed.reshape(blocks.batch, blocks.length, heads, HALF) if blocks.pair \
+        else kda.by_tile(owed, heads)
+    delta = jnp.sum(owed, -1).reshape(blocks.batch, blocks.length, heads).transpose(
+        0, 2, 1).reshape(lse.shape)
+    like = lambda a: jax.ShapeDtypeStruct(a.shape, jnp.float32)
+    return pl.pallas_call(
         functools.partial(_backward_kernel, scale=scale, exact=exact, window=window,
-                          pair=blocks.pair),
+                          pair=blocks.pair, group=blocks.group),
         out_shape=[like(q), like(k), like(v)],
-        in_specs=[blocks.whole(width), blocks.one(width), blocks.one(vdim),
-                  blocks.whole(vdim), blocks.rows, blocks.rows],
-        out_specs=[blocks.whole(width), blocks.one(width), blocks.one(vdim)],
+        in_specs=[blocks.whole(width), blocks.one(width, of_group=True),
+                  blocks.one(vdim, of_group=True), blocks.whole(vdim), blocks.rows,
+                  blocks.rows],
+        out_specs=[blocks.whole(width), blocks.gathered(width), blocks.gathered(vdim)],
         name="mla_attention_backward", **blocks.options,
-    )(_flat(q), _flat(k), _flat(v), _flat(kda._operand(d_out, exact)), lse, delta)
-    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
+    )(q, k, v, kda._operand(d_out, exact), lse, delta)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _fused_attention(q, k, v, scale: float, tile: int, exact: bool, interpret: bool,
-                     window: int | None):
-    """The causal softmax through the kernels; `q`, `k` ``[B, T, H, D]``
-    with D whole lanes (every part of the score in it), float32.  `exact`:
-    float32 products; `interpret`: no TPU to compile them for; `window`:
-    the key span, shorter than the sequence, or None."""
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _fused_attention(q, k, v, scale: float, tile: int, heads: int, exact: bool,
+                     interpret: bool, window: int | None):
+    """The causal softmax through the kernels, on the heads side by side:
+    `q` ``[B, T, H * D]`` with D whole lanes (every part of the score in
+    it) or half a row of them, `k` ``[B, T, G * D]``, `v` ``[B, T, G *
+    Dv]``, float32; the output ``[B, T, H * Dv]``.  `exact`: float32
+    products; `interpret`: no TPU to compile them for; `window`: the key
+    span, shorter than the sequence, or None."""
     q, k, v = (kda._operand(a, exact) for a in (q, k, v))
-    return _forward(q, k, v, scale, tile, exact, interpret, keep=False, window=window)[0]
+    return _forward(q, k, v, scale, tile, heads, exact, interpret, keep=False,
+                    window=window)[0]
 
 
-def _fused_attention_fwd(q, k, v, scale: float, tile: int, exact: bool, interpret: bool,
-                         window: int | None):
+def _fused_attention_fwd(q, k, v, scale: float, tile: int, heads: int, exact: bool,
+                         interpret: bool, window: int | None):
     # kept as the products take them: bfloat16 unless `exact`
     q, k, v = (kda._operand(a, exact) for a in (q, k, v))
-    out, lse = _forward(q, k, v, scale, tile, exact, interpret, keep=True, window=window)
+    out, lse = _forward(q, k, v, scale, tile, heads, exact, interpret, keep=True,
+                        window=window)
     # named for a checkpoint policy to keep (``token_blocks.remat_block``):
     # the backward rule then takes these two from the primal pass, and the
     # kernel has no consumer left in what the policy computes again.  The
@@ -625,9 +776,9 @@ def _fused_attention_fwd(q, k, v, scale: float, tile: int, exact: bool, interpre
     return out, (q, k, v, out, lse)
 
 
-def _fused_attention_bwd(scale: float, tile: int, exact: bool, interpret: bool,
+def _fused_attention_bwd(scale: float, tile: int, heads: int, exact: bool, interpret: bool,
                          window: int | None, residuals, d_out):
-    return _backward(*residuals, d_out, scale, tile, exact, interpret, window=window)
+    return _backward(*residuals, d_out, scale, tile, heads, exact, interpret, window=window)
 
 
 _fused_attention.defvjp(_fused_attention_fwd, _fused_attention_bwd)

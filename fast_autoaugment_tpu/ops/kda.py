@@ -94,6 +94,7 @@ program got: ``fused`` or ``chunked_xla``.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -102,11 +103,14 @@ from jax.experimental.pallas import tpu as pltpu
 
 from fast_autoaugment_tpu.core import telemetry
 
-__all__ = ["chunk_kda", "recurrent_kda", "unit_factor", "DEFAULT_CHUNK", "SUB_BLOCK"]
+__all__ = ["chunk_kda", "recurrent_kda", "unit_factor", "by_tile", "DEFAULT_CHUNK",
+           "SUB_BLOCK"]
 
 DEFAULT_CHUNK = 64
 SUB_BLOCK = 16
 LANES = 128
+#: tokens in a tile of a float32 ``[T, H * K]`` array on the chip
+SUBLANES = 8
 #: heads whose chunks the kernels stack for one product: two chunks of 64
 #: rows fill the MXU's 128
 GROUP = LANES // DEFAULT_CHUNK
@@ -188,6 +192,16 @@ def chunk_kda(q, k, v, g, beta, initial_state=None, *, chunk: int = DEFAULT_CHUN
 _NN = (((1,), (0,)), ((), ()))
 _NT = (((1,), (1,)), ((), ()))
 _TN = (((0,), (0,)), ((), ()))
+
+
+def by_tile(x, heads: int):
+    """``[B, T, H * K]`` cut as the chip tiles it, ``[B, T/8, 8, H, K]``: a
+    tile is eight tokens of one head's lanes.  Cut so, XLA reads and
+    writes a head's lanes where the array lies; cut to ``[B, T, H, K]`` it
+    first moves the whole array to tiles of ``(H, K)``, and back after."""
+    batch, length, width = x.shape
+    rows = math.gcd(length, SUBLANES)
+    return x.reshape(batch, length // rows, rows, heads, width // heads)
 
 
 def _on_tpu() -> bool:

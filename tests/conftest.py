@@ -35,6 +35,10 @@ if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
 import pytest  # noqa: E402
 
 
+#: metrics whose children a benchmark reader sums over the whole registry
+_READ_WHOLE = ("faa_moe_", "faa_attention_key_tiles_total")
+
+
 @pytest.fixture(scope="module", autouse=True)
 def expert_layer_metrics_end_with_their_module():
     """A token program publishes a counter and a gauge a layer
@@ -43,18 +47,25 @@ def expert_layer_metrics_end_with_their_module():
     module's runs registered is taken out again when the module ends, so
     that another token file's tests in the same xdist worker read their
     own model's layers alone (PR 41 saw ``layer="mtp"`` survive into the
-    Kimi file's rehearsal).  Counters that were there keep counting."""
+    Kimi file's rehearsal).  Counters that were there keep counting.  The
+    same for the key tiles the attention cores' loops meet
+    (``faa_attention_key_tiles_total{span,kind}``), whose reader divides the
+    registry's sums over every span: a file that traced fused cores under a
+    span left its tiles to whichever rehearsal shared its worker (PR 50's
+    ``tests/test_attention_groups.py`` moved the files about, and
+    ``swa_key_tiles_visited_share`` read 93% of a program that skips
+    nothing)."""
     from fast_autoaugment_tpu.core import telemetry
 
     registry = telemetry.registry()
 
-    def expert_layer_children():
+    def children():
         with registry._lock:
-            return {key for key in registry._metrics if key[0].startswith("faa_moe_")}
+            return {key for key in registry._metrics if key[0].startswith(_READ_WHOLE)}
 
-    held = expert_layer_children()
+    held = children()
     yield
-    new = expert_layer_children() - held
+    new = children() - held
     with registry._lock:
         for key in new:
             registry._metrics.pop(key, None)

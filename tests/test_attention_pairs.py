@@ -107,29 +107,24 @@ def test_heads_of_64_in_the_xla_form_are_the_explicit_softmax(window):
             np.abs(np.asarray(theirs)).max(), 1.0)
 
 
-def _shapes(length, heads, dim, vdim, shared=None):
-    struct = jax.ShapeDtypeStruct
-    q = struct((1, length, heads, dim), jnp.float32)
-    v = struct((1, length, heads, vdim), jnp.float32)
-    return q, v, None if shared is None else struct((1, length, heads, shared), jnp.float32)
-
-
 @pytest.mark.parametrize("length, heads, dim, vdim, shared, tile, why", [
-    (16384, 32, 64, 64, None, 512, "the configuration's: 16 pairs, 50.3 MB of VMEM"),
-    (256, 2, 64, 64, None, 128, "one pair over two tiles"),
-    (256, 3, 64, 64, None, None, "an odd head count"),
+    (16384, 32, 64, 64, 0, 512, "the configuration's: 16 pairs, 50.3 MB of VMEM"),
+    (256, 2, 64, 64, 0, 128, "one pair over two tiles"),
+    (256, 3, 64, 64, 0, None, "an odd head count"),
     (256, 4, 64, 64, 64, None, "a width of 64 beside a shared key part"),
-    (256, 4, 64, 128, None, None, "a key of 64 under values of whole lanes"),
-    (256, 4, 128, 64, None, None, "values of 64 under a key of whole lanes"),
-    (256, 4, 32, 32, None, None, "a quarter of a row"),
-    (128, 4, 64, 64, None, None, "a sequence under two tiles"),
-    (32768, 32, 64, 64, None, None, "a pair's whole sequence past the VMEM bound"),
-    (256, 4, 128, 128, None, 128, "heads of whole lanes, as before"),
-    (16384, 32, 128, 128, None, 512, "trinity_mini_train's"),
+    (256, 4, 64, 128, 0, None, "a key of 64 under values of whole lanes"),
+    (256, 4, 128, 64, 0, None, "values of 64 under a key of whole lanes"),
+    (256, 4, 32, 32, 0, None, "a quarter of a row"),
+    (128, 4, 64, 64, 0, None, "a sequence under two tiles"),
+    (32768, 32, 64, 64, 0, None, "a pair's whole sequence past the VMEM bound"),
+    (256, 4, 128, 128, 0, 128, "heads of whole lanes, as before"),
+    (16384, 32, 128, 128, 0, 512, "trinity_mini_train's"),
     (8192, 32, 128, 128, 64, 512, "kimi_linear_48b_a3b_train's"),
 ])
 def test_which_shapes_the_fused_kernels_admit(length, heads, dim, vdim, shared, tile, why):
-    assert attention._fused_tile(*_shapes(length, heads, dim, vdim, shared)) == tile, why
+    """Every head with keys and values of its own (``tests/test_attention_groups.py``
+    has the shapes a group of query heads on one key-value head adds)."""
+    assert attention._fused_tile(length, heads, 1, dim, vdim, shared) == tile, why
 
 
 def test_a_pair_of_heads_is_a_block_of_the_arrays_as_they_lie():

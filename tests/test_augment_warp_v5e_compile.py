@@ -7,6 +7,7 @@ pixels, PERF.md the times.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -99,8 +100,6 @@ def test_a_kda_mixer_moves_no_projection_between_tilings_on_a_v5e(
     some 25 times a layer round kernels that read ``[B, T * H, K]`` rows
     (PR 39 to PR 47).  Only the block's own input (8,192 x 2,304) is
     still copied."""
-    import re
-
     from flax import linen as nn
 
     from fast_autoaugment_tpu.models.kimi_linear import KDAMixer
@@ -127,6 +126,61 @@ def test_a_kda_mixer_moves_no_projection_between_tilings_on_a_v5e(
     moved = re.findall(r"= f32\[([\d,]+)\][^ ]* (copy|reshape|transpose)\(", text)
     sizes = [np.prod([int(d) for d in dims.split(",")]) for dims, _ in moved]
     assert moved and max(sizes) < 8192 * 4096, [m for m in moved if "4096" in m[0]]
+
+
+@pytest.mark.parametrize("parts", [dict(window=2048, rope_theta=10000.0), {}],
+                         ids=["window_rotary", "full"])
+def test_a_gqa_mixer_moves_no_projection_between_tilings_on_a_v5e(
+        one_chip, no_compile_cache, monkeypatch, parts):
+    """One grouped-query mixer of `trinity_mini_train` (32 heads of 128 on
+    4 key-value heads, a norm a head on queries and keys, gated; a window
+    layer's span with rotary, and the full layer) under ``remat_block``,
+    forward and backward at 16,384 tokens: each attention kernel once (the
+    kept output and log-sum-exp), the norm a head and the rotation of
+    queries and of keys one kernel a pass (``ops/headnorm.py``: forward,
+    again under the remat, backward), and the program XLA makes of it holds no
+    ``copy``, ``reshape`` or ``transpose`` of an array as large as ``q``
+    (16,384 x 4,096: 268 MB in float32) anywhere, and no ``broadcast`` of
+    one as an operation of its own — what stood some twenty times a layer
+    round kernels handed ``[B, T, H, D]`` and the key-value heads repeated
+    (PR 46 to PR 49)."""
+    from flax import linen as nn
+
+    from fast_autoaugment_tpu.models.token_blocks import GQAMixer, remat_block
+    from fast_autoaugment_tpu.ops import kda
+
+    monkeypatch.setattr(kda, "_on_tpu", lambda: True)
+
+    class Layer(nn.Module):
+        @nn.compact
+        def __call__(self, x):
+            return x + GQAMixer(32, 4, 128, qk_norm_eps=1e-5, gated=True, name="attn",
+                                **parts)(x)
+
+    layer = remat_block(Layer)()
+    x = jax.ShapeDtypeStruct((1, 16384, 2048), jnp.float32, sharding=one_chip)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(layer.init, jax.random.PRNGKey(0), x))
+    compiled = jax.jit(jax.grad(
+        lambda p, x: jnp.sum(layer.apply(p, x) ** 2), argnums=(0, 1))).lower(
+            params, x).compile()
+    text = compiled.as_text()
+    kernels = {name: len(re.findall(rf"%{name}[.\d]* = ", text)) for name in (
+        "mla_attention_forward", "mla_attention_backward", "head_norm_rotate_forward",
+        "head_norm_rotate_backward")}
+    assert kernels == {"mla_attention_forward": 1, "mla_attention_backward": 1,
+                       "head_norm_rotate_forward": 4, "head_norm_rotate_backward": 2}, kernels
+    assert text.count("tpu_custom_call") == 8
+
+    def largest(moved):
+        return max([np.prod([int(d) for d in dims.split(",")]) for dims in moved], default=0)
+
+    anywhere = re.findall(r"= \w+\[([\d,]+)\]\S* (?:copy|reshape|transpose)\(", text)
+    assert anywhere and largest(anywhere) < 16384 * 4096, sorted(set(anywhere))
+    entry = text[text.index("\nENTRY "):]
+    alone = re.findall(r"= \w+\[([\d,]+)\]\S* broadcast\(", entry)
+    assert largest(alone) < 16384 * 4096, sorted(set(alone))
 
 
 @pytest.mark.parametrize("ambient", ["default", "highest"])
@@ -175,8 +229,6 @@ def _assert_the_sum_is_combined_in_place(compiled, width):
     scatter over the whole sum is left, and inside a loop's body nothing
     but the kernel (and the body's own argument) makes an array the size
     of the sum — no copy in front of the aliased operand."""
-    import re
-
     text = compiled.as_text()
     assert text.count("moe_combine") >= 2 and text.count("tpu_custom_call") == 2
     whole = rf"f32\[8192,(?:1,)?{width}\]"
@@ -241,8 +293,10 @@ def test_grouped_experts_and_their_backward_compile_for_a_v5e(
 def test_fused_attention_kernels_take_repeated_key_value_heads_and_either_dtype(
         one_chip, no_compile_cache, monkeypatch, dtype, ambient):
     """`nemotron3_nano_30b_a3b_train`'s one attention layer: 32 query
-    heads of 128 at 8,192 tokens on 2 key-value heads repeated in front of
-    the kernels, no shared key part.  A model in ``precision: bf16`` hands
+    heads of 128 at 8,192 tokens on 2 key-value heads, handed over as the 2
+    they are — the kernels' index maps give a group of 16 its head, and
+    nothing the size of the repeat (67 MB a tensor in float32 where 4 MB
+    stand) is made — no shared key part.  A model in ``precision: bf16`` hands
     the core bfloat16 activations; under ``highest`` (the float32
     comparison's control) the kernels' products are float32 ones, which
     Mosaic refuses bfloat16 operands for — the chip refused it on PR 42's
@@ -256,7 +310,6 @@ def test_fused_attention_kernels_take_repeated_key_value_heads_and_either_dtype(
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
     def scalar(q, k, v):
-        k, v = (jnp.repeat(a, 16, axis=2) for a in (k, v))
         return jnp.sum(blocked_causal_attention(q, k, v, scale=128 ** -0.5))
 
     with jax.default_matmul_precision(ambient):
@@ -266,7 +319,10 @@ def test_fused_attention_kernels_take_repeated_key_value_heads_and_either_dtype(
     text = compiled.as_text()
     assert "mla_attention_forward" in text and "mla_attention_backward" in text
     assert text.count("tpu_custom_call") == 2
-    assert compiled.memory_analysis().temp_size_in_bytes < 1.4e9
+    assert not re.search(r"\[8192,(2,16|32),128\]\S* broadcast\(", text)
+    # q as an operand and its gradient, the output and its cotangent, float32
+    # under `highest`: 134 MB each
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.7e9
 
 
 def _scan_gradient_compiled(one_chip, length, chunk):
@@ -360,10 +416,6 @@ def test_batch_taken_from_the_stored_form_copies_no_whole_cache(
     it in front of every gather (PERF.md section 6, PR 37); from rows the
     compiled program touches the whole cache in the gather alone, and
     needs no temporary of its size."""
-    import re
-
-    import numpy as np
-
     from fast_autoaugment_tpu.data.pipeline import StoredRows
 
     cache = jax.tree.map(
@@ -392,14 +444,16 @@ def test_batch_taken_from_the_stored_form_copies_no_whole_cache(
 def test_fused_attention_kernels_take_a_key_span_at_16384_tokens(
         one_chip, no_compile_cache, monkeypatch, window, ambient):
     """`trinity_mini_train`'s mixers: 32 query heads of 128 at 16,384
-    tokens on 4 key-value heads repeated in front of the kernels, a window
-    layer's span of 2,048 keys (four tiles of 512), a full layer's whole
-    past, and a span that is no whole number of tiles (two tiles at the
-    band's trailing edge take the mask).  One head's whole sequence fits
-    the kernels' VMEM at 128 + 128 (2 x 4 x 16,384 x 384 = 50.3 MB of the
-    78.6 the reckoning allows), so all take the fused form, under
-    ``highest`` too; the loops' bounds are scalars the kernels compute from
-    the grid step, which Mosaic has to take."""
+    tokens on 4 key-value heads as they are (a group of 8 by index map), a
+    window layer's span of 2,048 keys (four tiles of 512), a full layer's
+    whole past, and a span that is no whole number of tiles (two tiles at
+    the band's trailing edge take the mask).  One head's whole sequence and
+    a group's sums of ``dk``, ``dv`` beside it fit the kernels' VMEM at 128
+    + 128 (2 x 4 x 16,384 x 384 + 4 x 16,384 x 256 = 67.1 MB of the 78.6
+    the reckoning allows), so all take the fused form, under ``highest``
+    too; the loops' bounds are scalars the kernels compute from the grid
+    step, which Mosaic has to take, as it has to take the sums' one buffer
+    and the head axis in order."""
     from fast_autoaugment_tpu.ops import kda
     from fast_autoaugment_tpu.ops.attention import blocked_causal_attention
 
@@ -409,7 +463,6 @@ def test_fused_attention_kernels_take_a_key_span_at_16384_tokens(
         return jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
 
     def scalar(q, k, v):
-        k, v = (jnp.repeat(a, 8, axis=2) for a in (k, v))
         return jnp.sum(blocked_causal_attention(q, k, v, scale=128 ** -0.5,
                                                 window=window))
 
@@ -420,9 +473,9 @@ def test_fused_attention_kernels_take_a_key_span_at_16384_tokens(
     text = compiled.as_text()
     assert "mla_attention_forward" in text and "mla_attention_backward" in text
     assert text.count("tpu_custom_call") == 2
-    # q, k, v at 32 heads of 128 as operands and as gradients, the output
-    # and its cotangent: 268 MB each in float32
-    assert compiled.memory_analysis().temp_size_in_bytes < 2.8e9
+    # q as an operand and as a gradient, the output and its cotangent: 268
+    # MB each in float32; k and v an eighth of that
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.4e9
 
 
 # --- the fifth token cell's shapes (PR 49): heads of 64, two to a 128-lane
@@ -433,7 +486,8 @@ def test_fused_attention_kernels_take_a_key_span_at_16384_tokens(
 def test_fused_attention_kernels_take_paired_heads_of_64_at_16384_tokens(
         one_chip, no_compile_cache, monkeypatch, ambient):
     """`lfm2_8b_a1b_train`'s attention mixers: 32 query heads of 64 at
-    16,384 tokens on 8 key-value heads repeated in front of the kernels.  A
+    16,384 tokens on 8 key-value heads, which ``ops/attention.py`` repeats
+    in front of the kernels (half a block of lanes a key-value head).  A
     pair of heads is one 128-lane block of ``[1, 16384, 2048]`` as it lies
     (2 x 4 x 16,384 x 384 = 50.3 MB of the 78.6 the reckoning allows a
     pair), so the kernels take the fused form, with bfloat16 operands and
@@ -448,7 +502,6 @@ def test_fused_attention_kernels_take_paired_heads_of_64_at_16384_tokens(
         return jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
 
     def scalar(q, k, v):
-        k, v = (jnp.repeat(a, 4, axis=2) for a in (k, v))
         return jnp.sum(blocked_causal_attention(q, k, v, scale=64 ** -0.5))
 
     with jax.default_matmul_precision(ambient):
