@@ -75,6 +75,7 @@ __all__ = [
     "ScopeMapError",
     "parse_scope_map",
     "parse_scope_members",
+    "stale_scopes",
     "scope_map",
     "scope_members",
 ]
@@ -248,6 +249,7 @@ class _SeamWrapped:
         self._seam_label = label
         self._first_done = False
         self._first_call_specs: tuple | None = None
+        self._first_call_verdict: str | None = None
         functools.update_wrapper(self, jitted, updated=())
 
     def __call__(self, *args: Any, **kwargs: Any):
@@ -266,7 +268,8 @@ class _SeamWrapped:
             out = _with_room(self._jitted, *args, **kwargs)
             sec = time.perf_counter() - t0
         self._first_done = True
-        _record(self._seam_label, sec, _classify(h0, m0))
+        self._first_call_verdict = _classify(h0, m0)
+        _record(self._seam_label, sec, self._first_call_verdict)
         return out
 
     def __getattr__(self, name: str):
@@ -376,7 +379,11 @@ def compile_cache_stats() -> dict:
 
 
 class ScopeMapError(RuntimeError):
-    """No instruction of a compiled module carries a ``faa_`` scope."""
+    """A compiled module's text is not this checkout's: no instruction of
+    it carries a ``faa_`` scope, or a scope this checkout's own lowering
+    names is nowhere in it while its instructions are (the persistent
+    cache answered with an executable that a checkout from before that
+    scope compiled)."""
 
 
 # "  ROOT %fusion.5 = f32[8]{0} fusion(...), kind=kLoop, calls=%fused_computation.2, metadata={op_name="..."}"
@@ -497,6 +504,38 @@ def parse_scope_members(hlo_text: str) -> tuple[str, dict[str, tuple[str, ...]]]
     return module, {name: tuple(sorted(hold(name))) for name in own if hold(name)}
 
 
+# `loc("jit(multi_fn)/jvp(faa_model)/.../dot_general"(...))` in a lowering's
+# text with debug info: the names XLA's ``op_name`` metadata is made from
+# (`loc("/root/repo/.../steps.py":12:4)` is a file's, and names nothing)
+_MLIR_LOC_NAME = re.compile(r'loc\("([^"]*)"(?!:)')
+_ANY_SCOPE = re.compile(re.escape(scopes.PREFIX) + r"\w+")
+
+
+def stale_scopes(lowered_text: str, compiled_text: str) -> set[str]:
+    """The scopes of a lowering's text (``lowered.as_text(debug_info=
+    True)``, the ``loc("...")`` names) that `compiled_text` got from a
+    checkout from before them: no ``op_name`` of it holds the scope
+    anywhere, not in a fused computation either, *and* it names an
+    instruction the way this lowering would without the scope — the
+    lowering's name less the ``/<scope>/`` component, where the lowering
+    itself names nothing so.  A scope XLA optimised away entirely (one
+    operation's branch folded into its twin's) leaves no such name behind
+    and is not stale; one that is never a plain component of a path (the
+    outermost, under ``jvp(...)``) has no name to look for and is."""
+    names = set(_MLIR_LOC_NAME.findall(lowered_text))
+    pieces = {piece for op_name in _HLO_OP_NAME.findall(compiled_text)
+              for piece in op_name.split(";")}
+    missing = ({scope for name in names for scope in scopes.scope_of(name)}
+               - set(_ANY_SCOPE.findall("\n".join(pieces))))
+    stale = set()
+    for scope in missing:
+        part = f"/{scope}/"
+        without = {name.replace(part, "/") for name in names if part in name} - names
+        if not without or without & pieces:
+            stale.add(scope)
+    return stale
+
+
 def scope_map(label: str) -> dict[str, dict[str, str]]:
     """``{hlo_module_name: {instruction_name: op_name}}`` of the programs
     compiled under seam `label` that have made their first call and are
@@ -509,13 +548,27 @@ def scope_map(label: str) -> dict[str, dict[str, str]]:
     it JAX still holds the executable (half a second for the WRN train
     steps on a v5e), elsewhere the persistent cache answers with a hit
     (trace + lower + load) — never a miss, while the specs reproduce the
-    first call; a full compile where the cache is off.  Raises
-    :class:`ScopeMapError` where a module carries no scope at all: the
-    cache's key leaves metadata out, so an executable cached by a
-    checkout from before the scopes comes back on a hit with its old
-    metadata."""
+    first call; a full compile where the cache is off.
+
+    The map must be this checkout's.  The cache's key leaves metadata
+    out, so an executable cached by a checkout from before a scope was
+    added comes back on a hit with its old metadata.  Raises
+    :class:`ScopeMapError` where a module carries no scope at all, and,
+    where the persistent cache may have answered, where a scope that this
+    checkout's own lowering of the program names is nowhere in the
+    compiled text while its instructions are, named without it
+    (:func:`stale_scopes`): a reader of that scope would read nothing, in
+    silence.  Where this process compiled the program itself
+    — its first call was a miss, or the cache is off
+    (``jax_enable_compilation_cache`` false, as
+    ``benchmarks/harness/scopes.py`` sets it to answer this error with one
+    compile of its own) — the text is this checkout's, and a scope XLA
+    optimised away entirely is then a fact, not staleness."""
+    import jax
+
     out: dict[str, dict[str, str]] = {}
-    for text in _compiled_texts(label):
+    for lowered, compiled_here in _lowerings(label):
+        text = lowered.compile().as_text()
         module, table = parse_scope_map(text)
         if not any(scopes.scope_of(op_name) for op_name in table.values()):
             raise ScopeMapError(
@@ -525,6 +578,17 @@ def scope_map(label: str) -> dict[str, dict[str, str]]:
                 f"an executable cached before the scopes were added (its "
                 f"key leaves metadata out): clear that directory and run "
                 f"again")
+        if jax.config.jax_enable_compilation_cache and not compiled_here:
+            stale = stale_scopes(lowered.as_text(debug_info=True), text)
+            if stale:
+                raise ScopeMapError(
+                    f"compile seam {label!r}: module {module!r} holds "
+                    f"{', '.join(sorted(stale))} nowhere and names its "
+                    f"instructions without it, which this checkout's "
+                    f"lowering does not.  The persistent compile "
+                    f"cache ({_dir or 'off'}) answered with an executable "
+                    f"cached by a checkout from before (its key leaves "
+                    f"metadata out): compile with the cache off")
         out.setdefault(module, {}).update(table)
     return out
 
@@ -542,16 +606,23 @@ def scope_members(label: str) -> dict[str, dict[str, tuple[str, ...]]]:
     return out
 
 
-def _compiled_texts(label: str) -> list[str]:
-    """The compiled text of each live program of seam `label`, lowered
-    again from the abstract arguments of its first call."""
+def _lowerings(label: str) -> list[tuple[Any, bool]]:
+    """Each live program of seam `label`, lowered again from the abstract
+    arguments of its first call, and whether that first call compiled it
+    in this process (a miss of the persistent cache)."""
     with _lock:
         wrapped = list(_called.get(label, ()))
-    texts = []
+    lowered = []
     for fn in wrapped:
         args, kwargs = fn._first_call_specs
-        texts.append(fn._jitted.lower(*args, **kwargs).compile().as_text())
-    return texts
+        lowered.append((fn._jitted.lower(*args, **kwargs),
+                        fn._first_call_verdict == "miss"))
+    return lowered
+
+
+def _compiled_texts(label: str) -> list[str]:
+    """The compiled text of each live program of seam `label`."""
+    return [lowered.compile().as_text() for lowered, _ in _lowerings(label)]
 
 
 def _reset_stats_for_tests() -> None:

@@ -6,8 +6,8 @@ optimisation (on fusion instructions too) and comes back out of
 ``compiled.as_text()``, where ``core/compilecache.py::scope_map`` reads
 it.  A scope is compile-time metadata: it costs nothing per step,
 changes no numerics and no compile-cache key.  This module is the one
-table of those names, and the two functions that find them again in an
-``op_name`` such as::
+table of those names, and the three functions that find them and the
+pass they belong to again in an ``op_name`` such as::
 
     jit(multi_fn)/vmap(faa_aug_policy)/faa_aug_op_Equalize/sort
     jit(multi_fn)/transpose(jvp(faa_model))/layer3_1/conv1/conv_general_dilated
@@ -51,6 +51,7 @@ __all__ = [
     "GQA_ATTENTION",
     "SHORT_CONV",
     "SHORT_CONV_GATE",
+    "MIXER_PROJ",
     "LOSS",
     "OPTIMIZER",
     "EMA",
@@ -58,6 +59,8 @@ __all__ = [
     "aug_op",
     "scope_of",
     "is_backward",
+    "pass_of",
+    "PASSES",
 ]
 
 PREFIX = "faa_"
@@ -159,13 +162,27 @@ GQA_ATTENTION = "faa_gqa_attention"
 #: hidden]``)
 SHORT_CONV = "faa_short_conv"
 SHORT_CONV_GATE = "faa_short_conv_gate"
+#: ``models/token_blocks.py::proj``, always nested in a mixer's scope
+#: (``faa_mla``, ``faa_gqa``, ``faa_short_conv``, ``faa_kda``,
+#: ``faa_mamba2``): the mixers' products with a weight matrix alone (the
+#: ``nn.Dense`` calls ``q_proj`` .. ``o_proj``, ``in_proj`` / ``out_proj``,
+#: the low-rank pairs; forward, backward and what ``nn.remat`` computes
+#: again), so that a mixer's scope less its core's less this one is what is
+#: neither kernel nor product: norms, rotary, gates, taps, casts, reshapes
+MIXER_PROJ = "faa_mixer_proj"
 LOSS = "faa_loss"
 #: ``train/steps.py::step_fn``: update and parameter add; EMA; top-k and sums
 OPTIMIZER = "faa_optimizer"
 EMA = "faa_ema"
 METRICS = "faa_metrics"
 
+#: the three passes :func:`pass_of` tells apart
+PASSES = ("forward", "recompute", "backward")
+
 _SCOPE = re.compile(r"faa_\w+")
+# the path component jax.checkpoint (``nn.remat``) puts over what its
+# backward pass computes again of the forward one
+_REMATTED = re.compile(r"(?:^|/)rematted_computation(?:/|$)")
 # a scope that jax.grad transposed: transpose(jvp(faa_model)), and
 # transpose(jvp(vmap(faa_...))) where a batching rule sits between
 _TRANSPOSED = re.compile(r"transpose\((?:\w+\()*faa_")
@@ -196,3 +213,24 @@ def is_backward(op_name: str) -> bool:
     """True where the instruction's scope sits under ``transpose(``: the
     backward pass of what ``jvp(<scope>)`` names in the forward one."""
     return bool(_TRANSPOSED.search(_scoped_path(op_name)))
+
+
+def pass_of(op_name: str) -> str:
+    """The pass an instruction belongs to, one of :data:`PASSES`:
+    ``recompute`` where its scoped path holds JAX's own
+    ``rematted_computation`` component (the forward pass that ``nn.remat``
+    runs a second time, which sits under ``transpose(`` and so is
+    :func:`is_backward` too), else ``backward`` where :func:`is_backward`,
+    else ``forward``.
+    The three shapes, one each from the recorded step of
+    ``lfm2_8b_a1b_train`` (``benchmarks/testdata/v5e_lfm2_moe_step_scopes.json``),
+    ``<hidden>`` standing for ``Lfm2Moe.loss_terms/Lfm2Moe._hidden``::
+
+        forward    jit(multi_fn)/jvp(faa_model)/<hidden>/layer6/faa_short_conv/conv/in_proj/dot_general
+        recompute  jit(multi_fn)/transpose(jvp(faa_model))/<hidden>/jvp(faa_model)/<hidden>/checkpoint/rematted_computation/layer2/faa_short_conv/conv/in_proj/dot_general
+        backward   jit(multi_fn)/transpose(jvp(faa_model))/<hidden>/jvp(faa_model)/<hidden>/checkpoint/layer2/faa_short_conv/conv/out_proj/dot_general
+    """
+    path = _scoped_path(op_name)
+    if _REMATTED.search(path):
+        return "recompute"
+    return "backward" if _TRANSPOSED.search(path) else "forward"

@@ -9,13 +9,12 @@ lists of layers; the FFN is a dense SwiGLU in the leading
 ``first_k_dense_replace`` layers and after them a sigmoid-routed expert
 layer beside one shared expert (``ops/moe.py``).
 
-The sizes are the published ``config.json``'s keys, handed over as the
-conf's ``model`` mapping (:func:`kimi_linear_from_conf`), with the three
-keys that say what *this chip* holds of a deployment in which 32 chips
-share each layer and further chips hold further layers
-(``models/token_blocks.py::CUT_KEYS``).  The blocks every token model
-here is made of, and the router's rule between steps behind
-:meth:`KimiLinear.after_step` and :meth:`KimiLinear.publish_counts`, are
+The sizes are the published ``config.json``'s keys, handed over as the conf's
+``model`` mapping (:func:`kimi_linear_from_conf`), with the three keys that say
+what *this chip* holds of a deployment in which 32 chips share each layer and
+further chips hold further layers (``models/token_blocks.py::CUT_KEYS``).  The
+blocks every token model here is made of, and the router's rule between steps
+behind :meth:`KimiLinear.after_step` and :meth:`KimiLinear.publish_counts`, are
 that module's.
 """
 
@@ -43,6 +42,7 @@ from fast_autoaugment_tpu.models.token_blocks import (
     causal_conv,
     dense as _dense,
     expert_share_of,
+    proj as _proj,
     publish_router_counts,
     refuse_unwritten_routing,
     remat_block,
@@ -95,27 +95,27 @@ class KDAMixer(nn.Module):
         width = heads * dim
 
         def branch(name):
-            y = _dense(width, f"{name}_proj", self.dtype)(x)
+            y = _proj(x, width, f"{name}_proj", self.dtype)
             return ShortConv(self.taps, name=f"{name}_conv")(y)
 
         q, k, v = branch("q"), branch("k"), branch("v")
         a_log = self.param("A_log", _a_log_init, (heads,))
         dt_bias = self.param("dt_bias", _dt_bias_init, (width,))
-        gate = _dense(width, "f_b_proj", self.dtype)(
-            _dense(dim, "f_a_proj", self.dtype)(x))
+        gate = _proj(_proj(x, dim, "f_a_proj", self.dtype), width, "f_b_proj",
+                     self.dtype)
         gate = jax.nn.softplus(gate.astype(jnp.float32) + dt_bias)
         g = jnp.repeat(-jnp.exp(a_log), dim) * gate
         beta = jax.nn.sigmoid(
-            _dense(heads, "b_proj", self.dtype)(x).astype(jnp.float32))
+            _proj(x, heads, "b_proj", self.dtype).astype(jnp.float32))
         with jax.named_scope(scopes.KDA_SCAN):
             # q and k to unit length a head, q by dim ** -0.5: in the kernels
             out, _ = chunk_kda(q, k, v, g, beta, unit_scale=dim ** -0.5)
         out = RMSNorm(self.eps, name="o_norm")(
             _by_tile(out.astype(self.dtype), heads)).reshape(out.shape)
-        out_gate = _dense(width, "g_b_proj", self.dtype)(
-            _dense(dim, "g_a_proj", self.dtype)(x))
-        return _dense(x.shape[-1], "o_proj", self.dtype)(
-            out * jax.nn.sigmoid(out_gate))
+        out_gate = _proj(_proj(x, dim, "g_a_proj", self.dtype), width, "g_b_proj",
+                         self.dtype)
+        return _proj(out * jax.nn.sigmoid(out_gate), x.shape[-1], "o_proj",
+                     self.dtype)
 
 
 class Block(nn.Module):

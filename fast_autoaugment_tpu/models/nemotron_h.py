@@ -73,8 +73,8 @@ from fast_autoaugment_tpu.models.token_blocks import (
     Sizes,
     balance_routers,
     causal_conv,
-    dense,
     expert_share_of,
+    proj,
     publish_router_counts,
     refuse_unwritten_routing,
     remat_block,
@@ -120,8 +120,8 @@ class Mamba2Mixer(nn.Module):
         batch, length, hidden = x.shape
         inner = c.mamba_heads * c.mamba_head_dim
         state = c.mamba_groups * c.state_size
-        joined = dense(2 * inner + 2 * state + c.mamba_heads, "in_proj",
-                       self.dtype)(x)
+        joined = proj(x, 2 * inner + 2 * state + c.mamba_heads, "in_proj",
+                      self.dtype)
         z, xbc, dt = (joined[..., :inner], joined[..., inner:2 * inner + 2 * state],
                       joined[..., 2 * inner + 2 * state:])
         taps = self.param("conv_kernel", nn.initializers.normal(
@@ -145,8 +145,8 @@ class Mamba2Mixer(nn.Module):
         y = gated_group_norm(y, z, weight, c.mamba_groups, c.eps)
         # rescale_prenorm_residual: the residual stream's writers start small
         out_init = nn.initializers.normal(0.02 / math.sqrt(c.layers))
-        return nn.Dense(hidden, use_bias=False, kernel_init=out_init,
-                        name="out_proj", dtype=self.dtype)(y)
+        return proj(y, hidden, "out_proj", self.dtype,
+                    kernel_init=out_init)
 
 
 class Layer(nn.Module):

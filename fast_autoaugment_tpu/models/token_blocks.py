@@ -3,8 +3,8 @@ FFN may be a sigmoid-routed expert layer this chip holds a share of
 (``models/kimi_linear.py``, ``models/glm4_moe_lite.py``,
 ``models/nemotron_h.py``, ``models/afmoe.py``, ``models/lfm2_moe.py``).
 
-- :class:`RMSNorm`, the bias-free :func:`dense`, a bare :class:`Kernel`,
-  and two feed-forward forms: :class:`SwiGLU` and :class:`SquaredReLU`;
+- :class:`RMSNorm`, the bias-free :func:`dense` and :func:`proj` (a mixer's: under
+  ``faa_mixer_proj``), a bare :class:`Kernel`, :class:`SwiGLU`, :class:`SquaredReLU`;
 - :class:`MLAMixer`, latent attention (DeepSeek-V2's MLA): the key-value
   latent always, the query whole or through a low-rank pair with a norm
   between (``q_rank``), the shared key part and every head's matching
@@ -66,7 +66,7 @@ from fast_autoaugment_tpu.ops.kda import LANES
 
 __all__ = ["RMSNorm", "SwiGLU", "SquaredReLU", "Kernel", "MLAMixer", "GQAMixer", "HeadNormRotate",
            "ShortConvMixer", "ExpertLayer", "causal_conv",
-           "FEED_FORWARDS", "Sizes", "remat_block", "dense", "step_bias_init",
+           "FEED_FORWARDS", "Sizes", "remat_block", "dense", "proj", "step_bias_init",
            "rotate_by_position", "expert_share_of", "refuse_unwritten_routing",
            "balance_routers", "publish_router_counts", "INIT", "STEP_STATS",
            "ROUTING", "CUT_KEYS"]
@@ -107,8 +107,8 @@ def step_bias_init(low: float, high: float, floor: float):
     return init
 
 
-def dense(features: int, name: str, dtype) -> nn.Dense:
-    return nn.Dense(features, use_bias=False, kernel_init=INIT, name=name,
+def dense(features: int, name: str, dtype, kernel_init=INIT) -> nn.Dense:
+    return nn.Dense(features, use_bias=False, kernel_init=kernel_init, name=name,
                     dtype=dtype)
 
 
@@ -156,17 +156,17 @@ class MLAMixer(nn.Module):
         batch, length, hidden = x.shape
         heads = self.heads
         if self.q_rank is None:
-            q = dense(heads * (self.nope_dim + self.pe_dim), "q_proj", self.dtype)(x)
+            q = proj(x, heads * (self.nope_dim + self.pe_dim), "q_proj", self.dtype)
         else:
-            q = dense(heads * (self.nope_dim + self.pe_dim), "q_b_proj", self.dtype)(
-                RMSNorm(self.eps, name="q_a_norm")(
-                    dense(self.q_rank, "q_a_proj", self.dtype)(x)))
+            q = proj(RMSNorm(self.eps, name="q_a_norm")(
+                proj(x, self.q_rank, "q_a_proj", self.dtype)),
+                heads * (self.nope_dim + self.pe_dim), "q_b_proj", self.dtype)
         q = q.reshape(batch, length, heads, self.nope_dim + self.pe_dim)
-        latent = dense(self.kv_rank + self.pe_dim, "kv_a_proj", self.dtype)(x)
+        latent = proj(x, self.kv_rank + self.pe_dim, "kv_a_proj", self.dtype)
         k_pe = latent[..., self.kv_rank:]
-        kv = dense(heads * (self.nope_dim + self.v_dim), "kv_b_proj", self.dtype)(
-            RMSNorm(self.eps, name="kv_a_norm")(latent[..., :self.kv_rank])
-        ).reshape(batch, length, heads, self.nope_dim + self.v_dim)
+        kv = proj(RMSNorm(self.eps, name="kv_a_norm")(latent[..., :self.kv_rank]),
+                  heads * (self.nope_dim + self.v_dim), "kv_b_proj", self.dtype
+                  ).reshape(batch, length, heads, self.nope_dim + self.v_dim)
         q_nope, k_nope, v = (q[..., :self.nope_dim], kv[..., :self.nope_dim],
                              kv[..., self.nope_dim:])
         q_pe = q[..., self.nope_dim:]
@@ -177,8 +177,8 @@ class MLAMixer(nn.Module):
             out = blocked_causal_attention(
                 q_nope, k_nope, v, q_shared=q_pe, k_shared=k_pe,
                 scale=(self.nope_dim + self.pe_dim) ** -0.5)
-        return dense(hidden, "o_proj", self.dtype)(
-            out.reshape(batch, length, heads * self.v_dim))
+        return proj(out.reshape(batch, length, heads * self.v_dim), hidden,
+                    "o_proj", self.dtype)
 
 
 class HeadNormRotate(nn.Module):
@@ -250,8 +250,8 @@ class GQAMixer(nn.Module):
         width = self.heads * self.head_dim
         # a head of one row of lanes: rows as the projections write them, all the way
         rows = self.head_dim == LANES
-        q = dense(width, "q_proj", self.dtype)(x)
-        k, v = (dense(self.kv_heads * self.head_dim, f"{name}_proj", self.dtype)(x)
+        q = proj(x, width, "q_proj", self.dtype)
+        k, v = (proj(x, self.kv_heads * self.head_dim, f"{name}_proj", self.dtype)
                 for name in "kv")
 
         def by_head(a, heads):
@@ -276,8 +276,8 @@ class GQAMixer(nn.Module):
                                            heads=self.heads if rows else None)
         out = out.astype(self.dtype).reshape(batch, length, width)
         if self.gated:
-            out = out * jax.nn.sigmoid(dense(width, "gate_proj", self.dtype)(x))
-        return dense(hidden, "o_proj", self.dtype)(out)
+            out = out * jax.nn.sigmoid(proj(x, width, "gate_proj", self.dtype))
+        return proj(out, hidden, "o_proj", self.dtype)
 
 
 def causal_conv(x, kernel, bias=None):
@@ -308,13 +308,13 @@ class ShortConvMixer(nn.Module):
         telemetry.registry().counter(
             "faa_short_conv_traces_total", "short-convolution mixers traced into a "
             "program, by their taps", taps=str(self.taps)).inc()
-        projected = dense(3 * hidden, "in_proj", self.dtype)(x)
+        projected = proj(x, 3 * hidden, "in_proj", self.dtype)
         kernel = self.param("conv_kernel", nn.initializers.normal(
             1.0 / math.sqrt(self.taps)), (self.taps, hidden))
         with jax.named_scope(scopes.SHORT_CONV_GATE):
             before, after, z = jnp.split(projected, 3, axis=-1)
             gated = after * causal_conv(before * z, kernel)
-        return dense(hidden, "out_proj", self.dtype)(gated)
+        return proj(gated, hidden, "out_proj", self.dtype)
 
 
 class Kernel(nn.Module):
@@ -442,6 +442,19 @@ def remat_block(block):
     a step: the backward kernel needs nothing else of it."""
     return nn.remat(block, policy=jax.checkpoint_policies.save_only_these_names(
         OUT_NAME, LSE_NAME))
+
+
+def proj(x, features: int, name: str, dtype, kernel_init=INIT):
+    """A mixer's projection of `x`: :func:`dense`'s layer called under
+    ``faa_mixer_proj``, where the product is traced; a norm, a rotation, a
+    gate, the taps, a cast or a reshape round the product stays outside it.
+
+    Down here and not beside :func:`dense`: a Mosaic kernel's payload in the
+    lowered step holds the line numbers of the frames that called it, the
+    mixers' and the blocks' among them.  With no such line moved the step
+    lowers to the text it lowered to before the scope, byte for byte."""
+    with jax.named_scope(scopes.MIXER_PROJ):
+        return dense(features, name, dtype, kernel_init)(x)
 
 
 def expert_share_of(conf: Any, experts: int) -> tuple[int, int]:

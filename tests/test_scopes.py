@@ -150,6 +150,9 @@ def test_every_scope_of_the_table_reaches_the_compiled_step(
     # every other name come through
     assert set(missing) <= posterize | AFFINE_OPS, missing
     assert scopes.AUG_WARP not in missing
+    # folded away, not left behind by a checkout from before them: no instruction
+    # of theirs is in the text under a name without the scope
+    assert cc.stale_scopes(traced, text) == set()
     # forward and backward of the model are told apart
     assert "jvp(faa_model)" in text
     assert "transpose(jvp(faa_model))" in text
