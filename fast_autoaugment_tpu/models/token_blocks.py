@@ -8,7 +8,13 @@ FFN may be a sigmoid-routed expert layer this chip holds a share of
 - :class:`MLAMixer`, latent attention (DeepSeek-V2's MLA): the key-value
   latent always, the query whole or through a low-rank pair with a norm
   between (``q_rank``), the shared key part and every head's matching
-  query part rotated by position or left as they are (``rope_theta``);
+  query part rotated by position or left as they are (``rope_theta``); at
+  heads of whole lanes (``nope + pe`` and the values multiples of 128:
+  GLM-4.7-Flash) on the projections' own rows from ``q_b_proj`` to
+  ``o_proj`` — ``kv_b_proj``'s kernel cut by columns
+  (:func:`key_value_columns`, :func:`proj_columns`), the shared part laid,
+  the rotation and the rounding passes over the rows inside the core
+  (``ops/mlarows.py``) — and cut into heads at any other shape;
 - :class:`GQAMixer`, grouped-query attention, bare (Nemotron-H's) or with
   a norm a head on queries and keys, rotary, a key span and a gate on the
   output (afmoe's), each by an argument; at a head of 128 on the
@@ -55,7 +61,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from fast_autoaugment_tpu.core import scopes, telemetry
-from fast_autoaugment_tpu.ops import moe
+from fast_autoaugment_tpu.ops import mlarows, moe
 from fast_autoaugment_tpu.ops.attention import (
     LSE_NAME,
     OUT_NAME,
@@ -66,7 +72,8 @@ from fast_autoaugment_tpu.ops.kda import LANES
 
 __all__ = ["RMSNorm", "SwiGLU", "SquaredReLU", "Kernel", "MLAMixer", "GQAMixer", "HeadNormRotate",
            "ShortConvMixer", "ExpertLayer", "causal_conv",
-           "FEED_FORWARDS", "Sizes", "remat_block", "dense", "proj", "step_bias_init",
+           "FEED_FORWARDS", "Sizes", "remat_block", "dense", "proj", "proj_columns",
+           "key_value_columns", "step_bias_init",
            "rotate_by_position", "expert_share_of", "refuse_unwritten_routing",
            "balance_routers", "publish_router_counts", "INIT", "STEP_STATS",
            "ROUTING", "CUT_KEYS"]
@@ -139,7 +146,29 @@ def rotate_by_position(x, theta: float, pairs: str = "interleaved"):
 
 class MLAMixer(nn.Module):
     """Latent attention.  `q_rank` None: the query is one product
-    (``q_lora_rank: null``); `rope_theta` None: no rotary."""
+    (``q_lora_rank: null``); `rope_theta` None: no rotary.
+
+    **Whole lanes a head: the projections' rows all the way.**  Where a
+    head's key width ``nope_dim + pe_dim`` and `v_dim` are whole multiples
+    of 128 lanes (GLM-4.7-Flash: 192 + 64 and 256; `pe_dim` at most 128, so
+    that the rotary part lies in a head's last 128-lane piece), no array is
+    cut into heads between the projections and ``o_proj``:
+    ``q_b_proj`` | ``q_proj`` writes the kernels' `q` rows ``[B, T, heads *
+    (nope + pe)]`` as they are; ``kv_b_proj``'s *kernel* is cut by columns
+    (:func:`key_value_columns`: 18 MB, where cutting its output moves 300 MB)
+    into one matrix whose product is the `v` rows and one — every head's
+    `nope_dim` columns and `pe_dim` zero columns — whose product is the `k`
+    rows with zeros where the shared key part belongs;
+    ``ops/attention.py`` (``heads=``, ``k_shared=``, ``theta=``) then lays
+    that part into every head and turns it and the queries' by position
+    there, a pass over the rows each whose store (or the product's own) is
+    the operands' rounding (``ops/mlarows.py``), and ``o_proj`` reads the
+    kernels' output rows.  The rotary pairs are turned
+    in place (a lane and its neighbour), not de-interleaved: a score is a
+    sum over the lanes and `q` and `k` share the order.  Any other shape
+    (Kimi Linear's 128 + 64 on values of 128, the tests' heads of 8) keeps
+    ``[B, T, H, D]`` and the core's own concatenate, broadcast and pad.  The
+    parameter tree is one and the same."""
 
     heads: int
     nope_dim: int
@@ -154,18 +183,29 @@ class MLAMixer(nn.Module):
     @nn.compact
     def __call__(self, x):
         batch, length, hidden = x.shape
-        heads = self.heads
+        heads, width = self.heads, self.nope_dim + self.pe_dim
+        # whole lanes a head: rows as the projections write them, all the way
+        rows = mlarows.admits(width, self.pe_dim) and self.v_dim % LANES == 0
         if self.q_rank is None:
-            q = proj(x, heads * (self.nope_dim + self.pe_dim), "q_proj", self.dtype)
+            q = proj(x, heads * width, "q_proj", self.dtype)
         else:
             q = proj(RMSNorm(self.eps, name="q_a_norm")(
                 proj(x, self.q_rank, "q_a_proj", self.dtype)),
-                heads * (self.nope_dim + self.pe_dim), "q_b_proj", self.dtype)
-        q = q.reshape(batch, length, heads, self.nope_dim + self.pe_dim)
+                heads * width, "q_b_proj", self.dtype)
         latent = proj(x, self.kv_rank + self.pe_dim, "kv_a_proj", self.dtype)
         k_pe = latent[..., self.kv_rank:]
-        kv = proj(RMSNorm(self.eps, name="kv_a_norm")(latent[..., :self.kv_rank]),
-                  heads * (self.nope_dim + self.v_dim), "kv_b_proj", self.dtype
+        normed = RMSNorm(self.eps, name="kv_a_norm")(latent[..., :self.kv_rank])
+        if rows:
+            kernel = Kernel((self.kv_rank, heads * (self.nope_dim + self.v_dim)),
+                            name="kv_b_proj")()
+            k, v = proj_columns(normed, key_value_columns(
+                kernel, heads, self.nope_dim, self.pe_dim), self.dtype)
+            with jax.named_scope(scopes.MLA_ATTENTION):
+                out = blocked_causal_attention(q, k, v, k_shared=k_pe, scale=width ** -0.5,
+                                               heads=heads, theta=self.rope_theta)
+            return proj(out, hidden, "o_proj", self.dtype)
+        q = q.reshape(batch, length, heads, width)
+        kv = proj(normed, heads * (self.nope_dim + self.v_dim), "kv_b_proj", self.dtype
                   ).reshape(batch, length, heads, self.nope_dim + self.v_dim)
         q_nope, k_nope, v = (q[..., :self.nope_dim], kv[..., :self.nope_dim],
                              kv[..., self.nope_dim:])
@@ -175,10 +215,21 @@ class MLAMixer(nn.Module):
             k_pe = rotate_by_position(k_pe, self.rope_theta)
         with jax.named_scope(scopes.MLA_ATTENTION):
             out = blocked_causal_attention(
-                q_nope, k_nope, v, q_shared=q_pe, k_shared=k_pe,
-                scale=(self.nope_dim + self.pe_dim) ** -0.5)
+                q_nope, k_nope, v, q_shared=q_pe, k_shared=k_pe, scale=width ** -0.5)
         return proj(out.reshape(batch, length, heads * self.v_dim), hidden,
                     "o_proj", self.dtype)
+
+
+def key_value_columns(kernel, heads: int, nope: int, pe: int):
+    """``kv_b_proj``'s kernel ``[rank, heads * (nope + v)]``, a head's key
+    columns in front of its value columns, cut into ``(keys [rank, heads *
+    (nope + pe)], values [rank, heads * v])``: the keys' matrix has `pe` zero
+    columns behind every head's `nope`, so that its product is the attention
+    kernels' `k` rows with room for the shared key part."""
+    rank = kernel.shape[0]
+    by_head = kernel.reshape(rank, heads, -1)
+    keys = jnp.pad(by_head[..., :nope], ((0, 0), (0, 0), (0, pe)))
+    return keys.reshape(rank, -1), by_head[..., nope:].reshape(rank, -1)
 
 
 class HeadNormRotate(nn.Module):
@@ -455,6 +506,20 @@ def proj(x, features: int, name: str, dtype, kernel_init=INIT):
     lowers to the text it lowered to before the scope, byte for byte."""
     with jax.named_scope(scopes.MIXER_PROJ):
         return dense(features, name, dtype, kernel_init)(x)
+
+
+def proj_columns(x, matrices, dtype):
+    """A mixer's projection wanted in other columns than its kernel has
+    (:class:`Kernel`: :func:`dense`'s parameter, handed out whole): `x`
+    times each of `matrices`, the kernel's columns arranged — a few MB of
+    weights, never the activation — under ``faa_mixer_proj`` as
+    :func:`proj`'s product is."""
+    # behind a barrier: fused into the products, the arrangement (and its
+    # transpose into the weight gradients) has XLA lay the products out by it
+    # and move the activations to match — 84 MB a product where this is 18
+    matrices = jax.lax.optimization_barrier(tuple(matrices))
+    with jax.named_scope(scopes.MIXER_PROJ):
+        return [jnp.dot(x.astype(dtype), w.astype(dtype)) for w in matrices]
 
 
 def expert_share_of(conf: Any, experts: int) -> tuple[int, int]:

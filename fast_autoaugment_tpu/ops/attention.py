@@ -31,10 +31,23 @@ of fused TPU kernels under a ``jax.custom_vjp`` (:func:`_fused_attention`;
 interpreted where the backend is no TPU, so a CPU test runs the code the
 chip runs).  The tile
 follows from the sequence length (512, else 256 or 128: the largest that
-divides it at least twice).  The shared key part is copied to every head
-and the key padded with zeros to whole lanes in HBM (256 a head in both
-configurations: 42 MB a layer in bfloat16) so that one product a tile
-serves both parts of the score; differentiating that copy sums the
+divides it at least twice).  One product a tile serves both parts of the
+score: every head's key holds the shared key part behind its own, 256
+lanes a head in both configurations.  *Who lays it there follows from the
+shapes.*  A caller whose heads are whole lanes with the shared part among
+them (``nope + pe`` a multiple of 128, GLM-4.7-Flash's 192 + 64;
+``models/token_blocks.py::MLAMixer``) hands the heads side by side with
+room for it — the query's part in `q`'s last lanes a head, zeros in `k`'s
+— and the part itself beside them (``heads=``, ``k_shared=``, ``theta=``):
+the core's row passes (``ops/mlarows.py``, inside the ``custom_vjp``: the
+operands' rounding is their store, or the product's own, and no cotangent
+is rounded) add it into every head's lanes, turn it and the queries' by
+position, and their transpose sums the part's gradient over the heads — no
+array is cut into heads and none padded in HBM.  Any other caller (Kimi Linear's 128 + 64,
+which is no whole lanes) hands ``[B, T, H, D]`` with the parts apart
+(``q_shared=``, ``k_shared=``, already turned): here the shared key part
+is then copied to every head and the key padded with zeros to whole lanes
+in HBM (42 MB a layer in bfloat16), and differentiating that copy sums the
 part's gradient over the heads.  The kernels read and write ``[B, T, H *
 D]``, the heads side by side as a projection of that width writes them: a
 head's tile is a strided block of 128-lane rows, and nothing is
@@ -46,7 +59,8 @@ every result between that and the kernels' rows (57.8 ms of a 752 ms step
 in `trinity_mini_train`, PERF.md section 6, PR 50).  A caller that has the
 rows hands them over as they are (``heads=``) and gets rows back
 (``models/token_blocks.py::GQAMixer``, whose norm a head and rotation are
-``ops/headnorm.py``'s pass over the same rows).
+``ops/headnorm.py``'s pass over the same rows; ``MLAMixer`` at whole lanes
+a head, above).
 
 *A group's key-value head by index map.*  Where `k` and `v` have fewer
 heads than `q`, the kernels' grid runs over the query heads and a block
@@ -172,6 +186,9 @@ query heads their index maps give a key-value head (``1`` where every head
 has its own, and for pairs), and
 ``faa_attention_kv_repeat_bytes_saved_total`` adds up the bytes of `k` and
 `v` a repeat would have written for them.
+``faa_attention_operands_traced_total{mixer, form}`` counts the fused cores
+by whom they serve (``mla``: a shared key part; ``gqa``: none) and how
+their operands came: ``rows``, the projections' own, or ``cut`` into heads.
 ``faa_attention_outputs_named_total{span}`` counts the cores whose forward
 rule named its products — the cores offered to a policy — and
 ``faa_attention_kept_bytes_total{span}`` the bytes of the two arrays, what
@@ -191,7 +208,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from fast_autoaugment_tpu.core import telemetry
-from fast_autoaugment_tpu.ops import kda
+from fast_autoaugment_tpu.ops import kda, mlarows
 
 __all__ = ["blocked_causal_attention", "DEFAULT_QUERY_BLOCK", "DEFAULT_SPANS",
            "OUT_NAME", "LSE_NAME"]
@@ -215,7 +232,7 @@ VMEM_LIMIT_BYTES = 100 * 1024 * 1024
 def blocked_causal_attention(q, k, v, *, scale: float, q_shared=None,
                              k_shared=None, block: int = DEFAULT_QUERY_BLOCK,
                              spans: int = DEFAULT_SPANS, window: int | None = None,
-                             heads: int | None = None):
+                             heads: int | None = None, theta: float | None = None):
     """``softmax(causal(q k^T + q_shared k_shared^T) * scale) v``.
 
     `q` ``[B, T, H, D]``, `k` ``[B, T, G, D]``, `v` ``[B, T, G, Dv]`` with H
@@ -225,13 +242,23 @@ def blocked_causal_attention(q, k, v, *, scale: float, q_shared=None,
     ``[B, T, H, Dv]``.  With `heads` = H the three are the heads side by
     side as a projection writes them, ``[B, T, H * D]``, ``[B, T, G * D]``
     and ``[B, T, G * Dv]``, and so is the result, ``[B, T, H * Dv]``: the
-    fused kernels' own rows, never cut into heads on the way.  `block` and
+    fused kernels' own rows, never cut into heads on the way.  Side by side
+    a shared key part lies *inside* every head's lanes, its last ``Ds``: `q`
+    comes with the query's part there (no `q_shared`), `k` with zeros there
+    (zero weight columns) and `k_shared` beside it, which the core's row
+    passes (``ops/mlarows.py``) add into those lanes of every head — both
+    turned by position there where `theta` is given (interleaved pairs, in
+    place), and all three written as the products take them.  `block` and
     `spans` shape the XLA form alone; the kernels' tile follows from the
     shapes.  `window`: the key span, query ``i`` sees key ``j`` iff ``0 <=
     i - j < window`` (its own among them); None, or a span the sequence
     does not outgrow: the whole causal past."""
     batch, length = q.shape[:2]
     rows = heads is not None
+    # the shared part in every head's own lanes: laid there by the row passes
+    lay = (k_shared.shape[-1], theta) if rows and k_shared is not None else None
+    if theta is not None and lay is None:
+        raise ValueError("only a shared key part inside the heads' lanes is turned here")
     if rows:
         if q_shared is not None:
             raise ValueError("heads side by side carry no shared key part")
@@ -259,7 +286,12 @@ def blocked_causal_attention(q, k, v, *, scale: float, q_shared=None,
         if window >= length:
             window = None
     shared = 0 if q_shared is None else q_shared.shape[-1]
-    tile = _fused_tile(length, heads, group, dim, vdim, shared)
+    if lay is None:
+        tile = _fused_tile(length, heads, group, dim, vdim, shared)
+    elif mlarows.admits(dim, lay[0]):
+        tile = _fused_tile(length, heads, group, dim - lay[0], vdim, lay[0])
+    else:
+        tile = None
     paired = tile is not None and vdim == HALF
     # trace time: which form each program that holds an attention core got
     form = "blocked_xla" if tile is None else "fused"
@@ -271,6 +303,9 @@ def blocked_causal_attention(q, k, v, *, scale: float, q_shared=None,
         "program, by the form that computes them and their key span",
         form=form, span=_span_label(window)).inc()
     if tile is None:
+        if lay is not None:
+            q, k = map(_flat, _laid_xla(cut(q, heads), cut(k, heads), k_shared, theta))
+            k_shared = None
         out = _blocked_xla(cut(q, heads), every_head(k), every_head(v), q_shared, k_shared,
                            scale, block, spans, window)
         return _flat(out) if rows else out
@@ -279,6 +314,12 @@ def blocked_causal_attention(q, k, v, *, scale: float, q_shared=None,
         "faa_attention_head_blocks_traced_total", "fused attention cores traced "
         "into a program, by the heads a 128-lane block of their kernels holds",
         heads_a_block="2" if paired else "1").inc()
+    telemetry.registry().counter(
+        "faa_attention_operands_traced_total", "fused attention cores traced into a "
+        "program, by the mixer they serve (mla: a shared key part) and how their "
+        "operands came: the projections' own rows, or cut into heads",
+        mixer="gqa" if q_shared is None and lay is None else "mla",
+        form="rows" if rows else "cut").inc()
     if paired and group > 1:
         # a key-value head of 64 is half a block of lanes: repeated in HBM, the
         # pair path as it was before the kernels mapped a group to its head
@@ -299,8 +340,9 @@ def blocked_causal_attention(q, k, v, *, scale: float, q_shared=None,
     # kernels' products are float32 ones, which Mosaic refuses bfloat16
     # operands for (a model in ``precision: bf16`` under a float32 comparison)
     q, k, v = (_flat(a).astype(jnp.float32) for a in (q, k, v))
-    out = _fused_attention(q, k, v, float(scale), tile, heads, kda._float32_products(),
-                           not kda._on_tpu(), window)
+    out = _fused_attention(q, k, v, None if lay is None else k_shared.astype(jnp.float32),
+                           float(scale), tile, heads, kda._float32_products(),
+                           not kda._on_tpu(), window, lay)
     return out if rows else out.reshape(batch, length, heads, vdim)
 
 
@@ -747,24 +789,50 @@ def _backward(q, k, v, out, lse, d_out, scale: float, tile: int, heads: int, exa
     )(q, k, v, kda._operand(d_out, exact), lse, delta)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _fused_attention(q, k, v, scale: float, tile: int, heads: int, exact: bool,
-                     interpret: bool, window: int | None):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
+def _fused_attention(q, k, v, k_shared, scale: float, tile: int, heads: int, exact: bool,
+                     interpret: bool, window: int | None, lay: tuple | None):
     """The causal softmax through the kernels, on the heads side by side:
     `q` ``[B, T, H * D]`` with D whole lanes (every part of the score in
     it) or half a row of them, `k` ``[B, T, G * D]``, `v` ``[B, T, G *
     Dv]``, float32; the output ``[B, T, H * Dv]``.  `exact`: float32
     products; `interpret`: no TPU to compile them for; `window`: the key
-    span, shorter than the sequence, or None."""
-    q, k, v = (kda._operand(a, exact) for a in (q, k, v))
+    span, shorter than the sequence, or None.  `lay` ``(Ds, theta)`` with
+    `k_shared` ``[B, T, Ds]``, or neither: the shared key part still to be
+    laid into every head's last ``Ds`` lanes and both sides turned there
+    (:func:`_operands`)."""
+    q, k, v = _operands(q, k, v, k_shared, heads, exact, interpret, lay)
     return _forward(q, k, v, scale, tile, heads, exact, interpret, keep=False,
                     window=window)[0]
 
 
-def _fused_attention_fwd(q, k, v, scale: float, tile: int, heads: int, exact: bool,
-                         interpret: bool, window: int | None):
+def _angle(lay: tuple, length: int):
+    """``[T, Ds / 2]``: what pair ``i`` of token ``t``'s shared part is turned
+    by (``models/token_blocks.py::rotate_by_position``'s), or None."""
+    shared, theta = lay
+    if theta is None:
+        return None
+    inverse = theta ** (-jnp.arange(0, shared, 2, dtype=jnp.float32) / shared)
+    return jnp.arange(length, dtype=jnp.float32)[:, None] * inverse[None, :]
+
+
+def _operands(q, k, v, k_shared, heads: int, exact: bool, interpret: bool, lay: tuple | None):
+    """`q`, `k`, `v` as the products take them: rounded to bfloat16 unless
+    `exact` — by whoever writes them last: the row passes that turn the
+    queries and lay the shared key part (``ops/mlarows.py``) where there is
+    one to lay."""
+    if lay is None:
+        return tuple(kda._operand(a, exact) for a in (q, k, v))
+    angle = _angle(lay, q.shape[1])
+    return (mlarows.turn(q, heads, angle, exact=exact, interpret=interpret),
+            mlarows.lay(k, heads, k_shared, angle, exact=exact, interpret=interpret),
+            kda._operand(v, exact))
+
+
+def _fused_attention_fwd(q, k, v, k_shared, scale: float, tile: int, heads: int, exact: bool,
+                         interpret: bool, window: int | None, lay: tuple | None):
     # kept as the products take them: bfloat16 unless `exact`
-    q, k, v = (kda._operand(a, exact) for a in (q, k, v))
+    q, k, v = _operands(q, k, v, k_shared, heads, exact, interpret, lay)
     out, lse = _forward(q, k, v, scale, tile, heads, exact, interpret, keep=True,
                         window=window)
     # named for a checkpoint policy to keep (``token_blocks.remat_block``):
@@ -777,14 +845,45 @@ def _fused_attention_fwd(q, k, v, scale: float, tile: int, heads: int, exact: bo
 
 
 def _fused_attention_bwd(scale: float, tile: int, heads: int, exact: bool, interpret: bool,
-                         window: int | None, residuals, d_out):
-    return _backward(*residuals, d_out, scale, tile, heads, exact, interpret, window=window)
+                         window: int | None, lay: tuple | None, residuals, d_out):
+    dq, dk, dv = _backward(*residuals, d_out, scale, tile, heads, exact, interpret,
+                           window=window)
+    if lay is None:
+        return dq, dk, dv, None
+    # the row passes' transpose: `dq` turned back where it was turned, the shared
+    # part's gradient `dk`'s lanes there summed over the heads
+    dq, d_shared = mlarows.unlay(dq, dk, heads, lay[0], _angle(lay, dq.shape[1]),
+                                 interpret=interpret)
+    return dq, dk, dv, d_shared
 
 
 _fused_attention.defvjp(_fused_attention_fwd, _fused_attention_bwd)
 
 
 # ------------------------------------------------- the form in jnp and XLA
+
+def _laid_xla(q, k, k_shared, theta: float | None):
+    """The row passes' arithmetic in jnp, for the shapes the kernels do not
+    take: `q` ``[B, T, H, D]`` with its last ``Ds`` channels turned, `k` with
+    `k_shared` ``[B, T, Ds]`` (turned) in those channels of every head."""
+    shared = k_shared.shape[-1]
+    own = q.shape[-1] - shared
+    q_shared, k_shared = q[..., own:], k_shared[:, :, None]
+    angle = _angle((shared, theta), q.shape[1])
+    if angle is not None:
+        cos, sin = jnp.cos(angle)[:, None], jnp.sin(angle)[:, None]
+
+        def turned(x):              # pair (2i, 2i + 1), in place
+            first, second = x[..., 0::2], x[..., 1::2]
+            return jnp.stack([first * cos - second * sin,
+                              first * sin + second * cos], -1).reshape(x.shape)
+
+        q_shared, k_shared = turned(q_shared), turned(k_shared)
+    return (jnp.concatenate([q[..., :own], q_shared], -1),
+            jnp.concatenate([k[..., :own], jnp.broadcast_to(
+                k_shared, k.shape[:-1] + (shared,))], -1))
+
+
 
 def _attend(q, q_shared, k, k_shared, v, first, scale: float, window: int | None):
     """One block of queries, whose first token is token `first`, against

@@ -184,6 +184,61 @@ def test_a_gqa_mixer_moves_no_projection_between_tilings_on_a_v5e(
 
 
 @pytest.mark.parametrize("ambient", ["default", "highest"])
+def test_an_mla_mixer_moves_no_projection_between_tilings_on_a_v5e(
+        one_chip, no_compile_cache, monkeypatch, ambient):
+    """One latent-attention mixer of `glm47_flash_train` (20 heads of 192 +
+    64 on values of 256, a low-rank query, rotary) under ``remat_block``,
+    forward and backward at 8,192 tokens, at the chip's default precision
+    and under ``highest``: each attention kernel once, the row passes that
+    turn the queries' rotary lanes and round them and that lay the shared key
+    part into `k` (``ops/mlarows.py``) once a pass each (forward, again under
+    the remat) and their transpose once, and the program XLA makes of it
+    holds no ``copy``, ``reshape`` or ``transpose`` of an array as large as a
+    projection's output (8,192 x 5,120: 168 MB in float32) anywhere, and no
+    ``broadcast``, ``pad``, ``slice`` or ``concatenate`` of one as an
+    operation of its own — what stood some thirty times a layer round
+    kernels handed ``[B, T, H, D]`` (PR 41 to PR 51)."""
+    from flax import linen as nn
+
+    from fast_autoaugment_tpu.models.token_blocks import MLAMixer, remat_block
+    from fast_autoaugment_tpu.ops import kda
+
+    monkeypatch.setattr(kda, "_on_tpu", lambda: True)
+
+    class Layer(nn.Module):
+        @nn.compact
+        def __call__(self, x):
+            return x + MLAMixer(20, 192, 64, 256, 512, 1e-5, q_rank=768, rope_theta=1e6,
+                                name="mla")(x)
+
+    layer = remat_block(Layer)()
+    x = jax.ShapeDtypeStruct((1, 8192, 2048), jnp.float32, sharding=one_chip)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(layer.init, jax.random.PRNGKey(0), x))
+    with jax.default_matmul_precision(ambient):
+        compiled = jax.jit(jax.grad(
+            lambda p, x: jnp.sum(layer.apply(p, x) ** 2), argnums=(0, 1))).lower(
+                params, x).compile()
+    text = compiled.as_text()
+    kernels = {name: len(re.findall(rf"%{name}[.\d]* = ", text)) for name in (
+        "mla_attention_forward", "mla_attention_backward", "mla_rows_turn", "mla_rows_lay",
+        "mla_rows_unlay")}
+    assert kernels == {"mla_attention_forward": 1, "mla_attention_backward": 1,
+                       "mla_rows_turn": 2, "mla_rows_lay": 2, "mla_rows_unlay": 1}, kernels
+    assert text.count("tpu_custom_call") == 7
+
+    def largest(moved):
+        return max([np.prod([int(d) for d in dims.split(",")]) for dims in moved], default=0)
+
+    anywhere = re.findall(r"= \w+\[([\d,]+)\]\S* (?:copy|reshape|transpose)\(", text)
+    assert anywhere and largest(anywhere) < 8192 * 5120, sorted(set(anywhere))
+    entry = text[text.index("\nENTRY "):]
+    alone = re.findall(r"= \w+\[([\d,]+)\]\S* (?:broadcast|pad|slice|concatenate)\(", entry)
+    assert largest(alone) < 8192 * 5120, sorted(set(alone))
+
+
+@pytest.mark.parametrize("ambient", ["default", "highest"])
 @pytest.mark.parametrize("heads, dim, shared, vdim", [(32, 128, 64, 128), (20, 192, 64, 256)],
                          ids=["kimi_linear_48b_a3b_train", "glm47_flash_train"])
 def test_fused_attention_kernels_compile_for_a_v5e(one_chip, no_compile_cache, monkeypatch,
